@@ -53,22 +53,28 @@ async def metrics(request: web.Request) -> web.Response:
 
 
 def _device_health() -> dict:
-    """Local accelerator snapshot; {} when no backend is initialized or the
-    platform exposes no memory stats (CPU)."""
-    try:
-        import jax
-        d = jax.local_devices()[0]
-        out = {"platform": d.platform, "device": str(d)}
-        mem = d.memory_stats() or {}
-        for k in ("bytes_in_use", "bytes_limit", "peak_bytes_in_use"):
-            if k in mem:
-                out[k] = int(mem[k])
-        if mem.get("bytes_limit"):
-            out["hbm_used_frac"] = round(
-                mem.get("bytes_in_use", 0) / mem["bytes_limit"], 4)
-        return out
-    except Exception:
-        return {}
+    """Local accelerator snapshot as JAX reports it: platform, device kind
+    and device count, the first device's memory, and (on more than one
+    device) each device's memory — so "is the model spread over the chips"
+    is read from the server that holds them. Memory keys are absent where
+    the platform exposes no stats (CPU). A broken backend raises: /health
+    answering 500 is the honest report, an empty block is not."""
+    import jax
+    devs = jax.local_devices()
+    d = devs[0]
+    out = {"platform": d.platform, "device": str(d),
+           "device_kind": d.device_kind, "count": len(jax.devices())}
+    keys = ("bytes_in_use", "bytes_limit", "peak_bytes_in_use")
+    mem = d.memory_stats() or {}
+    out.update({k: int(mem[k]) for k in keys if k in mem})
+    if mem.get("bytes_limit"):
+        out["hbm_used_frac"] = round(
+            mem.get("bytes_in_use", 0) / mem["bytes_limit"], 4)
+    if len(devs) > 1:
+        out["devices"] = [
+            {"id": x.id, **{k: int(m[k]) for k in keys if k in m}}
+            for x in devs for m in [x.memory_stats() or {}]]
+    return out
 
 
 def worker_health(model) -> list[dict]:

@@ -26,13 +26,15 @@ DISCOVERY_PORT = 18337
 MAGIC = "CTPU"
 MAX_DATAGRAM = 4096
 
-# chip kind -> (bf16 TFLOPS, HBM bytes) — public spec numbers
+# chip kind -> (bf16 TFLOPS, HBM bytes) — public spec numbers (v5e: 197
+# bf16; 394 is its int8 figure). Layer assignment is TFLOPS-proportional,
+# so a kind that is not here is an error, never a guessed peak.
 TPU_SPECS = {
     "TPU v2": (46.0, 8 << 30),
     "TPU v3": (123.0, 16 << 30),
     "TPU v4": (275.0, 32 << 30),
-    "TPU v5 lite": (394.0, 16 << 30),
-    "TPU v5e": (394.0, 16 << 30),
+    "TPU v5 lite": (197.0, 16 << 30),
+    "TPU v5e": (197.0, 16 << 30),
     "TPU v5p": (459.0, 95 << 30),
     "TPU v6 lite": (918.0, 32 << 30),
     "TPU v6e": (918.0, 32 << 30),
@@ -40,25 +42,23 @@ TPU_SPECS = {
 
 
 def detect_capabilities() -> dict:
-    """Report backend/devices/memory/tflops for this host."""
-    try:
-        import jax
-        devs = jax.devices()
-        kind = devs[0].device_kind
-        if devs[0].platform == "tpu":
-            for prefix, (tf, hbm) in TPU_SPECS.items():
-                if kind.startswith(prefix):
-                    return {"backend": "tpu", "device": kind,
-                            "n_devices": len(devs),
-                            "memory_bytes": hbm * len(devs),
-                            "tflops": tf * len(devs)}
+    """Report backend/devices/memory/tflops for this host. The CPU row is
+    for a host where JAX reports no TPU backend; a TPU that fails to
+    initialize raises (a broken chip is not advertised as a CPU), and so
+    does a TPU kind TPU_SPECS does not list."""
+    import jax
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        return {"backend": "cpu", "device": "cpu", "n_devices": 1,
+                "memory_bytes": _host_memory_bytes(), "tflops": 1.0}
+    kind = devs[0].device_kind
+    for prefix, (tf, hbm) in TPU_SPECS.items():
+        if kind.startswith(prefix):
             return {"backend": "tpu", "device": kind, "n_devices": len(devs),
-                    "memory_bytes": (16 << 30) * len(devs),
-                    "tflops": 200.0 * len(devs)}
-    except Exception:
-        pass
-    return {"backend": "cpu", "device": "cpu", "n_devices": 1,
-            "memory_bytes": _host_memory_bytes(), "tflops": 1.0}
+                    "memory_bytes": hbm * len(devs),
+                    "tflops": tf * len(devs)}
+    raise ValueError(f"unknown TPU kind {kind!r}: add its bf16 peak and HBM "
+                     "size to cluster/discovery.TPU_SPECS")
 
 
 def _host_memory_bytes() -> int:

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import knobs
-from ..models.common.cache import init_cache
 from ..models.common.config import ModelConfig
 from ..models.common.text_model import (PREFILL_BUCKETS, PREFILL_CHUNK,
                                         LocalStage, Token,
@@ -156,13 +155,13 @@ class DistributedTextModel:
         cache-length bucket and grow bucket-by-bucket during decode (same
         lever as TextModel's growth bucketing — short generations never
         attend over max_cache_len of mostly-empty buffer)."""
-        from ..parallel.sharding import shard_cache
+        from ..parallel.sharding import init_cache_sharded
         self._kv_len = min(kv_len or self.max_cache_len, self.max_cache_len)
         for s in self.stages:
             if s.kind == "local":
-                s.cache = shard_cache(
-                    init_cache(self.cfg, 1, self._kv_len,
-                               self.dtype, (s.start, s.end)), self.mesh)
+                s.cache = init_cache_sharded(
+                    self.mesh, self.cfg, 1, self._kv_len, self.dtype,
+                    (s.start, s.end))
             else:
                 s.runner.goodbye()
 
@@ -693,16 +692,18 @@ def master_setup(model_dir: str, cluster_key: str, cfg: ModelConfig,
             quant = fp8_native_quant()
         master_params = load_model_params(cfg, model_dir, dtype, quant=quant,
                                           layer_range=(0, 0),
-                                          include_embed=True, include_head=True)
+                                          include_embed=True,
+                                          include_head=True, mesh=mesh)
         for kind, lo, hi, runner in ranges:
             if kind == "local":
                 p = load_model_params(cfg, model_dir, dtype, quant=quant,
                                       layer_range=(lo, hi),
-                                      include_embed=False, include_head=False)
-                from ..parallel.sharding import shard_cache
+                                      include_embed=False,
+                                      include_head=False, mesh=mesh)
+                from ..parallel.sharding import init_cache_sharded
                 runner = LocalStage(cfg, p, lo, hi, mesh=mesh)
-                cache = shard_cache(init_cache(cfg, 1, max_cache_len, dtype,
-                                               (lo, hi)), mesh)
+                cache = init_cache_sharded(mesh, cfg, 1, max_cache_len,
+                                           dtype, (lo, hi))
                 stages.append(Stage("local", lo, hi, runner, cache))
             else:
                 stages.append(Stage("remote", lo, hi, runner))

@@ -20,7 +20,7 @@ import os
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.common.cache import cache_reset, init_cache
+from ..models.common.cache import cache_reset
 from ..models.common.config import config_from_hf_dict
 from ..models.common.text_model import LocalStage, select_flash_mode
 from ..obs import PhaseTimer, WORKER_FWD_SECONDS, WORKER_HEARTBEAT, now
@@ -293,7 +293,7 @@ class WorkerServer:
             params = load_model_params(
                 cfg, model_dir, st.dtype, quant=quant,
                 layer_range=(st.start, st.end),
-                include_embed=False, include_head=False)
+                include_embed=False, include_head=False, mesh=self.mesh)
             st.stage = LocalStage(cfg, params, st.start, st.end,
                                   mesh=self.mesh)
             # warm compiles during setup, not on first serve (ref hard-part
@@ -394,12 +394,12 @@ class WorkerServer:
     # -- inference -----------------------------------------------------------
 
     def _fresh_cache(self, kv_len: int | None = None):
-        from ..parallel.sharding import shard_cache
+        from ..parallel.sharding import init_cache_sharded
         st = self.state
-        return shard_cache(
-            init_cache(st.cfg, 1, min(kv_len or st.max_cache_len,
+        return init_cache_sharded(
+            self.mesh, st.cfg, 1, min(kv_len or st.max_cache_len,
                                       st.max_cache_len), st.dtype,
-                       layer_range=(st.start, st.end)), self.mesh)
+            (st.start, st.end))
 
     def _sized_cache(self, cache, needed: int):
         """Growth-bucketed per-connection cache (mirrors TextModel's

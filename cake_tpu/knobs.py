@@ -400,7 +400,7 @@ _knob("CAKE_FLIGHT_RECORDER", int, 256, "obs",
 # -- ops / kernels --------------------------------------------------------
 _knob("CAKE_MOE_RAGGED", bool, True, "ops",
       "ragged-dot MoE expert combine (falls back to the dense combine "
-      "when off or when the installed jax lacks ragged_dot_general)")
+      "when off)")
 _knob("CAKE_TPU_FLASH", bool, True, "ops",
       "flash prefill attention on TPU backends (CPU always uses the "
       "reference path)")

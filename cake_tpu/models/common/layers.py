@@ -169,6 +169,22 @@ def make_rope(cfg: ModelConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def flash_kernel_mode(flash_mode: str, s: int, window: int | None = None,
+                      has_cache: bool = True) -> str | None:
+    """"fresh" / "append" when a prefill of width s in this host-static
+    flash_mode runs the Pallas kernel on a layer with this window, else
+    None (the masked XLA path). The one rule: attention_forward dispatches
+    on it and the serve timeline reports it."""
+    from ...ops.flash import FLASH_MIN_SEQ, flash_enabled
+    if s < FLASH_MIN_SEQ or not flash_enabled():
+        return None
+    if flash_mode == "fresh":
+        return "fresh"
+    if flash_mode == "append" and window is None and has_cache:
+        return "append"
+    return None
+
+
 def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
                       layer_cache: dict, pos0, rope: dict, valid_len=None,
                       flash_mode: str = "off", mesh=None):
@@ -218,12 +234,9 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
     kv_pos_new = positions if valid_len is None else jnp.where(
         idx < valid_len, positions, -1)                    # pads invisible
     kv_pos_new = jnp.broadcast_to(kv_pos_new[None, :], (b, s))
-    from ...ops.flash import FLASH_MIN_SEQ, flash_attention, flash_enabled
-    flash_ok = s >= FLASH_MIN_SEQ and flash_enabled()
-    use_flash = flash_ok and (
-        flash_mode == "fresh"
-        or (flash_mode == "append" and spec.window is None
-            and layer_cache is not None))
+    from ...ops.flash import flash_attention
+    use_flash = flash_kernel_mode(flash_mode, s, spec.window,
+                                  layer_cache is not None) is not None
     if flash_mode == "ring" and mesh is not None and spec.window is None:
         # sp-sharded fresh prefill: sequence split over the mesh's sp axis,
         # K/V blocks rotate via collective permute (parallel/ring_attention)
@@ -252,7 +265,7 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
         # attention.rs:270-277). Inference-only — the kernel has no VJP;
         # flash_mode stays "off" on the training path.
         y = flash_attention(q, k, v, scale=cfg.attn_scale, valid_len=valid_len,
-                            window=spec.window)
+                            window=spec.window, mesh=mesh)
         new_cache = (update_kv_cache(layer_cache, k, v, pos0, valid_len)
                      if layer_cache is not None else None)
         kv_pos = k_all = v_all = None
@@ -263,7 +276,7 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
         new_cache = update_kv_cache(layer_cache, k, v, pos0, valid_len)
         y = flash_attention(q, new_cache["k"], new_cache["v"],
                             scale=cfg.attn_scale, valid_len=valid_len,
-                            q_offset=pos0)
+                            q_offset=pos0, mesh=mesh)
         kv_pos = k_all = v_all = None
     elif layer_cache is None:
         kv_pos, k_all, v_all = kv_pos_new, k, v

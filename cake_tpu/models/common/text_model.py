@@ -40,13 +40,14 @@ from ...obs import (DECODE_TOKEN_SECONDS, GENERATED_TOKENS, RECORDER,
 from ...ops.sampling import (SamplingConfig, config_has_filters,
                              push_recent_token, sample, sample_traced,
                              spec_accept)
-from .cache import (grow_cache, init_cache, kv_capacity, paged_block_of,
+from .cache import (grow_cache, kv_capacity, paged_block_of,
                     paged_gather_layer, paged_scatter_blocks,
                     slot_assign_layers, slot_extract_block_layers,
                     slot_reset_layers, slot_splice_block_layers,
                     truncate_layers)
 from .config import ModelConfig
-from .layers import embed_tokens, forward_layers, init_params, lm_head_logits
+from .layers import (embed_tokens, flash_kernel_mode, forward_layers,
+                     lm_head_logits)
 
 PREFILL_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096, 8192)
 
@@ -103,6 +104,13 @@ def select_flash_mode(pos0: int, width: int, capacity: int | None) -> str:
     return "off"
 
 
+def _chunk_attn(flash_mode: str, width: int) -> str:
+    """Attention path of a serve prefill chunk's full-attention layers, as
+    the request timeline names it."""
+    kernel = flash_kernel_mode(flash_mode, width)
+    return f"flash-{kernel}" if kernel else "masked"
+
+
 def check_prefill_bounds(n: int, pos0: int, capacity: int | None,
                          max_len: int) -> int:
     """Validate a prefill request against the cache; returns the prompt
@@ -150,7 +158,7 @@ class LocalStage:
             del padded  # static marker to separate prefill/decode programs
             return forward_layers(cfg, params, x, cache, pos0,
                                   layer_range=(lo, hi), valid_len=valid_len,
-                                  flash_mode=flash_mode)
+                                  flash_mode=flash_mode, mesh=mesh)
 
         self._fwd = _fwd
 
@@ -166,8 +174,8 @@ class TextModel:
     # first non-streaming decode segment (and so the initial KV bucket) is
     # capped at this many tokens; later segments fill the growing buckets
     UNTIL_SEGMENT = 256
-    # streaming decode keeps this many chunks in flight so the fixed
-    # device-link fetch latency overlaps the next chunk's device compute
+    # streaming decode keeps this many chunks in flight so each chunk's
+    # host fetch overlaps the next chunk's device compute
     STREAM_DEPTH = 2
 
     def __init__(self, cfg: ModelConfig, params: dict | None = None,
@@ -178,12 +186,11 @@ class TextModel:
         self.tokenizer = tokenizer
         self.mesh = mesh
         self.max_cache_len = min(max_cache_len or cfg.max_seq_len, cfg.max_seq_len)
-        if params is None:
-            params = init_params(cfg, jax.random.PRNGKey(seed), dtype)
         # in-host tensor parallelism on the product path: shard the weights
         # once, let GSPMD insert the psum after the row x col matmul pairs
         # in every compiled program below (no-op without a mesh)
-        from ...parallel.sharding import check_tp_divisibility, shard_params
+        from ...parallel.sharding import (check_tp_divisibility,
+                                          init_params_sharded, shard_params)
         if mesh is not None:
             check_tp_divisibility(cfg, mesh)
             sp = mesh.shape.get("sp", 1)
@@ -195,9 +202,13 @@ class TextModel:
                     f"sp={sp} must be a power of two dividing "
                     f"max_cache_len {self.max_cache_len} so every KV "
                     "growth bucket shards over it")
+        if params is None:
+            params = init_params_sharded(mesh, cfg,
+                                         jax.random.PRNGKey(seed), dtype)
         self.params = shard_params(params, mesh)
         self._rng = jax.random.PRNGKey(seed)
         self.last_prefill_mode: str | None = None
+        self.last_chunk_attn: str | None = None
         self._build()
 
     # -- compiled programs --------------------------------------------------
@@ -254,19 +265,19 @@ class TextModel:
         def _decode_until(params, token, cache, rng, recent, n_limit, scfg,
                           nbuf):
             """Decode up to n_limit tokens on device, stopping at EOS
-            (lax.while_loop): ONE host round trip per generation. Through a
-            high-latency device link the per-sync cost dominates chunked
-            decode (fetches are stream-ordered, so they cannot overlap queued
+            (lax.while_loop): ONE host round trip per generation. Every host
+            sync has a fixed cost that chunked decode pays per chunk
+            (fetches are stream-ordered, so they cannot overlap queued
             compute), and the while_loop also removes past-EOS overshoot.
             Returns [count, tok0, tok1, ...] packed into one array so the
             host pays a single small fetch.
 
             (Measured dead end, kept for the record: an outer-while over
             inner fori_loop(k) variant — static inner trip count to let XLA
-            pipeline weight prefetch — benched ~0.3 ms/tok SLOWER than this
-            flat loop on v5e; nested loop carries appear to defeat in-place
-            KV-cache aliasing. The flat loop runs at ~94% of the bf16
-            weight-read roofline, so there is no headroom worth chasing.)"""
+            pipeline weight prefetch — benched slower than this flat loop
+            on v5e in an early round; nested loop carries appear to defeat
+            in-place KV-cache aliasing. Not re-measured since: PERF.md
+            carries what has been measured on the current machine.)"""
             eos = jnp.asarray(cfg.eos_token_ids or (-1,), jnp.int32)
 
             def cond(c):
@@ -833,10 +844,9 @@ class TextModel:
     def new_cache(self, batch: int = 1, kv_len: int | None = None):
         """kv_len bounds the KV buffers (cache-length bucket); defaults to
         the full max_cache_len (distributed master / parity-test paths)."""
-        from ...parallel.sharding import shard_cache
-        return shard_cache(init_cache(self.cfg, batch,
-                                      kv_len or self.max_cache_len,
-                                      self.dtype), self.mesh)
+        from ...parallel.sharding import init_cache_sharded
+        return init_cache_sharded(self.mesh, self.cfg, batch,
+                                  kv_len or self.max_cache_len, self.dtype)
 
     def _grow_to(self, cache, new_len: int):
         """Grow the KV bucket; re-pin shardings on the grown buffers (the
@@ -885,6 +895,7 @@ class TextModel:
         padded = np.zeros((1, bkt), np.int32)
         padded[0, :n] = ids
         flash_mode = select_flash_mode(pos0, bkt, cap)
+        self.last_chunk_attn = _chunk_attn(flash_mode, bkt)
         return self._prefill_slot(self.params, jnp.asarray(padded), layers,
                                   jnp.asarray(slot, jnp.int32),
                                   jnp.asarray(pos0, jnp.int32),
@@ -955,6 +966,7 @@ class TextModel:
         padded = np.zeros((1, bkt), np.int32)
         padded[0, :n] = ids
         flash_mode = select_flash_mode(pos0, bkt, ctx)
+        self.last_chunk_attn = _chunk_attn(flash_mode, bkt)
         return self._prefill_slot_paged(self.params, jnp.asarray(padded),
                                         pool, rows, tables,
                                         jnp.asarray(slot, jnp.int32),
@@ -1105,8 +1117,8 @@ class TextModel:
 
         Without an `on_token` callback the whole decode runs as ONE device
         call (`_decode_until`: while_loop to EOS/budget, single fetch) —
-        syncs are stream-ordered through the host↔device link, so their
-        fixed latency is paid per call, not per token. With a callback,
+        host syncs are stream-ordered, so their fixed cost is paid per
+        call, not per token. With a callback,
         decode runs in on-device chunks of `chunk` tokens kept
         STREAM_DEPTH-deep in flight (the next chunk chains off the device
         carry, no host round trip), so tokens stream with bounded latency

@@ -217,7 +217,7 @@ def load_gdn_params(loader, lp: str):
     conv_w = g(f"{base}.conv1d.weight")
     if conv_w.ndim == 3 and conv_w.shape[1] != 1:       # [C, K, 1] variant
         conv_w = conv_w.transpose(0, 2, 1)
-    from ..utils.loaders import _to_dev
+    _to_dev = loader._dev
     dt = loader.dtype
     return {
         "in_proj": {"weight": _to_dev(in_proj, dt)},

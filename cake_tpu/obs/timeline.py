@@ -73,7 +73,9 @@ EVENT_KINDS: dict[str, str] = {
              "carries `qos`",
     "prefix_hit": "prefix-cache splice skipped `tokens` prompt tokens",
     "prefill_chunk": "one chunk scattered into the pool row (`pos0`, "
-                     "`tokens`)",
+                     "`tokens`, `attn`: `flash-fresh` / `flash-append` "
+                     "when the chunk's program holds the Pallas kernel, "
+                     "`masked` for the XLA path)",
     "prefill_done": "prompt fully prefilled; first token sampled "
                     "(`chunks`, `hit_tokens`)",
     "first_token": "first token fetched to the host (client-visible "

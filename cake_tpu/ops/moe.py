@@ -34,19 +34,11 @@ import jax.numpy as jnp
 RAGGED_MIN_TOKENS = 32
 
 
-def _ragged_available() -> bool:
-    """lax.ragged_dot_general landed in newer jax releases; on older ones
-    the dense combine serves every shape (same math, more FLOPs)."""
-    import jax.lax
-    return hasattr(jax.lax, "ragged_dot_general")
-
-
 def _ragged_enabled() -> bool:
     """CAKE_MOE_RAGGED=0 pins every shape to the dense combine (escape
-    hatch if a backend mishandles ragged_dot_general); also gated on the
-    installed jax actually providing ragged_dot_general."""
+    hatch if a backend mishandles ragged_dot_general)."""
     from .. import knobs
-    return knobs.get("CAKE_MOE_RAGGED") and _ragged_available()
+    return knobs.get("CAKE_MOE_RAGGED")
 
 
 def router_topk(logits, k: int, norm_topk_prob: bool, gate_act: str = "softmax"):

@@ -65,8 +65,7 @@ def ring_attention_sharded(q, k, v, axis_name: str, scale: float | None = None,
     vary_axes: additional manual mesh axes the inputs vary over (e.g. the
     tp head axis) — the accumulators must be cast varying over them too or
     the fori_loop carry type mismatches. axis_size: static ring size from
-    the mesh — older jax has no jax.lax.axis_size accessor, and the
-    ppermute schedule below needs the concrete value either way."""
+    the mesh — the ppermute schedule below needs the concrete value."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     n = axis_size if axis_size is not None else jax.lax.axis_size(axis_name)

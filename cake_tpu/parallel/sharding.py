@@ -20,9 +20,12 @@ chunks stay head-aligned — see layers.py init_attention_params):
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..models.common.cache import init_cache
 from ..models.common.config import ModelConfig
 
 
@@ -124,16 +127,48 @@ def cache_shardings(cache, mesh: Mesh):
 
 
 def shard_params(params, mesh: Mesh | None):
-    """No-op without a mesh so product call sites need no guard."""
+    """No-op without a mesh so product call sites need no guard. Host
+    (numpy) leaves go straight into their shards — only each device's
+    slice crosses to it — and leaves already placed are left alone."""
     if mesh is None:
         return params
     return jax.device_put(params, params_shardings(params, mesh))
+
+
+def init_params_sharded(mesh: Mesh | None, cfg: ModelConfig, key, dtype):
+    """init_params with every leaf created where it lives: under a mesh
+    the init runs as one jit with out_shardings, so a model that needs
+    every chip is never materialized whole on the first one."""
+    from ..models.common.layers import init_params
+    fn = functools.partial(init_params, cfg, dtype=dtype)
+    if mesh is None:
+        return fn(key)
+    shardings = params_shardings(jax.eval_shape(fn, key), mesh)
+    return jax.jit(fn, out_shardings=shardings)(key)
 
 
 def shard_cache(cache, mesh: Mesh | None):
     if mesh is None:
         return cache
     return jax.device_put(cache, cache_shardings(cache, mesh))
+
+
+@functools.lru_cache(maxsize=None)
+def _cache_maker(mesh: Mesh, *init_args):
+    make = functools.partial(init_cache, *init_args)
+    return jax.jit(make, out_shardings=cache_shardings(jax.eval_shape(make),
+                                                       mesh))
+
+
+def init_cache_sharded(mesh: Mesh | None, cfg: ModelConfig, batch: int,
+                       kv_len: int, dtype, layer_range=None):
+    """init_cache with every buffer created where it lives. Under a mesh
+    the zeros are born sharded (one jitted maker per shape, memoized), so
+    a KV pool sized for every chip never lands whole on the first one —
+    which is what shard_cache(init_cache(...)) did."""
+    if mesh is None:
+        return init_cache(cfg, batch, kv_len, dtype, layer_range)
+    return _cache_maker(mesh, cfg, batch, kv_len, dtype, layer_range)()
 
 
 def check_tp_divisibility(cfg: ModelConfig, mesh: Mesh):

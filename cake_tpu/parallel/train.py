@@ -41,7 +41,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, params,
     # explicit respec, GSPMD may hand back a propagated layout for a
     # donated buffer (e.g. a tied embed row-sharded by the lm_head
     # matmul) and the second step either raises an in_shardings/arg
-    # mismatch or breaks donation aliasing on older jax. Committing the
+    # mismatch or breaks donation aliasing. Committing the
     # params here keeps the first/steady-state layouts identical.
     params = jax.device_put(params, params_shardings(params, mesh))
     opt_state = tx.init(params)

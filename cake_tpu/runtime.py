@@ -259,6 +259,9 @@ def build_text_model(model: str, dtype: str = "bf16", arch: str | None = None,
         if mesh is not None:
             log.warning("--tp/--sp apply to the resident path only; "
                         "ignoring them for --expert-offload serving")
+    # with a mesh each tensor goes from the host straight into its shards
+    # (a model that needs every chip never fits whole on the first one)
+    load_mesh = None if expert_offload else mesh
     gguf_files = [f for f in os.listdir(model_dir) if f.endswith(".gguf")]
     if gguf_files and not any(f.endswith(".safetensors")
                               for f in os.listdir(model_dir)):
@@ -267,11 +270,13 @@ def build_text_model(model: str, dtype: str = "bf16", arch: str | None = None,
         storage = GgufStorage(os.path.join(model_dir, gguf_files[0]),
                               cfg.model_prefix)
         params = ParamLoader(cfg, storage, dt, quant,
-                             expert_offload=expert_offload).load()
+                             expert_offload=expert_offload,
+                             mesh=load_mesh).load()
     else:
         from .utils.loaders import load_model_params
         params = load_model_params(cfg, model_dir, dt, quant=quant,
-                                   expert_offload=expert_offload)
+                                   expert_offload=expert_offload,
+                                   mesh=load_mesh)
     if expert_offload:
         from .models.common.offload_model import OffloadedTextModel
         gen = OffloadedTextModel(cfg, params, tokenizer=tokenizer, dtype=dt,

@@ -55,7 +55,12 @@ class QueueFull(Exception):
 
 # every live AdmissionQueue, so depth transitions can publish the SUM —
 # the plane's request queue and job queue share one gauge pair
-_BOARD_LOCK = threading.Lock()
+# Re-entrant on purpose: every queue's weakref.finalize callback is
+# _publish, and a garbage collection can fire it on the thread that is
+# already inside _publish's locked `list(_QUEUES)` (the copy allocates).
+# With a plain Lock that thread deadlocked against itself and took the
+# engine's scheduler thread with it (seen as a tier-1 run stuck at 98 %).
+_BOARD_LOCK = threading.RLock()
 _QUEUES: "weakref.WeakSet[AdmissionQueue]" = weakref.WeakSet()
 
 

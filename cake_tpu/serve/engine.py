@@ -1120,7 +1120,8 @@ class ServeEngine:
             pf.pos += take
             pf.chunks += 1
             TIMELINES.event(pf.req.id, "prefill_chunk",
-                            pos0=pf.pos - take, tokens=take)
+                            pos0=pf.pos - take, tokens=take,
+                            attn=self.model.last_chunk_attn)
             pf.next_block = self._capture_blocks(pf.ids, pf.slot, pf.pos,
                                                  pf.n, pf.next_block,
                                                  pf.keys)

@@ -4,8 +4,8 @@ budgeted rebuild state machine, poison attribution, and a wedge watchdog.
 Before this module, `ServeEngine._loop` answered every step exception the
 same way: set `self.dead`, release the waiters, refuse all future submits
 ("serve engine is down") until a human restarted the process. That is the
-wrong trade on the hardware this project actually runs on — the container
-TPU wedges intermittently (BENCH_r04/r05), and PR 4 already proved the
+wrong trade for a server whose device can fail a step or stop answering
+— and PR 4 already proved the
 recovery recipe for the cluster plane: classify, rebuild by replay,
 budget the retries, degrade honestly. This module applies the same state
 machine to the engine itself:

@@ -20,21 +20,29 @@ from .quant import NoQuantization
 from .safetensors_io import TensorStorage
 
 
-def _to_dev(arr, dtype):
+def _to_dev(arr, dtype, host: bool = False):
+    """Checkpoint tensor -> model leaf. host=True keeps it a numpy array in
+    the target dtype (the mesh path: ParamLoader.load places every leaf
+    straight into its shards, so no tensor lands whole on device 0)."""
+    asarray = np.asarray if host else jnp.asarray
     if isinstance(arr, dict) and "__fp8__" in arr:
         # native-dtype FP8: weight stays 1 byte/param in HBM; the forward
         # dequantizes per layer (ref: utils/native_dtype_backend.rs)
-        return {"fp8": jnp.asarray(arr["__fp8__"]),
-                "scale_inv": jnp.asarray(arr["scale_inv"])}
-    return jnp.asarray(arr).astype(dtype)
+        return {"fp8": asarray(arr["__fp8__"]),
+                "scale_inv": asarray(arr["scale_inv"])}
+    return asarray(arr).astype(dtype)
 
 
 class ParamLoader:
     def __init__(self, cfg: ModelConfig, storage: TensorStorage,
                  dtype=jnp.bfloat16, quant=None,
-                 expert_offload: bool = False, expert_lru_size: int = 32):
+                 expert_offload: bool = False, expert_lru_size: int = 32,
+                 mesh=None):
         self.cfg = cfg
         self.st = storage
+        # with a mesh, leaves stay on the host until load() places each one
+        # where parallel/sharding.py says it lives
+        self.mesh = mesh
         self.dtype = dtype
         self.quant = quant or NoQuantization()
         self.prefix = cfg.model_prefix
@@ -48,6 +56,9 @@ class ParamLoader:
 
     def _get(self, name: str):
         return self.quant.load(self.st, name)
+
+    def _dev(self, arr, dtype=None):
+        return _to_dev(arr, dtype or self.dtype, host=self.mesh is not None)
 
     _warned_dense_fallback = False
 
@@ -80,7 +91,7 @@ class ParamLoader:
     def _norm(self, name: str):
         """RMS-norm weight with the (1+w) residual pattern applied in f32 at
         load (ref: config.rs load_rms_norm_weight)."""
-        w = _to_dev(self._get(name), self.dtype)
+        w = self._dev(self._get(name))
         return load_rms_norm_weight(w, self.cfg.residual_rms_norm)
 
     # -- sub-loaders --------------------------------------------------------
@@ -91,19 +102,19 @@ class ParamLoader:
         p: dict = {}
         if cfg.fused_qkv and self._has(f"{lp}.self_attn.qkv_proj.weight"):
             w = self._get_dense(f"{lp}.self_attn.qkv_proj.weight")
-            p["q_proj"] = {"weight": _to_dev(w[:sq], self.dtype)}
-            p["k_proj"] = {"weight": _to_dev(w[sq:sq + skv], self.dtype)}
-            p["v_proj"] = {"weight": _to_dev(w[sq + skv:], self.dtype)}
+            p["q_proj"] = {"weight": self._dev(w[:sq])}
+            p["k_proj"] = {"weight": self._dev(w[sq:sq + skv])}
+            p["v_proj"] = {"weight": self._dev(w[sq + skv:])}
         else:
             for proj in ("q_proj", "k_proj", "v_proj"):
-                d = {"weight": _to_dev(
-                    self._get(f"{lp}.self_attn.{proj}.weight"), self.dtype)}
+                d = {"weight": self._dev(
+                    self._get(f"{lp}.self_attn.{proj}.weight"))}
                 bias = f"{lp}.self_attn.{proj}.bias"
                 if cfg.qkv_bias and self._has(bias):
-                    d["bias"] = _to_dev(self._get(bias), self.dtype)
+                    d["bias"] = self._dev(self._get(bias))
                 p[proj] = d
-        p["o_proj"] = {"weight": _to_dev(
-            self._get(f"{lp}.self_attn.o_proj.weight"), self.dtype)}
+        p["o_proj"] = {"weight": self._dev(
+            self._get(f"{lp}.self_attn.o_proj.weight"))}
         if cfg.qk_norm:
             p["q_norm"] = {"weight": self._norm(f"{lp}.self_attn.q_norm.weight")}
             p["k_norm"] = {"weight": self._norm(f"{lp}.self_attn.k_norm.weight")}
@@ -115,20 +126,19 @@ class ParamLoader:
             w = self._get_dense(f"{mp}.gate_up_proj.weight")
             i = w.shape[0] // 2
             return {
-                "gate_proj": {"weight": _to_dev(w[:i], self.dtype)},
-                "up_proj": {"weight": _to_dev(w[i:], self.dtype)},
-                "down_proj": {"weight": _to_dev(
-                    self._get(f"{mp}.down_proj.weight"), self.dtype)},
+                "gate_proj": {"weight": self._dev(w[:i])},
+                "up_proj": {"weight": self._dev(w[i:])},
+                "down_proj": {"weight": self._dev(
+                    self._get(f"{mp}.down_proj.weight"))},
             }
-        return {proj: {"weight": _to_dev(self._get(f"{mp}.{proj}.weight"),
-                                         self.dtype)}
+        return {proj: {"weight": self._dev(self._get(f"{mp}.{proj}.weight"))}
                 for proj in ("gate_proj", "up_proj", "down_proj")}
 
     def _moe(self, mp: str) -> dict:
         cfg = self.cfg
         # router gate feeds a raw einsum (ops/moe.py), not linear(): dense
-        p: dict = {"gate": {"weight": _to_dev(
-            self._get_dense(f"{mp}.gate.weight"), self.dtype)}}
+        p: dict = {"gate": {"weight": self._dev(
+            self._get_dense(f"{mp}.gate.weight"))}}
         if self.expert_offload:
             # experts stream from disk through a dequant-LRU provider
             # instead of residing stacked in HBM; the provider object is a
@@ -144,12 +154,12 @@ class ParamLoader:
                 for proj in stacked:
                     stacked[proj].append(
                         self._get_dense(f"{mp}.experts.{e}.{proj}.weight"))
-            p["experts"] = {proj: _to_dev(np.stack(ws), self.dtype)
+            p["experts"] = {proj: self._dev(np.stack(ws))
                             for proj, ws in stacked.items()}
         if cfg.shared_expert_intermediate_size:
             p["shared_expert"] = self._mlp(f"{mp}.shared_expert")
-            p["shared_expert_gate"] = {"weight": _to_dev(
-                self._get(f"{mp}.shared_expert_gate.weight"), self.dtype)}
+            p["shared_expert_gate"] = {"weight": self._dev(
+                self._get(f"{mp}.shared_expert_gate.weight"))}
         return p
 
     def _layer(self, i: int) -> dict:
@@ -190,25 +200,25 @@ class ParamLoader:
         params: dict = {"layers": [self._layer(i) for i in range(lo, hi)]}
         if include_embed:
             # embeddings feed jnp.take, not linear(): dense
-            params["embed_tokens"] = {"weight": _to_dev(
-                self._get_dense(f"{self.prefix}.embed_tokens.weight"),
-                self.dtype)}
+            params["embed_tokens"] = {"weight": self._dev(
+                self._get_dense(f"{self.prefix}.embed_tokens.weight"))}
         if include_head:
             params["norm"] = {"weight": self._norm(f"{self.prefix}.norm.weight")}
             if not cfg.tie_word_embeddings:
                 head = ("lm_head.weight" if self._has("lm_head.weight")
                         else f"{self.prefix}.lm_head.weight")
-                params["lm_head"] = {"weight": _to_dev(self._get(head),
-                                                       self.dtype)}
+                params["lm_head"] = {"weight": self._dev(self._get(head))}
         params["rope"] = make_rope(cfg)
-        return params
+        from ..parallel.sharding import shard_params
+        return shard_params(params, self.mesh)
 
 
 def load_model_params(cfg: ModelConfig, model_dir: str, dtype=jnp.bfloat16,
                       quant=None, layer_range=None, include_embed=None,
                       include_head=None, expert_offload: bool = False,
-                      expert_lru_size: int = 32) -> dict:
-    """One-call load: storage + quant detection + pytree assembly."""
+                      expert_lru_size: int = 32, mesh=None) -> dict:
+    """One-call load: storage + quant detection + pytree assembly. With a
+    mesh every leaf goes from the host straight into its shards."""
     import json
     import os
 
@@ -220,5 +230,5 @@ def load_model_params(cfg: ModelConfig, model_dir: str, dtype=jnp.bfloat16,
             quant = detect_quantization(json.load(f))
     loader = ParamLoader(cfg, storage, dtype, quant,
                          expert_offload=expert_offload,
-                         expert_lru_size=expert_lru_size)
+                         expert_lru_size=expert_lru_size, mesh=mesh)
     return loader.load(layer_range, include_embed, include_head)

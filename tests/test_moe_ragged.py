@@ -12,15 +12,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from cake_tpu.ops.moe import (RAGGED_MIN_TOKENS, _moe_ragged,
-                              _ragged_available, moe_ffn, router_topk)
-
-# on jax builds without lax.ragged_dot_general the dense combine serves
-# every shape (ops/moe._ragged_enabled gates it); the tests that pin the
-# ragged machinery itself have nothing to measure there
-needs_ragged = pytest.mark.skipif(
-    not _ragged_available(),
-    reason="installed jax lacks lax.ragged_dot_general")
+from cake_tpu.ops.moe import (RAGGED_MIN_TOKENS, _moe_ragged, moe_ffn,
+                              router_topk)
 
 
 def _bank(rng, e, i, h):
@@ -58,7 +51,6 @@ def test_ragged_matches_dense(act, gate_act, rng):
     assert np.max(np.abs(np.asarray(got) - ref)) < 2e-4
 
 
-@needs_ragged
 def test_decode_still_dense_and_consistent(rng):
     """T below the threshold uses the dense combine; same numerics."""
     e, i, h, k = 8, 16, 32, 2
@@ -72,7 +64,6 @@ def test_decode_still_dense_and_consistent(rng):
     assert np.max(np.abs(np.asarray(dense) - np.asarray(ragged))) < 2e-4
 
 
-@needs_ragged
 def test_dispatch_structure_by_token_count(rng):
     """Prefill-sized T emits ragged_dot_general (TPU segment-GEMM whose
     FLOPs are (k/E) * dense — the CPU backend densifies it in lowering, so

@@ -13,8 +13,12 @@ from cake_tpu import obs
 from cake_tpu.api import ApiState, create_app
 from tests.test_api import MockTokenizer, with_client
 
+# label VALUES are quoted strings and may hold braces: the registry is
+# process-global, and whichever test file shared this xdist worker may have
+# hit a templated route (endpoint="/api/v1/requests/{rid}")
 PROM_LINE = re.compile(
-    r'^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^}]*\})? (-?[0-9.e+-]+|NaN|[+-]Inf)$')
+    r'^[a-zA-Z_:][a-zA-Z0-9_:]*(\{(?:[^"}]|"[^"]*")*\})? '
+    r'(-?[0-9.e+-]+|NaN|[+-]Inf)$')
 
 
 def _metric_value(text: str, name: str) -> float:

@@ -529,3 +529,21 @@ def test_api_audio_traced_and_draining(monkeypatch):
                               json={"prompt": "x", "size": "16x16"})
         assert r.status == 503
     _with_client(state, scenario)
+
+
+def test_depth_publish_is_reentrant_under_gc():
+    """A dead queue's finalizer is _publish, and the collector can run it
+    on the thread that already holds the board lock inside _publish (the
+    locked WeakSet copy allocates). That must not deadlock: the engine's
+    scheduler thread and every submitter go through this lock."""
+    from cake_tpu.serve.admission import queue as qmod
+    done = threading.Event()
+
+    def nested():
+        with qmod._BOARD_LOCK:          # where the collector interrupts
+            qmod._publish()             # what the finalizer calls
+        done.set()
+
+    t = threading.Thread(target=nested, daemon=True)
+    t.start()
+    assert done.wait(10), "_publish deadlocked against its own lock"

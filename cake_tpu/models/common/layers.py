@@ -342,18 +342,25 @@ def moe_forward(cfg: ModelConfig, p: dict, x):
     return y.reshape(b, s, h)
 
 
+# The jax.named_scope blocks below (and in ops/moe.py, ops/sampling.py)
+# are metadata on the traced ops, nothing else: a device trace's reader
+# sums time by scope. Their names are obs.spans.SCOPE_CATALOG.
+
 def _ffn(cfg, spec, p, x):
-    return moe_forward(cfg, p["mlp"], x) if spec.is_moe \
-        else mlp_forward(cfg, p["mlp"], x)
+    with jax.named_scope("cake.ffn"):
+        return moe_forward(cfg, p["mlp"], x) if spec.is_moe \
+            else mlp_forward(cfg, p["mlp"], x)
 
 
 def _attn(cfg, spec, p, x, lc, pos0, rope, valid_len=None,
           flash_mode="off", mesh=None):
-    if spec.kind == "linear":
-        from ..qwen3_5 import gdn_forward
-        return gdn_forward(cfg, p["linear_attn"], x, lc, pos0, valid_len)
-    return attention_forward(cfg, spec, p["self_attn"], x, lc, pos0, rope,
-                             valid_len, flash_mode, mesh=mesh)
+    with jax.named_scope("cake.attn"):
+        if spec.kind == "linear":
+            from ..qwen3_5 import gdn_forward
+            return gdn_forward(cfg, p["linear_attn"], x, lc, pos0,
+                               valid_len)
+        return attention_forward(cfg, spec, p["self_attn"], x, lc, pos0,
+                                 rope, valid_len, flash_mode, mesh=mesh)
 
 
 def block_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
@@ -427,17 +434,19 @@ def forward_train(cfg: ModelConfig, params: dict, tokens):
 
 
 def embed_tokens(cfg: ModelConfig, params: dict, tokens):
-    x = embedding(tokens, params["embed_tokens"]["weight"])
-    if cfg.embed_scale is not None:
-        # Gemma scales embeddings by sqrt(hidden) in the model dtype
-        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
-    return x
+    with jax.named_scope("cake.embed"):
+        x = embedding(tokens, params["embed_tokens"]["weight"])
+        if cfg.embed_scale is not None:
+            # Gemma scales embeddings by sqrt(hidden) in the model dtype
+            x = x * jnp.asarray(cfg.embed_scale, x.dtype)
+        return x
 
 
 def lm_head_logits(cfg: ModelConfig, params: dict, x_last):
     """Final norm + head on the last position only (ref: text_model.rs:336-352
     last-token lm_head)."""
-    h = rms_norm(x_last, params["norm"]["weight"], cfg.rms_norm_eps)
-    w = (params["embed_tokens"]["weight"] if cfg.tie_word_embeddings
-         else params["lm_head"]["weight"])
-    return linear(h, w).astype(jnp.float32)
+    with jax.named_scope("cake.lm_head"):
+        h = rms_norm(x_last, params["norm"]["weight"], cfg.rms_norm_eps)
+        w = (params["embed_tokens"]["weight"] if cfg.tie_word_embeddings
+             else params["lm_head"]["weight"])
+        return linear(h, w).astype(jnp.float32)

@@ -5,8 +5,9 @@ The hand-written observability page predated serve/paged/spec/fleet and
 went three subsystems stale; like docs/knobs.md it is now GENERATED
 (`make metrics-doc`, `python -m cake_tpu.obs`) and pinned to this module
 by test. The metric table renders the process-global REGISTRY after the
-canonical declarations in obs/__init__.py import, the span table renders
-spans.SPAN_CATALOG, and the timeline-event table renders
+canonical declarations in obs/__init__.py import, the span and scope tables
+render spans.SPAN_CATALOG and spans.SCOPE_CATALOG, and the timeline-event
+table renders
 timeline.EVENT_KINDS — so the `metric-registry` lint (which checks every
 constructed instrument name against the generated file) closes the loop:
 an instrument cannot ship undocumented, and the doc cannot drift from
@@ -19,8 +20,9 @@ _HEADER = """\
 
 <!-- GENERATED FILE — do not edit. Source of truth is
      cake_tpu/obs/catalog.py (metric table: the canonical declarations
-     in cake_tpu/obs/__init__.py; span table: obs/spans.py
-     SPAN_CATALOG; timeline events: obs/timeline.py EVENT_KINDS).
+     in cake_tpu/obs/__init__.py; span and scope tables: obs/spans.py
+     SPAN_CATALOG / SCOPE_CATALOG; timeline events: obs/timeline.py
+     EVENT_KINDS).
      Regenerate with `make metrics-doc`; tests/test_analysis.py pins
      this file, and the `metric-registry` lint checks every
      constructed instrument name against it. -->
@@ -68,12 +70,46 @@ request carry the request id in their args, and a timeline's Perfetto
 export uses the same perf_counter clock, so both merge on one axis at
 <https://ui.perfetto.dev>.
 
+Every span carries an `id` (one counter for the process) and, when it
+was opened inside another span of its thread, that span's id as
+`parent`, both in `args`: nest spans by id, not by comparing intervals.
+`RECORDER.span()` yields the id; `RECORDER.add(..., parent=)` takes it
+for a span emitted from stamps.
+
+## One step, one id, one clock
+
+A scheduler iteration that does work takes the next flight `seq` as its
+step id. Its `serve.step` span carries it as `step`; so do the leaf spans
+under it (`serve.sweep`, `serve.admit`, `serve.plan`,
+`serve.decode_dispatch`, `serve.prefill_chunk`, `serve.prefill_finish`,
+`serve.fetch`, `serve.fanout`: they cover the step end to end and do not
+overlap), the flight record, and the `decode` / `spec_verify` /
+`first_token` / `prefill_chunk` events of every request the step touched.
+So a token in `/api/v1/requests/<id>` names the iteration that produced
+it, and that iteration's spans say where its time went. The phases come
+from eight clock reads a step; with the recorder off nothing else is
+paid, and the same reads give the flight record its `host_ms` /
+`fetch_ms` split. `spec.verify` overlaps `serve.decode_dispatch` (both
+are children of `serve.step`).
+
+A timeline snapshot carries `t0_us`, the instant it opened on the span
+recorder's clock (perf_counter microseconds; on Linux also every local
+client's `time.monotonic`): `t0_us + 1000 * t_ms` lays an event beside
+the spans. `obs.spans.sync_mark()` ties that clock to the profiler's: it
+writes a `cake.sync` annotation into the running `jax.profiler` trace
+and records the perf_counter instant taken beside it as a `trace.sync`
+span; `jax_trace()` calls it on entry, so a span export and an xplane
+taken together can be merged.
+
 ## Engine flight recorder
 
-The serve engine appends one record per scheduler iteration (occupancy,
-dispatch bucket, dispatch+fetch wall ms, spec accepts, queue depth,
-paged-pool free/used) into a ring of the last `CAKE_FLIGHT_RECORDER`
-iterations. The supervisor dumps the ring to `CAKE_TRACE_DIR` as JSON
+The serve engine appends one record per scheduler iteration (`seq` = the
+step id, occupancy, dispatch bucket, `fetch_ms` = the scheduler blocked
+on the device for the sampled ids, `host_ms` = the rest of the step's
+wall time, spec accepts, queue depth, paged-pool free/used) into a ring
+of the last `CAKE_FLIGHT_RECORDER` iterations: a stuck or slow step says
+which side of the fetch it was on. An iteration that failed or found
+nothing to do leaves its `seq` out of the ring. The supervisor dumps the ring to `CAKE_TRACE_DIR` as JSON
 when the wedge watchdog flags a stuck dispatch or the rebuild budget
 puts the engine DOWN — the post-mortem for the wedge failure mode where
 the process usually gets killed with the evidence in memory. The same
@@ -140,7 +176,7 @@ def generate_doc() -> str:
     # the canonical instrument declarations live in obs/__init__.py;
     # importing the package populates REGISTRY before we render it
     from . import REGISTRY
-    from .spans import SPAN_CATALOG
+    from .spans import SCOPE_CATALOG, SPAN_CATALOG
     from .timeline import EVENT_KINDS
 
     out = [_HEADER]
@@ -156,6 +192,23 @@ def generate_doc() -> str:
             "layer that records them:", "",
             "| span | recorded by |", "|---|---|"]
     for name, where in SPAN_CATALOG:
+        out.append(f"| `{name}` | {where} |")
+    out += ["", "## Scope catalog", "",
+            "`jax.named_scope` names inside the compiled programs. A scope "
+            "is metadata on the", "ops traced inside it: in a device trace "
+            "(xprof's op view; in a TPU xplane the `tf_op` stat of the "
+            "op's event metadata)", "the op's name holds "
+            "`.../cake.attn/...`, or `.../vmap(cake.attn)/...` where the",
+            "batching transform wraps the outermost scope, so device time "
+            "sums by part of the", "model across edits that renumber the "
+            "fusions: `python scripts/xplane_scopes.py", "<trace_dir>` "
+            "prints it per execution of `_decode_slots` and "
+            "`_prefill_slot`. The", "persistent compile cache keys on "
+            "this metadata (`utils/compile_cache.py`), so a", "cached "
+            "executable always carries the scopes of the code that asked "
+            "for it.",
+            "", "| scope | wraps |", "|---|---|"]
+    for name, where in SCOPE_CATALOG:
         out.append(f"| `{name}` | {where} |")
     out += ["", "## Timeline event catalog", "",
             "Typed per-request lifecycle events "

@@ -11,11 +11,16 @@ The recorder is off by default — a disabled span() is one attribute check —
 and turns on explicitly (RECORDER.enable()) or via the CAKE_TRACE_DIR env
 var. Timestamps are monotonic microseconds (perf_counter_ns), so exported
 events always satisfy the Perfetto monotonic-ts requirement.
+
+Every recorded span carries an `id` (process-wide counter) and, when it was
+opened inside another span of the same thread, that span's id as `parent`,
+both in `args`: a reader nests spans by id, not by comparing intervals.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import json
 import os
 import threading
@@ -59,6 +64,11 @@ def _now_us() -> int:
     return time.perf_counter_ns() // 1000
 
 
+# span ids: one counter for the process, so ids from several recorders (the
+# tests build their own) never collide in a merged export
+_next_id = itertools.count(1).__next__
+
+
 class SpanRecorder:
     """Bounded ring buffer of Chrome-trace complete events."""
 
@@ -68,6 +78,7 @@ class SpanRecorder:
         self._events: deque = deque(maxlen=max_events)
         self._lock = threading.Lock()
         self._export_seq = 0
+        self._open = threading.local()      # per-thread stack: _stack()
         if enabled is None:
             enabled = bool(knobs.get_str("CAKE_TRACE_DIR"))
         self.enabled = enabled
@@ -88,34 +99,63 @@ class SpanRecorder:
     # -- recording -----------------------------------------------------------
 
     def add(self, name: str, ts_us: int, dur_us: int, cat: str = "phase",
-            **args):
+            parent: int | None = None, **args) -> int | None:
         """Record a complete event from externally measured timestamps
-        (microseconds on the perf_counter clock)."""
+        (microseconds on the perf_counter clock). `parent` is the id of
+        the span that caused it; left out, it is the span open on this
+        thread, if any. Returns the new span's id (None when disabled)."""
         if not self.enabled:
-            return
+            return None
+        if parent is None:
+            stack = self._stack()
+            parent = stack[-1] if stack else None
+        return self._record(name, ts_us, dur_us, cat, _next_id(), parent,
+                            args)
+
+    def _stack(self) -> list:
+        """Ids of the spans open on the calling thread, innermost last."""
+        try:
+            return self._open.stack
+        except AttributeError:
+            self._open.stack = []
+            return self._open.stack
+
+    def _record(self, name, ts_us, dur_us, cat, sid, parent, args) -> int:
+        args["id"] = sid
+        if parent is not None:
+            args["parent"] = parent
         rid = _request_id.get()
         if rid is not None:
             args.setdefault("request_id", rid)
         ev = {"name": name, "cat": cat, "ph": "X", "ts": int(ts_us),
               "dur": max(int(dur_us), 0), "pid": os.getpid(),
-              "tid": threading.get_ident()}
-        if args:
-            ev["args"] = args
+              "tid": threading.get_ident(), "args": args}
         with self._lock:
             self._events.append(ev)
+        return sid
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "phase", **args):
-        """Record the wrapped block as one complete event. Disabled-path
-        cost is a single attribute check."""
+        """Record the wrapped block as one complete event; yields the
+        span's id (None when disabled). Spans opened inside the block on
+        this thread get it as their parent. Disabled-path cost is a single
+        attribute check."""
         if not self.enabled:
-            yield
+            yield None
             return
+        stack = self._stack()
+        sid = _next_id()
+        parent = stack[-1] if stack else None
+        stack.append(sid)
         t0 = _now_us()
         try:
-            yield
+            yield sid
         finally:
-            self.add(name, t0, _now_us() - t0, cat=cat, **args)
+            dur = _now_us() - t0
+            stack.pop()
+            # a recorder switched off mid-span drops the span, as add() does
+            if self.enabled:
+                self._record(name, t0, dur, cat, sid, parent, args)
 
     def instant(self, name: str, cat: str = "mark", **args):
         if not self.enabled:
@@ -182,9 +222,29 @@ SPAN_CATALOG: tuple[tuple[str, str], ...] = (
     ("replay_prefill", "cluster master: rebuild-by-replay prefill "
                        "reconstructing lost worker KV"),
     ("serve.step", "serve engine: one scheduler iteration (args: "
-                   "slots, queued)"),
+                   "slots, queued, `step` = the flight record's `seq`); "
+                   "the eight spans below are its children, carry its "
+                   "`step`, and cover it end to end"),
+    ("serve.sweep", "serve engine: cancel, queue-deadline and "
+                    "request-deadline sweeps"),
+    ("serve.admit", "serve engine: preempted slots resumed, queued "
+                    "requests started into free slots incl. the prefix "
+                    "splice (args: admitted)"),
+    ("serve.plan", "serve engine: choice of the chunk job, block "
+                   "reservation, draft building"),
+    ("serve.decode_dispatch", "serve engine: host cost of dispatching "
+                              "the batched decode / verify program "
+                              "(args: slots, bucket)"),
     ("serve.prefill_chunk", "serve engine: one chunked-admission "
-                            "prefill dispatch"),
+                            "prefill dispatch (args: tokens, pos0, slot)"),
+    ("serve.prefill_finish", "serve engine: prefix-cache block capture "
+                             "and, on the last chunk, first-token sample "
+                             "and slot activation (args: final)"),
+    ("serve.fetch", "serve engine: the one device->host fetch of the "
+                    "packed ids; the scheduler is blocked on the device"),
+    ("serve.fanout", "serve engine: sampled ids fanned out to the "
+                     "streams, finished rows released (args: tokens, "
+                     "finished)"),
     ("serve.replay", "serve engine: one slot's crash/preemption replay"),
     ("spec.verify", "speculative verify dispatch (generate path and "
                     "batched serve path)"),
@@ -192,14 +252,60 @@ SPAN_CATALOG: tuple[tuple[str, str], ...] = (
     ("deser", "worker wire phase: payload deserialization (PhaseTimer)"),
     ("fwd", "worker wire phase: stage forward compute (PhaseTimer)"),
     ("ser", "worker wire phase: result serialization (PhaseTimer)"),
+    ("trace.sync", "clock tie: the perf_counter stamp taken beside the "
+                   "`cake.sync` annotation in the profiler's trace "
+                   "(sync_mark)"),
 )
+
+# device-side vocabulary: every jax.named_scope the programs carry, with the
+# code it wraps. A scope is metadata on the ops traced inside it (the HLO
+# `op_name` holds `.../cake.attn/...`), so a trace reader can sum device
+# time by part of the model across edits that renumber the fusions. Nested
+# scopes nest in the name: `cake.sample/cake.sample.sort`.
+SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
+    ("cake.embed", "token embedding lookup (layers.embed_tokens)"),
+    ("cake.attn", "one layer's attention incl. its KV write "
+                  "(layers._attn: attention_forward / gdn_forward)"),
+    ("cake.ffn", "one layer's feed-forward (layers._ffn: mlp_forward or "
+                 "moe_forward)"),
+    ("cake.ffn.route", "MoE router: logits and top-k (ops.moe.moe_ffn)"),
+    ("cake.ffn.experts", "MoE expert GEMMs and combine (ops.moe.moe_ffn)"),
+    ("cake.lm_head", "final norm and vocabulary projection "
+                     "(layers.lm_head_logits)"),
+    ("cake.sample", "on-device sampling: sample, sample_traced and the "
+                    "verify programs' spec_accept (ops.sampling)"),
+    ("cake.sample.penalty", "sample_traced: repeat-penalty flag scatter "
+                            "and select"),
+    ("cake.sample.sort", "sample_traced: descending argsort of the "
+                         "vocabulary and the gather by it"),
+    ("cake.sample.top_p", "sample_traced: softmax, cumulative mass and "
+                          "the keep mask"),
+    ("cake.sample.draw", "sample_traced: gumbel noise and argmax"),
+)
+
+
+def sync_mark() -> int:
+    """Tie the recorder's clock to the profiler's: a `cake.sync`
+    TraceAnnotation in the running jax.profiler trace, and the
+    perf_counter instant taken beside it recorded as a `trace.sync` span.
+    A reader subtracts the two to lay RECORDER exports and timelines on
+    the xplane's axis. Returns the perf_counter nanosecond stamp."""
+    import jax
+    t_ns = time.perf_counter_ns()
+    with jax.profiler.TraceAnnotation("cake.sync"):
+        time.sleep(0.001)       # wide enough for a viewer to show
+    RECORDER.add("trace.sync", t_ns // 1000,
+                 (time.perf_counter_ns() - t_ns) // 1000, cat="trace",
+                 perf_ns=t_ns)
+    return t_ns
 
 
 @contextlib.contextmanager
 def jax_trace(log_dir: str | None):
     """Wrap a region in a JAX profiler trace (xprof / Perfetto viewable).
     No-op when log_dir is None. Device-side complement to SpanRecorder's
-    host-side spans (ref: tracing-chrome behind --sd-tracing)."""
+    host-side spans (ref: tracing-chrome behind --sd-tracing); sync_mark()
+    on entry ties the two clocks."""
     if not log_dir:
         yield
         return
@@ -207,6 +313,7 @@ def jax_trace(log_dir: str | None):
 
     import jax
     jax.profiler.start_trace(log_dir)
+    sync_mark()
     try:
         yield
     finally:

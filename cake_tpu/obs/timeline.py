@@ -72,18 +72,20 @@ EVENT_KINDS: dict[str, str] = {
              "`queue_wait_ms`) or heavy job started (`workload`); "
              "carries `qos`",
     "prefix_hit": "prefix-cache splice skipped `tokens` prompt tokens",
-    "prefill_chunk": "one chunk scattered into the pool row (`pos0`, "
-                     "`tokens`, `attn`: `flash-fresh` / `flash-append` "
+    "prefill_chunk": "one chunk scattered into the pool row (`step`, "
+                     "`pos0`, `tokens`, `attn`: `flash-fresh` / `flash-append` "
                      "when the chunk's program holds the Pallas kernel, "
                      "`masked` for the XLA path)",
     "prefill_done": "prompt fully prefilled; first token sampled "
                     "(`chunks`, `hit_tokens`)",
     "first_token": "first token fetched to the host (client-visible "
-                   "TTFT stamps here)",
+                   "TTFT stamps here; `step`)",
     "decode": "one batched decode iteration this slot participated in "
-              "(`bucket` = dispatch slot-count bucket)",
+              "(`bucket` = dispatch slot-count bucket, `step` = the "
+              "iteration's flight `seq`, which its `serve.*` spans carry)",
     "spec_verify": "one batched speculative verify this slot "
-                   "participated in (`bucket`, `proposed`, `accepted`)",
+                   "participated in (`step`, `bucket`, `proposed`, "
+                   "`accepted`)",
     "preempt": "slot evicted under KV-pool pressure (`mode` = "
                "swap | recompute | requeue, `tokens`)",
     "resume": "preempted request re-entered a slot (`mode`, `slot`)",
@@ -220,7 +222,10 @@ class TimelineStore:
         """JSON-shaped snapshot of one timeline (by id or alias).
         `t_ms` is milliseconds since the timeline opened; `start_unix`
         anchors the monotonic offsets to wall clock so tiers recorded in
-        different processes can be laid on one axis."""
+        different processes can be laid on one axis, and `t0_us` is the
+        opening instant on the span recorder's clock (perf_counter
+        microseconds, which on Linux is every local process's monotonic
+        clock): `t0_us + 1000 * t_ms` lays an event beside the spans."""
         with self._lock:
             tl = self._by_id.get(rid) or self._by_id.get(
                 self._aliases.get(rid, ""))
@@ -230,6 +235,7 @@ class TimelineStore:
                 "request_id": tl.rid,
                 "tier": tl.tier,
                 "start_unix": round(tl.start_unix, 6),
+                "t0_us": tl.t0_us,
                 "events": [dict(e) for e in tl.events],
                 "dropped": tl.dropped,
             }

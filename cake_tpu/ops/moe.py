@@ -83,19 +83,21 @@ def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
     prefill-sized batches, dense combine for decode.
     """
     e = gate_proj.shape[0]
-    logits = jnp.einsum("th,eh->te", x, router_weight,
-                        preferred_element_type=jnp.float32)
-    weights, idx = router_topk(logits, k, norm_topk_prob, gate_act)
+    with jax.named_scope("cake.ffn.route"):
+        logits = jnp.einsum("th,eh->te", x, router_weight,
+                            preferred_element_type=jnp.float32)
+        weights, idx = router_topk(logits, k, norm_topk_prob, gate_act)
 
-    if x.shape[0] >= RAGGED_MIN_TOKENS and _ragged_enabled():
-        return _moe_ragged(x, weights, idx, gate_proj, up_proj, down_proj,
-                           act)
-    w_te = combine_weights(weights, idx, e).astype(x.dtype)
-    g = jnp.einsum("th,eih->tei", x, gate_proj)         # [T, E, I]
-    u = jnp.einsum("th,eih->tei", x, up_proj)
-    a = _expert_act(g, u, act)
-    y_e = jnp.einsum("tei,ehi->teh", a, down_proj)      # [T, E, H]
-    return jnp.einsum("te,teh->th", w_te, y_e).astype(x.dtype)
+    with jax.named_scope("cake.ffn.experts"):
+        if x.shape[0] >= RAGGED_MIN_TOKENS and _ragged_enabled():
+            return _moe_ragged(x, weights, idx, gate_proj, up_proj,
+                               down_proj, act)
+        w_te = combine_weights(weights, idx, e).astype(x.dtype)
+        g = jnp.einsum("th,eih->tei", x, gate_proj)         # [T, E, I]
+        u = jnp.einsum("th,eih->tei", x, up_proj)
+        a = _expert_act(g, u, act)
+        y_e = jnp.einsum("tei,ehi->teh", a, down_proj)      # [T, E, H]
+        return jnp.einsum("te,teh->th", w_te, y_e).astype(x.dtype)
 
 
 def _ragged_dn(lhs_contract: int, rhs_contract: int):

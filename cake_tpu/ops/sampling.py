@@ -97,6 +97,7 @@ def sample_top_k_top_p(logits, rng, k: int, p: float, temperature: float):
     return jnp.take_along_axis(idx, choice[..., None], axis=-1)[..., 0].astype(jnp.int32)
 
 
+@jax.named_scope("cake.sample")
 def sample(logits, rng, cfg: SamplingConfig, recent_tokens=None):
     """Dispatch on the static SamplingConfig (ref: create_logits_processor).
 
@@ -116,6 +117,7 @@ def sample(logits, rng, cfg: SamplingConfig, recent_tokens=None):
     return sample_top_k_top_p(logits, rng, cfg.top_k, cfg.top_p, cfg.temperature)
 
 
+@jax.named_scope("cake.sample")
 def sample_traced(logits, rng, temperature, top_k, top_p, repeat_penalty,
                   recent_tokens):
     """Fully-traced sampling: every parameter is a runtime value, so ONE
@@ -133,30 +135,41 @@ def sample_traced(logits, rng, temperature, top_k, top_p, repeat_penalty,
     stable, so ties break to the lowest id exactly like jnp.argmax); the
     stochastic paths draw gumbel noise over the full sorted vocab instead
     of the top-k prefix, so they match in distribution, not per-key.
+
+    The named scopes (obs.spans.SCOPE_CATALOG) are metadata for a device
+    trace's reader: which of the four parts the step's time is in.
     """
     v = logits.shape[-1]
-    lf = logits.astype(jnp.float32)
-    # sign-aware repeat penalty with a traced strength (identity at 1.0)
-    idx = jnp.where(recent_tokens < 0, v, recent_tokens)
-    flagged = jnp.zeros((v,), jnp.bool_).at[idx].set(True, mode="drop")
-    penalized = jnp.where(lf >= 0, lf / repeat_penalty, lf * repeat_penalty)
-    lf = jnp.where(flagged, penalized, lf)
-    # one descending sort serves argmax (rank 0), top-k (rank mask) and
-    # top-p (cumulative-mass mask) — same O(V log V) the static top-p pays
-    scaled = lf / jnp.maximum(temperature, 1e-6)
-    order = jnp.argsort(-scaled)                       # stable: ties -> low id
-    sorted_logits = scaled[order]
-    rank = jnp.arange(v, dtype=jnp.int32)
-    # top-p mass is measured on the top-k-truncated RENORMALIZED
-    # distribution, matching sample_top_k_top_p's softmax-within-top-k
-    # (with top_k >= V the where is identity, so pure top-p matches too)
-    probs = jax.nn.softmax(jnp.where(rank < top_k, sorted_logits, -jnp.inf))
-    prev_mass = jnp.cumsum(probs) - probs
-    keep = (rank < top_k) & (prev_mass < top_p)
-    keep = keep.at[0].set(True)                        # never mask every token
-    z = jnp.where(keep, sorted_logits, -jnp.inf) + _gumbel(rng, (v,))
-    choice = order[jnp.argmax(z)]
-    return jnp.where(temperature > 0.0, choice, order[0]).astype(jnp.int32)
+    with jax.named_scope("cake.sample.penalty"):
+        lf = logits.astype(jnp.float32)
+        # sign-aware repeat penalty with a traced strength (identity at 1.0)
+        idx = jnp.where(recent_tokens < 0, v, recent_tokens)
+        flagged = jnp.zeros((v,), jnp.bool_).at[idx].set(True, mode="drop")
+        penalized = jnp.where(lf >= 0, lf / repeat_penalty,
+                              lf * repeat_penalty)
+        lf = jnp.where(flagged, penalized, lf)
+    with jax.named_scope("cake.sample.sort"):
+        # one descending sort serves argmax (rank 0), top-k (rank mask) and
+        # top-p (cumulative-mass mask) — same O(V log V) the static top-p
+        # pays
+        scaled = lf / jnp.maximum(temperature, 1e-6)
+        order = jnp.argsort(-scaled)                   # stable: ties -> low id
+        sorted_logits = scaled[order]
+    with jax.named_scope("cake.sample.top_p"):
+        rank = jnp.arange(v, dtype=jnp.int32)
+        # top-p mass is measured on the top-k-truncated RENORMALIZED
+        # distribution, matching sample_top_k_top_p's softmax-within-top-k
+        # (with top_k >= V the where is identity, so pure top-p matches too)
+        probs = jax.nn.softmax(
+            jnp.where(rank < top_k, sorted_logits, -jnp.inf))
+        prev_mass = jnp.cumsum(probs) - probs
+        keep = (rank < top_k) & (prev_mass < top_p)
+        keep = keep.at[0].set(True)                    # never mask every token
+    with jax.named_scope("cake.sample.draw"):
+        z = jnp.where(keep, sorted_logits, -jnp.inf) + _gumbel(rng, (v,))
+        choice = order[jnp.argmax(z)]
+        return jnp.where(temperature > 0.0, choice,
+                         order[0]).astype(jnp.int32)
 
 
 def config_has_filters(scfg: "SamplingConfig") -> bool:
@@ -221,6 +234,7 @@ def filtered_probs(logits, temperature, top_k, top_p, repeat_penalty,
     return jnp.zeros((v,), jnp.float32).at[order].set(kept)
 
 
+@jax.named_scope("cake.sample")
 def spec_accept(logits, draft, n_draft, rng, temperature, top_k, top_p,
                 repeat_penalty, recent_tokens, use_filters: bool = True):
     """Traced speculative accept/reject loop (Leviathan et al. 2023; Chen

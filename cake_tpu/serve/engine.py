@@ -387,6 +387,8 @@ class ServeEngine:
         # supervisor dumps to CAKE_TRACE_DIR on wedge/DOWN — built
         # before the supervisor so the watchdog can always reach it
         self.flight = FlightRecorder()
+        self._step_id = 0           # the running iteration's flight seq
+        self._chunk_end = None      # (stamp, final) of its prefill chunk
         self.dead: BaseException | None = None
         # the supervisor needs _stop (watchdog lifetime) — build it after
         # the events, before the scheduler thread can possibly fail
@@ -819,13 +821,23 @@ class ServeEngine:
         queued = self.queue.depth() > 0
         if not (busy or queued or self._preempted):
             return False
+        # the iteration's id: its flight record's `seq`, carried by its
+        # spans and by the timeline events it stamps
+        step = self._step_id = self.flight.begin()
         with RECORDER.span("serve.step", cat="serve", slots=len(busy),
-                           queued=self.queue.depth()):
+                           queued=self.queue.depth(), step=step):
             # reset failure-attribution context: a crash in the host
             # bookkeeping below must not implicate the PREVIOUS step's
             # request set (the decode/prefill dispatches re-arm with
             # their own sets)
             self.supervisor.arm("step", ())
+            # phase boundaries: one clock read each, every read ending one
+            # phase and opening the next, so the phases cover the step. The
+            # flight record always gets the split at the fetch; the span
+            # recorder, when on, gets one child span per phase
+            # (_emit_phases) — nothing else is paid per step when it is off
+            rec_on = RECORDER.enabled
+            t_sweep = now()
             # 1. cancel sweeps: decoding slots, mid-prefill slots, and
             # abandoned-while-queued requests (those would otherwise pin
             # queue capacity and 429 live clients while slots sit idle)
@@ -883,6 +895,8 @@ class ServeEngine:
                     self._fail(entry.req, RequestDeadlineExceeded(
                         now() - entry.req.t_enqueue,
                         self.request_deadline_s))
+            t_admit = now()
+            busy0 = self.pool.busy_count
             # 2. preempted slots resume FIRST (oldest-first, as soon as a
             # slot + enough blocks free up — their clients are mid-stream),
             # then every queued request takes a free slot (cheap: at most
@@ -897,6 +911,8 @@ class ServeEngine:
                 # report idle so _run waits on the wake event (0.5s
                 # heartbeat retries the resume) instead of hot-spinning
                 return False
+            t_plan = now()
+            admitted = self.pool.busy_count - busy0
             # 3. dispatch ONE batched step over the slots whose prefill
             # has completed (mid-prefill rows ride along frozen under the
             # active mask): a speculative verify step when the drafter
@@ -934,7 +950,7 @@ class ServeEngine:
                 active = self._ensure_decode_blocks(active, spec_job)
             packed = None
             nb = 0
-            td0 = now()                 # dispatch + fetch wall clock
+            t_dispatch = now()
             spec_acc0 = self.spec_accepted
             active_ids = tuple(self._reqs[i].id for i in active)
             if active:
@@ -992,6 +1008,8 @@ class ServeEngine:
                         self._layers, self._toks, self._pos, self._rngs,
                         self._recents, self._temps, self._top_ks,
                         self._top_ps, self._pens, self._act, nb=nb)
+            t_prefill = now()
+            self._chunk_end = None
             # 4. ...then advance the chosen admission by one chunk.
             # Dispatch order matters: the decode program is already queued
             # on the device, so the packed-ids fetch below never waits for
@@ -1006,6 +1024,8 @@ class ServeEngine:
                 else:
                     self._rr = idx          # removed: next job slid here
             # 5. ONE host fetch per iteration: fan the sampled ids out
+            t_fetch = t_fanout = now()
+            tokens = finished = 0
             if packed is not None:
                 # the fetch is where an async device failure (or a wedge)
                 # actually materializes on the host: re-arm with the
@@ -1017,17 +1037,36 @@ class ServeEngine:
                 # speculative iteration) for every slot in one transfer,
                 # after the next work is already dispatched
                 arr = np.asarray(packed)
+                t_fanout = now()
+                if rec_on:
+                    live = [self._reqs[i] for i in active]
+                    tokens = -sum(len(r.tokens) for r in live)
+                    finished = self.pool.busy_count
                 if spec_job is not None:
                     self._fanout_spec(active, arr, spec_job[0],
                                       spec_job[1], nb)
                 else:
                     self._fanout(active, arr, nb)
+                if rec_on:
+                    tokens += sum(len(r.tokens) for r in live)
+                    finished -= self.pool.busy_count
+            t_end = now()
+            if rec_on:
+                self._emit_phases(
+                    step, (t_sweep, t_admit, t_plan, t_dispatch, t_prefill,
+                           t_fetch, t_fanout, t_end),
+                    admitted=admitted, slots=len(active), bucket=nb,
+                    decoded=packed is not None, tokens=tokens,
+                    finished=finished)
             # flight record: one bounded dict per iteration — the black
-            # box the supervisor dumps on wedge/DOWN (see flight.py)
+            # box the supervisor dumps on wedge/DOWN (see flight.py).
+            # fetch_ms is the scheduler blocked on the device, host_ms the
+            # rest of the step: which side of the fetch a slow step was on
+            fetch_s = t_fanout - t_fetch
             rec = {
                 "occupancy": len(active), "bucket": nb,
-                "dispatch_ms": round((now() - td0) * 1e3, 3)
-                if packed is not None else 0.0,
+                "host_ms": round((t_end - t_sweep - fetch_s) * 1e3, 3),
+                "fetch_ms": round(fetch_s * 1e3, 3),
                 "queued": self.queue.depth(),
                 "prefilling": len(self._prefills),
                 "spec_accepted": self.spec_accepted - spec_acc0,
@@ -1035,8 +1074,38 @@ class ServeEngine:
             if self.paged is not None:
                 rec["kv_free"] = self.paged.alloc.free_count
                 rec["kv_used"] = self.paged.alloc.used_count
-            self.flight.record(**rec)
+            self.flight.record(step, **rec)
         return True
+
+    def _emit_phases(self, step: int, t: tuple, *, admitted: int,
+                     slots: int, bucket: int, decoded: bool, tokens: int,
+                     finished: int):
+        """The children of `serve.step` from the step's own stamps (seconds
+        on obs.now()'s clock, which is the recorder's). The prefill
+        interval is split at the end of the chunk's dispatch: the
+        `serve.prefill_chunk` span records itself (the roofline reader
+        ties device executions to it), the remainder is
+        `serve.prefill_finish`. Recorder-on only."""
+        t_sweep, t_admit, t_plan, t_dispatch, t_prefill, t_fetch, \
+            t_fanout, t_end = (int(x * 1e6) for x in t)
+
+        def add(name, t0, t1, **args):
+            RECORDER.add(name, t0, t1 - t0, cat="serve", step=step, **args)
+
+        add("serve.sweep", t_sweep, t_admit)
+        add("serve.admit", t_admit, t_plan, admitted=admitted)
+        add("serve.plan", t_plan, t_dispatch)
+        if decoded:
+            add("serve.decode_dispatch", t_dispatch, t_prefill,
+                slots=slots, bucket=bucket)
+        if self._chunk_end is not None:
+            t_chunk, final = self._chunk_end
+            add("serve.prefill_finish", int(t_chunk * 1e6), t_fetch,
+                final=final)
+        if decoded:
+            add("serve.fetch", t_fetch, t_fanout)
+            add("serve.fanout", t_fanout, t_end, tokens=tokens,
+                finished=finished)
 
     # -- chunked admission --------------------------------------------------
 
@@ -1105,7 +1174,8 @@ class ServeEngine:
         set_request_id(pf.req.id)
         try:
             with RECORDER.span("serve.prefill_chunk", cat="serve",
-                               tokens=take, pos0=pf.pos, slot=pf.slot):
+                               tokens=take, pos0=pf.pos, slot=pf.slot,
+                               step=self._step_id):
                 self.supervisor.arm("prefill", (pf.req.id,))
                 hook = faults.FAULT_HOOK
                 if hook is not None:
@@ -1119,7 +1189,10 @@ class ServeEngine:
                         pf.ids[pf.pos:pf.pos + take], pf.pos)
             pf.pos += take
             pf.chunks += 1
-            TIMELINES.event(pf.req.id, "prefill_chunk",
+            if RECORDER.enabled:
+                # where serve.prefill_finish begins (_emit_phases)
+                self._chunk_end = (now(), pf.pos >= pf.n)
+            TIMELINES.event(pf.req.id, "prefill_chunk", step=self._step_id,
                             pos0=pf.pos - take, tokens=take,
                             attn=self.model.last_chunk_attn)
             pf.next_block = self._capture_blocks(pf.ids, pf.slot, pf.pos,
@@ -1756,13 +1829,14 @@ class ServeEngine:
         verify step's correction/bonus token. The host already knows the
         drafts it proposed, so n_acc + 1 tokens per slot ride a fetch no
         bigger than the plain decode path's."""
+        step = self._step_id
         for i in active:
             req = self._reqs[i]
             if req._first_pending:
                 req._first_pending = False
                 req.t_first = now()
                 req.stats["ttft_s"] = req.t_first - req.t_enqueue
-                TIMELINES.event(req.id, "first_token")
+                TIMELINES.event(req.id, "first_token", step=step)
                 first = int(arr[0, i])
                 self._emit(req, first)
                 if self.model.cfg.is_eos(first) or req.budget <= 0:
@@ -1775,10 +1849,10 @@ class ServeEngine:
                 self.spec_proposed += n_prop
                 self.spec_accepted += n_acc
                 record_step(n_prop, n_acc, bucket=nb)
-                TIMELINES.event(req.id, "spec_verify", bucket=nb,
-                                proposed=n_prop, accepted=n_acc)
+                TIMELINES.event(req.id, "spec_verify", step=step,
+                                bucket=nb, proposed=n_prop, accepted=n_acc)
             else:
-                TIMELINES.event(req.id, "decode", bucket=nb)
+                TIMELINES.event(req.id, "decode", step=step, bucket=nb)
             for t in list(drafts[i, :n_acc]) + [nxt]:
                 req.budget -= 1
                 self._emit(req, int(t))
@@ -1792,14 +1866,15 @@ class ServeEngine:
         """Fan one decode iteration's packed ids out to the streams: row 0
         carries each slot's input token (a just-activated slot's unemitted
         FIRST token), row 1 the token this step sampled."""
+        step = self._step_id
         for i in active:
             req = self._reqs[i]
-            TIMELINES.event(req.id, "decode", bucket=nb)
+            TIMELINES.event(req.id, "decode", step=step, bucket=nb)
             if req._first_pending:
                 req._first_pending = False
                 req.t_first = now()     # first token actually on host:
                 req.stats["ttft_s"] = req.t_first - req.t_enqueue
-                TIMELINES.event(req.id, "first_token")
+                TIMELINES.event(req.id, "first_token", step=step)
                 first = int(arr[0, i])
                 self._emit(req, first)
                 if self.model.cfg.is_eos(first) or req.budget <= 0:

@@ -6,8 +6,9 @@ supervisor.py). When it happens, a gauge flips and /health says wedged,
 but the evidence of WHAT the engine was doing in the seconds before is
 gone: the span recorder is off by default and metrics are aggregates.
 This module is the black box: every completed scheduler iteration
-appends one small record (occupancy, dispatch bucket, dispatch+fetch
-wall time, spec accept counts, queue depth, KV-pool occupancy) into a
+appends one small record (occupancy, dispatch bucket, the step's wall
+time split at the device fetch into `host_ms` and `fetch_ms`, spec accept
+counts, queue depth, KV-pool occupancy) into a
 ring of the last `CAKE_FLIGHT_RECORDER` iterations, and the supervisor
 dumps the ring to `CAKE_TRACE_DIR` as JSON when the watchdog flags a
 wedge or the rebuild budget puts the engine DOWN — the post-mortem an
@@ -45,13 +46,24 @@ class FlightRecorder:
         self._ring: deque = deque(maxlen=self.capacity)
         self._seq = 0
 
-    def record(self, **fields) -> None:
-        """Append one iteration record; `t` (monotonic seconds) and a
-        process-lifetime sequence number are stamped here."""
+    def begin(self) -> int:
+        """Reserve the sequence number of the iteration that starts now:
+        the step id its spans and timeline events carry. An iteration that
+        fails or finds nothing to do writes no record, so its number is
+        missing from the ring."""
         with self._lock:
             self._seq += 1
-            rec = {"seq": self._seq, "t": round(now(), 6)}
-            rec.update(fields)
+            return self._seq
+
+    def record(self, seq: int | None = None, **fields) -> None:
+        """Append one iteration record under the number begin() gave (a
+        new one when none is passed); `t` (monotonic seconds) is stamped
+        here."""
+        if seq is None:
+            seq = self.begin()
+        rec = {"seq": seq, "t": round(now(), 6)}
+        rec.update(fields)
+        with self._lock:
             self._ring.append(rec)
 
     def snapshot(self) -> list[dict]:

@@ -22,11 +22,18 @@ def default_cache_dir() -> str:
 
 def enable_compile_cache() -> str:
     """Call once per process before the first compile. Returns the
-    directory in effect."""
+    directory in effect.
+
+    The key covers the ops' metadata too: the programs' named scopes
+    (obs.spans.SCOPE_CATALOG) live there, and JAX's default key ignores
+    it, so a cache written by a build with other scopes would hand back
+    executables whose device trace names the wrong parts, or none. The
+    price is a recompile after an edit that moves a traced line."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get(ENV)
     if placed:
         return placed
-    import jax
     path = default_cache_dir()
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -14,6 +14,7 @@ import pytest
 from cake_tpu.utils import compile_cache
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+METADATA_IN_KEY = ("jax_compilation_cache_include_metadata_in_key", True)
 
 
 @pytest.fixture
@@ -30,7 +31,9 @@ def test_cache_helper_leaves_a_placed_cache_alone(monkeypatch,
                                                   config_updates):
     monkeypatch.setenv(compile_cache.ENV, "/placed/from/outside")
     assert compile_cache.enable_compile_cache() == "/placed/from/outside"
-    assert config_updates == []         # JAX reads the variable itself
+    # JAX reads the variable itself: no directory is set in code, only
+    # that the key covers the ops' metadata (the named scopes, PR 26)
+    assert config_updates == [METADATA_IN_KEY]
 
 
 def test_cache_helper_fixed_path_inside_the_checkout(monkeypatch,
@@ -39,7 +42,8 @@ def test_cache_helper_fixed_path_inside_the_checkout(monkeypatch,
     want = os.path.join(ROOT, ".jax_cache")
     assert compile_cache.enable_compile_cache() == want
     assert compile_cache.enable_compile_cache() == want
-    assert config_updates == [("jax_compilation_cache_dir", want)] * 2
+    assert config_updates == [METADATA_IN_KEY,
+                              ("jax_compilation_cache_dir", want)] * 2
     # another pid derives the same path: nothing of the process is in it
     env = {k: v for k, v in os.environ.items() if k != compile_cache.ENV}
     out = subprocess.run(

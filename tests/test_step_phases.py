@@ -1,0 +1,344 @@
+"""Inside the step (ISSUE 26): span ids and parents, the phase spans under
+`serve.step` with their step id, the flight record's host/fetch split, the
+timelines on the recorder's clock, and the named scopes inside the decode
+and prefill programs."""
+import contextlib
+import re
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cake_tpu.models import TextModel, tiny_config
+from cake_tpu.obs import RECORDER, TIMELINES, SpanRecorder, TimelineStore
+from cake_tpu.obs.spans import SCOPE_CATALOG, SPAN_CATALOG, sync_mark
+from cake_tpu.ops import sampling
+from cake_tpu.ops.sampling import SamplingConfig
+from cake_tpu.serve import ServeEngine
+from cake_tpu.serve.flight import FlightRecorder
+
+GREEDY = SamplingConfig(temperature=0.0)
+CTX, CHUNK = 128, 16
+LEAVES = ("serve.sweep", "serve.admit", "serve.plan", "serve.decode_dispatch",
+          "serve.prefill_chunk", "serve.prefill_finish", "serve.fetch",
+          "serve.fanout")
+SCOPES = [name for name, _ in SCOPE_CATALOG]
+# a scope in an op's name: `/cake.attn/`, or `vmap(cake.attn)/` where the
+# batching transform wraps the outermost one
+SCOPE_RE = r"[/(](cake\.[a-z_.]+)(?=[/)])"
+
+
+# -- the recorder: ids and parents ------------------------------------------
+
+def test_span_ids_nest_by_thread():
+    """A span's parent is the span open on ITS thread; ids are unique over
+    threads; add() takes the open span as parent unless told another."""
+    rec = SpanRecorder(max_events=64, enabled=True)
+    inner_open = threading.Event()
+    release = threading.Event()
+
+    def other():
+        with rec.span("t2.outer") as outer:
+            inner_open.set()
+            release.wait(10)
+            with rec.span("t2.inner"):
+                pass
+            rec.add("t2.stamped", 1, 2)
+            rec.add("t2.told", 1, 2, parent=outer + 10_000)
+
+    t = threading.Thread(target=other)
+    with rec.span("t1.outer") as sid:
+        t.start()
+        assert inner_open.wait(10)
+        with rec.span("t1.inner") as inner:
+            assert inner != sid
+        release.set()
+        t.join(10)
+        assert not t.is_alive()
+    ev = {e["name"]: e["args"] for e in rec.events()}
+    assert ev["t1.inner"]["parent"] == ev["t1.outer"]["id"] == sid
+    assert "parent" not in ev["t1.outer"] and "parent" not in ev["t2.outer"]
+    assert ev["t2.inner"]["parent"] == ev["t2.outer"]["id"]
+    assert ev["t2.stamped"]["parent"] == ev["t2.outer"]["id"]
+    assert ev["t2.told"]["parent"] == ev["t2.outer"]["id"] + 10_000
+    ids = [a["id"] for a in ev.values()]
+    assert len(set(ids)) == len(ids) == 6
+
+
+def test_disabled_recorder_records_nothing_and_keeps_no_stack():
+    rec = SpanRecorder(max_events=8, enabled=False)
+    with rec.span("a") as sid:
+        assert sid is None
+        with rec.span("b"):
+            assert rec.add("c", 1, 2) is None
+    assert len(rec) == 0
+    assert not rec._stack()
+    # switched on inside a block that was entered off: the new span has no
+    # parent, and the stack is empty again after it
+    with rec.span("off"):
+        rec.enable()
+        with rec.span("on"):
+            pass
+    (ev,) = rec.events()
+    assert ev["name"] == "on" and "parent" not in ev["args"]
+    assert rec._stack() == []
+
+
+def test_flight_begin_reserves_the_step_id():
+    fr = FlightRecorder(capacity=4)
+    a = fr.begin()
+    b = fr.begin()              # an iteration that wrote nothing
+    fr.record(b, occupancy=1)
+    fr.record(occupancy=2)      # numbered on the spot
+    assert b == a + 1
+    assert [r["seq"] for r in fr.snapshot()] == [b, b + 1]
+
+
+def test_timeline_snapshot_is_on_the_recorders_clock():
+    st = TimelineStore(capacity=2)
+    st.begin("r")
+    st.event("r", "decode", step=7, bucket=1)
+    tl = st.get("r")
+    chrome = st.to_chrome("r")["traceEvents"][0]
+    assert tl["events"][0]["step"] == 7
+    assert chrome["ts"] == int(tl["t0_us"] + tl["events"][0]["t_ms"] * 1e3)
+
+
+def test_sync_mark_records_the_clock_tie():
+    RECORDER.clear()
+    RECORDER.enable()
+    try:
+        t_ns = sync_mark()
+    finally:
+        RECORDER.disable()
+    (ev,) = [e for e in RECORDER.events() if e["name"] == "trace.sync"]
+    assert ev["args"]["perf_ns"] == t_ns and ev["ts"] == t_ns // 1000
+    RECORDER.clear()
+
+
+def test_catalogs_name_the_phases_and_scopes():
+    spans = {n for n, _ in SPAN_CATALOG}
+    assert set(LEAVES) | {"serve.step", "trace.sync"} <= spans
+    assert len(set(SCOPES)) == len(SCOPES) == 11
+
+
+# -- the engine: phases of one iteration ------------------------------------
+
+@pytest.fixture(scope="module")
+def model():
+    return TextModel(tiny_config(), dtype=jnp.float32, seed=0,
+                     max_cache_len=CTX)
+
+
+@pytest.fixture(scope="module")
+def traced(model):
+    """Three requests through an engine with the recorder on: the spans,
+    the flight records and the timelines of the same iterations."""
+    eng = ServeEngine(model, slots=4, max_queue=8, ctx_len=CTX,
+                      prefill_chunk=CHUNK)
+    try:
+        warm = eng.submit(list(range(3, 40)), max_new_tokens=3,
+                          sampling=GREEDY)
+        assert warm.wait(600) and "error" not in warm.result
+        seq0 = eng.flight.snapshot()[-1]["seq"]
+        RECORDER.clear()
+        RECORDER.enable()
+        # no prompt is another's prefix: every chunk is computed
+        reqs = [eng.submit(list(range(50 + n, 50 + 2 * n)), max_new_tokens=6,
+                           sampling=GREEDY, request_id=f"phase-{n}")
+                for n in (5, 20, 40)]
+        for r in reqs:
+            assert r.wait(600) and "error" not in r.result
+        eng.close()
+    finally:
+        RECORDER.disable()
+        eng.close()
+    spans = [e for e in RECORDER.events() if e["cat"] == "serve"]
+    RECORDER.clear()
+    return {"spans": spans,
+            "flight": [r for r in eng.flight.snapshot() if r["seq"] > seq0],
+            "timelines": {r.id: TIMELINES.get(r.id) for r in reqs},
+            "reqs": reqs}
+
+
+def _children(spans, step_span):
+    return sorted((e for e in spans if e["name"] in LEAVES
+                   and e["args"].get("parent") == step_span["args"]["id"]),
+                  key=lambda e: e["ts"])
+
+
+def test_every_worked_step_has_one_step_span(traced):
+    steps = [e for e in traced["spans"] if e["name"] == "serve.step"]
+    by_step = {e["args"]["step"]: e for e in steps}
+    assert len(by_step) == len(steps)
+    assert set(by_step) == {r["seq"] for r in traced["flight"]}
+    assert all({"slots", "queued", "id"} <= set(e["args"]) for e in steps)
+
+
+def test_phases_cover_the_step_without_overlap(traced):
+    spans = traced["spans"]
+    for step in (e for e in spans if e["name"] == "serve.step"):
+        kids = _children(spans, step)
+        assert kids, step
+        assert {k["args"]["step"] for k in kids} == {step["args"]["step"]}
+        assert kids[0]["ts"] >= step["ts"]
+        assert kids[-1]["ts"] + kids[-1]["dur"] <= step["ts"] + step["dur"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"], (a, b)
+        names = [k["name"] for k in kids]
+        assert names == [n for n in LEAVES if n in names]     # in order
+        assert len(set(names)) == len(names)
+        # the holes are a fixed cost, not a share: the emission of these
+        # very spans after the last stamp, and the few lines between the
+        # decode dispatch and the chunk's own span (a 2 ms step on this CPU;
+        # 30-50 ms on the chip)
+        hole = step["dur"] - sum(k["dur"] for k in kids)
+        assert hole <= 0.05 * step["dur"] + 500, (hole, step["dur"])
+
+
+def test_a_step_that_decoded_fetched_and_fanned_out_once(traced):
+    spans = traced["spans"]
+    by_step = {e["args"]["step"]: _children(spans, e)
+               for e in spans if e["name"] == "serve.step"}
+    decoded = [r for r in traced["flight"] if r["occupancy"] > 0]
+    assert decoded
+    tokens = finished = 0
+    for r in traced["flight"]:
+        names = [k["name"] for k in by_step[r["seq"]]]
+        want = 1 if r["occupancy"] > 0 else 0
+        for n in ("serve.decode_dispatch", "serve.fetch", "serve.fanout"):
+            assert names.count(n) == want, (r, names)
+        for k in by_step[r["seq"]]:
+            if k["name"] == "serve.fanout":
+                tokens += k["args"]["tokens"]
+                finished += k["args"]["finished"]
+            if k["name"] == "serve.decode_dispatch":
+                assert k["args"]["slots"] == r["occupancy"]
+                assert k["args"]["bucket"] == r["bucket"]
+    assert tokens == sum(len(r.result["tokens"]) for r in traced["reqs"])
+    assert finished == len(traced["reqs"])
+    chunks = [e for e in spans if e["name"] == "serve.prefill_chunk"]
+    finals = [e for e in spans if e["name"] == "serve.prefill_finish"]
+    assert len(chunks) == len(finals) == 1 + 2 + 3     # 5, 20, 40 tokens
+    assert sum(e["args"]["final"] for e in finals) == len(traced["reqs"])
+    assert all({"tokens", "pos0", "slot", "step"} <= set(e["args"])
+               for e in chunks)
+    assert sum(k["args"]["admitted"] for kids in by_step.values()
+               for k in kids if k["name"] == "serve.admit") == 3
+
+
+def test_timeline_events_name_their_step(traced):
+    steps = {r["seq"] for r in traced["flight"]}
+    fetch = {e["args"]["step"]: e for e in traced["spans"]
+             if e["name"] == "serve.fetch"}
+    for tl in traced["timelines"].values():
+        assert tl["t0_us"] > 0
+        stamped = [e for e in tl["events"] if e["kind"] in
+                   ("decode", "first_token", "prefill_chunk")]
+        assert stamped and all(e["step"] in steps for e in stamped)
+        for e in stamped:
+            if e["kind"] != "decode":
+                continue
+            # on one clock: the token was stamped after its step's fetch
+            f = fetch[e["step"]]
+            t_us = tl["t0_us"] + e["t_ms"] * 1e3
+            assert t_us >= f["ts"] + f["dur"] - 1
+
+
+def test_flight_records_split_at_the_fetch_with_the_recorder_off(model):
+    assert not RECORDER.enabled
+    before = len(RECORDER)
+    eng = ServeEngine(model, slots=2, max_queue=4, ctx_len=CTX,
+                      prefill_chunk=CHUNK)
+    try:
+        req = eng.submit([3, 17, 42, 99, 7], max_new_tokens=5,
+                         sampling=GREEDY)
+        assert req.wait(600) and "error" not in req.result
+    finally:
+        eng.close()
+    recs = eng.flight.snapshot()
+    assert recs and all("dispatch_ms" not in r for r in recs)
+    assert all(r["host_ms"] >= 0 and r["fetch_ms"] >= 0 for r in recs)
+    assert any(r["fetch_ms"] > 0 for r in recs if r["occupancy"])
+    assert all(r["fetch_ms"] == 0 for r in recs if not r["occupancy"])
+    assert len(RECORDER) == before
+
+
+# -- the programs: named scopes ---------------------------------------------
+
+@pytest.fixture(scope="module")
+def moe_model():
+    return TextModel(tiny_config("qwen3_moe", num_hidden_layers=2),
+                     dtype=jnp.float32, seed=0, max_cache_len=CTX)
+
+
+def _lowered(model, program: str) -> str:
+    b, n = 2, 8
+    layers = model.new_cache(b, kv_len=CTX)["layers"]
+    if program == "_prefill_slot":
+        return model._prefill_slot.lower(
+            model.params, jnp.zeros((1, CHUNK), jnp.int32), layers,
+            jnp.int32(0), jnp.int32(0), jnp.int32(CHUNK),
+            flash_mode="off").as_text(debug_info=True)
+    return model._decode_slots.lower(
+        model.params, layers, jnp.zeros((b,), jnp.int32),
+        jnp.zeros((b,), jnp.int32),
+        jax.vmap(jax.random.PRNGKey)(jnp.arange(b)),
+        jnp.full((b, n), -1, jnp.int32), jnp.ones((b,), jnp.float32),
+        jnp.full((b,), 256, jnp.int32), jnp.ones((b,), jnp.float32),
+        jnp.ones((b,), jnp.float32), jnp.ones((b,), jnp.bool_),
+        nb=b).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("program", ["_decode_slots", "_prefill_slot"])
+def test_programs_carry_the_scopes_of_the_catalog(moe_model, program):
+    """The MoE family reaches every scope; the chunk program samples
+    nothing (its first token is drawn by a program of its own)."""
+    text = _lowered(moe_model, program)
+    found = set(re.findall(SCOPE_RE, text))
+    want = set(SCOPES) if program == "_decode_slots" else \
+        {s for s in SCOPES if not s.startswith("cake.sample")}
+    assert found == want
+    if program == "_decode_slots":
+        assert "vmap(cake.sample)/cake.sample.sort/" in text
+    else:
+        assert "/cake.ffn/cake.ffn.route/" in text
+
+
+def test_dense_model_has_no_router_scope(model):
+    found = set(re.findall(SCOPE_RE,
+                           _lowered(model, "_decode_slots")))
+    assert found == set(SCOPES) - {"cake.ffn.route", "cake.ffn.experts"}
+
+
+def test_scopes_change_no_number_and_no_instruction(monkeypatch):
+    """sample_traced with and without its scopes: the same program text
+    once the locations are left out (the compiler is given the same
+    instructions in the same order), and the same ids."""
+    v = 1000
+    keys = jax.random.split(jax.random.PRNGKey(3), 12)
+    logits = jax.random.normal(jax.random.PRNGKey(4), (12, v)) * 3
+    recent = jnp.array([5, 9, -1, -1], jnp.int32)
+    rest = (jnp.float32(0.8), jnp.int32(50), jnp.float32(0.9),
+            jnp.float32(1.2), recent)
+
+    def build(fn):
+        one = jax.jit(fn)
+        hlo = one.lower(logits[0], keys[0], *rest).as_text()
+        ids = [int(one(lg, k, *rest)) for lg, k in zip(logits, keys)]
+        return hlo, ids
+
+    with_scopes = build(sampling.sample_traced)
+    assert "cake.sample" in jax.jit(sampling.sample_traced).lower(
+        logits[0], keys[0], *rest).as_text(debug_info=True)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = build(sampling.sample_traced.__wrapped__)
+    assert "cake." not in jax.jit(sampling.sample_traced.__wrapped__).lower(
+        logits[0], keys[0], *rest).as_text(debug_info=True)
+    assert bare[1] == with_scopes[1]
+    assert bare[0] == with_scopes[0]
+    assert len(set(with_scopes[1])) > 1 and max(with_scopes[1]) < v
+    assert np.isfinite(np.asarray(logits)).all()

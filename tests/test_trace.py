@@ -304,7 +304,7 @@ def test_flight_recorder_dumps_on_injected_wedge(model, tmp_path,
         assert body["reason"] == "wedge"
         assert body["iterations"], "dump carries no iteration records"
         rec = body["iterations"][-1]
-        assert {"seq", "t", "occupancy", "bucket", "dispatch_ms",
+        assert {"seq", "t", "occupancy", "bucket", "host_ms", "fetch_ms",
                 "queued"} <= set(rec)
         assert eng.supervisor.wedge_count >= 1
     finally:

@@ -276,8 +276,9 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
                     "verify programs' spec_accept (ops.sampling)"),
     ("cake.sample.penalty", "sample_traced: repeat-penalty flag scatter "
                             "and select"),
-    ("cake.sample.sort", "sample_traced: descending argsort of the "
-                         "vocabulary and the gather by it"),
+    ("cake.sample.sort", "sample_traced: temperature scaling and the one "
+                         "descending sort of the vocabulary that returns "
+                         "both the sorted logits and their ids"),
     ("cake.sample.top_p", "sample_traced: softmax, cumulative mass and "
                           "the keep mask"),
     ("cake.sample.draw", "sample_traced: gumbel noise and argmax"),

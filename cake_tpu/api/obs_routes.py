@@ -29,12 +29,13 @@ STALE_WORKER_S = 120.0
 
 # serve engine with pending work but no completed scheduler iteration for
 # this long reports wedged (must exceed any single in-iteration XLA
-# compile — the first decode of each slot-count bucket and each prefill
-# chunk bucket compiles in-line; chunked admission means a long prompt is
-# otherwise spread over MANY short iterations, so a quiet scheduler really
-# is stuck, not just prefilling). The engine block also surfaces
-# `prefilling` (in-flight chunked admissions) and `prefix_cache` occupancy
-# (blocks/bytes/hits/misses/evictions) straight from engine.health()
+# compile — the first decode (of each slot-count bucket on a paged pool)
+# and each prefill chunk bucket compiles in-line; chunked admission means
+# a long prompt is otherwise spread over MANY short iterations, so a quiet
+# scheduler really is stuck, not just prefilling). The engine block also
+# surfaces `prefilling` (in-flight chunked admissions) and `prefix_cache`
+# occupancy (blocks/bytes/hits/misses/evictions) straight from
+# engine.health()
 ENGINE_WEDGED_S = 120.0
 
 PROM_CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"

@@ -164,8 +164,9 @@ _knob("CAKE_SPEC", str, None, "spec",
 _knob("CAKE_SPEC_K", int, 6, "spec",
       "per-slot draft window: tokens proposed per verify step, clamped "
       "to [1, 32]; in the serve engine every occupied slot carries its "
-      "own window through ONE batched verify dispatch (one executable "
-      "per slot-bucket, k static via the draft shape)")
+      "own window through ONE batched verify dispatch (k static "
+      "via the draft shape: one executable on contiguous rows, one per "
+      "slot-bucket on a paged pool)")
 _knob("CAKE_SPEC_NGRAM", int, 3, "spec",
       "n-gram drafter max match window: the prompt-lookup drafter "
       "matches the last [2, this] tokens against the slot's own history "

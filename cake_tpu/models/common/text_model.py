@@ -313,20 +313,21 @@ class TextModel:
         def _grow(cache, new_len):
             return grow_cache(cfg, cache, new_len)
 
-        @functools.partial(jax.jit, static_argnames=("nb",),
-                           donate_argnums=(1, 2, 3, 4, 5))
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4, 5))
         def _decode_slots(params, layers, toks, pos, rngs, recents,
-                          temps, top_ks, top_ps, penalties, active, nb):
-            """One batched sampled decode step over pool rows 0..nb-1 with
+                          temps, top_ks, top_ps, penalties, active):
+            """One batched sampled decode step over EVERY pool row with
             per-slot positions, RNG keys, recent-token windows and TRACED
             sampling params (sample_traced): the continuous-batching
-            engine's iteration unit. nb is the only static argument — one
-            executable per slot-count bucket (serve.slots.slot_bucket:
-            powers of two up to the pool size), so the serve path adds
-            O(log slots) programs total and a mixed bag of
-            client sampling configs cannot grow the compile cache (the
+            engine's iteration unit. No static argument — ONE executable
+            per pool shape at every occupancy, so a mixed bag of client
+            sampling configs cannot grow the compile cache (the
             api/text.py quantization grid stays the only bound on the
-            legacy static-SamplingConfig programs).
+            legacy static-SamplingConfig programs). The donated pool
+            buffers update in place: a program that ran on a prefix of
+            the rows would slice them out and write them back, and those
+            copies of every layer's K and V cost more than the masked
+            rows' work (PERF.md, PR 33).
 
             The per-slot step is the SAME embed -> layers -> head ->
             sample pipeline as sampled_step, vmapped over the slot axis.
@@ -357,37 +358,18 @@ class TextModel:
                     jnp.where(act, rng2, rng),
                     jnp.where(act, push_recent_token(recent, nxt), recent))
 
-            step = active[:nb].astype(jnp.int32)
+            # the whole per-slot carry advances ON DEVICE: the engine ships
+            # nothing per iteration and fetches only the packed ids
+            nxt, layers, rngs, recents = jax.vmap(one)(
+                toks, layers, pos, rngs, recents, temps, top_ks, top_ps,
+                penalties, active)
             # the fetch target packs [input token ; sampled token] per slot:
             # a freshly admitted slot's first token (sampled at admission,
             # never fetched — admission stays sync-free) rides the SAME
             # device->host transfer as this step's ids, so an iteration
             # costs exactly one fetch no matter how many slots joined
-            # lint: disable=recompile-hazard — nb is STATIC (slot_bucket powers of
-            # two) and the pool shape is fixed per engine: this branch resolves
-            # once per bucket at trace time, never per call
-            if nb == toks.shape[0]:
-                # full-occupancy fast path: no prefix slice / write-back —
-                # the donated pool buffers update in place instead of
-                # round-tripping through slice copies every token
-                nxt, layers, rngs, recents = jax.vmap(one)(
-                    toks, layers, pos, rngs, recents, temps, top_ks,
-                    top_ps, penalties, active)
-                return (jnp.stack([toks, nxt]), layers, nxt, pos + step,
-                        rngs, recents)
-            sub = jax.tree_util.tree_map(lambda a: a[:nb], layers)
-            nxt, new_sub, new_rngs, new_recents = jax.vmap(one)(
-                toks[:nb], sub, pos[:nb], rngs[:nb], recents[:nb],
-                temps[:nb], top_ks[:nb], top_ps[:nb], penalties[:nb],
-                active[:nb])
-            layers = jax.tree_util.tree_map(
-                lambda full, s: full.at[:nb].set(s), layers, new_sub)
-            # the whole per-slot carry advances ON DEVICE: the engine ships
-            # nothing per iteration and fetches only the packed ids
-            return (jnp.stack([toks[:nb], nxt]), layers,
-                    toks.at[:nb].set(nxt), pos.at[:nb].add(step),
-                    rngs.at[:nb].set(new_rngs),
-                    recents.at[:nb].set(new_recents))
+            return (jnp.stack([toks, nxt]), layers, nxt,
+                    pos + active.astype(jnp.int32), rngs, recents)
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _slot_assign(layers, src_layers, slot):
@@ -483,13 +465,13 @@ class TextModel:
                 temp, top_k, top_p, penalty, filt)
             return jnp.stack([n_acc, nxt]), cache, recent
 
-        @functools.partial(jax.jit, static_argnames=("nb", "filt"),
+        @functools.partial(jax.jit, static_argnames=("filt",),
                            donate_argnums=(1, 2, 3, 4, 5))
         def _spec_slots(params, layers, toks, pos, rngs, recents, temps,
                         top_ks, top_ps, penalties, active, drafts,
-                        n_drafts, nb, filt):
-            """Batched multi-token speculative verify over pool rows
-            0..nb-1 — the `_decode_slots` of the speculative path. Each
+                        n_drafts, filt):
+            """Batched multi-token speculative verify over every pool
+            row — the `_decode_slots` of the speculative path. Each
             slot forwards [input_token, d_0 .. d_{k-1}] at its OWN
             position in one vmapped program, runs the traced
             accept/reject rule with its own sampling params, commits
@@ -501,11 +483,11 @@ class TextModel:
             A slot whose drafter abstained (n_drafts == 0) degenerates to
             a plain decode step inside the same program, so mixed
             draft/no-draft iterations never fall back to a second
-            dispatch. nb and `filt` (False = no slot in the dispatch
-            filters the vocabulary — the accept rule skips its per-row
-            sorts) are the only static arguments; the draft width k
-            rides the drafts shape — one executable per (slot-bucket, k,
-            filt), zero recompiles in steady state.
+            dispatch. `filt` (False = no slot in the dispatch filters
+            the vocabulary — the accept rule skips its per-row sorts) is
+            the only static argument; the draft width k rides the
+            drafts shape — one executable per (k, filt), zero recompiles
+            in steady state.
 
             Inactive rows (free / mid-chunked-prefill) ride along frozen
             exactly like _decode_slots: valid_len 0 drops the KV scatter
@@ -543,27 +525,11 @@ class TextModel:
                         jnp.where(act, rng2, rng),
                         jnp.where(act, recent2, recent))
 
-            # lint: disable=recompile-hazard — nb is STATIC (slot_bucket powers of
-            # two) and the pool shape is fixed per engine: this branch resolves
-            # once per bucket at trace time, never per call
-            if nb == toks.shape[0]:
-                nxt, n_accs, adv, layers, rngs, recents = jax.vmap(one)(
-                    toks, layers, pos, rngs, recents, temps, top_ks,
-                    top_ps, penalties, active, drafts, n_drafts)
-                return (jnp.stack([toks, n_accs, nxt]), layers, nxt,
-                        pos + adv, rngs, recents)
-            sub = jax.tree_util.tree_map(lambda a: a[:nb], layers)
-            nxt, n_accs, adv, new_sub, new_rngs, new_recents = \
-                jax.vmap(one)(
-                    toks[:nb], sub, pos[:nb], rngs[:nb], recents[:nb],
-                    temps[:nb], top_ks[:nb], top_ps[:nb], penalties[:nb],
-                    active[:nb], drafts[:nb], n_drafts[:nb])
-            layers = jax.tree_util.tree_map(
-                lambda full, s: full.at[:nb].set(s), layers, new_sub)
-            return (jnp.stack([toks[:nb], n_accs, nxt]), layers,
-                    toks.at[:nb].set(nxt), pos.at[:nb].add(adv),
-                    rngs.at[:nb].set(new_rngs),
-                    recents.at[:nb].set(new_recents))
+            nxt, n_accs, adv, layers, rngs, recents = jax.vmap(one)(
+                toks, layers, pos, rngs, recents, temps, top_ks, top_ps,
+                penalties, active, drafts, n_drafts)
+            return (jnp.stack([toks, n_accs, nxt]), layers, nxt, pos + adv,
+                    rngs, recents)
 
         @functools.partial(jax.jit, static_argnames=("width",))
         def _slot_extract(layers, slot, start, width):
@@ -579,8 +545,9 @@ class TextModel:
         # ([num_blocks, block_tokens, ...] per layer); a slot addresses its
         # logical row through a TRACED [B, max_blocks] block table, so the
         # host-side allocator can remap/extend tables every iteration
-        # without compiling anything new — `nb` stays the only static
-        # argument, exactly like the contiguous _decode_slots. SWA rings
+        # without compiling anything new — `nb` (the slot-count bucket the
+        # small per-slot `rows` are sliced to) is the only static
+        # argument; the contiguous _decode_slots has none. SWA rings
         # and linear-attention state stay per-slot rows (`rows` pytree);
         # the gathered view reproduces the contiguous row's layout
         # byte-for-byte, so paged greedy decode is bit-identical to the
@@ -858,8 +825,9 @@ class TextModel:
     # -- continuous-batching slot programs (serve engine) -------------------
 
     def decode_slots(self, layers, toks, pos, rngs, recents,
-                     temps, top_ks, top_ps, penalties, active, nb: int):
-        """One batched sampled decode step over pool rows 0..nb-1.
+                     temps, top_ks, top_ps, penalties, active,
+                     nb: int | None = None):
+        """One batched sampled decode step over every pool row.
 
         layers: a pool cache's per-layer list (leaves [B, ...]); toks/pos:
         [B] int32; rngs: [B] PRNG keys; recents: [B, N] int32;
@@ -869,15 +837,18 @@ class TextModel:
         byte-identical. All per-slot carries are device-resident and
         DONATED except `active` (the scheduler mutates it only at
         admission/release transitions and keeps its own handle). nb:
-        static slot-count bucket (occupied slots must sit below it).
-        Returns (packed_ids [2, nb] = [input token ; sampled token] per
+        accepted for the paged twin's callers and ignored — the program
+        runs on all B rows in place at every occupancy, `active` alone
+        says which rows step.
+        Returns (packed_ids [2, B] = [input token ; sampled token] per
         slot — one fetch serves this step's ids AND any just-admitted
         slot's unfetched first token — then layers, toks, pos, rngs,
         recents).
         """
+        del nb
         return self._decode_slots(self.params, layers, toks, pos, rngs,
                                   recents, temps, top_ks, top_ps, penalties,
-                                  active, nb=nb)
+                                  active)
 
     def prefill_chunk(self, layers, slot: int, token_ids, pos0: int):
         """Prefill one chunk of a prompt into pool row `slot` at absolute
@@ -1030,22 +1001,24 @@ class TextModel:
                                  filt=config_has_filters(scfg))
 
     def spec_slots(self, layers, toks, pos, rngs, recents, temps, top_ks,
-                   top_ps, penalties, active, drafts, n_drafts, nb: int,
-                   filt: bool = True):
-        """Batched multi-token speculative verify over pool rows 0..nb-1
+                   top_ps, penalties, active, drafts, n_drafts,
+                   nb: int | None = None, filt: bool = True):
+        """Batched multi-token speculative verify over every pool row
         (the serve engine's speculative iteration unit — decode_slots'
-        contract with a per-slot draft window). drafts: [B, k] int32
+        contract with a per-slot draft window, `nb` ignored as there:
+        one program per (k, filt)). drafts: [B, k] int32
         (host-built proposals, right-padded); n_drafts: [B] int32 valid
         draft counts (0 = plain decode step for that slot). Acceptance is
         ragged per slot; each slot's carries advance by its own accepted
         length. `filt` (static): pass False when no slot in the dispatch
         uses top-k/top-p — the accept rule skips its per-row sorts.
-        Returns (packed_ids [3, nb] = [input token ; n_acc ; next token]
+        Returns (packed_ids [3, B] = [input token ; n_acc ; next token]
         per slot, layers, toks, pos, rngs, recents)."""
+        del nb
         return self._spec_slots(self.params, layers, toks, pos, rngs,
                                 recents, temps, top_ks, top_ps, penalties,
                                 active, jnp.asarray(drafts, jnp.int32),
-                                jnp.asarray(n_drafts, jnp.int32), nb=nb,
+                                jnp.asarray(n_drafts, jnp.int32),
                                 filt=bool(filt))
 
     def spec_slots_paged(self, pool, rows, tables, toks, pos, rngs,

@@ -123,9 +123,9 @@ SPEC_ACCEPTED_LEN = REGISTRY.histogram(
 
 SPEC_BUCKET_ACCEPTED = REGISTRY.histogram(
     "cake_serve_spec_bucket_accepted_length",
-    "Accepted draft tokens per slot verify, labeled by the batched "
-    "dispatch's slot-count bucket — the acceptance x occupancy tradeoff "
-    "the serve bench reports",
+    "Accepted draft tokens per slot verify, labeled by the row count "
+    "the batched dispatch ran (the pool size on contiguous rows, the "
+    "slot-count bucket on a paged pool)",
     labelnames=("bucket",),
     buckets=(0, 1, 2, 3, 4, 6, 8, 12, 16))
 

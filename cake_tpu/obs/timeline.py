@@ -81,7 +81,7 @@ EVENT_KINDS: dict[str, str] = {
     "first_token": "first token fetched to the host (client-visible "
                    "TTFT stamps here; `step`)",
     "decode": "one batched decode iteration this slot participated in "
-              "(`bucket` = dispatch slot-count bucket, `step` = the "
+              "(`bucket` = rows the dispatched program ran, `step` = the "
               "iteration's flight `seq`, which its `serve.*` spans carry)",
     "spec_verify": "one batched speculative verify this slot "
                    "participated in (`step`, `bucket`, `proposed`, "

@@ -410,7 +410,7 @@ class ServeEngine:
         admission/release only, and the whole carry (tokens, positions,
         RNG, recent windows) advances inside the batched decode program
         — an iteration ships nothing host->device and fetches only the
-        nb sampled ids. In paged mode the pool is a PagedKV (shared
+        packed sampled ids. In paged mode the pool is a PagedKV (shared
         physical blocks + per-slot tables) instead of B contiguous
         rows; the carries are identical."""
         slots = self.slots
@@ -954,7 +954,11 @@ class ServeEngine:
             spec_acc0 = self.spec_accepted
             active_ids = tuple(self._reqs[i].id for i in active)
             if active:
-                nb = slot_bucket(active[-1] + 1, self.slots)
+                # the rows the dispatched program runs: the contiguous one
+                # takes the whole pool in place under the active mask, the
+                # paged twins the bucketed occupied prefix of their `rows`
+                nb = self.slots if self.paged is None else \
+                    slot_bucket(active[-1] + 1, self.slots)
                 SERVE_BATCH_OCCUPANCY.observe(len(active))
                 # arm BEFORE the fault hook: an injected stall simulates a
                 # dispatch stuck on the device, and the watchdog must see
@@ -967,9 +971,9 @@ class ServeEngine:
                     drafts, n_drafts = spec_job
                     # static no-vocab-filters fast path: when no slot in
                     # the dispatch uses top-k/top-p the accept rule skips
-                    # its per-row sorts (at most one extra executable per
-                    # bucket — traffic mixes flip between two programs,
-                    # both warm in steady state)
+                    # its per-row sorts (at most one extra executable —
+                    # traffic mixes flip between two programs, both warm
+                    # in steady state)
                     filt = any(config_has_filters(self._reqs[i].sampling)
                                for i in active)
                     with RECORDER.span("spec.verify", cat="serve",
@@ -992,8 +996,7 @@ class ServeEngine:
                                 self._layers, self._toks, self._pos,
                                 self._rngs, self._recents, self._temps,
                                 self._top_ks, self._top_ps, self._pens,
-                                self._act, drafts, n_drafts, nb=nb,
-                                filt=filt)
+                                self._act, drafts, n_drafts, filt=filt)
                 elif self.paged is not None:
                     (packed, self.paged.pool, self.paged.rows, self._toks,
                      self._pos, self._rngs,
@@ -1007,7 +1010,7 @@ class ServeEngine:
                      self._rngs, self._recents) = self.model.decode_slots(
                         self._layers, self._toks, self._pos, self._rngs,
                         self._recents, self._temps, self._top_ks,
-                        self._top_ps, self._pens, self._act, nb=nb)
+                        self._top_ps, self._pens, self._act)
             t_prefill = now()
             self._chunk_end = None
             # 4. ...then advance the chosen admission by one chunk.

@@ -2,10 +2,12 @@
 
 A SlotPool tracks which rows of the fixed B-row KV-cache pool are busy.
 Allocation always returns the LOWEST free index: occupied slots cluster at
-the bottom of the pool, so the batched decode program only has to cover the
-prefix 0..highest_busy (power-of-two bucketed by `slot_bucket`) — as load
-drops, high slots drain and the decode executable shrinks a bucket at a
-time.
+the bottom of the pool, so the PAGED decode programs only have to cover the
+prefix 0..highest_busy of their per-slot rows (power-of-two bucketed by
+`slot_bucket`) — as load drops, high slots drain and the paged executable
+shrinks a bucket at a time. The contiguous decode program takes no bucket:
+it runs on every row of the pool in place, under the engine's active mask
+(text_model._decode_slots says why).
 
 Pure host-side bookkeeping (no jax): unit-testable without a model. All
 methods are called from the single scheduler thread; no locking.
@@ -14,8 +16,8 @@ from __future__ import annotations
 
 
 def slot_bucket(n: int, cap: int) -> int:
-    """Smallest power-of-two >= n, capped at cap — the batched decode
-    program's static row count. (PREFILL_BUCKETS starts at 32, so
+    """Smallest power-of-two >= n, capped at cap — the paged decode
+    programs' static row count. (PREFILL_BUCKETS starts at 32, so
     text_model.bucket_for would pin every pool <= 32 slots to its full
     size and the occupied-prefix shrink would never engage.)"""
     b = 1
@@ -25,13 +27,13 @@ def slot_bucket(n: int, cap: int) -> int:
 
 
 def slot_buckets(cap: int) -> tuple[int, ...]:
-    """The bucket ladder a cap-slot pool can dispatch at: 1, 2, 4, ...
-    cap (cap itself included even when not a power of two). Scaling
-    CAKE_SERVE_SLOTS from 4 to 8/16 adds exactly ONE rung per doubling —
-    a bucket transition compiles only the new bucket's executable, and
-    existing rungs keep their compiled programs (pinned in
-    tests/test_spec_serve.py). Warmup code and benches iterate this
-    ladder instead of hand-rolling powers of two."""
+    """The bucket ladder a cap-slot PAGED pool can dispatch at: 1, 2, 4,
+    ... cap (cap itself included even when not a power of two). Scaling
+    CAKE_SERVE_SLOTS from 4 to 8/16 adds exactly ONE rung per doubling.
+    The contiguous pool has one decode program whatever its occupancy
+    (pinned in tests/test_spec_serve.py), so a warm-up that walks this
+    ladder there only walks the occupancies. Warmup code and benches
+    iterate it instead of hand-rolling powers of two."""
     out = []
     b = 1
     while b < cap:
@@ -61,7 +63,8 @@ class SlotPool:
         return sorted(self._busy)
 
     def alloc(self) -> int | None:
-        """Claim the lowest free slot; None when the pool is full."""
+        """Claim the lowest free slot (keeps the paged programs' occupied
+        prefix short); None when the pool is full."""
         for i in range(self.n):
             if i not in self._busy:
                 self._busy.add(i)
@@ -75,5 +78,5 @@ class SlotPool:
 
     def prefix_len(self) -> int:
         """Smallest prefix length covering every busy slot (0 when idle) —
-        the batched decode program's row count before bucketing."""
+        the paged decode programs' row count before bucketing."""
         return max(self._busy) + 1 if self._busy else 0

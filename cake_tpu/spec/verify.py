@@ -22,9 +22,10 @@ from ..obs import (RECORDER, SPEC_ACCEPTED, SPEC_ACCEPTED_LEN,
 def record_step(n_proposed: int, n_acc: int, bucket: int | None = None) -> None:
     """Feed the shared spec instruments from one completed verify step
     (generate loop and serve engine both call this — one call-site shape,
-    both paths). `bucket` is the batched dispatch's slot-count bucket
-    (engine path only): it labels the acceptance-x-occupancy histogram
-    the serve bench reads."""
+    both paths). `bucket` is the row count the batched dispatch ran
+    (engine path only; the whole pool on contiguous rows, the slot-count
+    bucket on a paged pool): it labels the acceptance-x-occupancy
+    histogram the serve bench reads."""
     SPEC_PROPOSED.inc(n_proposed)
     SPEC_ACCEPTED.inc(n_acc)
     SPEC_ACCEPTED_LEN.observe(n_acc)

@@ -479,33 +479,34 @@ def tiny_model():
                      max_cache_len=64)
 
 
-def make_state(m):
-    """A warmed 2-slot pool mid-decode (the steady state the sanitizers
-    must hold over). Fresh per test: the negative tests donate or kill
-    buffers, so shared mutable state would leak between tests."""
-    layers = m.new_cache(SLOTS, kv_len=64)["layers"]
-    for s in range(SLOTS):
+def make_state(m, slots=SLOTS):
+    """A warmed pool (2 slots unless told otherwise) mid-decode (the
+    steady state the sanitizers must hold over). Fresh per test: the
+    negative tests donate or kill buffers, so shared mutable state would
+    leak between tests."""
+    layers = m.new_cache(slots, kv_len=64)["layers"]
+    for s in range(slots):
         _, layers = m.prefill_chunk(layers, s, [1, 2, 3], 0)
     return {
         "layers": layers,
-        "toks": jnp.zeros((SLOTS,), jnp.int32),
-        "pos": jnp.full((SLOTS,), 3, jnp.int32),
-        "rngs": jnp.stack([jax.random.PRNGKey(i) for i in range(SLOTS)]),
-        "recents": jnp.full((SLOTS, 64), -1, jnp.int32),
-        "temps": jnp.zeros((SLOTS,), jnp.float32),
-        "top_ks": jnp.full((SLOTS,), m.cfg.vocab_size, jnp.int32),
-        "top_ps": jnp.ones((SLOTS,), jnp.float32),
-        "pens": jnp.ones((SLOTS,), jnp.float32),
-        "act": jnp.ones((SLOTS,), jnp.bool_),
+        "toks": jnp.zeros((slots,), jnp.int32),
+        "pos": jnp.full((slots,), 3, jnp.int32),
+        "rngs": jnp.stack([jax.random.PRNGKey(i) for i in range(slots)]),
+        "recents": jnp.full((slots, 64), -1, jnp.int32),
+        "temps": jnp.zeros((slots,), jnp.float32),
+        "top_ks": jnp.full((slots,), m.cfg.vocab_size, jnp.int32),
+        "top_ps": jnp.ones((slots,), jnp.float32),
+        "pens": jnp.ones((slots,), jnp.float32),
+        "act": jnp.ones((slots,), jnp.bool_),
     }
 
 
-def _step(m, st, toks=None, nb=SLOTS):
+def _step(m, st, toks=None):
     (packed, st["layers"], st["toks"], st["pos"], st["rngs"],
      st["recents"]) = m.decode_slots(
         st["layers"], st["toks"] if toks is None else toks, st["pos"],
         st["rngs"], st["recents"], st["temps"], st["top_ks"],
-        st["top_ps"], st["pens"], st["act"], nb=nb)
+        st["top_ps"], st["pens"], st["act"])
     return packed
 
 
@@ -516,7 +517,7 @@ def test_steady_state_decode_zero_recompiles_no_transfers(tiny_model):
     outside the guard)."""
     m = tiny_model
     st = make_state(m)
-    _step(m, st)                            # warm the nb bucket
+    _step(m, st)                            # warm the one program
     with assert_no_recompiles(m, label="decode_slots steady state"):
         for _ in range(8):
             with no_implicit_transfers():
@@ -526,12 +527,15 @@ def test_steady_state_decode_zero_recompiles_no_transfers(tiny_model):
 
 
 def test_recompile_sanitizer_catches_new_bucket(tiny_model):
+    """Occupancy compiles nothing (one program per pool shape), so the
+    new program comes from a pool of another size."""
     m = tiny_model
     st = make_state(m)
     _step(m, st)
+    wider = make_state(m, slots=SLOTS + 1)
     with pytest.raises(RecompileError, match="_decode_slots"):
         with assert_no_recompiles(m):
-            _step(m, st, nb=1)              # unwarmed bucket: new program
+            _step(m, wider)                 # unwarmed pool shape: new program
 
 
 def test_transfer_sanitizer_catches_implicit_host_to_device(tiny_model):

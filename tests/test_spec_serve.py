@@ -1,5 +1,5 @@
 """Batched speculative decoding in the serve engine (ISSUE 11): ragged
-multi-token verify over the occupied slot bucket, paged block-cursor
+multi-token verify over the pool's rows, paged block-cursor
 advance, drafter-free n-gram mode.
 
 The invariants pinned here:
@@ -9,14 +9,14 @@ The invariants pinned here:
     paged KV layouts (speculation no longer stands down in paged mode);
   * ragged acceptance (one slot accepting, a neighbor abstaining or
     rejecting, in the same dispatch) compiles NOTHING in steady state —
-    one executable per (slot-bucket, k);
+    one executable per k on contiguous rows ((slot-bucket, k) paged);
   * rejection rollback survives preempt-by-swap: a swapped-out victim
     carries only committed KV (uncommitted speculative blocks are
     trimmed back to the pool) and resumes bit-identically;
   * sampled streams keep rng-rebase correctness on rejection: the rng
     carry advances exactly once per verify step regardless of the
     accepted length, so identical runs replay identical streams;
-  * slot-bucket growth to 8/16 compiles ONLY the new bucket.
+  * every occupancy of a contiguous pool runs ONE decode program.
 
 Pool shapes match tests/test_paged.py (12 x 8-token blocks, chunk 16,
 ctx 128) so paged executables stay cheap on the timeout-capped tier-1
@@ -317,38 +317,41 @@ def test_slot_buckets_ladder():
             assert slot_bucket(n, cap) in slot_buckets(cap)
 
 
-def test_slot_bucket_growth_compiles_only_new_bucket(model):
-    """Scaling occupancy past 4 into the 8-slot bucket compiles exactly
-    the new buckets' executables — existing rungs of the ladder keep
-    their programs (no churn), so raising CAKE_SERVE_SLOTS is O(new
-    buckets) compile cost, not a recompile of the pool."""
-    from cake_tpu.analysis.sanitizers import cache_size
+def test_every_occupancy_runs_one_decode_program(model):
+    """Occupancy 1 -> 8 -> every occupancy compiles ONE decode program
+    and then nothing: the contiguous step runs on the whole pool in
+    place under the active mask, so neither load nor CAKE_SERVE_SLOTS'
+    ladder adds an executable."""
+    from cake_tpu.analysis.sanitizers import (assert_no_recompiles,
+                                              cache_size)
+    base = cache_size(model._decode_slots)  # other pool shapes' programs
     eng = ServeEngine(model, slots=8, max_queue=16, ctx_len=CTX,
                       prefill_chunk=CHUNK, prefix_cache_mb=0)
     try:
-        # warm the low rungs: two concurrent requests touch nb=1 and 2
-        w = [eng.submit(P_B, max_new_tokens=6, sampling=GREEDY)
-             for _ in range(2)]
-        assert all(r.wait(600) for r in w)
-        low = cache_size(model._decode_slots)
-        # 8 concurrent requests climb to nb=8: exactly the 4- and
-        # 8-slot buckets are new
+        # a lone request: occupancy 1 compiles the pool's program
+        r = eng.submit(P_B, max_new_tokens=6, sampling=GREEDY)
+        assert r.wait(600)
+        assert r.tokens == _ref(model, P_B, 6)
+        assert cache_size(model._decode_slots) - base == 1
+        # 8 concurrent requests climb through every occupancy to 8
         rs = [eng.submit(P_B, max_new_tokens=8, sampling=GREEDY)
               for _ in range(8)]
         assert all(r.wait(600) for r in rs)
         for r in rs:
             assert "error" not in r.result, r.result.get("error")
             assert r.tokens == _ref(model, P_B, 8)
-        grown = cache_size(model._decode_slots) - low
-        assert grown == 2, \
-            f"bucket growth compiled {grown} executables, expected the " \
-            "2 new rungs (nb=4, nb=8) only"
+        grown = cache_size(model._decode_slots) - base
+        assert grown == 1, \
+            f"occupancy growth compiled {grown} executables, expected " \
+            "the one program of the 8-slot pool"
         # and re-running at every occupancy compiles nothing further
-        from cake_tpu.analysis.sanitizers import assert_no_recompiles
         with assert_no_recompiles(model._decode_slots,
-                                  label="bucket ladder steady state"):
+                                  label="every occupancy, one program"):
             rs = [eng.submit(P_B, max_new_tokens=4, sampling=GREEDY)
                   for _ in range(8)]
             assert all(r.wait(600) for r in rs)
+        buckets = {f["bucket"] for f in eng.flight.snapshot()
+                   if f["occupancy"]}
+        assert buckets == {8}       # the rows the dispatched program ran
     finally:
         eng.close()

@@ -288,8 +288,8 @@ def _lowered(model, program: str) -> str:
         jax.vmap(jax.random.PRNGKey)(jnp.arange(b)),
         jnp.full((b, n), -1, jnp.int32), jnp.ones((b,), jnp.float32),
         jnp.full((b,), 256, jnp.int32), jnp.ones((b,), jnp.float32),
-        jnp.ones((b,), jnp.float32), jnp.ones((b,), jnp.bool_),
-        nb=b).as_text(debug_info=True)
+        jnp.ones((b,), jnp.float32), jnp.ones((b,), jnp.bool_)
+        ).as_text(debug_info=True)
 
 
 @pytest.mark.parametrize("program", ["_decode_slots", "_prefill_slot"])

@@ -1,7 +1,7 @@
 # cake-tpu developer entry points (ref: the reference Makefile's build/test
 # targets; mobile app targets have no analog here — see PARITY.md §2f).
 
-.PHONY: install test lint knobs-doc metrics-doc bench bench-micro obs-smoke trace-smoke serve-smoke qos-smoke serve-bench serve-bench-longtail serve-bench-spec serve-bench-fleet serve-bench-qos serve-bench-telemetry serve-bench-kvshare paged-smoke chaos-smoke serve-chaos-smoke fleet-chaos-smoke partition-smoke fleet-soak kvshare-smoke telemetry-smoke spec-smoke spec-serve-smoke spec-bench native clean docker
+.PHONY: install test lint knobs-doc metrics-doc obs-smoke trace-smoke serve-smoke qos-smoke paged-smoke chaos-smoke serve-chaos-smoke fleet-chaos-smoke partition-smoke fleet-soak kvshare-smoke telemetry-smoke spec-smoke spec-serve-smoke native clean docker
 
 install:
 	pip install -e . --no-build-isolation
@@ -30,12 +30,6 @@ native:
 test:
 	python -m pytest tests/ -x -q
 
-bench:
-	python bench.py
-
-bench-micro:
-	python benches/bench_micro.py
-
 # request-tracing gate: one chat driven through a REAL router + replica
 # (tiny CPU model) must yield a stitched timeline with events from BOTH
 # tiers retrievable by its trace id from the router, and non-zero
@@ -63,11 +57,6 @@ serve-smoke: lint
 # complete, and the class-labeled queue gauges must be live in /metrics
 qos-smoke: lint
 	JAX_PLATFORMS=cpu python scripts/qos_smoke.py
-
-# mixed-workload QoS bench: idle vs batch-saturated interactive TTFT,
-# weighted-fair service shares, job throughput (BENCH_QOS_<tag>.json)
-serve-bench-qos:
-	JAX_PLATFORMS=cpu python scripts/serve_bench.py --qos --tag qos
 
 # fault-tolerance gate: master + 2 real workers on localhost, one worker
 # killed mid-stream by a deterministic fault plan — the generation must
@@ -125,12 +114,6 @@ fleet-soak: lint
 kvshare-smoke: lint
 	JAX_PLATFORMS=cpu python scripts/kvshare_smoke.py
 
-# fleet-shared KV bench: cold-fetch (directory-driven peer fetch) vs
-# cold-recompute (kvshare off) vs local-warm TTFT on a shared-prefix
-# follow-up. Writes BENCH_KVSHARE_<tag>.json.
-serve-bench-kvshare:
-	JAX_PLATFORMS=cpu python scripts/serve_bench.py --kvshare --tag r20
-
 # fleet telemetry gate: 2 real engine-backed replicas behind the router,
 # a traffic burst -> live rollup (merged fleet TTFT p95 from bucket-wise
 # histogram sums, non-zero capacity headroom, burn-rate gauges on
@@ -140,33 +123,6 @@ serve-bench-kvshare:
 # rule; docs/telemetry.md)
 telemetry-smoke: lint
 	JAX_PLATFORMS=cpu python scripts/telemetry_smoke.py
-
-# telemetry rollup overhead bench: synthetic fleet scrapes driven through
-# FleetTelemetry.ingest (no sockets) — per-cycle rollup cost gated
-# < 5 ms mean. Writes BENCH_TELEM_<tag>.json.
-serve-bench-telemetry:
-	JAX_PLATFORMS=cpu python scripts/serve_bench.py --telemetry --tag r16
-
-# fleet affinity bench: 2 replicas + router, conversational follow-up
-# traffic with prefix-affinity routing vs round-robin — affinity must
-# beat round-robin on warm follow-up TTFT (the owning replica holds the
-# conversation's prefix KV blocks) — plus the self-healing resume stat
-# (splice gap vs cold client retry). Writes BENCH_FLEET_<tag>.json.
-serve-bench-fleet:
-	JAX_PLATFORMS=cpu python scripts/serve_bench.py --fleet --tag fleet
-
-# serve scheduler bench: TTFT p50/p99 + tok/s for a shared-system-prompt
-# workload cold (no prefix cache) vs warm (prefix cached), and the
-# decode-interference probe (tokens still flowing while a long prompt is
-# admitted chunk-by-chunk). Writes BENCH_SERVE_<tag>.json.
-serve-bench:
-	JAX_PLATFORMS=cpu python scripts/serve_bench.py
-
-# paged-KV long-tail bench: mixed short/long contexts through the paged
-# pool sized to the OLD 4-row pool's bytes — records peak concurrent
-# streams (> 4 = the paging win) + preemption/swap counts
-serve-bench-longtail:
-	JAX_PLATFORMS=cpu python scripts/serve_bench.py --long-tail --tag longtail
 
 # paged-KV gate: paged greedy bit-identical to the sequential path,
 # prefix hit = refcount bump (shared-blocks gauge > 0, no KV copy),
@@ -187,20 +143,6 @@ spec-smoke:
 # batched spec block in /health
 spec-serve-smoke: lint
 	JAX_PLATFORMS=cpu python scripts/spec_serve_smoke.py
-
-# batched-speculation bench: acceptance-rate x occupancy x effective
-# tok/s, spec on vs off, contiguous + paged engines; fails if greedy
-# parity breaks or the best effective speedup on templated traffic
-# lands under 1.3x. Writes BENCH_SERVE_<tag>.json.
-serve-bench-spec:
-	JAX_PLATFORMS=cpu python scripts/serve_bench.py --spec --tag spec
-
-# speculation bench: tokens/s + acceptance (accepted tokens per verify
-# step), spec on vs off, repetitive vs non-repetitive prompt. Writes
-# BENCH_SPEC_<tag>.json; fails if spec breaks greedy parity or the
-# repetitive case does not beat 1.0 accepted/step.
-spec-bench:
-	JAX_PLATFORMS=cpu python scripts/spec_bench.py
 
 dryrun:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \

@@ -265,7 +265,7 @@ class RemoteStage:
         self._observe_hop(rtt, tm)
         # per-request timeline: attribute this hop to the generation in
         # flight (request-id contextvar). A no-op dict lookup when no
-        # tier opened a timeline for the id (bench scripts, tests)
+        # tier opened a timeline for the id (scripts, tests)
         TIMELINES.event(None, "cluster_hop", worker=self.name,
                         ms=round(rtt * 1e3, 3))
         if self.degraded_ms > 0:

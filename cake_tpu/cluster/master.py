@@ -392,7 +392,7 @@ class DistributedTextModel:
                     recent = push_recent_token(recent, tok)
                     # lint: disable=host-sync — the distributed loop is host-driven by
                     # design: the sampled id must reach the host to feed the next hop's
-                    # wire frame (one small fetch per token, measured in BENCH_CLUSTER)
+                    # wire frame (one small fetch per token)
                     tid = int(tok)
             pos += 1
             out.append(tid)

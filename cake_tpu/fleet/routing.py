@@ -30,10 +30,10 @@ insight, minus the remote radix trees):
     expectation, so heterogeneous fleets place load proportionally.
     With equal weights the score is monotone in u, which makes the
     ranking IDENTICAL to the classic unweighted digest sort (placement
-    is backward-compatible; benches stay comparable). Adding or
-    ejecting a replica reshuffles only the conversations it owned,
-    changing ONE replica's weight remaps only conversations moving to
-    or from it, and the failover order is DETERMINISTIC — when the
+    is backward-compatible). Adding or ejecting a replica reshuffles
+    only the conversations it owned, changing ONE replica's weight
+    remaps only conversations moving to or from it, and the failover
+    order is DETERMINISTIC — when the
     owner is ejected, every router instance agrees on the same
     next-best replica, so the reroute itself stays cache-friendly.
 

@@ -57,7 +57,7 @@ __all__ = ["FleetTelemetry", "parse_prom_text", "replica_signals",
 _TTFT_SCALE_FLOOR_S = 0.005
 _ERR_SCALE_FLOOR = 0.02
 
-# rollup-overhead ring length (the < 5ms bench gate averages these)
+# rollup-overhead ring length (`rollup_ms` in the body averages these)
 _OVERHEAD_SAMPLES = 128
 
 

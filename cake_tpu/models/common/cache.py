@@ -56,6 +56,16 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq_len: int,
     }
 
 
+def is_positional(lc: dict) -> bool:
+    """What a layer's row of state is, read off its leaves: with a `pos`
+    leaf it holds entries addressed by position (a full buffer or a ring,
+    remapped at position % size and rolled back by marking pos -1);
+    without one it is recurrent state of fixed size, copied whole. The
+    row operations below go by this and by nothing else, so a layer kind
+    with new state leaves needs no arm in any of them."""
+    return "pos" in lc
+
+
 def update_kv_cache(layer_cache: dict, k_new, v_new, pos, valid_len=None):
     """Write S new KV entries at absolute positions pos..pos+S-1.
 
@@ -99,12 +109,11 @@ def kv_capacity(cfg: ModelConfig, cache: dict,
                 layer_range: tuple[int, int] | None = None) -> int | None:
     """Smallest full-attention buffer length in the cache — positions past
     it would silently wrap. None when the range has only ring (SWA) or
-    linear-attention layers, which wrap/forget by design."""
+    recurrent-state layers, which wrap/forget by design."""
     lo, hi = layer_range or (0, cfg.num_hidden_layers)
-    caps = [lc["k"].shape[1]
+    caps = [lc["pos"].shape[1]
             for i, lc in zip(range(lo, hi), cache["layers"])
-            if cfg.layer_spec(i).kind != "linear"
-            and cfg.layer_spec(i).window is None]
+            if is_positional(lc) and cfg.layer_spec(i).window is None]
     return min(caps) if caps else None
 
 
@@ -143,16 +152,16 @@ def grow_cache(cfg: ModelConfig, cache: dict, new_len: int,
     max_cache_len worth of attention bandwidth per token (the reference
     trims to actual length per step instead — cache.rs:163-210; under XLA
     we recompile per bucket, which happens O(log max_len) times).
-    Linear-attention state is O(1) and passes through untouched.
+    Recurrent state is O(1) and passes through untouched.
     """
     lo, hi = layer_range or (0, cfg.num_hidden_layers)
     new_layers = []
     for i, lc in zip(range(lo, hi), cache["layers"]):
-        spec = cfg.layer_spec(i)
-        if spec.kind == "linear":
+        if not is_positional(lc):
             new_layers.append(lc)
             continue
-        target = new_len if spec.window is None else min(spec.window, new_len)
+        window = cfg.layer_spec(i).window
+        target = new_len if window is None else min(window, new_len)
         new_layers.append(grow_layer_kv(lc, target))
     return {"layers": new_layers, "pos": cache["pos"]}
 
@@ -175,9 +184,8 @@ def slot_reset_layers(layers: list[dict], slot) -> list[dict]:
     return out
 
 
-def slot_assign_layers(cfg: ModelConfig, pool_layers: list[dict],
-                       src_layers: list[dict], slot,
-                       layer_range: tuple[int, int] | None = None) -> list[dict]:
+def slot_assign_layers(pool_layers: list[dict], src_layers: list[dict],
+                       slot) -> list[dict]:
     """Write a batch-1 cache (a fresh request's bucketed prefill) into row
     `slot` of the batched pool, replacing whatever the row held.
 
@@ -185,15 +193,13 @@ def slot_assign_layers(cfg: ModelConfig, pool_layers: list[dict],
     grow_layer_kv uses — so a prompt prefilled into a small-bucket cache
     lands correctly in the pool's larger full-attention buffers and
     sliding-window rings (the pool ring is never smaller than the source
-    ring, so the scatter stays injective). Linear-attention conv/recurrent
-    state copies through row-wise. `slot` may be a traced scalar.
+    ring, so the scatter stays injective). Recurrent state copies through
+    row-wise, leaf by leaf. `slot` may be a traced scalar.
     """
-    lo, hi = layer_range or (0, cfg.num_hidden_layers)
     out = []
-    for i, pl, sl in zip(range(lo, hi), pool_layers, src_layers):
-        if cfg.layer_spec(i).kind == "linear":
-            out.append({"conv": pl["conv"].at[slot].set(sl["conv"][0]),
-                        "state": pl["state"].at[slot].set(sl["state"][0])})
+    for pl, sl in zip(pool_layers, src_layers):
+        if not is_positional(pl):
+            out.append({n: pl[n].at[slot].set(sl[n][0]) for n in pl})
             continue
         size = pl["k"].shape[1]
         pos = sl["pos"][0]                                 # [src_size]
@@ -209,10 +215,8 @@ def slot_assign_layers(cfg: ModelConfig, pool_layers: list[dict],
     return out
 
 
-def slot_extract_block_layers(cfg: ModelConfig, pool_layers: list[dict],
-                              slot, start, width: int,
-                              layer_range: tuple[int, int] | None = None
-                              ) -> list[dict]:
+def slot_extract_block_layers(pool_layers: list[dict], slot, start,
+                              width: int) -> list[dict]:
     """Copy one prefix BLOCK (absolute positions start .. start+width-1) out
     of pool row `slot` into a batch-1 pytree — the shared-prefix cache's
     insert path. Must be called right after prefill has advanced the row to
@@ -221,19 +225,17 @@ def slot_extract_block_layers(cfg: ModelConfig, pool_layers: list[dict],
       * full/SWA layers: gather the block's K/V/pos through the ring map
         (index = position % buffer); valid as long as width <= the smallest
         sliding window, which the PrefixCache gates at construction;
-      * linear layers: the conv + recurrent state IS the prefix summary at
-        this boundary, so the snapshot is exact only at the current
+      * recurrent layers: the state IS the prefix summary at this
+        boundary, so the snapshot (every leaf) is exact only at the current
         position — the reason blocks are captured at chunk boundaries
         during prefill instead of after the fact.
 
     `slot`/`start` may be traced scalars; `width` is static (one program
     per block size)."""
-    lo, hi = layer_range or (0, cfg.num_hidden_layers)
     out = []
-    for i, pl in zip(range(lo, hi), pool_layers):
-        if cfg.layer_spec(i).kind == "linear":
-            out.append({"conv": pl["conv"][slot][None],
-                        "state": pl["state"][slot][None]})
+    for pl in pool_layers:
+        if not is_positional(pl):
+            out.append({n: pl[n][slot][None] for n in pl})
             continue
         size = pl["k"].shape[1]
         idx = (start + jnp.arange(width, dtype=jnp.int32)) % size
@@ -243,10 +245,8 @@ def slot_extract_block_layers(cfg: ModelConfig, pool_layers: list[dict],
     return out
 
 
-def slot_splice_block_layers(cfg: ModelConfig, pool_layers: list[dict],
-                             src_layers: list[dict], slot, final,
-                             layer_range: tuple[int, int] | None = None
-                             ) -> list[dict]:
+def slot_splice_block_layers(pool_layers: list[dict], src_layers: list[dict],
+                             slot, final) -> list[dict]:
     """Scatter a cached prefix block (slot_extract_block_layers output) into
     pool row `slot` WITHOUT resetting the rest of the row, so consecutive
     blocks of a matched prefix chain merge — admission then only prefills
@@ -254,17 +254,14 @@ def slot_splice_block_layers(cfg: ModelConfig, pool_layers: list[dict],
     the row must have been wiped at release, so everything outside the
     spliced prefix is still empty.
 
-    `final` (traced bool): linear-attention conv/recurrent state is a
-    block-END snapshot, so only the LAST block of the chain may install it.
+    `final` (traced bool): recurrent state is a block-END snapshot, so
+    only the LAST block of the chain may install it.
     """
-    lo, hi = layer_range or (0, cfg.num_hidden_layers)
     out = []
-    for i, pl, sl in zip(range(lo, hi), pool_layers, src_layers):
-        if cfg.layer_spec(i).kind == "linear":
-            conv = jnp.where(final, sl["conv"][0], pl["conv"][slot])
-            state = jnp.where(final, sl["state"][0], pl["state"][slot])
-            out.append({"conv": pl["conv"].at[slot].set(conv),
-                        "state": pl["state"].at[slot].set(state)})
+    for pl, sl in zip(pool_layers, src_layers):
+        if not is_positional(pl):
+            new = {n: jnp.where(final, sl[n][0], pl[n][slot]) for n in pl}
+            out.append({n: pl[n].at[slot].set(new[n]) for n in pl})
             continue
         size = pl["k"].shape[1]
         pos = sl["pos"][0]                                 # [width]
@@ -277,8 +274,7 @@ def slot_splice_block_layers(cfg: ModelConfig, pool_layers: list[dict],
     return out
 
 
-def truncate_layers(cfg: ModelConfig, layers: list[dict], new_end,
-                    layer_range: tuple[int, int] | None = None) -> list[dict]:
+def truncate_layers(layers: list[dict], new_end) -> list[dict]:
     """Mark every KV entry at absolute position >= new_end empty (pos -1)
     across the whole batch — the speculative-decoding rejected-suffix
     rollback, traceable (new_end may be a traced scalar) so the verify
@@ -286,42 +282,30 @@ def truncate_layers(cfg: ModelConfig, layers: list[dict], new_end,
     rejection. K/V bytes are left in place: position-based masking makes
     a pos==-1 slot invisible, and the next write re-scatters over it.
 
-    Linear-attention layers pass through UNCHANGED: a recurrent state
-    cannot be truncated after the fact. Callers with linear layers must
-    instead rebuild the state with a valid_len-masked commit forward
-    (TextModel's verify program does exactly that — the same machinery
-    that keeps bucketed-prefill padding out of the state).
+    Recurrent layers pass through UNCHANGED: their state cannot be
+    truncated after the fact. Callers with such layers must instead
+    rebuild the state with a valid_len-masked commit forward
+    (text_model.slot_verify does exactly that — the same machinery that
+    keeps bucketed-prefill padding out of the state).
     """
-    lo, hi = layer_range or (0, cfg.num_hidden_layers)
-    out = []
-    for i, lc in zip(range(lo, hi), layers):
-        if cfg.layer_spec(i).kind == "linear":
-            out.append(lc)
-            continue
-        pos = lc["pos"]
-        out.append({"k": lc["k"], "v": lc["v"],
-                    "pos": jnp.where(pos >= new_end, -1, pos)})
-    return out
+    return [{**lc, "pos": jnp.where(lc["pos"] >= new_end, -1, lc["pos"])}
+            if is_positional(lc) else lc for lc in layers]
 
 
-def truncate_cache(cfg: ModelConfig, cache: dict, new_end: int,
-                   layer_range: tuple[int, int] | None = None) -> dict:
+def truncate_cache(cache: dict, new_end: int) -> dict:
     """Host-level cache rollback to positions < new_end (pos scalar
     clamped too) — the draft-model drafter discards its own speculative
-    suffix with this between proposals. Raises for linear-attention
-    layers: their state cannot roll back, and a silent pass-through here
-    would hand the caller a cache that CLAIMS new_end tokens but carries
-    state from more (truncate_layers documents pass-through instead
-    because its in-trace callers — the verify programs — handle the
-    linear commit themselves via a valid_len-masked re-forward)."""
-    lo, hi = layer_range or (0, cfg.num_hidden_layers)
-    for i in range(lo, hi):
-        if cfg.layer_spec(i).kind == "linear":
-            raise ValueError(
-                "truncate_cache cannot roll back linear-attention state; "
-                "use a valid_len-masked re-forward instead")
-    return {"layers": truncate_layers(cfg, cache["layers"], new_end,
-                                      (lo, hi)),
+    suffix with this between proposals. Raises for recurrent layers:
+    their state cannot roll back, and a silent pass-through here would
+    hand the caller a cache that CLAIMS new_end tokens but carries state
+    from more (truncate_layers documents pass-through instead because its
+    in-trace callers — the verify programs — handle the recurrent commit
+    themselves via a valid_len-masked re-forward)."""
+    if not all(is_positional(lc) for lc in cache["layers"]):
+        raise ValueError(
+            "truncate_cache cannot roll back linear-attention state; "
+            "use a valid_len-masked re-forward instead")
+    return {"layers": truncate_layers(cache["layers"], new_end),
             "pos": jnp.minimum(cache["pos"], new_end)}
 
 
@@ -345,6 +329,12 @@ def truncate_cache(cfg: ModelConfig, cache: dict, new_end: int,
 # the contiguous path and lets forward_layers run unchanged.
 
 
+def layer_is_pooled(spec: LayerSpec) -> bool:
+    """Does this layer's KV live in the shared block pool: positional and
+    without a window (see the note above)."""
+    return not spec.recurrent and spec.window is None
+
+
 def init_paged_layers(cfg: ModelConfig, num_blocks: int, block_tokens: int,
                       batch: int, ctx: int, dtype=jnp.bfloat16,
                       layer_range: tuple[int, int] | None = None
@@ -354,7 +344,7 @@ def init_paged_layers(cfg: ModelConfig, num_blocks: int, block_tokens: int,
     pool_layers[i] holds the physical block pool for full-attention layer
     i ({k,v: [num_blocks, block_tokens, H, D], pos: [num_blocks,
     block_tokens]}) and an EMPTY dict elsewhere; row_layers[i] holds the
-    per-slot state for sliding-window rings and linear-attention layers
+    per-slot state for sliding-window rings and recurrent layers
     (leading batch axis) and an empty dict at pooled positions. Empty
     dicts keep both lists layer-aligned pytrees with zero leaves at the
     other list's positions, so they vmap/donate cleanly side by side.
@@ -363,7 +353,7 @@ def init_paged_layers(cfg: ModelConfig, num_blocks: int, block_tokens: int,
     pool, rows = [], []
     for i in range(lo, hi):
         spec = cfg.layer_spec(i)
-        if spec.kind == "linear" or spec.window is not None:
+        if not layer_is_pooled(spec):
             pool.append({})
             rows.append(init_layer_cache(cfg, spec, batch, ctx, dtype))
         else:
@@ -427,6 +417,39 @@ def paged_block_of(view_lc: dict, wb, bt: int) -> dict:
         "pos": jax.lax.dynamic_slice_in_dim(view_lc["pos"], start, bt,
                                             axis=0),
     }
+
+
+def paged_block_window(view_lcs: list[dict], table_row, first_pos, n_written,
+                       width: int, bt: int, nblocks: int
+                       ) -> tuple[jax.Array, list[dict]]:
+    """The write-back window after a forward wrote positions first_pos ..
+    first_pos + n_written - 1 into a slot's gathered row views: the
+    multi-block paged_block_of. `width` table entries (static: what the
+    widest write of this program can span) are slid to start at the first
+    written block — never clamped mid-block, so block alignment survives at
+    the table's tail — and the entries outside [first written block, last
+    written block] are masked to the drop sentinel `nblocks`.
+
+    view_lcs: per layer the updated row view without a batch axis, or {}
+    for a layer that is not pooled. Returns (pids [nwb], blks): the
+    physical ids to scatter to, and per pooled layer {k, v: [nwb, bt, H,
+    D], pos: [nwb, bt]} cut from its view ({} elsewhere) — the arguments
+    of paged_scatter_blocks."""
+    m = table_row.shape[0]
+    nwb = min(width, m)
+    b0 = first_pos // bt
+    last_b = (first_pos + jnp.maximum(n_written, 1) - 1) // bt
+    shift = jnp.clip(b0, 0, m - nwb)
+    bidx = shift + jnp.arange(nwb, dtype=jnp.int32)
+    touched = jnp.logical_and(bidx >= b0, bidx <= last_b)
+    pids = jnp.where(touched, table_row[bidx], nblocks)
+
+    def cut(a):
+        return jax.lax.dynamic_slice_in_dim(
+            a, shift * bt, nwb * bt, axis=0).reshape((nwb, bt) + a.shape[1:])
+
+    return pids, [{n: cut(lc[n]) for n in ("k", "v", "pos")} if lc else {}
+                  for lc in view_lcs]
 
 
 def paged_scatter_blocks(pl: dict, pids, blk: dict) -> dict:

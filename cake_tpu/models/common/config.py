@@ -38,6 +38,13 @@ class LayerSpec:
     is_moe: bool = False
     norm_style: str = "pre"       # 'pre' | 'post' (OLMo2) | 'sandwich' (Gemma3)
 
+    @property
+    def recurrent(self) -> bool:
+        """The layer carries a fixed-size state that only moves forward
+        (no `pos` leaf in its cache: cache.is_positional is False) in
+        place of entries addressed by position."""
+        return self.kind == "linear"
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -124,6 +131,12 @@ class ModelConfig:
 
     def layer_specs(self) -> tuple[LayerSpec, ...]:
         return tuple(self.layer_spec(i) for i in range(self.num_hidden_layers))
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """Some layer's state cannot be rolled back by position: a
+        speculative commit re-forwards, and a draft model is refused."""
+        return any(s.recurrent for s in self.layer_specs())
 
     @property
     def size_q(self) -> int:

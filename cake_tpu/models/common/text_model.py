@@ -41,10 +41,10 @@ from ...ops.sampling import (SamplingConfig, config_has_filters,
                              push_recent_token, sample, sample_traced,
                              spec_accept)
 from .cache import (grow_cache, kv_capacity, paged_block_of,
-                    paged_gather_layer, paged_scatter_blocks,
-                    slot_assign_layers, slot_extract_block_layers,
-                    slot_reset_layers, slot_splice_block_layers,
-                    truncate_layers)
+                    paged_block_window, paged_gather_layer,
+                    paged_scatter_blocks, slot_assign_layers,
+                    slot_extract_block_layers, slot_reset_layers,
+                    slot_splice_block_layers, truncate_layers)
 from .config import ModelConfig
 from .layers import (embed_tokens, flash_kernel_mode, forward_layers,
                      lm_head_logits)
@@ -134,6 +134,84 @@ class Token:
     is_end_of_stream: bool
 
 
+# -- what the compiled programs do -------------------------------------------
+# One traced body for each thing TextModel's programs do to a batch-1 cache
+# view; the jitted shells in TextModel._build keep only what differs between
+# the KV layouts — how the view of a slot is made (a pool row / a gather
+# through the block table) and what is written back.
+
+def chunk_logits(cfg: ModelConfig, params: dict, cache: dict, tokens, pos0,
+                 valid_len, flash_mode: str, mesh):
+    """Forward one right-padded chunk of a prompt (tokens [1, S]) at
+    absolute position pos0 and keep the logits at its last valid position.
+    Returns (logits [1, V], the advanced cache)."""
+    x = embed_tokens(cfg, params, tokens)
+    x, cache = forward_layers(cfg, params, x, cache, pos0,
+                              valid_len=valid_len, flash_mode=flash_mode,
+                              mesh=mesh)
+    idx = jnp.clip(valid_len - 1, 0, x.shape[1] - 1)
+    x_last = jax.lax.dynamic_slice_in_dim(x, idx, 1, axis=1)
+    return lm_head_logits(cfg, params, x_last)[:, 0], cache
+
+
+def slot_step(cfg: ModelConfig, params: dict, cache: dict, tok, p, rng,
+              recent, temp, tk, tp, pen, act):
+    """One slot's sampled decode step on its batch-1 cache view: embed ->
+    layers -> head -> sample_traced -> recent-token push, the sampled_step
+    pipeline with TRACED sampling parameters. `act` (traced bool) masks
+    the slot OUT without changing the program: valid_len 0 leaves its
+    KV/conv/recurrent state byte-identical (the scatter is dropped, the GDN
+    scan masks the state advance) and its token/rng/recent carries pass
+    through; for an active slot valid_len 1 is numerically the unmasked
+    step. Returns (next token, the view's layers without their batch axis,
+    rng, recent)."""
+    x = embed_tokens(cfg, params, tok[None, None])
+    x, cache = forward_layers(cfg, params, x, cache, p,
+                              valid_len=act.astype(jnp.int32))
+    logits = lm_head_logits(cfg, params, x)[0, -1]
+    rng2, sk = jax.random.split(rng)
+    nxt = sample_traced(logits, sk, temp, tk, tp, pen, recent)
+    nxt = jnp.where(act, nxt, tok)
+    return (nxt, jax.tree_util.tree_map(lambda a: a[0], cache["layers"]),
+            jnp.where(act, rng2, rng),
+            jnp.where(act, push_recent_token(recent, nxt), recent))
+
+
+def slot_verify(cfg: ModelConfig, params: dict, cache: dict, tokens, p,
+                n_input, draft, ndr, sk, recent, temp, tk, tp, pen,
+                filt: bool, has_linear: bool):
+    """One slot's speculative verify on its batch-1 cache view. tokens:
+    [1, K+1] = [input token, d_0 .. d_{K-1}], entries >= n_input are
+    padding (n_input 0 freezes the slot); draft: [K] with ndr valid
+    entries; sk: the consumed key. Returns (n_acc, next token, commit,
+    the view's layers with their batch axis, recent'), commit = n_acc + 1
+    <= n_input the number of input positions that stay.
+
+    Pass 1 forwards all n_input tokens (valid_len keeps padding out of the
+    KV scatter and the GDN state scan), keeps logits at every position and
+    runs the traced accept/reject rule. What `layers` holds splits on the
+    model's layer mix, statically:
+      * attention only: pass 1's layers, all n_input entries written — the
+        caller rolls the rejected suffix back by position (truncate_layers
+        on a row, a pos mask on the written blocks of a paged pool), zero
+        extra compute;
+      * any recurrent layer: a state cannot be truncated, so the commit
+        re-runs the forward with valid_len = commit from the ORIGINAL
+        cache — the masking that keeps bucketed-prefill padding out of the
+        state keeps the rejected suffix out, bit-exactly. XLA dead-code-
+        eliminates pass 1's unused cache outputs.
+    """
+    x = embed_tokens(cfg, params, tokens)
+    x1, c1 = forward_layers(cfg, params, x, cache, p, valid_len=n_input)
+    logits = lm_head_logits(cfg, params, x1)[0]                # [K+1, V]
+    n_acc, nxt, recent = spec_accept(logits, draft, ndr, sk, temp, tk, tp,
+                                     pen, recent, use_filters=filt)
+    commit = jnp.minimum(n_acc + 1, n_input)
+    if has_linear:
+        _, c1 = forward_layers(cfg, params, x, cache, p, valid_len=commit)
+    return n_acc, nxt, commit, c1["layers"], recent
+
+
 class LocalStage:
     """A contiguous range of layers resident on this host's TPU(s).
 
@@ -221,15 +299,8 @@ class TextModel:
         @functools.partial(jax.jit, donate_argnums=(2,),
                            static_argnames=("flash_mode",))
         def _prefill(params, tokens, cache, pos0, valid_len, flash_mode):
-            x = embed_tokens(cfg, params, tokens)
-            x, cache = forward_layers(cfg, params, x, cache, pos0,
-                                      valid_len=valid_len,
-                                      flash_mode=flash_mode, mesh=mesh)
-            # logits at the last valid position
-            idx = jnp.clip(valid_len - 1, 0, x.shape[1] - 1)
-            x_last = jax.lax.dynamic_slice_in_dim(x, idx, 1, axis=1)
-            logits = lm_head_logits(cfg, params, x_last)[:, 0]
-            return logits, cache
+            return chunk_logits(cfg, params, cache, tokens, pos0, valid_len,
+                                flash_mode, mesh)
 
         def sampled_step(params, tok, cache, rng, recent, scfg):
             """The one decode step shared by every sampling decode program
@@ -329,34 +400,20 @@ class TextModel:
             copies of every layer's K and V cost more than the masked
             rows' work (PERF.md, PR 33).
 
-            The per-slot step is the SAME embed -> layers -> head ->
-            sample pipeline as sampled_step, vmapped over the slot axis.
+            The per-slot step is slot_step, vmapped over the slot axis.
             `active` [B] bool masks rows OUT of the step without changing
             the executable: an inactive row (free, or mid-way through a
-            CHUNKED admission prefill) runs the forward with valid_len=0 —
-            its KV/conv/recurrent state is left byte-identical (the scatter
-            is dropped, the GDN scan masks the state advance) and its
-            token/pos/rng/recent carries pass through unchanged. That is
-            what lets a chunked prefill build a row IN PLACE across
-            iterations while the surrounding slots keep decoding — decode
-            can never smear a garbage KV entry into a half-built prefix.
-            For an ACTIVE row valid_len=1 is numerically identical to the
-            unmasked step, so greedy parity with the sequential path is
-            untouched."""
+            CHUNKED admission prefill) keeps its state byte-identical and
+            its token/pos/rng/recent carries. That is what lets a chunked
+            prefill build a row IN PLACE across iterations while the
+            surrounding slots keep decoding — decode can never smear a
+            garbage KV entry into a half-built prefix — and greedy parity
+            with the sequential path is untouched."""
             def one(tok, lcs, p, rng, recent, temp, tk, tp, pen, act):
                 cache = {"layers": jax.tree_util.tree_map(
                     lambda a: a[None], lcs), "pos": p}
-                x = embed_tokens(cfg, params, tok[None, None])
-                x, cache = forward_layers(cfg, params, x, cache, p,
-                                          valid_len=act.astype(jnp.int32))
-                logits = lm_head_logits(cfg, params, x)[0, -1]
-                rng2, sk = jax.random.split(rng)
-                nxt = sample_traced(logits, sk, temp, tk, tp, pen, recent)
-                nxt = jnp.where(act, nxt, tok)
-                return (nxt, jax.tree_util.tree_map(
-                    lambda a: a[0], cache["layers"]),
-                    jnp.where(act, rng2, rng),
-                    jnp.where(act, push_recent_token(recent, nxt), recent))
+                return slot_step(cfg, params, cache, tok, p, rng, recent,
+                                 temp, tk, tp, pen, act)
 
             # the whole per-slot carry advances ON DEVICE: the engine ships
             # nothing per iteration and fetches only the packed ids
@@ -373,7 +430,7 @@ class TextModel:
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _slot_assign(layers, src_layers, slot):
-            return slot_assign_layers(cfg, layers, src_layers, slot)
+            return slot_assign_layers(layers, src_layers, slot)
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _slot_reset(layers, slot):
@@ -394,14 +451,9 @@ class TextModel:
             (chunk-bucket, flash_mode); slot/pos0/valid_len are traced.
             Returns (logits at the last valid chunk position, layers)."""
             row = jax.tree_util.tree_map(lambda a: a[slot][None], layers)
-            x = embed_tokens(cfg, params, tokens)
-            x, rcache = forward_layers(cfg, params, x,
-                                       {"layers": row, "pos": pos0}, pos0,
-                                       valid_len=valid_len,
-                                       flash_mode=flash_mode, mesh=mesh)
-            idx = jnp.clip(valid_len - 1, 0, x.shape[1] - 1)
-            x_last = jax.lax.dynamic_slice_in_dim(x, idx, 1, axis=1)
-            logits = lm_head_logits(cfg, params, x_last)[:, 0]
+            logits, rcache = chunk_logits(
+                cfg, params, {"layers": row, "pos": pos0}, tokens, pos0,
+                valid_len, flash_mode, mesh)
             layers = jax.tree_util.tree_map(
                 lambda full, r: full.at[slot].set(r[0]), layers,
                 rcache["layers"])
@@ -414,56 +466,25 @@ class TextModel:
         # (ops.sampling.spec_accept) and the rejected-suffix rollback —
         # everything inside one compiled program, so a verify costs one
         # device call exactly like a decode step.
-        has_linear = any(s.kind == "linear" for s in cfg.layer_specs())
-
-        def _verify_core(params, tokens, cache, pos0, n_input, draft, rng,
-                         recent, temp, top_k, top_p, penalty, filt=True):
-            """tokens: [1, S] (S = K+1, entries >= n_input are padding);
-            draft: [K]; n_input = n_draft + 1 (traced). Returns
-            (n_acc, next_token, committed_cache, recent').
-
-            Pass 1 forwards all n_input tokens (valid_len keeps padding out
-            of the KV scatter and the GDN state scan) and keeps logits at
-            every position. The rollback of the rejected suffix splits on
-            the model's layer mix, statically:
-              * attention-only: pass 1's cache already holds all n_input
-                entries; truncate_layers marks positions past the accepted
-                prefix empty — zero extra compute;
-              * any linear layer: the recurrent state cannot be truncated,
-                so the commit re-runs the forward with valid_len =
-                n_acc + 1 from the ORIGINAL cache — the same masking that
-                keeps bucketed-prefill padding out of the state now keeps
-                the rejected suffix out, bit-exactly. XLA dead-code-
-                eliminates pass 1's unused cache outputs.
-            """
-            x = embed_tokens(cfg, params, tokens)
-            x1, c1 = forward_layers(cfg, params, x, cache, pos0,
-                                    valid_len=n_input, mesh=mesh)
-            logits = lm_head_logits(cfg, params, x1)[0]        # [S, V]
-            n_acc, nxt, recent = spec_accept(logits, draft, n_input - 1,
-                                             rng, temp, top_k, top_p,
-                                             penalty, recent,
-                                             use_filters=filt)
-            commit = n_acc + 1
-            if has_linear:
-                _, committed = forward_layers(cfg, params, x, cache, pos0,
-                                              valid_len=commit, mesh=mesh)
-            else:
-                committed = {"layers": truncate_layers(
-                    cfg, c1["layers"], pos0 + commit), "pos": pos0 + commit}
-            return n_acc, nxt, committed, recent
+        has_linear = cfg.has_recurrent_state
 
         @functools.partial(jax.jit, donate_argnums=(2,),
                            static_argnames=("filt",))
         def _spec_verify(params, tokens, cache, pos0, n_input, draft, rng,
                          recent, temp, top_k, top_p, penalty, filt):
-            """Batch-1 verify (the generate() speculative loop). `filt`
-            is the static no-vocab-filters escape hatch (one executable
-            per value — two at most)."""
-            n_acc, nxt, cache, recent = _verify_core(
-                params, tokens, cache, pos0, n_input, draft, rng, recent,
-                temp, top_k, top_p, penalty, filt)
-            return jnp.stack([n_acc, nxt]), cache, recent
+            """Batch-1 verify (the generate() speculative loop): slot_verify
+            on the cache itself. tokens: [1, K+1]; draft: [K]; n_input =
+            n_draft + 1 (traced); rng is the consumed key. `filt` is the
+            static no-vocab-filters escape hatch (one executable per value
+            — two at most)."""
+            n_acc, nxt, commit, layers, recent = slot_verify(
+                cfg, params, cache, tokens, pos0, n_input, draft,
+                n_input - 1, rng, recent, temp, top_k, top_p, penalty, filt,
+                has_linear)
+            if not has_linear:
+                layers = truncate_layers(layers, pos0 + commit)
+            return (jnp.stack([n_acc, nxt]),
+                    {"layers": layers, "pos": pos0 + commit}, recent)
 
         @functools.partial(jax.jit, static_argnames=("filt",),
                            donate_argnums=(1, 2, 3, 4, 5))
@@ -498,30 +519,19 @@ class TextModel:
                 cache = {"layers": jax.tree_util.tree_map(
                     lambda a: a[None], lcs), "pos": p}
                 tokens = jnp.concatenate([tok[None], draft])[None, :]
-                n_input = jnp.where(act, ndr + 1, 0)
-                x = embed_tokens(cfg, params, tokens)
-                x1, c1 = forward_layers(cfg, params, x, cache, p,
-                                        valid_len=n_input)
-                logits = lm_head_logits(cfg, params, x1)[0]     # [k+1, V]
                 rng2, sk = jax.random.split(rng)
-                n_acc, nxt, recent2 = spec_accept(
-                    logits, draft, ndr, sk, temp, tk, tp, pen, recent,
-                    use_filters=filt)
-                commit = n_acc + 1
-                if has_linear:
-                    _, committed = forward_layers(
-                        cfg, params, x, cache, p,
-                        valid_len=jnp.where(act, commit, 0))
-                    new_layers = committed["layers"]
-                else:
+                n_acc, nxt, commit, new_layers, recent2 = slot_verify(
+                    cfg, params, cache, tokens, p,
+                    jnp.where(act, ndr + 1, 0), draft, ndr, sk, recent,
+                    temp, tk, tp, pen, filt, has_linear)
+                if not has_linear:
                     new_layers = truncate_layers(
-                        cfg, c1["layers"],
+                        new_layers,
                         jnp.where(act, p + commit, jnp.int32(2**30)))
                 new_lcs = jax.tree_util.tree_map(lambda a: a[0],
                                                  new_layers)
                 return (jnp.where(act, nxt, tok),
-                        jnp.where(act, n_acc, 0),
-                        jnp.where(act, commit, 0), new_lcs,
+                        jnp.where(act, n_acc, 0), commit, new_lcs,
                         jnp.where(act, rng2, rng),
                         jnp.where(act, recent2, recent))
 
@@ -533,12 +543,11 @@ class TextModel:
 
         @functools.partial(jax.jit, static_argnames=("width",))
         def _slot_extract(layers, slot, start, width):
-            return slot_extract_block_layers(cfg, layers, slot, start, width)
+            return slot_extract_block_layers(layers, slot, start, width)
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _slot_splice(layers, src_layers, slot, final):
-            return slot_splice_block_layers(cfg, layers, src_layers, slot,
-                                            final)
+            return slot_splice_block_layers(layers, src_layers, slot, final)
 
         # -- paged KV: decode/prefill through a block table ----------------
         # Full-attention KV lives in a shared physical block pool
@@ -569,36 +578,26 @@ class TextModel:
                                 recents, temps, top_ks, top_ps, penalties,
                                 active, nb):
             """_decode_slots over a paged pool: per slot, gather the
-            logical row view, run the same embed -> layers -> head ->
-            sample step, then write back ONLY the block the step's KV
-            landed in (position p lives in table entry p // bt). Inactive
-            rows ride along with the write dropped (pid -> sentinel), so
-            their pool bytes stay untouched just like the contiguous
-            active-mask contract."""
+            logical row view, run the same slot_step, then write back ONLY
+            the block the step's KV landed in (position p lives in table
+            entry p // bt). Inactive rows ride along with the write
+            dropped (pid -> sentinel), so their pool bytes stay untouched
+            just like the contiguous active-mask contract."""
             bt = next(pl["pos"].shape[1] for pl in pool if pl)
             nblocks = next(pl["pos"].shape[0] for pl in pool if pl)
 
             def one(table_row, rows_slot, tok, p, rng, recent, temp, tk,
                     tp, pen, act):
                 cache = _paged_row_cache(pool, rows_slot, table_row, p)
-                x = embed_tokens(cfg, params, tok[None, None])
-                x, cache = forward_layers(cfg, params, x, cache, p,
-                                          valid_len=act.astype(jnp.int32))
-                logits = lm_head_logits(cfg, params, x)[0, -1]
-                rng2, sk = jax.random.split(rng)
-                nxt = sample_traced(logits, sk, temp, tk, tp, pen, recent)
-                nxt = jnp.where(act, nxt, tok)
-                new_lcs = jax.tree_util.tree_map(lambda a: a[0],
-                                                 cache["layers"])
+                nxt, new_lcs, rng, recent = slot_step(
+                    cfg, params, cache, tok, p, rng, recent, temp, tk, tp,
+                    pen, act)
                 wb = jnp.clip(p // bt, 0, table_row.shape[0] - 1)
                 blks = [paged_block_of(lc, wb, bt) if pl else {}
                         for pl, lc in zip(pool, new_lcs)]
                 new_rows = [{} if pl else lc
                             for pl, lc in zip(pool, new_lcs)]
-                return (nxt, blks, new_rows, wb,
-                        jnp.where(act, rng2, rng),
-                        jnp.where(act, push_recent_token(recent, nxt),
-                                  recent))
+                return nxt, blks, new_rows, wb, rng, recent
 
             step = active[:nb].astype(jnp.int32)
             rows_nb = jax.tree_util.tree_map(lambda a: a[:nb], rows)
@@ -630,44 +629,24 @@ class TextModel:
             bt = next(pl["pos"].shape[1] for pl in pool if pl)
             nblocks = next(pl["pos"].shape[0] for pl in pool if pl)
             table_row = tables[slot]
-            m = table_row.shape[0]
             rows_slot = [jax.tree_util.tree_map(lambda a: a[slot], rl)
                          for rl in rows]
-            cache = _paged_row_cache(pool, rows_slot, table_row, pos0)
-            x = embed_tokens(cfg, params, tokens)
-            x, rcache = forward_layers(cfg, params, x, cache, pos0,
-                                       valid_len=valid_len,
-                                       flash_mode=flash_mode, mesh=mesh)
-            idx = jnp.clip(valid_len - 1, 0, x.shape[1] - 1)
-            x_last = jax.lax.dynamic_slice_in_dim(x, idx, 1, axis=1)
-            logits = lm_head_logits(cfg, params, x_last)[:, 0]
-            # write-back window: blocks b0..last_b changed; the window is
-            # sized statically by the chunk bucket and slid (never
-            # clamped mid-block) so block alignment survives at the pool
-            # tail, with out-of-range entries masked to the drop sentinel
-            nwb = min(tokens.shape[1] // bt + 1, m)
-            b0 = pos0 // bt
-            last_b = (pos0 + jnp.maximum(valid_len, 1) - 1) // bt
-            shift = jnp.clip(b0, 0, m - nwb)
-            bidx = shift + jnp.arange(nwb, dtype=jnp.int32)
-            touched = jnp.logical_and(bidx >= b0, bidx <= last_b)
-            pids = jnp.where(touched, table_row[bidx], nblocks)
-            new_pool = []
-            new_rows = []
-            for pl, rl, nl in zip(pool, rows, rcache["layers"]):
-                if not pl:
-                    new_pool.append(pl)
-                    new_rows.append(jax.tree_util.tree_map(
-                        lambda full, r: full.at[slot].set(r[0]), rl, nl))
-                    continue
-                view = jax.tree_util.tree_map(lambda a: a[0], nl)
-                blk = {
-                    name: jax.lax.dynamic_slice_in_dim(
-                        view[name], shift * bt, nwb * bt, axis=0
-                    ).reshape((nwb, bt) + view[name].shape[1:])
-                    for name in ("k", "v", "pos")}
-                new_pool.append(paged_scatter_blocks(pl, pids, blk))
-                new_rows.append(rl)
+            logits, rcache = chunk_logits(
+                cfg, params,
+                _paged_row_cache(pool, rows_slot, table_row, pos0), tokens,
+                pos0, valid_len, flash_mode, mesh)
+            # the blocks the chunk wrote: a window sized statically by the
+            # chunk bucket
+            views = [jax.tree_util.tree_map(lambda a: a[0], nl) if pl else {}
+                     for pl, nl in zip(pool, rcache["layers"])]
+            pids, blks = paged_block_window(
+                views, table_row, pos0, valid_len, tokens.shape[1] // bt + 1,
+                bt, nblocks)
+            new_pool = [paged_scatter_blocks(pl, pids, blk) if pl else pl
+                        for pl, blk in zip(pool, blks)]
+            new_rows = [rl if pl else jax.tree_util.tree_map(
+                            lambda full, r: full.at[slot].set(r[0]), rl, nl)
+                        for pl, rl, nl in zip(pool, rows, rcache["layers"])]
             return logits, new_pool, new_rows
 
         @functools.partial(jax.jit, static_argnames=("nb", "filt"),
@@ -693,55 +672,28 @@ class TextModel:
 
             def one(table_row, rows_slot, tok, p, rng, recent, temp, tk,
                     tp, pen, act, draft, ndr):
-                m = table_row.shape[0]
                 cache = _paged_row_cache(pool, rows_slot, table_row, p)
                 tokens = jnp.concatenate([tok[None], draft])[None, :]
-                n_input = jnp.where(act, ndr + 1, 0)
-                x = embed_tokens(cfg, params, tokens)
-                x1, c1 = forward_layers(cfg, params, x, cache, p,
-                                        valid_len=n_input)
-                logits = lm_head_logits(cfg, params, x1)[0]
                 rng2, sk = jax.random.split(rng)
-                n_acc, nxt, recent2 = spec_accept(
-                    logits, draft, ndr, sk, temp, tk, tp, pen, recent,
-                    use_filters=filt)
-                commit = jnp.where(act, n_acc + 1, 0)
-                if has_linear:
-                    _, committed = forward_layers(cfg, params, x, cache,
-                                                  p, valid_len=commit)
-                else:
-                    committed = c1
-                new_lcs = jax.tree_util.tree_map(lambda a: a[0],
-                                                 committed["layers"])
-                # write-back window: blocks b0..last_b hold the committed
-                # positions; sized statically by the draft width, slid
-                # (never clamped mid-block) like the prefill window
-                nwb = min(k // bt + 2, m)
-                b0 = p // bt
-                last_b = (p + jnp.maximum(commit, 1) - 1) // bt
-                shift = jnp.clip(b0, 0, m - nwb)
-                bidx = shift + jnp.arange(nwb, dtype=jnp.int32)
-                touched = jnp.logical_and(bidx >= b0, bidx <= last_b)
-                touched = jnp.logical_and(touched, act)
-                pids = jnp.where(touched, table_row[bidx], nblocks)
-                blks = []
-                new_rows = []
-                for pl, lc in zip(pool, new_lcs):
-                    if not pl:
-                        blks.append({})
-                        new_rows.append(lc)
-                        continue
-                    blk = {
-                        name: jax.lax.dynamic_slice_in_dim(
-                            lc[name], shift * bt, nwb * bt, axis=0
-                        ).reshape((nwb, bt) + lc[name].shape[1:])
-                        for name in ("k", "v", "pos")}
-                    # the speculative suffix never reaches the pool: a
-                    # swapped-out victim must not carry uncommitted KV
-                    blk["pos"] = jnp.where(blk["pos"] >= p + commit, -1,
-                                           blk["pos"])
-                    blks.append(blk)
-                    new_rows.append({})
+                n_acc, nxt, commit, new_layers, recent2 = slot_verify(
+                    cfg, params, cache, tokens, p,
+                    jnp.where(act, ndr + 1, 0), draft, ndr, sk, recent,
+                    temp, tk, tp, pen, filt, has_linear)
+                new_lcs = jax.tree_util.tree_map(lambda a: a[0], new_layers)
+                # the blocks holding the committed positions: a window
+                # sized statically by the draft width; an inactive slot
+                # writes none
+                pids, blks = paged_block_window(
+                    [lc if pl else {} for pl, lc in zip(pool, new_lcs)],
+                    table_row, p, commit, k // bt + 2, bt, nblocks)
+                pids = jnp.where(act, pids, nblocks)
+                # the speculative suffix never reaches the pool: a
+                # swapped-out victim must not carry uncommitted KV
+                blks = [{**blk, "pos": jnp.where(blk["pos"] >= p + commit,
+                                                 -1, blk["pos"])}
+                        if blk else blk for blk in blks]
+                new_rows = [{} if pl else lc
+                            for pl, lc in zip(pool, new_lcs)]
                 return (jnp.where(act, nxt, tok),
                         jnp.where(act, n_acc, 0), commit, blks, new_rows,
                         pids, jnp.where(act, rng2, rng),

@@ -58,7 +58,7 @@ def tiny_flux_config() -> FluxPipelineConfig:
 
 class DummyTextEncoder:
     """Deterministic hash-based embeddings — lets the full pipeline run
-    without encoder weights (tests, random-weight benches)."""
+    without encoder weights (tests, random-weight smokes)."""
 
     def __init__(self, txt_dim: int, vec_dim: int, seq_len: int = 16):
         self.txt_dim, self.vec_dim, self.seq_len = txt_dim, vec_dim, seq_len

@@ -201,9 +201,9 @@ def generate_doc() -> str:
             "`.../cake.attn/...`, or `.../vmap(cake.attn)/...` where the",
             "batching transform wraps the outermost scope, so device time "
             "sums by part of the", "model across edits that renumber the "
-            "fusions: `python scripts/xplane_scopes.py", "<trace_dir>` "
-            "prints it per execution of `_decode_slots` and "
-            "`_prefill_slot`. The", "persistent compile cache keys on "
+            "fusions: `benchmark/trace_reduce.py`'s", "`compact` "
+            "reduces a run's trace to it per execution of `_decode_slots` "
+            "and `_prefill_slot`. The", "persistent compile cache keys on "
             "this metadata (`utils/compile_cache.py`), so a", "cached "
             "executable always carries the scopes of the code that asked "
             "for it.",

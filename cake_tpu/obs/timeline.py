@@ -190,7 +190,7 @@ class TimelineStore:
         """Append one typed event. rid=None reads the request-id
         contextvar (the cluster-hop recorder's path). Unknown ids are
         dropped silently: recording is always on, so a tier that never
-        opened a timeline (bench scripts, tests driving the model
+        opened a timeline (scripts, tests driving the model
         directly) costs one dict lookup and nothing else."""
         if kind not in EVENT_KINDS:
             raise ValueError(f"unknown timeline event kind {kind!r} — "

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.common.cache import init_paged_layers
+from ...models.common.cache import init_paged_layers, layer_is_pooled
 from ...obs import (SERVE_KV_BLOCKS_FREE, SERVE_KV_BLOCKS_SHARED,
                     SERVE_KV_BLOCKS_USED)
 from .allocator import BlockAllocator
@@ -90,8 +90,7 @@ class PagedKV:
             raise ValueError(
                 f"CAKE_KV_BLOCK_TOKENS={bt} must divide the serve context "
                 f"{ctx} so the paged view keeps the contiguous row layout")
-        if not any(s.kind != "linear" and s.window is None
-                   for s in model.cfg.layer_specs()):
+        if not any(layer_is_pooled(s) for s in model.cfg.layer_specs()):
             raise ValueError(
                 "paged KV needs at least one full-attention layer — "
                 "SWA rings and linear state are O(window)/O(1) per slot "

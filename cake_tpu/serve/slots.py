@@ -32,8 +32,9 @@ def slot_buckets(cap: int) -> tuple[int, ...]:
     CAKE_SERVE_SLOTS from 4 to 8/16 adds exactly ONE rung per doubling.
     The contiguous pool has one decode program whatever its occupancy
     (pinned in tests/test_spec_serve.py), so a warm-up that walks this
-    ladder there only walks the occupancies. Warmup code and benches
-    iterate it instead of hand-rolling powers of two."""
+    ladder there only walks the occupancies. Warm-up code (today only
+    benchmark/launch_server.py) iterates it instead of hand-rolling
+    powers of two."""
     out = []
     b = 1
     while b < cap:

@@ -134,8 +134,7 @@ class DraftModelDrafter:
     shareable = False
 
     def __init__(self, model):
-        specs = model.cfg.layer_specs()
-        if any(s.kind == "linear" for s in specs):
+        if model.cfg.has_recurrent_state:
             raise ValueError(
                 "draft model has linear-attention layers; their recurrent "
                 "state cannot roll back between proposals — use an "
@@ -184,7 +183,7 @@ class DraftModelDrafter:
         if len(props) > 1:
             # decode committed positions n .. n+k-2 — our own speculation;
             # drop it so the cache again holds exactly the confirmed prefix
-            self.cache = truncate_cache(m.cfg, self.cache, n)
+            self.cache = truncate_cache(self.cache, n)
         return props
 
 

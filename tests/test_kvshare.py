@@ -233,7 +233,13 @@ def test_stream_blob_migration_splice_parity(model, engines):
     from cake_tpu.fleet.kvshare import StreamMigrated
     eng_a, eng_b = engines
     prompt = [3, 17, 42, 99, 7]
-    n = 12
+    # long enough that the stream cannot end between its 4th token and the
+    # export (a 12-token stream did, on a fast scheduler: export_stream
+    # then finds nothing to park), and inside the pool: 5 + 64 tokens are
+    # 9 of its 12 blocks
+    n = 64
+    ref = _ref(model, prompt, n)
+    assert len(ref) == n, "the reference stream ends early"
     req = eng_a.submit(prompt, max_new_tokens=n, sampling=GREEDY)
     deadline = time.monotonic() + 60
     while len(req.tokens) < 4 and time.monotonic() < deadline:
@@ -254,7 +260,7 @@ def test_stream_blob_migration_splice_parity(model, engines):
     assert req2 is not None
     assert req2.wait(180)
     assert "error" not in req2.result, req2.result.get("error")
-    assert req2.result["tokens"] == _ref(model, prompt, n), \
+    assert req2.result["tokens"] == ref, \
         "migrated stream diverged from the uninterrupted reference"
     assert req2.stats.get("kv_migrated") is True
     # adopting twice is a miss (inbound is consumed), not a crash

@@ -67,7 +67,7 @@ def test_decode_still_dense_and_consistent(rng):
 def test_dispatch_structure_by_token_count(rng):
     """Prefill-sized T emits ragged_dot_general (TPU segment-GEMM whose
     FLOPs are (k/E) * dense — the CPU backend densifies it in lowering, so
-    the k/E claim is measured on hardware by benches/bench_micro.py, and
+    the k/E claim is a hardware one no CPU test can make, and
     here we pin the *dispatch structure* at the jaxpr level); decode-sized
     T stays on the dense combine with no gather/sort machinery."""
     e, i, h, k = 16, 8, 32, 2

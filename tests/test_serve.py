@@ -99,7 +99,7 @@ def test_slot_assign_and_reset_rehome():
         pos = lc["pos"].at[0, :n].set(jnp.arange(n))
         src_layers.append({**lc, "k": k, "v": lc["v"], "pos": pos})
 
-    out = slot_assign_layers(cfg, layers, src_layers, jnp.asarray(1))
+    out = slot_assign_layers(layers, src_layers, jnp.asarray(1))
     for lc in out:
         np.testing.assert_array_equal(np.asarray(lc["pos"][1, :n]),
                                       np.arange(n))
@@ -117,6 +117,121 @@ def test_slot_assign_and_reset_rehome():
         assert int(jnp.max(lc["pos"][1])) == -1
         assert float(jnp.abs(lc["k"][1]).max()) == 0.0
         assert float(lc["k"][0, 0, 0, 0]) == 7.0         # row 0 survives
+
+
+def test_row_operations_go_by_the_leaves():
+    """What a row of state is, is read off the layer's leaves (a `pos`
+    leaf: entries by position; none: recurrent state copied whole), never
+    off a kind's name: a pool whose recurrent layer carries leaves the code
+    has never heard of goes assign -> extract -> splice (final False, then
+    True) -> truncate -> reset, the named row changes as specified and
+    every other row keeps its bytes. Pure cache ops, no model, no cfg."""
+    from cake_tpu.models.common.cache import (
+        is_positional, slot_assign_layers, slot_extract_block_layers,
+        slot_reset_layers, slot_splice_block_layers, truncate_layers)
+    B, FULL, RING, H, D = 3, 32, 8, 2, 4
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), 32))
+
+    def rnd(shape, dtype=jnp.float32):
+        return jax.random.normal(next(keys), shape, jnp.float32).astype(dtype)
+
+    def kv(batch, size, held):
+        """A positional layer holding the positions `held` at p % size."""
+        held = np.asarray(held)
+        pos = np.full((batch, size), -1, np.int32)
+        pos[:, held % size] = held
+        return {"k": rnd((batch, size, H, D)), "v": rnd((batch, size, H, D)),
+                "pos": jnp.asarray(pos)}
+
+    def state(batch):
+        return {"ssm": rnd((batch, 4, 8)),
+                "tail": rnd((batch, 6, 3), jnp.bfloat16)}
+
+    def same_bytes(a, b):
+        return np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+    def others_untouched(new, old, row):
+        for ln, lo in zip(new, old):
+            assert ln.keys() == lo.keys()
+            for name in lo:
+                assert ln[name].dtype == lo[name].dtype
+                for r in set(range(B)) - {row}:
+                    assert same_bytes(ln[name][r], lo[name][r]), (name, r)
+
+    # every row of the pool starts non-empty: a full buffer, a ring, a state
+    pool = [kv(B, FULL, range(3)), kv(B, RING, range(3)), state(B)]
+    assert [is_positional(lc) for lc in pool] == [True, True, False]
+    n = 10
+    src = [kv(1, 16, range(n)), kv(1, RING, range(n - RING, n)), state(1)]
+
+    # assign: row 1 holds exactly the source, re-homed at position % size
+    out = slot_assign_layers(pool, src, jnp.asarray(1))
+    others_untouched(out, pool, 1)
+    for lo, ls, held in ((out[0], src[0], range(n)),
+                         (out[1], src[1], range(n - RING, n))):
+        size, ssize = lo["pos"].shape[1], ls["pos"].shape[1]
+        want = np.full((size,), -1, np.int32)
+        for p_ in held:
+            want[p_ % size] = p_
+            for name in ("k", "v"):
+                assert same_bytes(lo[name][1, p_ % size],
+                                  ls[name][0, p_ % ssize])
+        np.testing.assert_array_equal(np.asarray(lo["pos"][1]), want)
+    for name in src[2]:
+        assert same_bytes(out[2][name][1], src[2][name][0])
+
+    # extract positions 4..7 of row 1: entries by position, the state whole
+    blk = slot_extract_block_layers(out, jnp.asarray(1), jnp.asarray(4), 4)
+    for lb, lo in zip(blk[:2], out[:2]):
+        size = lo["pos"].shape[1]
+        np.testing.assert_array_equal(np.asarray(lb["pos"]),
+                                      np.arange(4, 8)[None])
+        for name in ("k", "v"):
+            assert lb[name].shape == (1, 4, H, D)
+            assert same_bytes(lb[name][0], lo[name][1, np.arange(4, 8) % size])
+    assert blk[2].keys() == out[2].keys()
+    for name in blk[2]:
+        assert blk[2][name].shape == (1,) + out[2][name].shape[1:]
+        assert same_bytes(blk[2][name][0], out[2][name][1])
+
+    # splice into a wiped row 2: the block's entries land by position; the
+    # state is a block-end snapshot, installed by the final block only
+    wiped = slot_reset_layers(out, jnp.asarray(2))
+    for final in (False, True):
+        got = slot_splice_block_layers(wiped, blk, jnp.asarray(2),
+                                       jnp.asarray(final))
+        others_untouched(got, wiped, 2)
+        for lg, lb in zip(got[:2], blk[:2]):
+            size = lg["pos"].shape[1]
+            want = np.full((size,), -1, np.int32)
+            want[np.arange(4, 8) % size] = np.arange(4, 8)
+            np.testing.assert_array_equal(np.asarray(lg["pos"][2]), want)
+            for name in ("k", "v"):
+                assert same_bytes(lg[name][2, np.arange(4, 8) % size],
+                                  lb[name][0])
+        for name in blk[2]:
+            want = blk[2][name][0] if final else wiped[2][name][2]
+            assert same_bytes(got[2][name][2], want)
+
+    # truncate (the whole batch): positions >= 6 become empty, K/V bytes
+    # and recurrent state stay
+    cut = truncate_layers(got, jnp.asarray(6))
+    for lc, lg in zip(cut[:2], got[:2]):
+        pos = np.asarray(lg["pos"])
+        np.testing.assert_array_equal(np.asarray(lc["pos"]),
+                                      np.where(pos >= 6, -1, pos))
+        assert same_bytes(lc["k"], lg["k"]) and same_bytes(lc["v"], lg["v"])
+    assert int(jnp.max(cut[0]["pos"][1])) == 5           # 0..5 of 0..9 stay
+    for name in got[2]:
+        assert same_bytes(cut[2][name], got[2][name])
+
+    # reset: row 1 empty (pos -1, every other leaf zero), the rest as it was
+    clr = slot_reset_layers(cut, jnp.asarray(1))
+    others_untouched(clr, cut, 1)
+    for lc in clr:
+        for name, buf in lc.items():
+            row = np.asarray(buf[1].astype(jnp.float32))
+            assert (row == (-1 if name == "pos" else 0)).all(), name
 
 
 def test_sample_traced_matches_static_greedy():

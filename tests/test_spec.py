@@ -292,7 +292,7 @@ def test_truncate_cache_drops_suffix(llama):
     t = int(np.argmax(np.asarray(logits[0])))
     _, cache = m.decode_logits(cache, t)        # position 8
     _, cache = m.decode_logits(cache, t)        # position 9
-    cache = truncate_cache(m.cfg, cache, len(prompt))
+    cache = truncate_cache(cache, len(prompt))
     assert int(cache["pos"]) == len(prompt)
     for lc in cache["layers"]:
         assert int(np.asarray(lc["pos"]).max()) < len(prompt)
@@ -308,7 +308,7 @@ def test_truncate_cache_rejects_linear(gdn):
     from cake_tpu.models.common.cache import truncate_cache
     cache = gdn.new_cache(1, kv_len=32)
     with pytest.raises(ValueError, match="linear"):
-        truncate_cache(gdn.cfg, cache, 4)
+        truncate_cache(cache, 4)
 
 
 def test_draft_model_drafter_consistent_after_rejection(llama):

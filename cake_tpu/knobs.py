@@ -403,8 +403,8 @@ _knob("CAKE_MOE_RAGGED", bool, True, "ops",
       "ragged-dot MoE expert combine (falls back to the dense combine "
       "when off)")
 _knob("CAKE_TPU_FLASH", bool, True, "ops",
-      "flash prefill attention on TPU backends (CPU always uses the "
-      "reference path)")
+      "the Pallas attention kernels (prefill and decode) on TPU backends "
+      "(CPU always uses the reference path)")
 
 # -- paths ----------------------------------------------------------------
 _knob("CAKE_TPU_CACHE", str, "~/.cache/cake-tpu", "paths",

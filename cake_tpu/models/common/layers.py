@@ -185,6 +185,33 @@ def flash_kernel_mode(flash_mode: str, s: int, window: int | None = None,
     return None
 
 
+def decode_kernel_block(s: int, window: int | None, layer_cache, dtype,
+                        mesh=None) -> int | None:
+    """The block length the Pallas decode kernel walks this layer's cache
+    in when a step of width s runs it, else None (the masked XLA path). It
+    runs on a one-token step over a cache addressed by position (a `pos`
+    leaf) that is unwrapped (no window: buffer index == position; SWA rings
+    keep the masked path), at least one block long, in the queries' dtype
+    and one whose rows the kernel can split (32-bit, or 16-bit with an even
+    number of K/V heads a device), not sharded over its rows or its length (`dp`, `sp`:
+    the kernel splits heads over `tp` alone), where the Pallas attention
+    kernels are on. attention_forward alone dispatches on it."""
+    from ...ops.decode_attention import decode_block_k
+    from ...ops.flash import flash_enabled
+    if (s != 1 or window is not None or layer_cache is None
+            or "pos" not in layer_cache or not flash_enabled()):
+        return None
+    if mesh is not None and any(mesh.shape.get(a, 1) > 1
+                                for a in ("dp", "sp")):
+        return None
+    k = layer_cache["k"]
+    heads = k.shape[2] // (mesh.shape.get("tp", 1) if mesh is not None else 1)
+    if k.dtype != dtype or (k.dtype.itemsize != 4 and
+                            (k.dtype.itemsize != 2 or heads % 2)):
+        return None
+    return decode_block_k(k.shape[1])
+
+
 def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
                       layer_cache: dict, pos0, rope: dict, valid_len=None,
                       flash_mode: str = "off", mesh=None):
@@ -289,6 +316,18 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
         new_cache = update_kv_cache(layer_cache, k, v, pos0, valid_len)
         kv_pos, k_all, v_all = (new_cache["pos"], new_cache["k"],
                                 new_cache["v"])
+        block_k = decode_kernel_block(s, spec.window, layer_cache, q.dtype,
+                                      mesh)
+        if block_k is not None:
+            # the Pallas kernel reads the buffers in place, each row only
+            # up to its frontier pos0 + 1, and no block of a row that
+            # valid_len 0 masks out of the step
+            from ...ops.decode_attention import decode_attention
+            y = decode_attention(
+                q, k_all, v_all, kv_pos, pos0,
+                None if valid_len is None else valid_len > 0,
+                scale=cfg.attn_scale, block_k=block_k, mesh=mesh)
+            use_flash = True      # skip the masked fallback below
     else:
         new_cache = None
         kv_pos = jnp.concatenate([layer_cache["pos"], kv_pos_new], axis=1)

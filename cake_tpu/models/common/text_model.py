@@ -155,7 +155,7 @@ def chunk_logits(cfg: ModelConfig, params: dict, cache: dict, tokens, pos0,
 
 
 def slot_step(cfg: ModelConfig, params: dict, cache: dict, tok, p, rng,
-              recent, temp, tk, tp, pen, act):
+              recent, temp, tk, tp, pen, act, mesh=None):
     """One slot's sampled decode step on its batch-1 cache view: embed ->
     layers -> head -> sample_traced -> recent-token push, the sampled_step
     pipeline with TRACED sampling parameters. `act` (traced bool) masks
@@ -167,7 +167,7 @@ def slot_step(cfg: ModelConfig, params: dict, cache: dict, tok, p, rng,
     rng, recent)."""
     x = embed_tokens(cfg, params, tok[None, None])
     x, cache = forward_layers(cfg, params, x, cache, p,
-                              valid_len=act.astype(jnp.int32))
+                              valid_len=act.astype(jnp.int32), mesh=mesh)
     logits = lm_head_logits(cfg, params, x)[0, -1]
     rng2, sk = jax.random.split(rng)
     nxt = sample_traced(logits, sk, temp, tk, tp, pen, recent)
@@ -311,7 +311,8 @@ class TextModel:
             the invariant structural)."""
             rng, sk = jax.random.split(rng)
             x = embed_tokens(cfg, params, tok[:, None])
-            x, cache = forward_layers(cfg, params, x, cache, cache["pos"])
+            x, cache = forward_layers(cfg, params, x, cache, cache["pos"],
+                                      mesh=mesh)
             logits = lm_head_logits(cfg, params, x)[:, -1]
             nxt = sample(logits[0], sk, scfg, recent)
             recent = push_recent_token(recent, nxt)
@@ -374,7 +375,8 @@ class TextModel:
             """One decode step returning raw logits (distributed master path +
             logit-parity tests)."""
             x = embed_tokens(cfg, params, token[:, None])
-            x, cache = forward_layers(cfg, params, x, cache, cache["pos"])
+            x, cache = forward_layers(cfg, params, x, cache, cache["pos"],
+                                      mesh=mesh)
             logits = lm_head_logits(cfg, params, x)[:, -1]
             return logits, cache
 
@@ -413,7 +415,7 @@ class TextModel:
                 cache = {"layers": jax.tree_util.tree_map(
                     lambda a: a[None], lcs), "pos": p}
                 return slot_step(cfg, params, cache, tok, p, rng, recent,
-                                 temp, tk, tp, pen, act)
+                                 temp, tk, tp, pen, act, mesh)
 
             # the whole per-slot carry advances ON DEVICE: the engine ships
             # nothing per iteration and fetches only the packed ids
@@ -591,7 +593,7 @@ class TextModel:
                 cache = _paged_row_cache(pool, rows_slot, table_row, p)
                 nxt, new_lcs, rng, recent = slot_step(
                     cfg, params, cache, tok, p, rng, recent, temp, tk, tp,
-                    pen, act)
+                    pen, act, mesh)
                 wb = jnp.clip(p // bt, 0, table_row.shape[0] - 1)
                 blks = [paged_block_of(lc, wb, bt) if pl else {}
                         for pl, lc in zip(pool, new_lcs)]

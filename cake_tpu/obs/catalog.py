@@ -104,7 +104,10 @@ taken together can be merged.
 ## Engine flight recorder
 
 The serve engine appends one record per scheduler iteration (`seq` = the
-step id, occupancy, dispatch bucket, `fetch_ms` = the scheduler blocked
+step id, occupancy, dispatch bucket, `kv_tokens` = the tokens the
+stepping rows hold (prompt + generated: `bucket` x the context length
+minus it is what a step that walks rows to their frontier leaves unread),
+`fetch_ms` = the scheduler blocked
 on the device for the sampled ids, `host_ms` = the rest of the step's
 wall time, spec accepts, queue depth, paged-pool free/used) into a ring
 of the last `CAKE_FLIGHT_RECORDER` iterations: a stuck or slow step says

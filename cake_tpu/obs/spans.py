@@ -234,7 +234,8 @@ SPAN_CATALOG: tuple[tuple[str, str], ...] = (
                    "reservation, draft building"),
     ("serve.decode_dispatch", "serve engine: host cost of dispatching "
                               "the batched decode / verify program "
-                              "(args: slots, bucket)"),
+                              "(args: slots, bucket, kv_tokens = the "
+                              "tokens the stepping rows hold)"),
     ("serve.prefill_chunk", "serve engine: one chunked-admission "
                             "prefill dispatch (args: tokens, pos0, slot)"),
     ("serve.prefill_finish", "serve engine: prefix-cache block capture "

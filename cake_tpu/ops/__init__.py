@@ -3,7 +3,8 @@
 The reference's ComputeBackend trait (~35 methods over CUDA/Metal/Vulkan/
 ROCm/CPU — ref: cake-core/src/backends/mod.rs) collapses on TPU into this
 flat module of jit-fusable functions plus Pallas kernels for the few ops
-where hand-scheduling beats XLA (flash attention for long prefill).
+where hand-scheduling beats XLA (flash attention for long prefill, decode
+attention that walks each cache row to its frontier).
 """
 from .activations import (add3, add_scaled, adaln_modulate, exp_mul, gelu,
                           gelu_mul, gelu_tanh, sigmoid, silu, silu_mul,

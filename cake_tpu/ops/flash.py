@@ -242,6 +242,7 @@ def flash_attention(q, k, v, scale: float | None = None, causal: bool = True,
 
 
 def flash_enabled() -> bool:
-    """Flash prefill opt-in: on for TPU backends unless CAKE_TPU_FLASH=0."""
+    """The Pallas attention kernels (prefill here, decode in
+    ops/decode_attention.py): on for TPU backends unless CAKE_TPU_FLASH=0."""
     from .. import knobs
     return bool(knobs.get("CAKE_TPU_FLASH")) and jax.default_backend() == "tpu"

@@ -949,7 +949,7 @@ class ServeEngine:
                 # (which may shrink `active`) — see _ensure_decode_blocks
                 active = self._ensure_decode_blocks(active, spec_job)
             packed = None
-            nb = 0
+            nb = kv_tokens = 0
             t_dispatch = now()
             spec_acc0 = self.spec_accepted
             active_ids = tuple(self._reqs[i].id for i in active)
@@ -960,6 +960,12 @@ class ServeEngine:
                 nb = self.slots if self.paged is None else \
                     slot_bucket(active[-1] + 1, self.slots)
                 SERVE_BATCH_OCCUPANCY.observe(len(active))
+                # what the active rows hold (prompt + generated so far):
+                # nb * the context length minus this is the part of the
+                # pool a step that walks rows to their frontier leaves
+                # unread
+                kv_tokens = sum(len(self._reqs[i].prompt_ids)
+                                + len(self._reqs[i].tokens) for i in active)
                 # arm BEFORE the fault hook: an injected stall simulates a
                 # dispatch stuck on the device, and the watchdog must see
                 # it; real crashes here implicate every active request
@@ -1059,7 +1065,8 @@ class ServeEngine:
                     step, (t_sweep, t_admit, t_plan, t_dispatch, t_prefill,
                            t_fetch, t_fanout, t_end),
                     admitted=admitted, slots=len(active), bucket=nb,
-                    decoded=packed is not None, tokens=tokens,
+                    kv_tokens=kv_tokens, decoded=packed is not None,
+                    tokens=tokens,
                     finished=finished)
             # flight record: one bounded dict per iteration — the black
             # box the supervisor dumps on wedge/DOWN (see flight.py).
@@ -1068,6 +1075,7 @@ class ServeEngine:
             fetch_s = t_fanout - t_fetch
             rec = {
                 "occupancy": len(active), "bucket": nb,
+                "kv_tokens": kv_tokens,
                 "host_ms": round((t_end - t_sweep - fetch_s) * 1e3, 3),
                 "fetch_ms": round(fetch_s * 1e3, 3),
                 "queued": self.queue.depth(),
@@ -1081,8 +1089,8 @@ class ServeEngine:
         return True
 
     def _emit_phases(self, step: int, t: tuple, *, admitted: int,
-                     slots: int, bucket: int, decoded: bool, tokens: int,
-                     finished: int):
+                     slots: int, bucket: int, kv_tokens: int, decoded: bool,
+                     tokens: int, finished: int):
         """The children of `serve.step` from the step's own stamps (seconds
         on obs.now()'s clock, which is the recorder's). The prefill
         interval is split at the end of the chunk's dispatch: the
@@ -1100,7 +1108,7 @@ class ServeEngine:
         add("serve.plan", t_plan, t_dispatch)
         if decoded:
             add("serve.decode_dispatch", t_dispatch, t_prefill,
-                slots=slots, bucket=bucket)
+                slots=slots, bucket=bucket, kv_tokens=kv_tokens)
         if self._chunk_end is not None:
             t_chunk, final = self._chunk_end
             add("serve.prefill_finish", int(t_chunk * 1e6), t_fetch,

@@ -1,4 +1,5 @@
-"""Compile the flash kernel for a DESCRIBED TPU v5e (nothing runs).
+"""Compile the Pallas attention kernels (prefill flash, decode) for a
+DESCRIBED TPU v5e (nothing runs).
 
 The TPU compiler is installed without a chip: it lowers for a topology
 description and refuses what the chip would refuse — a kernel that does
@@ -10,6 +11,7 @@ import, and every compile runs in this test's own process.
 """
 import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
+from cake_tpu.ops.decode_attention import decode_attention
 from cake_tpu.ops.flash import flash_attention
 
 # Qwen3-0.6B attention widths (the chip_smoke.py model)
@@ -104,4 +107,51 @@ def test_flash_compiles_head_sharded_under_tp_mesh(tp_mesh, append):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # heads stay where they are: no collective moves q/k/v/out
+    assert "all-gather" not in text and "all-to-all" not in text
+
+
+# -- the decode kernel over a pool as the benchmark's cells hold it ----------
+
+def _compile_decode(sharding, rows, hq, hkv, *, ctx=4096, mesh=None,
+                    row_sharding=None):
+    row_sharding = row_sharding or sharding
+    q = jax.ShapeDtypeStruct((rows, 1, hq, D), jnp.bfloat16,
+                             sharding=sharding)
+    kv = jax.ShapeDtypeStruct((rows, ctx, hkv, D), jnp.bfloat16,
+                              sharding=sharding)
+    pos = jax.ShapeDtypeStruct((rows, ctx), jnp.int32, sharding=row_sharding)
+    q_pos = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=row_sharding)
+    act = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=row_sharding)
+
+    def f(q, k, v, kv_pos, q_pos, act):
+        return decode_attention(q, k, v, kv_pos, q_pos, act, mesh=mesh)
+
+    with _no_compile_cache():
+        return jax.jit(f).lower(q, kv, kv, pos, q_pos, act).compile()
+
+
+@pytest.mark.parametrize("rows,hq,hkv", [
+    (8, 32, 8),                      # qwen3-4b's pool: 8 slots x 4096
+    (16, 32, 4),                     # qwen3-30b-a3b's: 16 slots, 4 K/V heads
+    (1, 16, 8),                      # a sequential generate's batch-1 cache
+], ids=["4b-pool", "moe-pool", "batch1"])
+def test_decode_kernel_compiles_on_one_chip(one_chip, rows, hq, hkv):
+    """The K and V buffers reach the kernel as they lie: viewing
+    [rows, T, Hkv, D] as [rows, T * Hkv, D] is a bitcast in the tiled
+    layout, so no copy, transpose or slice of a pool-shaped operand
+    stands around the call."""
+    text = _compile_decode(one_chip, rows, hq, hkv).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    pool = re.compile(r"= bf16\[%d,(4096,%d|%d),128\]\S* (\w[\w-]*)\("
+                      % (rows, hkv, 4096 * hkv))
+    ops = {m.group(2) for m in pool.finditer(text)}
+    assert ops <= {"parameter", "bitcast"}, ops
+
+
+def test_decode_kernel_compiles_head_sharded_under_tp_mesh(tp_mesh):
+    heads = NamedSharding(tp_mesh, P(None, None, "tp", None))
+    text = _compile_decode(heads, 8, 32, 8, mesh=tp_mesh,
+                           row_sharding=NamedSharding(tp_mesh, P())
+                           ).as_text()
+    assert "tpu_custom_call" in text
     assert "all-gather" not in text and "all-to-all" not in text

@@ -217,6 +217,9 @@ def test_a_step_that_decoded_fetched_and_fanned_out_once(traced):
             if k["name"] == "serve.decode_dispatch":
                 assert k["args"]["slots"] == r["occupancy"]
                 assert k["args"]["bucket"] == r["bucket"]
+                # what the stepping rows hold: at least a prompt token each
+                assert k["args"]["kv_tokens"] == r["kv_tokens"] \
+                    >= r["occupancy"]
     assert tokens == sum(len(r.result["tokens"]) for r in traced["reqs"])
     assert finished == len(traced["reqs"])
     chunks = [e for e in spans if e["name"] == "serve.prefill_chunk"]

@@ -10,7 +10,9 @@ scatters new entries in, carrying an absolute-position array per layer
   * sliding-window layers: ring buffer of window size W, slot p%W holds
     position p (ref cache.rs:173-182 trims instead — same visibility);
   * linear-attention layers: O(1) recurrent + conv state instead of KV
-    (ref cache.rs:18-23,221-238 GDN states).
+    (ref cache.rs:18-23,221-238 GDN states);
+  * Mamba layers: a conv tail and a diagonal state per channel
+    (models/jamba.py), recurrent rows like the above.
 
 The cache is a plain pytree (list of per-layer dicts + scalar pos) so it
 flows through jit/donate/shard unchanged. Each connection gets a fresh
@@ -36,6 +38,9 @@ def init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
             "state": jnp.zeros((batch, la.num_value_heads, la.key_head_dim,
                                 la.value_head_dim), jnp.float32),
         }
+    if spec.kind == "mamba":
+        from ..jamba import init_mamba_cache
+        return init_mamba_cache(cfg, batch, dtype)
     size = max_seq_len if spec.window is None else min(spec.window, max_seq_len)
     return {
         "k": jnp.zeros((batch, size, cfg.num_key_value_heads, cfg.head_dim), dtype),
@@ -64,6 +69,14 @@ def is_positional(lc: dict) -> bool:
     row operations below go by this and by nothing else, so a layer kind
     with new state leaves needs no arm in any of them."""
     return "pos" in lc
+
+
+def row_state_bytes(layers: list[dict]) -> int:
+    """Bytes ONE row of a pool holds in leaves that are not addressed by
+    position (recurrent state: read and written whole by every step the
+    row takes, whatever its length); 0 for a model with none."""
+    return sum(leaf.nbytes // leaf.shape[0] for lc in layers
+               if not is_positional(lc) for leaf in lc.values())
 
 
 def update_kv_cache(layer_cache: dict, k_new, v_new, pos, valid_len=None):

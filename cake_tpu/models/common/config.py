@@ -29,9 +29,20 @@ class LinearAttnConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class MambaConfig:
+    """Mamba-1 selective-state-space hyperparameters (Jamba's mixer)."""
+    d_inner: int = 5120            # mamba_expand x hidden_size
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: int = 160
+    conv_bias: bool = True
+    attn_layers: tuple[int, ...] = ()   # the layers that mix by attention
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """Resolved per-layer behavior, consumed by the generic decoder block."""
-    kind: str = "full"            # 'full' | 'swa' | 'linear'
+    kind: str = "full"            # 'full' | 'swa' | 'linear' | 'mamba'
     use_rope: bool = True
     local_rope_table: bool = False  # Gemma3 SWA layers: rope_local_base_freq
     window: int | None = None     # sliding-window size when kind == 'swa'
@@ -43,7 +54,7 @@ class LayerSpec:
         """The layer carries a fixed-size state that only moves forward
         (no `pos` leaf in its cache: cache.is_positional is False) in
         place of entries addressed by position."""
-        return self.kind == "linear"
+        return self.kind in ("linear", "mamba")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,6 +106,8 @@ class ModelConfig:
     # Linear (recurrent) attention
     linear_attn: LinearAttnConfig | None = None
     attn_output_gate: bool = False
+    # Mamba-1 state-space layers (Jamba); its attention layers are NoPE
+    mamba: MambaConfig | None = None
     # Attention logit scale override (None = head_dim**-0.5); Gemma3 models
     # may set query_pre_attn_scalar.
     attn_scale: float | None = None
@@ -102,6 +115,10 @@ class ModelConfig:
     # ---- per-layer resolution ----
 
     def layer_spec(self, i: int) -> LayerSpec:
+        if self.mamba is not None:
+            attn = i in self.mamba.attn_layers
+            return LayerSpec(kind="full" if attn else "mamba", use_rope=False,
+                             norm_style=self.norm_style)
         if self.linear_attn is not None and i < len(self.linear_attn.layer_types):
             if self.linear_attn.layer_types[i] == "linear_attention":
                 return LayerSpec(kind="linear", use_rope=False,
@@ -369,6 +386,34 @@ def _qwen3_5_moe(d):
     )
 
 
+def _jamba(d):
+    """Jamba / Jamba2 (HF JambaForCausalLM): Mamba-1 layers with attention
+    at every `attn_layer_period`-th layer from `attn_layer_offset`
+    (JambaConfig.layers_block_type), attention without rope, every FFN a
+    dense SwiGLU when `num_experts` is 1. The sparse variants are another
+    model: refused, never guessed at."""
+    if int(d.get("num_experts") or 1) > 1:
+        raise ValueError(
+            "jamba: num_experts > 1 (the sparse Jamba variants) is not "
+            "implemented; only dense-FFN checkpoints load")
+    if bool(d.get("mamba_proj_bias", False)):
+        raise ValueError("jamba: mamba_proj_bias true is not implemented")
+    n, hidden = int(d["num_hidden_layers"]), int(d["hidden_size"])
+    period = int(d.get("attn_layer_period", 8))
+    offset = int(d.get("attn_layer_offset", 4))
+    rank = d.get("mamba_dt_rank", "auto")
+    mamba = MambaConfig(
+        d_inner=int(d.get("mamba_expand", 2)) * hidden,
+        d_state=int(d.get("mamba_d_state", 16)),
+        d_conv=int(d.get("mamba_d_conv", 4)),
+        dt_rank=-(-hidden // 16) if rank == "auto" else int(rank),
+        conv_bias=bool(d.get("mamba_conv_bias", True)),
+        attn_layers=tuple(i for i in range(n) if i % period == offset))
+    return ModelConfig(**_base(
+        d, "jamba", mamba=mamba,
+        rms_norm_eps=float(d.get("rms_norm_eps", 1e-6))))
+
+
 # HF architectures string -> adapter (ref: cake/mod.rs arch_str_to_text_model_arch;
 # unknown strings fall back to llama, matching the reference)
 ARCH_ADAPTERS = {
@@ -388,6 +433,7 @@ ARCH_ADAPTERS = {
     "Olmo2ForCausalLM": _olmo2,
     "ExaoneForCausalLM": _exaone4,
     "Exaone4ForCausalLM": _exaone4,
+    "JambaForCausalLM": _jamba,
 }
 
 # short family names (CLI --arch overrides, tests)
@@ -397,7 +443,7 @@ FAMILY_ADAPTERS = {
     "qwen3_5": _qwen3_5, "qwen3_5_moe": _qwen3_5_moe,
     "phi4": _phi4, "phi3": _phi4,
     "mistral": _mistral, "gemma3": _gemma3, "falcon3": _falcon3,
-    "olmo2": _olmo2, "exaone4": _exaone4,
+    "olmo2": _olmo2, "exaone4": _exaone4, "jamba": _jamba,
 }
 
 
@@ -430,6 +476,12 @@ def tiny_config(arch: str = "llama", **over) -> ModelConfig:
     )
     if arch in ("qwen3_moe", "qwen3_5_moe"):
         d.update(num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32)
+    if arch == "jamba":
+        # both kinds occur: attention at layers 2 and 6 of a period of 4
+        d.update(attn_layer_period=4, attn_layer_offset=2, num_experts=1,
+                 mamba_expand=2, mamba_d_state=8, mamba_d_conv=4,
+                 mamba_dt_rank=8, tie_word_embeddings=True,
+                 rms_norm_eps=1e-6)
     d.update(over)
     if arch in ("qwen3_5", "qwen3_5_moe"):
         d["text_config"] = dict(d)

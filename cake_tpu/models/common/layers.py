@@ -96,6 +96,9 @@ def init_layer_params(cfg: ModelConfig, spec: LayerSpec, key, dtype):
     if spec.kind == "linear":
         from ..qwen3_5 import init_gdn_params  # lazy: GDN lives with its family
         p["linear_attn"] = init_gdn_params(cfg, ks[0], dtype)
+    elif spec.kind == "mamba":
+        from ..jamba import init_mamba_params
+        p["mamba"] = init_mamba_params(cfg, ks[0], dtype)
     else:
         p["self_attn"] = init_attention_params(cfg, spec, ks[0], dtype)
     p["mlp"] = (init_moe_params(cfg, ks[1], dtype) if spec.is_moe
@@ -152,6 +155,8 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
 
 
 def make_rope(cfg: ModelConfig) -> dict:
+    if not any(spec.use_rope for spec in cfg.layer_specs()):
+        return {}       # no layer rotates (Jamba): no table of max_seq_len
     cos, sin = rope_tables(cfg.max_seq_len, cfg.rotary_dim, cfg.rope_theta,
                            cfg.rope_scaling)
     rope = {"cos": cos, "sin": sin}
@@ -393,6 +398,10 @@ def _ffn(cfg, spec, p, x):
 
 def _attn(cfg, spec, p, x, lc, pos0, rope, valid_len=None,
           flash_mode="off", mesh=None):
+    if spec.kind == "mamba":
+        from ..jamba import mamba_forward
+        with jax.named_scope("cake.ssm"):
+            return mamba_forward(cfg, p["mamba"], x, lc, pos0, valid_len)
     with jax.named_scope("cake.attn"):
         if spec.kind == "linear":
             from ..qwen3_5 import gdn_forward

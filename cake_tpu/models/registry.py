@@ -21,6 +21,8 @@ Family notes (distinguishers, ref SURVEY §2e):
   falcon3  - vanilla GQA (models/falcon3/)
   olmo2    - post-norm, pre-reshape QK-norm (models/olmo2/)
   exaone4  - 3:1 local(SWA+RoPE)/global(NoPE) (models/exaone4/)
+  jamba    - Mamba-1 state-space layers, NoPE attention every
+             attn_layer_period-th layer, dense FFNs (models/jamba.py)
 """
 from __future__ import annotations
 

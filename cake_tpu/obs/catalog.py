@@ -107,6 +107,9 @@ The serve engine appends one record per scheduler iteration (`seq` = the
 step id, occupancy, dispatch bucket, `kv_tokens` = the tokens the
 stepping rows hold (prompt + generated: `bucket` x the context length
 minus it is what a step that walks rows to their frontier leaves unread),
+`state_bytes` = the bytes of recurrent state those rows hold (leaves no
+position addresses, read and written whole by every step; 0 for a model
+with none),
 `fetch_ms` = the scheduler blocked
 on the device for the sampled ids, `host_ms` = the rest of the step's
 wall time, spec accepts, queue depth, paged-pool free/used) into a ring

@@ -235,7 +235,8 @@ SPAN_CATALOG: tuple[tuple[str, str], ...] = (
     ("serve.decode_dispatch", "serve engine: host cost of dispatching "
                               "the batched decode / verify program "
                               "(args: slots, bucket, kv_tokens = the "
-                              "tokens the stepping rows hold)"),
+                              "tokens the stepping rows hold, state_bytes "
+                              "= the bytes of recurrent state they hold)"),
     ("serve.prefill_chunk", "serve engine: one chunked-admission "
                             "prefill dispatch (args: tokens, pos0, slot)"),
     ("serve.prefill_finish", "serve engine: prefix-cache block capture "
@@ -267,6 +268,16 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
     ("cake.embed", "token embedding lookup (layers.embed_tokens)"),
     ("cake.attn", "one layer's attention incl. its KV write "
                   "(layers._attn: attention_forward / gdn_forward)"),
+    ("cake.ssm", "one Mamba layer's state-space mixer, in cake.attn's "
+                 "place for that layer kind (layers._attn: "
+                 "jamba.mamba_forward)"),
+    ("cake.ssm.proj", "mamba_forward: the in, x, dt and out projections "
+                      "with the norms on dt, B and C"),
+    ("cake.ssm.conv", "mamba_forward: depthwise causal conv over the "
+                      "row's conv tail and the chunk, and the new tail"),
+    ("cake.ssm.scan", "mamba_forward: the state update (closed form for "
+                      "one token, a scan along a chunk's tokens) and the "
+                      "gated output"),
     ("cake.ffn", "one layer's feed-forward (layers._ffn: mlp_forward or "
                  "moe_forward)"),
     ("cake.ffn.route", "MoE router: logits and top-k (ops.moe.moe_ffn)"),

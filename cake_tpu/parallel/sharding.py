@@ -173,6 +173,11 @@ def init_cache_sharded(mesh: Mesh | None, cfg: ModelConfig, batch: int,
 
 def check_tp_divisibility(cfg: ModelConfig, mesh: Mesh):
     tp = mesh.shape.get("tp", 1)
+    if cfg.mamba is not None and tp > 1:
+        raise ValueError(
+            f"--tp {tp} is not supported for {cfg.arch}: its attention has "
+            "one KV head, which cannot be split, and its Mamba projections "
+            "and state have no placement over tp (they replicate)")
     if cfg.num_key_value_heads % tp or cfg.num_attention_heads % tp:
         raise ValueError(
             f"tp={tp} must divide heads {cfg.num_attention_heads}/"

@@ -70,6 +70,7 @@ from ..obs import (RECORDER, SERVE_BATCH_OCCUPANCY, SERVE_E2E_SECONDS,
                    SERVE_QUEUE_WAIT_SECONDS, SERVE_REQUEST_TIMEOUTS,
                    SERVE_SLOTS_BUSY, SERVE_TTFT_SECONDS, TIMELINES, now,
                    set_request_id)
+from ..models.common.cache import row_state_bytes
 from ..ops.sampling import SamplingConfig, config_has_filters
 from ..spec import resolve_drafter
 from ..spec.verify import record_step
@@ -424,6 +425,10 @@ class ServeEngine:
                 layers = self.model.new_cache(slots,
                                               kv_len=self.ctx)["layers"]
             self._layers = layers
+        # what a stepping row's recurrent state weighs, read off the pool's
+        # leaves once (the flight record's `state_bytes`)
+        self._row_state_bytes = row_state_bytes(
+            self.paged.rows if self._layers is None else self._layers)
         self._toks = jnp.zeros((slots,), jnp.int32)
         self._pos = jnp.zeros((slots,), jnp.int32)
         self._temps = jnp.zeros((slots,), jnp.float32)
@@ -949,7 +954,7 @@ class ServeEngine:
                 # (which may shrink `active`) — see _ensure_decode_blocks
                 active = self._ensure_decode_blocks(active, spec_job)
             packed = None
-            nb = kv_tokens = 0
+            nb = kv_tokens = state_bytes = 0
             t_dispatch = now()
             spec_acc0 = self.spec_accepted
             active_ids = tuple(self._reqs[i].id for i in active)
@@ -966,6 +971,9 @@ class ServeEngine:
                 # unread
                 kv_tokens = sum(len(self._reqs[i].prompt_ids)
                                 + len(self._reqs[i].tokens) for i in active)
+                # and what they hold that no position addresses: recurrent
+                # state, read and written whole whatever the row's length
+                state_bytes = self._row_state_bytes * len(active)
                 # arm BEFORE the fault hook: an injected stall simulates a
                 # dispatch stuck on the device, and the watchdog must see
                 # it; real crashes here implicate every active request
@@ -1065,7 +1073,8 @@ class ServeEngine:
                     step, (t_sweep, t_admit, t_plan, t_dispatch, t_prefill,
                            t_fetch, t_fanout, t_end),
                     admitted=admitted, slots=len(active), bucket=nb,
-                    kv_tokens=kv_tokens, decoded=packed is not None,
+                    kv_tokens=kv_tokens, state_bytes=state_bytes,
+                    decoded=packed is not None,
                     tokens=tokens,
                     finished=finished)
             # flight record: one bounded dict per iteration — the black
@@ -1075,7 +1084,7 @@ class ServeEngine:
             fetch_s = t_fanout - t_fetch
             rec = {
                 "occupancy": len(active), "bucket": nb,
-                "kv_tokens": kv_tokens,
+                "kv_tokens": kv_tokens, "state_bytes": state_bytes,
                 "host_ms": round((t_end - t_sweep - fetch_s) * 1e3, 3),
                 "fetch_ms": round(fetch_s * 1e3, 3),
                 "queued": self.queue.depth(),
@@ -1089,7 +1098,8 @@ class ServeEngine:
         return True
 
     def _emit_phases(self, step: int, t: tuple, *, admitted: int,
-                     slots: int, bucket: int, kv_tokens: int, decoded: bool,
+                     slots: int, bucket: int, kv_tokens: int,
+                     state_bytes: int, decoded: bool,
                      tokens: int, finished: int):
         """The children of `serve.step` from the step's own stamps (seconds
         on obs.now()'s clock, which is the recorder's). The prefill
@@ -1108,7 +1118,8 @@ class ServeEngine:
         add("serve.plan", t_plan, t_dispatch)
         if decoded:
             add("serve.decode_dispatch", t_dispatch, t_prefill,
-                slots=slots, bucket=bucket, kv_tokens=kv_tokens)
+                slots=slots, bucket=bucket, kv_tokens=kv_tokens,
+                state_bytes=state_bytes)
         if self._chunk_end is not None:
             t_chunk, final = self._chunk_end
             add("serve.prefill_finish", int(t_chunk * 1e6), t_fetch,

@@ -7,7 +7,7 @@ but the evidence of WHAT the engine was doing in the seconds before is
 gone: the span recorder is off by default and metrics are aggregates.
 This module is the black box: every completed scheduler iteration
 appends one small record (occupancy, dispatch bucket, the tokens the
-stepping rows hold, the step's wall
+stepping rows hold and the bytes of recurrent state beside them, the step's wall
 time split at the device fetch into `host_ms` and `fetch_ms`, spec accept
 counts, queue depth, KV-pool occupancy) into a
 ring of the last `CAKE_FLIGHT_RECORDER` iterations, and the supervisor

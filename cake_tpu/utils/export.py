@@ -20,6 +20,10 @@ def params_to_hf_tensors(cfg: ModelConfig, params: dict,
     """fuse_phi: write Phi-style fused qkv_proj/gate_up_proj names."""
     out: dict[str, np.ndarray] = {}
     pre = cfg.model_prefix
+    ckpt = {}
+    if cfg.mamba is not None:
+        from ..models.jamba import CHECKPOINT_NAMES as ckpt
+    ffn = ckpt.get("mlp", "mlp")
 
     def put_norm(name, w):
         arr = _np(w).astype(np.float32)
@@ -30,7 +34,8 @@ def params_to_hf_tensors(cfg: ModelConfig, params: dict,
     if "embed_tokens" in params:
         out[f"{pre}.embed_tokens.weight"] = _np(params["embed_tokens"]["weight"])
     if "norm" in params:
-        put_norm(f"{pre}.norm.weight", params["norm"]["weight"])
+        put_norm(f"{pre}.{ckpt.get('norm', 'norm')}.weight",
+                 params["norm"]["weight"])
     if "lm_head" in params:
         out["lm_head.weight"] = _np(params["lm_head"]["weight"])
 
@@ -40,7 +45,8 @@ def params_to_hf_tensors(cfg: ModelConfig, params: dict,
         for norm in ("input_layernorm", "post_attention_layernorm",
                      "pre_feedforward_layernorm", "post_feedforward_layernorm"):
             if norm in layer:
-                put_norm(f"{lp}.{norm}.weight", layer[norm]["weight"])
+                put_norm(f"{lp}.{ckpt.get(norm, norm)}.weight",
+                         layer[norm]["weight"])
         if "self_attn" in layer:
             a = layer["self_attn"]
             if fuse_phi:
@@ -59,6 +65,9 @@ def params_to_hf_tensors(cfg: ModelConfig, params: dict,
         if "linear_attn" in layer:
             from ..models.qwen3_5 import export_gdn_params
             out.update(export_gdn_params(cfg, layer["linear_attn"], lp))
+        if "mamba" in layer:
+            from ..models.jamba import export_mamba_params
+            out.update(export_mamba_params(layer["mamba"], lp))
         mlp = layer["mlp"]
         if "experts" in mlp:    # MoE
             out[f"{lp}.mlp.gate.weight"] = _np(mlp["gate"]["weight"])
@@ -80,5 +89,5 @@ def params_to_hf_tensors(cfg: ModelConfig, params: dict,
                 out[f"{lp}.mlp.down_proj.weight"] = _np(mlp["down_proj"]["weight"])
             else:
                 for proj in ("gate_proj", "up_proj", "down_proj"):
-                    out[f"{lp}.mlp.{proj}.weight"] = _np(mlp[proj]["weight"])
+                    out[f"{lp}.{ffn}.{proj}.weight"] = _np(mlp[proj]["weight"])
     return out

@@ -88,6 +88,13 @@ class ParamLoader:
     def _has(self, name: str) -> bool:
         return self.quant.has(self.st, name)
 
+    def _ckpt(self, name: str) -> str:
+        """The checkpoint's name for a leaf of the parameter tree."""
+        if self.cfg.mamba is not None:
+            from ..models.jamba import CHECKPOINT_NAMES
+            return CHECKPOINT_NAMES.get(name, name)
+        return name
+
     def _norm(self, name: str):
         """RMS-norm weight with the (1+w) residual pattern applied in f32 at
         load (ref: config.rs load_rms_norm_weight)."""
@@ -170,9 +177,13 @@ class ParamLoader:
         if spec.kind == "linear":
             from ..models.qwen3_5 import load_gdn_params
             p["linear_attn"] = load_gdn_params(self, lp)
+        elif spec.kind == "mamba":
+            from ..models.jamba import load_mamba_params
+            p["mamba"] = load_mamba_params(self, lp)
         else:
             p["self_attn"] = self._attention(lp, spec)
-        p["mlp"] = self._moe(f"{lp}.mlp") if spec.is_moe else self._mlp(f"{lp}.mlp")
+        mp = f"{lp}.{self._ckpt('mlp')}"
+        p["mlp"] = self._moe(mp) if spec.is_moe else self._mlp(mp)
         if spec.norm_style == "pre":
             names = ("input_layernorm", "post_attention_layernorm")
         elif spec.norm_style == "post":
@@ -181,7 +192,7 @@ class ParamLoader:
             names = ("input_layernorm", "post_attention_layernorm",
                      "pre_feedforward_layernorm", "post_feedforward_layernorm")
         for n in names:
-            p[n] = {"weight": self._norm(f"{lp}.{n}.weight")}
+            p[n] = {"weight": self._norm(f"{lp}.{self._ckpt(n)}.weight")}
         return p
 
     # -- public -------------------------------------------------------------
@@ -203,7 +214,8 @@ class ParamLoader:
             params["embed_tokens"] = {"weight": self._dev(
                 self._get_dense(f"{self.prefix}.embed_tokens.weight"))}
         if include_head:
-            params["norm"] = {"weight": self._norm(f"{self.prefix}.norm.weight")}
+            params["norm"] = {"weight": self._norm(
+                f"{self.prefix}.{self._ckpt('norm')}.weight")}
             if not cfg.tie_word_embeddings:
                 head = ("lm_head.weight" if self._has("lm_head.weight")
                         else f"{self.prefix}.lm_head.weight")

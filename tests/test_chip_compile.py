@@ -64,10 +64,10 @@ def _no_compile_cache():
 
 
 def _compile(sharding, sq, skv, *, d=D, append=False, window=None,
-             mesh=None, scalar_sharding=None):
+             mesh=None, scalar_sharding=None, hq=HQ, hkv=HKV):
     scalar_sharding = scalar_sharding or sharding
-    q = jax.ShapeDtypeStruct((1, sq, HQ, d), jnp.bfloat16, sharding=sharding)
-    kv = jax.ShapeDtypeStruct((1, skv, HKV, d), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((1, sq, hq, d), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((1, skv, hkv, d), jnp.bfloat16,
                               sharding=sharding)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar_sharding)
 
@@ -88,8 +88,11 @@ def _compile(sharding, sq, skv, *, d=D, append=False, window=None,
     (512, 512, {"d": 64}),
     (512, 512, {"d": 256}),
     (512, 512, {"window": 128}),                     # SWA layers
+    # Jamba2-3B's attention: a group of 20 query heads on ONE K/V head
+    (256, 256, {"hq": 20, "hkv": 1}),
+    (256, 4096, {"hq": 20, "hkv": 1, "append": True}),
 ], ids=["fresh512", "fresh4096", "append256x4096", "append256x32768",
-        "d64", "d256", "windowed"])
+        "d64", "d256", "windowed", "group20-fresh", "group20-append"])
 def test_flash_compiles_on_one_chip(one_chip, sq, skv, kw):
     compiled = _compile(one_chip, sq, skv, **kw)
     assert "tpu_custom_call" in compiled.as_text()

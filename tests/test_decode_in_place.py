@@ -6,7 +6,8 @@ and no write-back.
 Pinned here:
   * a half-active pool steps with every inactive row's K / V / pos / conv /
     state byte-identical and every active row's tokens equal to the same
-    request decoded alone — for a `full`, an `swa` and a `linear` family;
+    request decoded alone — for a `full`, an `swa`, a `linear` and a `mamba`
+    family;
   * the lowered programs hold no `slice` / `dynamic_slice` /
     `dynamic_update_slice` over an operand of the pool's leaf shape;
   * `decode_slots(..., nb=k)` for every rung of the paged ladder returns
@@ -34,6 +35,7 @@ FAMILIES = {
     "full": lambda: tiny_config("llama"),
     "swa": lambda: tiny_config("mistral", sliding_window=8),
     "linear": lambda: tiny_config("qwen3_5"),
+    "mamba": lambda: tiny_config("jamba"),
 }
 
 

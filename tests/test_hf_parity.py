@@ -277,6 +277,26 @@ def test_qwen3_moe():
                                 **_TINY_HF))
 
 
+def test_jamba():
+    """Mamba-1 layers with an attention layer without rope between them vs
+    HF Jamba's slow path (no fused kernels), dense FFNs."""
+    from transformers import JambaConfig, JambaForCausalLM
+    cfg = tiny_config("jamba")
+    assert [s.kind for s in cfg.layer_specs()] == ["mamba", "mamba", "full",
+                                                   "mamba"]
+    hf = JambaConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+        rms_norm_eps=1e-6, max_position_embeddings=128,
+        tie_word_embeddings=True, attn_layer_period=4, attn_layer_offset=2,
+        expert_layer_period=2, expert_layer_offset=1, num_experts=1,
+        num_experts_per_tok=1, mamba_expand=2, mamba_d_state=8,
+        mamba_d_conv=4, mamba_dt_rank=8, mamba_conv_bias=True,
+        mamba_proj_bias=False, use_mamba_kernels=False, pad_token_id=0,
+        bos_token_id=1, eos_token_id=2)
+    check_family(cfg, JambaForCausalLM, hf, allow_missing=("lm_head.weight",))
+
+
 # ---------------------------------------------------------------------------
 # diffusion text encoders (FLUX.1 / SD / SDXL conditioning)
 # ---------------------------------------------------------------------------

@@ -25,6 +25,7 @@ LEAVES = ("serve.sweep", "serve.admit", "serve.plan", "serve.decode_dispatch",
           "serve.prefill_chunk", "serve.prefill_finish", "serve.fetch",
           "serve.fanout")
 SCOPES = [name for name, _ in SCOPE_CATALOG]
+SSM = {s for s in SCOPES if s.startswith("cake.ssm")}
 # a scope in an op's name: `/cake.attn/`, or `vmap(cake.attn)/` where the
 # batching transform wraps the outermost one
 SCOPE_RE = r"[/(](cake\.[a-z_.]+)(?=[/)])"
@@ -121,7 +122,7 @@ def test_sync_mark_records_the_clock_tie():
 def test_catalogs_name_the_phases_and_scopes():
     spans = {n for n, _ in SPAN_CATALOG}
     assert set(LEAVES) | {"serve.step", "trace.sync"} <= spans
-    assert len(set(SCOPES)) == len(SCOPES) == 11
+    assert len(set(SCOPES)) == len(SCOPES) == 15
 
 
 # -- the engine: phases of one iteration ------------------------------------
@@ -177,9 +178,18 @@ def test_every_worked_step_has_one_step_span(traced):
     assert all({"slots", "queued", "id"} <= set(e["args"]) for e in steps)
 
 
+# phases that share a stamp: the one ends at the very microsecond the next
+# begins, so no clock and no load can open a hole between them
+TOUCHING = {("serve.sweep", "serve.admit"), ("serve.admit", "serve.plan"),
+            ("serve.plan", "serve.decode_dispatch"),
+            ("serve.fetch", "serve.fanout")}
+
+
 def test_phases_cover_the_step_without_overlap(traced):
     spans = traced["spans"]
-    for step in (e for e in spans if e["name"] == "serve.step"):
+    steps = [e for e in spans if e["name"] == "serve.step"]
+    over = []
+    for step in steps:
         kids = _children(spans, step)
         assert kids, step
         assert {k["args"]["step"] for k in kids} == {step["args"]["step"]}
@@ -187,15 +197,23 @@ def test_phases_cover_the_step_without_overlap(traced):
         assert kids[-1]["ts"] + kids[-1]["dur"] <= step["ts"] + step["dur"]
         for a, b in zip(kids, kids[1:]):
             assert a["ts"] + a["dur"] <= b["ts"], (a, b)
+            if (a["name"], b["name"]) in TOUCHING:
+                assert a["ts"] + a["dur"] == b["ts"], (a, b)
         names = [k["name"] for k in kids]
         assert names == [n for n in LEAVES if n in names]     # in order
         assert len(set(names)) == len(names)
-        # the holes are a fixed cost, not a share: the emission of these
-        # very spans after the last stamp, and the few lines between the
-        # decode dispatch and the chunk's own span (a 2 ms step on this CPU;
-        # 30-50 ms on the chip)
+        # what is left are the seams where a phase stamps its own clock:
+        # the few lines around the chunk's own span, and the emission of
+        # these very spans after the last stamp. They are a fixed cost, not
+        # a share (a 2 ms step on this CPU; 30-50 ms on the chip)
         hole = step["dur"] - sum(k["dur"] for k in kids)
-        assert hole <= 0.05 * step["dur"] + 500, (hole, step["dur"])
+        if hole > 0.05 * step["dur"] + 500:
+            over.append((step["args"]["step"], hole, step["dur"], names))
+    # A hole in the code opens in every step of its kind (a sixth of these
+    # steps carry a chunk); a scheduler that takes the core away under
+    # six test workers opens one in a step or two. So the bound holds over
+    # all steps as a share, not in each.
+    assert len(over) <= max(1, len(steps) // 10), over
 
 
 def test_a_step_that_decoded_fetched_and_fanned_out_once(traced):
@@ -297,12 +315,13 @@ def _lowered(model, program: str) -> str:
 
 @pytest.mark.parametrize("program", ["_decode_slots", "_prefill_slot"])
 def test_programs_carry_the_scopes_of_the_catalog(moe_model, program):
-    """The MoE family reaches every scope; the chunk program samples
-    nothing (its first token is drawn by a program of its own)."""
+    """The MoE family reaches every scope but the state-space mixer's; the
+    chunk program samples nothing (its first token is drawn by a program
+    of its own)."""
     text = _lowered(moe_model, program)
     found = set(re.findall(SCOPE_RE, text))
-    want = set(SCOPES) if program == "_decode_slots" else \
-        {s for s in SCOPES if not s.startswith("cake.sample")}
+    want = set(SCOPES) - SSM if program == "_decode_slots" else \
+        {s for s in SCOPES if not s.startswith("cake.sample")} - SSM
     assert found == want
     if program == "_decode_slots":
         assert "vmap(cake.sample)/cake.sample.sort/" in text
@@ -313,7 +332,24 @@ def test_programs_carry_the_scopes_of_the_catalog(moe_model, program):
 def test_dense_model_has_no_router_scope(model):
     found = set(re.findall(SCOPE_RE,
                            _lowered(model, "_decode_slots")))
-    assert found == set(SCOPES) - {"cake.ffn.route", "cake.ffn.experts"}
+    assert found == set(SCOPES) - SSM - {"cake.ffn.route",
+                                         "cake.ffn.experts"}
+
+
+@pytest.mark.parametrize("program", ["_decode_slots", "_prefill_slot"])
+def test_mamba_layers_trace_under_ssm_and_not_under_attn(program):
+    """A Mamba layer's mixer stands in cake.attn's place under a scope of
+    its own, so the readers of `programs.decode.*_ms` still add up; the
+    family's attention layers keep cake.attn."""
+    jamba = TextModel(tiny_config("jamba"), dtype=jnp.float32, seed=0,
+                      max_cache_len=CTX)
+    text = _lowered(jamba, program)
+    found = set(re.findall(SCOPE_RE, text))
+    assert SSM == {"cake.ssm", "cake.ssm.proj", "cake.ssm.conv",
+                   "cake.ssm.scan"} <= found
+    assert "cake.attn" in found and "cake.ffn.route" not in found
+    assert re.search(r"[/(]cake\.ssm[/)]/?cake\.ssm\.scan/", text)
+    assert not re.search(r"cake\.attn[/)][^\n]*cake\.ssm", text)
 
 
 def test_scopes_change_no_number_and_no_instruction(monkeypatch):

@@ -81,13 +81,20 @@ for a span emitted from stamps.
 A scheduler iteration that does work takes the next flight `seq` as its
 step id. Its `serve.step` span carries it as `step`; so do the leaf spans
 under it (`serve.sweep`, `serve.admit`, `serve.plan`,
-`serve.decode_dispatch`, `serve.prefill_chunk`, `serve.prefill_finish`,
-`serve.fetch`, `serve.fanout`: they cover the step end to end and do not
-overlap), the flight record, and the `decode` / `spec_verify` /
-`first_token` / `prefill_chunk` events of every request the step touched.
-So a token in `/api/v1/requests/<id>` names the iteration that produced
-it, and that iteration's spans say where its time went. The phases come
-from eight clock reads a step; with the recorder off nothing else is
+`serve.decode_dispatch`, `serve.fetch`, `serve.fanout`,
+`serve.prefill_chunk`, `serve.prefill_finish`, in the order a step runs
+them — an engine that lands each step in its own iteration, a drafter's
+or kvshare's, runs the chunk's two before the fetch: they cover the step
+end to end and do not overlap), the flight record,
+and the `decode` / `spec_verify` / `first_token` / `prefill_chunk` events
+of every request the step touched. The ids a step fetches and fans out
+are those of the decode step the iteration BEFORE it dispatched (its own
+is queued behind that one and runs while the host works): `serve.fetch`,
+`serve.fanout` and the token events carry that iteration's id as
+`of_step` beside their own `step`. So a token in
+`/api/v1/requests/<id>` names the iteration that delivered it and the
+one that dispatched it, and their spans say where its time went. The
+phases come from eight clock reads a step; with the recorder off nothing else is
 paid, and the same reads give the flight record its `host_ms` /
 `fetch_ms` split. `spec.verify` overlaps `serve.decode_dispatch` (both
 are children of `serve.step`).
@@ -112,7 +119,15 @@ position addresses, read and written whole by every step; 0 for a model
 with none),
 `fetch_ms` = the scheduler blocked
 on the device for the sampled ids, `host_ms` = the rest of the step's
-wall time, spec accepts, queue depth, paged-pool free/used) into a ring
+wall time, `lag` = 1 when the ids fetched were dispatched by an earlier
+iteration and this one's own decode step was queued behind them before
+the fetch (the share of decode iterations with `lag` 1 is how often the
+device went from one step straight into the next), 0 when they were
+fetched with nothing queued behind (a drafter's or kvshare's engine, a
+paged preemption, the iteration that finds nothing to dispatch) or none
+were, `dropped` = ids fetched and not delivered because their request
+had ended since the dispatch (over the tokens: what the lag wastes),
+spec accepts, queue depth, paged-pool free/used) into a ring
 of the last `CAKE_FLIGHT_RECORDER` iterations: a stuck or slow step says
 which side of the fetch it was on. An iteration that failed or found
 nothing to do leaves its `seq` out of the ring. The supervisor dumps the ring to `CAKE_TRACE_DIR` as JSON

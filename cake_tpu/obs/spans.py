@@ -242,11 +242,17 @@ SPAN_CATALOG: tuple[tuple[str, str], ...] = (
     ("serve.prefill_finish", "serve engine: prefix-cache block capture "
                              "and, on the last chunk, first-token sample "
                              "and slot activation (args: final)"),
-    ("serve.fetch", "serve engine: the one device->host fetch of the "
-                    "packed ids; the scheduler is blocked on the device"),
-    ("serve.fanout", "serve engine: sampled ids fanned out to the "
-                     "streams, finished rows released (args: tokens, "
-                     "finished)"),
+    ("serve.fetch", "serve engine: the one device->host fetch of packed "
+                    "ids, those of the step iteration `of_step` "
+                    "dispatched; the scheduler is blocked on the device "
+                    "(args: of_step, lag = 1 when that was an earlier "
+                    "iteration and this one's own decode step was "
+                    "already queued behind it, else 0)"),
+    ("serve.fanout", "serve engine: the fetched ids fanned out to the "
+                     "streams of the requests that were active when "
+                     "`of_step` dispatched, finished rows released (args: "
+                     "of_step, lag, tokens, finished, dropped = ids whose "
+                     "request had ended since)"),
     ("serve.replay", "serve engine: one slot's crash/preemption replay"),
     ("spec.verify", "speculative verify dispatch (generate path and "
                     "batched serve path)"),

@@ -79,13 +79,16 @@ EVENT_KINDS: dict[str, str] = {
     "prefill_done": "prompt fully prefilled; first token sampled "
                     "(`chunks`, `hit_tokens`)",
     "first_token": "first token fetched to the host (client-visible "
-                   "TTFT stamps here; `step`)",
-    "decode": "one batched decode iteration this slot participated in "
-              "(`bucket` = rows the dispatched program ran, `step` = the "
-              "iteration's flight `seq`, which its `serve.*` spans carry)",
+                   "TTFT stamps here; `step`, `of_step`)",
+    "decode": "one batched decode step this slot participated in, as "
+              "its id reaches the host (`bucket` = rows the dispatched "
+              "program ran, `step` = the flight `seq` of the iteration "
+              "that fetched and fanned the id out, which its `serve.*` "
+              "spans carry, `of_step` = that of the iteration that had "
+              "dispatched the step: the one before, or the same)",
     "spec_verify": "one batched speculative verify this slot "
-                   "participated in (`step`, `bucket`, `proposed`, "
-                   "`accepted`)",
+                   "participated in (`step`, `of_step`, `bucket`, "
+                   "`proposed`, `accepted`)",
     "preempt": "slot evicted under KV-pool pressure (`mode` = "
                "swap | recompute | requeue, `tokens`)",
     "resume": "preempted request re-entered a slot (`mode`, `slot`)",

@@ -23,11 +23,21 @@ paging — slots here are whole KV rows of a preallocated batch-B cache):
     which every slot carries its own draft window and accepts a RAGGED
     per-slot prefix (Leviathan-style speculative decoding folded into
     continuous batching; the paged layout moves each slot's block cursor
-    by its accepted length). Either way the iteration fans each slot's
+    by its accepted length). Either way an iteration fans each slot's
     new tokens out to its request's stream — decode latency under
     admission is bounded by the CHUNK, not the prompt, which kills the
     head-of-line blocking a monolithic prefill imposed on every active
     decode;
+  * the fetch of a step's sampled ids LAGS its dispatch by one iteration:
+    every carry the next step needs is device-resident, so iteration k
+    dispatches step k and only then fetches step k-1's ids — the device
+    runs step k while the host fans out, sweeps, admits and plans, and a
+    step costs max(device, host) instead of their sum. What the host
+    learns one step late (EOS, a spent budget, a cancel) costs the row
+    one masked-in step whose id is dropped; fan-out goes by the (slot,
+    request) pairs recorded at dispatch, never by what a slot holds now.
+    The lag is 0 where the next plan needs every id on the host (a
+    drafter, kvshare's stream parking, a paged preemption) — `_step` 4.;
   * EOS / budget / client-cancel free the slot for the next admission.
 
 Every jax call happens on the scheduler thread, so the engine needs no
@@ -154,6 +164,24 @@ class _Prefill:
         self.next_block = 0     # next prefix-cache block index to capture
         self.hit_tokens = 0     # tokens skipped via prefix-cache splice
         self.keys: list = []    # per-block hash chain (computed once)
+
+
+class _InFlight:
+    """A dispatched decode step whose packed ids the host has not fetched
+    yet. `rows` is the (slot, request) snapshot taken at dispatch: by the
+    time the ids are fanned out a slot may have been freed and given to
+    another request, so fan-out goes by this record and never by what the
+    slot holds now."""
+
+    __slots__ = ("step", "packed", "rows", "ids", "nb", "spec")
+
+    def __init__(self, step: int, rows: list, nb: int, spec):
+        self.step = step        # id of the dispatching iteration
+        self.packed = None      # device array [2|3, slots], set by dispatch
+        self.rows = rows        # [(slot, ServeRequest)] active at dispatch
+        self.ids = tuple(r.id for _, r in rows)
+        self.nb = nb            # rows the program ran (the flight `bucket`)
+        self.spec = spec        # (drafts, n_drafts) of a verify step, or None
 
 
 class ServeRequest:
@@ -389,6 +417,12 @@ class ServeEngine:
         # before the supervisor so the watchdog can always reach it
         self.flight = FlightRecorder()
         self._step_id = 0           # the running iteration's flight seq
+        # running totals the iteration's record takes differences of
+        # (_land): tokens emitted, requests a fan-out finished, ids fetched
+        # and not delivered (their request had ended), seconds blocked in
+        # a fetch
+        self._emitted = self._retired = self._dropped = 0
+        self._fetch_s = 0.0
         self._chunk_end = None      # (stamp, final) of its prefill chunk
         self.dead: BaseException | None = None
         # the supervisor needs _stop (watchdog lifetime) — build it after
@@ -438,6 +472,10 @@ class ServeEngine:
         self._rngs = jnp.stack([jax.random.PRNGKey(self._seed + i)
                                 for i in range(slots)])
         self._recents = jnp.full((slots, RECENT_N), -1, jnp.int32)
+        # the decode step dispatched and not yet fetched (_step 4.): what a
+        # failure leaves there is dropped with the pool it ran on, and the
+        # replay regenerates its ids as it does a crashed step's
+        self._inflight: _InFlight | None = None
         # decode-eligibility mask: True only for slots whose prefill has
         # COMPLETED. Mutated at transitions only (prefill done / release),
         # never donated — the engine keeps its handle across iterations,
@@ -673,7 +711,8 @@ class ServeEngine:
         from any thread; blocks the caller, not the scheduler."""
         self.begin_drain()
         deadline = None if timeout is None else now() + timeout
-        while self.pool.busy_count or self.queue.depth() or self._preempted:
+        while self.pool.busy_count or self.queue.depth() or self._preempted \
+                or self._inflight is not None:
             if self.dead is not None or not self._thread.is_alive():
                 return False
             if deadline is not None and now() >= deadline:
@@ -698,6 +737,8 @@ class ServeEngine:
                 if req is not None:
                     self._fail(req, EngineDown("serve engine shut down"))
             return
+        # ids still on the device belong to requests cancelled below
+        self._inflight = None
         self._prefills.clear()
         for entry in self._drain_preempted():
             self._fail(entry.req, EngineDown("serve engine shut down"))
@@ -818,13 +859,17 @@ class ServeEngine:
     def _step(self) -> bool:
         # kvshare mailbox FIRST — before the idle early-return below, so
         # an idle engine still serves blob export/import jobs (submit
-        # sets _wake, which lands the _run loop here)
+        # sets _wake, which lands the _run loop here). It parks streams by
+        # swapping out their carries, so an engine that has one runs at
+        # depth 0 (see 4. below) and nothing is in flight here
         ks = self.kv_share
         if ks is not None:
+            self._land_inflight()       # attached with a step in flight
             ks.run_pending()
         busy = self.pool.busy()
         queued = self.queue.depth() > 0
-        if not (busy or queued or self._preempted):
+        if not (busy or queued or self._preempted
+                or self._inflight is not None):
             return False
         # the iteration's id: its flight record's `seq`, carried by its
         # spans and by the timeline events it stamps
@@ -833,8 +878,8 @@ class ServeEngine:
                            queued=self.queue.depth(), step=step):
             # reset failure-attribution context: a crash in the host
             # bookkeeping below must not implicate the PREVIOUS step's
-            # request set (the decode/prefill dispatches re-arm with
-            # their own sets)
+            # request set (the decode/prefill dispatches and the fetch
+            # re-arm with their own sets)
             self.supervisor.arm("step", ())
             # phase boundaries: one clock read each, every read ending one
             # phase and opening the next, so the phases cover the step. The
@@ -843,9 +888,13 @@ class ServeEngine:
             # (_emit_phases) — nothing else is paid per step when it is off
             rec_on = RECORDER.enabled
             t_sweep = now()
+            emitted0, retired0 = self._emitted, self._retired
+            dropped0, fetch_s0 = self._dropped, self._fetch_s
             # 1. cancel sweeps: decoding slots, mid-prefill slots, and
             # abandoned-while-queued requests (those would otherwise pin
-            # queue capacity and 429 live clients while slots sit idle)
+            # queue capacity and 429 live clients while slots sit idle).
+            # A row finished here may still ride the step in flight: its
+            # id is dropped when that step is fanned out
             prefilling = {p.slot for p in self._prefills}
             for i in busy:
                 req = self._reqs[i]
@@ -911,7 +960,8 @@ class ServeEngine:
                 self._resume_preempted()
             while self.pool.free_count > 0 and self._start_admission():
                 pass
-            if not (self.pool.busy_count or self.queue.depth()):
+            if not (self.pool.busy_count or self.queue.depth()
+                    or self._inflight is not None):
                 # only parked entries remain and none could resume yet:
                 # report idle so _run waits on the wake event (0.5s
                 # heartbeat retries the resume) instead of hot-spinning
@@ -928,10 +978,11 @@ class ServeEngine:
             # 3a. choose the admission to advance this iteration (round-
             # robin) and, in paged mode, reserve its chunk's blocks NOW —
             # BEFORE the decode dispatch. The reservation may preempt a
-            # decoding victim, and preemption is only safe pre-dispatch:
-            # a swap-out after the decode was dispatched would capture
-            # post-step carries holding a sampled token the host never
-            # fanned out, silently dropping it from the stream on resume
+            # decoding victim, and preemption is only safe on carries the
+            # host has fanned out: a swap-out behind a step whose ids are
+            # still on the device would capture a sampled token the host
+            # never saw, silently dropping it from the stream on resume
+            # (_preempt_one lands the step in flight first)
             self._cur_nd = {}
             pf_job = None
             if self._prefills:
@@ -941,9 +992,9 @@ class ServeEngine:
             prefilling = {p.slot for p in self._prefills}   # post-admission
             active = [i for i in self.pool.busy()
                       if self._reqs[i] is not None and i not in prefilling]
-            # 3b. host-side draft building (the n-gram lookup runs while
-            # the PREVIOUS iteration's prefill chunk is still on the
-            # device — host work here is overlapped, not serialized)
+            # 3b. host-side draft building (the n-gram lookup reads the
+            # tokens the host holds, all of them: a drafter's engine runs
+            # at depth 0)
             spec_job = None
             if active and self.spec_drafter is not None:
                 spec_job = self._build_drafts(active)
@@ -953,11 +1004,10 @@ class ServeEngine:
                 # mapped BEFORE dispatch; exhaustion preempts a victim
                 # (which may shrink `active`) — see _ensure_decode_blocks
                 active = self._ensure_decode_blocks(active, spec_job)
-            packed = None
+            cur = None
             nb = kv_tokens = state_bytes = 0
             t_dispatch = now()
             spec_acc0 = self.spec_accepted
-            active_ids = tuple(self._reqs[i].id for i in active)
             if active:
                 # the rows the dispatched program runs: the contiguous one
                 # takes the whole pool in place under the active mask, the
@@ -965,22 +1015,24 @@ class ServeEngine:
                 nb = self.slots if self.paged is None else \
                     slot_bucket(active[-1] + 1, self.slots)
                 SERVE_BATCH_OCCUPANCY.observe(len(active))
-                # what the active rows hold (prompt + generated so far):
-                # nb * the context length minus this is the part of the
-                # pool a step that walks rows to their frontier leaves
-                # unread
-                kv_tokens = sum(len(self._reqs[i].prompt_ids)
-                                + len(self._reqs[i].tokens) for i in active)
+                rows = [(i, self._reqs[i]) for i in active]
+                # what the active rows hold (prompt + generated so far, as
+                # far as the host has them): nb * the context length minus
+                # this is the part of the pool a step that walks rows to
+                # their frontier leaves unread
+                kv_tokens = sum(len(r.prompt_ids) + len(r.tokens)
+                                for _, r in rows)
                 # and what they hold that no position addresses: recurrent
                 # state, read and written whole whatever the row's length
                 state_bytes = self._row_state_bytes * len(active)
                 # arm BEFORE the fault hook: an injected stall simulates a
                 # dispatch stuck on the device, and the watchdog must see
                 # it; real crashes here implicate every active request
-                self.supervisor.arm("decode", active_ids)
+                cur = _InFlight(step, rows, nb, spec_job)
+                self.supervisor.arm("decode", cur.ids)
                 hook = faults.FAULT_HOOK
                 if hook is not None:
-                    hook.on_decode([self._reqs[i] for i in active])
+                    hook.on_decode([r for _, r in rows])
                 if spec_job is not None:
                     drafts, n_drafts = spec_job
                     # static no-vocab-filters fast path: when no slot in
@@ -988,8 +1040,8 @@ class ServeEngine:
                     # its per-row sorts (at most one extra executable —
                     # traffic mixes flip between two programs, both warm
                     # in steady state)
-                    filt = any(config_has_filters(self._reqs[i].sampling)
-                               for i in active)
+                    filt = any(config_has_filters(r.sampling)
+                               for _, r in rows)
                     with RECORDER.span("spec.verify", cat="serve",
                                        slots=len(active),
                                        drafts=int(n_drafts.sum())):
@@ -1025,13 +1077,45 @@ class ServeEngine:
                         self._layers, self._toks, self._pos, self._rngs,
                         self._recents, self._temps, self._top_ks,
                         self._top_ps, self._pens, self._act)
-            t_prefill = now()
+                cur.packed = packed
+            # 4. ONE host fetch per iteration, and it LAGS the dispatch by
+            # one: the ids fetched here are those of the step the PREVIOUS
+            # iteration dispatched, whose successor is queued behind it
+            # already — so the device goes from one step into the next
+            # while the host fans out, sweeps, admits and plans. Every
+            # carry the successor needed was device-resident; what the
+            # host could not know when it dispatched (the previous step
+            # sampled EOS, spent a budget, or its client left) costs that
+            # row one more step whose id `_fanout` drops. The ids go out
+            # to their streams at once, before the chunk below: what the
+            # chunk's dispatch costs the host must not hold back tokens
+            # that are already here. (An iteration with nothing to
+            # dispatch lands what is in flight all the same and leaves
+            # nothing behind, so the engine never idles on a step; a paged
+            # preemption lands it before it swaps anything out,
+            # `_preempt_one`.)
+            prev = self._inflight
+            lag = int(prev is not None and cur is not None)
+            # The depth is 0 — the step just dispatched is landed in its
+            # own iteration, AFTER the chunk (6.) — where the next plan
+            # needs every id on the host: a drafter proposes from
+            # `req.tokens`, kvshare parks streams by their carries. Such
+            # an engine never has a step in flight here.
+            keep = self.spec_drafter is None and ks is None
+            self._inflight = cur if keep else None
+            of_step = t_fan = None
+            t_land = now()
+            if prev is not None:
+                of_step, t_fan = prev.step, self._land(prev)
+            t_chunk = now()
             self._chunk_end = None
-            # 4. ...then advance the chosen admission by one chunk.
-            # Dispatch order matters: the decode program is already queued
-            # on the device, so the packed-ids fetch below never waits for
-            # this chunk — on real hardware the chunk overlaps the host's
-            # token fan-out. (Its blocks were reserved in 3a; the job may
+            # 5. ...then advance the chosen admission by one chunk, AFTER
+            # the lagged fetch: the step fetched has ended, so the chunk
+            # queued behind it has begun and at most one other waits
+            # behind the running one (a reader of the trace ties a chunk's
+            # execution to the last `serve.prefill_chunk` span that began
+            # before it). The device's queue still holds this iteration's
+            # decode step. (Its blocks were reserved in 3a; the job may
             # have been requeued by a decode slot's own preemption since,
             # hence the membership re-check.)
             if pf_job is not None and pf_job in self._prefills:
@@ -1040,53 +1124,34 @@ class ServeEngine:
                     self._rr = idx + 1      # still in flight: move past it
                 else:
                     self._rr = idx          # removed: next job slid here
-            # 5. ONE host fetch per iteration: fan the sampled ids out
-            t_fetch = t_fanout = now()
-            tokens = finished = 0
-            if packed is not None:
-                # the fetch is where an async device failure (or a wedge)
-                # actually materializes on the host: re-arm with the
-                # decode set so the supervisor attributes it correctly
-                # even if a prefill chunk was dispatched in between
-                self.supervisor.arm("decode", active_ids)
-                # lint: disable=host-sync — THE one planned fetch per iteration: the
-                # packed ids ([input;sampled], or [input;n_acc;next] on a
-                # speculative iteration) for every slot in one transfer,
-                # after the next work is already dispatched
-                arr = np.asarray(packed)
-                t_fanout = now()
-                if rec_on:
-                    live = [self._reqs[i] for i in active]
-                    tokens = -sum(len(r.tokens) for r in live)
-                    finished = self.pool.busy_count
-                if spec_job is not None:
-                    self._fanout_spec(active, arr, spec_job[0],
-                                      spec_job[1], nb)
-                else:
-                    self._fanout(active, arr, nb)
-                if rec_on:
-                    tokens += sum(len(r.tokens) for r in live)
-                    finished -= self.pool.busy_count
+            # 6. at depth 0 this iteration's own step lands here, behind
+            # the chunk's dispatch: the chunk runs while the host fans out
+            t_late = now()
+            if cur is not None and not keep:
+                of_step, t_fan = cur.step, self._land(cur)
             t_end = now()
+            tokens = self._emitted - emitted0
+            dropped = self._dropped - dropped0
             if rec_on:
                 self._emit_phases(
-                    step, (t_sweep, t_admit, t_plan, t_dispatch, t_prefill,
-                           t_fetch, t_fanout, t_end),
+                    step, (t_sweep, t_admit, t_plan, t_dispatch, t_land,
+                           t_chunk, t_late, t_end), t_fan,
                     admitted=admitted, slots=len(active), bucket=nb,
                     kv_tokens=kv_tokens, state_bytes=state_bytes,
-                    decoded=packed is not None,
-                    tokens=tokens,
-                    finished=finished)
+                    decoded=bool(active), of_step=of_step, lag=lag,
+                    tokens=tokens, finished=self._retired - retired0,
+                    dropped=dropped)
             # flight record: one bounded dict per iteration — the black
             # box the supervisor dumps on wedge/DOWN (see flight.py).
             # fetch_ms is the scheduler blocked on the device, host_ms the
             # rest of the step: which side of the fetch a slow step was on
-            fetch_s = t_fanout - t_fetch
+            fetch_s = self._fetch_s - fetch_s0
             rec = {
                 "occupancy": len(active), "bucket": nb,
                 "kv_tokens": kv_tokens, "state_bytes": state_bytes,
                 "host_ms": round((t_end - t_sweep - fetch_s) * 1e3, 3),
                 "fetch_ms": round(fetch_s * 1e3, 3),
+                "lag": lag, "dropped": dropped,
                 "queued": self.queue.depth(),
                 "prefilling": len(self._prefills),
                 "spec_accepted": self.spec_accepted - spec_acc0,
@@ -1097,37 +1162,80 @@ class ServeEngine:
             self.flight.record(step, **rec)
         return True
 
-    def _emit_phases(self, step: int, t: tuple, *, admitted: int,
-                     slots: int, bucket: int, kv_tokens: int,
-                     state_bytes: int, decoded: bool,
-                     tokens: int, finished: int):
+    def _land(self, fl: _InFlight) -> float:
+        """Fetch one dispatched step's ids and fan them out to their
+        streams; returns the stamp between the two. `_step` lands the
+        previous step once a successor is queued behind it (or its own, at
+        depth 0); `_land_inflight` lands it out of that order. Either way
+        the iteration's record takes its `fetch_ms`, tokens and `dropped`
+        from the running totals this moves.
+
+        The fetch is where an async device failure (or a wedge) of that
+        step materializes: armed with ITS request set, whichever iteration
+        dispatched it and whatever was dispatched since; a crash in the
+        fan-out implicates the same set."""
+        t0 = now()
+        self.supervisor.arm("decode", fl.ids)
+        # lint: disable=host-sync — THE one planned fetch per iteration: the
+        # packed ids ([input;sampled], or [input;n_acc;next] on a
+        # speculative step) for every slot in one transfer
+        arr = np.asarray(fl.packed)
+        t1 = now()
+        self._fetch_s += t1 - t0
+        busy = self.pool.busy_count
+        self._fanout(fl, arr)
+        self._retired += busy - self.pool.busy_count
+        return t1
+
+    def _land_inflight(self):
+        """Land the step in flight NOW, if there is one: the caller is
+        about to read or move carries and needs every sampled id on the
+        host first. Under no span of its own."""
+        fl, self._inflight = self._inflight, None
+        if fl is not None:
+            self._land(fl)
+
+    def _emit_phases(self, step: int, t: tuple, t_fan: float | None, *,
+                     admitted: int, slots: int, bucket: int, kv_tokens: int,
+                     state_bytes: int, decoded: bool, of_step: int | None,
+                     lag: int, tokens: int, finished: int, dropped: int):
         """The children of `serve.step` from the step's own stamps (seconds
-        on obs.now()'s clock, which is the recorder's). The prefill
-        interval is split at the end of the chunk's dispatch: the
-        `serve.prefill_chunk` span records itself (the roofline reader
+        on obs.now()'s clock, which is the recorder's), in the order the
+        step runs them: sweep, admit, plan, this step's decode dispatch,
+        the fetch of the ids of step `of_step` and their fan-out (`t_fan`
+        is the stamp between the two), the chunk — or, at depth 0, the
+        chunk and then the fetch and the fan-out of this step's own ids.
+        The prefill interval is split at the end of the chunk's dispatch:
+        the `serve.prefill_chunk` span records itself (the roofline reader
         ties device executions to it), the remainder is
         `serve.prefill_finish`. Recorder-on only."""
-        t_sweep, t_admit, t_plan, t_dispatch, t_prefill, t_fetch, \
-            t_fanout, t_end = (int(x * 1e6) for x in t)
+        t_sweep, t_admit, t_plan, t_dispatch, t_land, t_chunk, \
+            t_late, t_end = (int(x * 1e6) for x in t)
 
         def add(name, t0, t1, **args):
             RECORDER.add(name, t0, t1 - t0, cat="serve", step=step, **args)
+
+        def landed(t0, t1):
+            mid = int(t_fan * 1e6)
+            add("serve.fetch", t0, mid, of_step=of_step, lag=lag)
+            add("serve.fanout", mid, t1, of_step=of_step, lag=lag,
+                tokens=tokens, finished=finished, dropped=dropped)
 
         add("serve.sweep", t_sweep, t_admit)
         add("serve.admit", t_admit, t_plan, admitted=admitted)
         add("serve.plan", t_plan, t_dispatch)
         if decoded:
-            add("serve.decode_dispatch", t_dispatch, t_prefill,
+            add("serve.decode_dispatch", t_dispatch, t_land,
                 slots=slots, bucket=bucket, kv_tokens=kv_tokens,
                 state_bytes=state_bytes)
+        if of_step is not None and of_step != step:
+            landed(t_land, t_chunk)
         if self._chunk_end is not None:
-            t_chunk, final = self._chunk_end
-            add("serve.prefill_finish", int(t_chunk * 1e6), t_fetch,
+            t_done, final = self._chunk_end
+            add("serve.prefill_finish", int(t_done * 1e6), t_late,
                 final=final)
-        if decoded:
-            add("serve.fetch", t_fetch, t_fanout)
-            add("serve.fanout", t_fanout, t_end, tokens=tokens,
-                finished=finished)
+        if of_step == step:
+            landed(t_late, t_end)
 
     # -- chunked admission --------------------------------------------------
 
@@ -1389,9 +1497,15 @@ class ServeEngine:
             req = self._reqs[i]
             if req is None:
                 continue        # preempted by an earlier slot's ensure
-            wp = len(req.prompt_ids) + max(len(req.tokens) - 1, 0)
+            # the row's write position as the DEVICE has it: one past the
+            # host's count while a step that carries the row is in flight
+            # (landing that step moves a token from the one to the other)
+            fl = self._inflight
+            wp = len(req.prompt_ids) + max(len(req.tokens) - 1, 0) \
+                + (fl is not None and (i, req) in fl.rows)
             reach = 1 + (int(n_drafts[i]) if n_drafts is not None else 0)
-            while not self.paged.reserve_range(i, wp, reach):
+            while self._reqs[i] is req \
+                    and not self.paged.reserve_range(i, wp, reach):
                 if reach > 1:
                     # speculation never costs anyone their blocks: under
                     # pressure the slot DROPS its draft window to a plain
@@ -1432,7 +1546,15 @@ class ServeEngine:
         class, returns "self": the caller's slot must park itself
         rather than displace higher-class work (a batch decoder never
         requeues an interactive admission). False = nothing left to
-        reclaim or preempt."""
+        reclaim or preempt.
+
+        Before any of it, the step in flight lands: a victim's carries
+        and `req.tokens` must hold every id its row has sampled (a
+        swap-out behind unfetched ids would lose one on resume), and what
+        that fan-out finishes frees blocks for nothing."""
+        if self._inflight is not None:
+            self._land_inflight()
+            return True
         if self.spec_drafter is not None and self._trim_spec_tails(exclude):
             return True
 
@@ -1773,6 +1895,7 @@ class ServeEngine:
                 self._reqs[i] = None
                 self._fail(req, err)
         self.pool = SlotPool(self.slots)
+        self._inflight = None
         self._act = jnp.zeros((self.slots,), jnp.bool_)
         # drop the device pool AND the prefix cache's blocks: an
         # oom-downed engine must not pin the old HBM while the restore
@@ -1843,75 +1966,66 @@ class ServeEngine:
                 i, wp + self._cur_nd.get(i, 0) + 1)
         return freed > 0
 
-    def _fanout_spec(self, active: list[int], arr: np.ndarray, drafts,
-                     n_drafts, nb: int):
-        """Fan one speculative iteration's packed ids out to the streams:
-        row 0 carries each slot's input token (a just-activated slot's
-        unemitted FIRST token), row 1 its accepted-draft count, row 2 the
-        verify step's correction/bonus token. The host already knows the
-        drafts it proposed, so n_acc + 1 tokens per slot ride a fetch no
-        bigger than the plain decode path's."""
-        step = self._step_id
-        for i in active:
-            req = self._reqs[i]
+    def _fanout(self, fl: _InFlight, arr: np.ndarray):
+        """Fan one step's packed ids out to the streams of the requests
+        that were active when it was DISPATCHED (`fl.rows`, not what the
+        slots hold now). Row 0 carries each slot's input token (a
+        just-activated slot's unemitted FIRST token: the first step a row
+        is active in is the one whose row 0 it gets); a plain step's row 1
+        is the token it sampled; a verify step's row 1 is its
+        accepted-draft count and row 2 its correction/bonus token — the
+        host already knows the drafts it proposed, so n_acc + 1 tokens per
+        slot ride a fetch no bigger than the plain decode path's.
+
+        A request that ended between the dispatch and this fan-out (EOS or
+        a spent budget in the step before, a cancel, a deadline) ran this
+        step for nothing: its id is dropped and counted, and whoever holds
+        its slot now never sees it."""
+        step, nb = self._step_id, fl.nb
+        drafts, n_drafts = fl.spec or (None, None)
+        for i, req in fl.rows:
+            if self._reqs[i] is not req:
+                self._dropped += 1
+                continue
             if req._first_pending:
                 req._first_pending = False
-                req.t_first = now()
+                req.t_first = now()     # first token actually on host:
                 req.stats["ttft_s"] = req.t_first - req.t_enqueue
-                TIMELINES.event(req.id, "first_token", step=step)
+                TIMELINES.event(req.id, "first_token", step=step,
+                                of_step=fl.step)
                 first = int(arr[0, i])
                 self._emit(req, first)
                 if self.model.cfg.is_eos(first) or req.budget <= 0:
+                    # the overshoot token is discarded — a wasted slot-row
+                    # step, no recompute
                     self._finish(i, req)
                     continue
-            n_prop = int(n_drafts[i])
-            n_acc, nxt = int(arr[1, i]), int(arr[2, i])
+            if drafts is None:
+                n_prop, new = 0, (int(arr[1, i]),)
+            else:
+                n_prop, n_acc = int(n_drafts[i]), int(arr[1, i])
+                new = [int(t) for t in drafts[i, :n_acc]] + [int(arr[2, i])]
             if n_prop:
                 self.spec_steps += 1
                 self.spec_proposed += n_prop
                 self.spec_accepted += n_acc
                 record_step(n_prop, n_acc, bucket=nb)
                 TIMELINES.event(req.id, "spec_verify", step=step,
-                                bucket=nb, proposed=n_prop, accepted=n_acc)
+                                of_step=fl.step, bucket=nb,
+                                proposed=n_prop, accepted=n_acc)
             else:
-                TIMELINES.event(req.id, "decode", step=step, bucket=nb)
-            for t in list(drafts[i, :n_acc]) + [nxt]:
+                TIMELINES.event(req.id, "decode", step=step,
+                                of_step=fl.step, bucket=nb)
+            for tid in new:
                 req.budget -= 1
-                self._emit(req, int(t))
-                if self.model.cfg.is_eos(int(t)) or req.budget <= 0:
+                self._emit(req, tid)
+                if self.model.cfg.is_eos(tid) or req.budget <= 0:
                     self._finish(i, req)
                     break
 
-    # -- batched decode -----------------------------------------------------
-
-    def _fanout(self, active: list[int], arr: np.ndarray, nb: int):
-        """Fan one decode iteration's packed ids out to the streams: row 0
-        carries each slot's input token (a just-activated slot's unemitted
-        FIRST token), row 1 the token this step sampled."""
-        step = self._step_id
-        for i in active:
-            req = self._reqs[i]
-            TIMELINES.event(req.id, "decode", step=step, bucket=nb)
-            if req._first_pending:
-                req._first_pending = False
-                req.t_first = now()     # first token actually on host:
-                req.stats["ttft_s"] = req.t_first - req.t_enqueue
-                TIMELINES.event(req.id, "first_token", step=step)
-                first = int(arr[0, i])
-                self._emit(req, first)
-                if self.model.cfg.is_eos(first) or req.budget <= 0:
-                    # this step's overshoot token is discarded — one
-                    # wasted slot-row step, no recompute
-                    self._finish(i, req)
-                    continue
-            tid = int(arr[1, i])
-            req.budget -= 1
-            self._emit(req, tid)
-            if self.model.cfg.is_eos(tid) or req.budget <= 0:
-                self._finish(i, req)
-
     def _emit(self, req: ServeRequest, tid: int):
         req.tokens.append(tid)
+        self._emitted += 1
         if not req.cancelled.is_set():
             req._deliver(self.model._mk_token(tid))
 

@@ -8,7 +8,11 @@ gone: the span recorder is off by default and metrics are aggregates.
 This module is the black box: every completed scheduler iteration
 appends one small record (occupancy, dispatch bucket, the tokens the
 stepping rows hold and the bytes of recurrent state beside them, the step's wall
-time split at the device fetch into `host_ms` and `fetch_ms`, spec accept
+time split at the device fetch into `host_ms` and `fetch_ms`, `lag` = 1
+when that fetch held the ids of the step an EARLIER iteration dispatched
+and ran under this iteration's own, already queued — 0 when nothing was
+queued behind it or nothing was fetched — `dropped` = ids fetched and not
+delivered because their request had ended since the dispatch, spec accept
 counts, queue depth, KV-pool occupancy) into a
 ring of the last `CAKE_FLIGHT_RECORDER` iterations, and the supervisor
 dumps the ring to `CAKE_TRACE_DIR` as JSON when the watchdog flags a

@@ -298,6 +298,12 @@ def test_paged_exhaustion_preempts_then_bit_identical(model, mode):
         assert h["preempted_slots"] == 0            # everyone resumed
         if mode == "swap":
             assert h["swaps"] >= 1
+        # the paged pool runs with its fetch one step behind too, and a
+        # preemption first lands the step in flight: an iteration that
+        # dispatched a step and fetched with none queued behind the fetch
+        decoded = [r for r in eng.flight.snapshot() if r["occupancy"]]
+        assert sum(r["lag"] for r in decoded) > len(decoded) // 2
+        assert any(r["lag"] == 0 and r["fetch_ms"] > 0 for r in decoded[1:])
     finally:
         eng.close()
 

@@ -1077,3 +1077,208 @@ def test_engine_continuation_splice_bit_identical(model, engine):
     assert resumed.wait(120)
     assert resumed.result["tokens"] == toks[k:]
     assert resumed.result["stats"].get("continuation") is True
+
+
+# ---------------------------------------------------------------------------
+# the lagged fetch (ISSUE 37): a decode step is dispatched before the step
+# before it is fetched, so fan-out goes by the record taken at dispatch
+# ---------------------------------------------------------------------------
+
+class _Abstains:
+    """A drafter that never proposes: the engine sees a drafter and runs
+    at depth 0 (every step fetched before the next is planned), on the
+    plain decode program — the schedule this engine had before the lag."""
+
+    name, shareable = "abstains", True
+
+    def propose(self, ids, k):
+        return []
+
+    def reset(self):
+        pass
+
+
+def _settle(eng, timeout=60.0):
+    """Wait until the scheduler has nothing busy, queued or in flight and
+    the iteration that saw so has written its record; returns the ring."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        ring = eng.flight.snapshot()
+        if eng._inflight is None and not eng.pool.busy_count \
+                and not eng.queue.depth() and ring \
+                and not ring[-1]["occupancy"]:
+            return ring
+        time.sleep(0.005)
+    raise AssertionError("engine did not go idle")
+
+
+def _delivered(req):
+    """Subscribe to a request's stream: the token ids delivered so far
+    and to come, in order (DONE excluded)."""
+    got = []
+
+    def on_item(item):
+        if item is not req.DONE:
+            got.append(item.id)
+
+    for item in req.subscribe(on_item):
+        on_item(item)
+    return got
+
+
+_STREAMS = ((P_LONG, 12), (P_A, 3), (P_B, 9), (P_A + P_B, 5), (P_B[::-1], 7))
+
+
+@pytest.mark.parametrize("scfg", [
+    GREEDY,
+    SamplingConfig(temperature=0.9, top_k=40, top_p=0.95,
+                   repeat_penalty=1.1)], ids=["greedy", "sampled"])
+def test_lagged_streams_equal_the_unlagged_schedule(model, scfg):
+    """Five requests through two slots (so slots free and are taken again
+    mid-run): every stream is the same function of (seed, prompt,
+    sampling, admission order) whether each step's ids are fetched one
+    iteration late or before the next step is planned — the lag changes
+    when the host learns a token, never which token it is."""
+    runs = {}
+    for name, spec in (("lagged", False), ("unlagged", _Abstains())):
+        eng = ServeEngine(model, slots=2, max_queue=8, ctx_len=CTX, seed=7,
+                          spec=spec)
+        try:
+            reqs = [eng.submit(p, max_new_tokens=n, sampling=scfg)
+                    for p, n in _STREAMS]
+            for r in reqs:
+                assert r.wait(300) and "error" not in r.result
+            runs[name] = ([r.result["tokens"] for r in reqs],
+                          _settle(eng) if not spec else eng.flight.snapshot())
+        finally:
+            eng.close()
+    (lagged, ring), (unlagged, ring0) = runs["lagged"], runs["unlagged"]
+    assert lagged == unlagged
+    assert [len(t) for t in lagged] == [n for _, n in _STREAMS]
+    if scfg is GREEDY:
+        assert lagged == [_ref(model, p, n) for p, n in _STREAMS]
+    else:
+        assert lagged != [_ref(model, p, n) for p, n in _STREAMS]
+    decoded = [r for r in ring if r["occupancy"]]
+    assert sum(r["lag"] for r in decoded) >= len(decoded) - 2
+    assert sum(r["dropped"] for r in ring) == len(_STREAMS)
+    assert all(r["lag"] == 0 and r["dropped"] == 0 for r in ring0)
+
+
+@pytest.mark.parametrize("ending", ["max_tokens", "eos", "cancel"])
+def test_request_ending_with_a_step_in_flight_gets_exactly_its_tokens(
+        model, monkeypatch, ending):
+    """The host learns that a stream ended one step late: the row has run
+    one more step by then. That step's id is dropped and counted, never
+    delivered; what was delivered is the stream, to the token."""
+    import dataclasses
+    n = 9
+    ref = _ref(model, P_LONG, n)
+    if ending == "eos":
+        # the 6th token becomes the stop token
+        eos = ref[5]
+        assert eos not in ref[:5]
+        monkeypatch.setattr(model, "cfg", dataclasses.replace(
+            model.cfg, eos_token_ids=(eos,)))
+        want = ref[:6]
+    eng = ServeEngine(model, slots=2, max_queue=4, ctx_len=CTX)
+    try:
+        req = eng.submit(P_LONG, max_new_tokens=200 if ending == "cancel"
+                         else n, sampling=GREEDY)
+        got = _delivered(req)
+        if ending == "cancel":
+            while len(req.tokens) < 4:
+                time.sleep(0.002)
+            req.cancel()
+        assert req.wait(120) and "error" not in req.result
+        ring = _settle(eng)
+    finally:
+        eng.close()
+    if ending == "cancel":
+        long_ref = _ref(model, P_LONG, len(req.tokens))
+        assert 4 <= len(got) <= len(req.tokens) < 200
+        assert req.tokens == long_ref and got == long_ref[:len(got)]
+    else:
+        want = ref if ending == "max_tokens" else want
+        assert req.result["tokens"] == want == got
+    assert sum(r["dropped"] for r in ring) == 1
+    assert ring[-1]["dropped"] == 1 and ring[-1]["occupancy"] == 0
+
+
+def test_readmitted_slot_never_gets_the_old_tenants_id(model):
+    """One slot, two requests: the second takes the slot in the very
+    iteration that fans out the first one's last, overshot step. That id
+    belongs to nobody; the new tenant's stream starts with its own first
+    token and is its own to the end."""
+    eng = ServeEngine(model, slots=1, max_queue=4, ctx_len=CTX)
+    try:
+        first = eng.submit(P_A, max_new_tokens=5, sampling=GREEDY)
+        second = eng.submit(P_B, max_new_tokens=6, sampling=GREEDY)
+        got = [_delivered(first), _delivered(second)]
+        assert first.wait(120) and second.wait(120)
+        ring = _settle(eng)
+    finally:
+        eng.close()
+    assert first.result["tokens"] == got[0] == _ref(model, P_A, 5)
+    assert second.result["tokens"] == got[1] == _ref(model, P_B, 6)
+    assert second.result["stats"]["ttft_s"] > 0
+    # the overshot step of `first` was fanned out with `second` in its slot
+    drops = [r for r in ring if r["dropped"]]
+    assert [r["dropped"] for r in drops] == [1, 1]
+    # the first: `second` held the slot, still prefilling, so no step went
+    assert drops[0]["occupancy"] == 0 and drops[0]["queued"] == 0
+    assert drops[0]["seq"] < drops[1]["seq"] == ring[-1]["seq"]
+
+
+def test_engine_goes_idle_with_nothing_in_flight(model):
+    """The iteration that finds nothing to dispatch fetches the step in
+    flight (`lag` 0: no program of its own is queued behind the fetch)
+    and only then does the scheduler wait for work."""
+    eng = ServeEngine(model, slots=2, max_queue=4, ctx_len=CTX)
+    try:
+        req = eng.submit(P_LONG, max_new_tokens=6, sampling=GREEDY)
+        assert req.wait(120)
+        ring = _settle(eng)
+        last = ring[-1]
+        assert last["occupancy"] == 0 and last["lag"] == 0
+        assert last["fetch_ms"] > 0 and last["dropped"] == 1
+        assert ring[-2]["occupancy"] == 1 and ring[-2]["lag"] == 1
+        # the first step after idle has nothing to fetch
+        first = next(r for r in ring if r["occupancy"])
+        assert first["lag"] == 0 and first["fetch_ms"] == 0
+        # (`steps` moves after `_step` has written its record: let the
+        # last iteration's count land before reading it)
+        time.sleep(0.05)
+        steps = eng.steps
+        time.sleep(0.1)
+        assert eng.steps == steps and eng._inflight is None
+        assert eng.flight.snapshot()[-1]["seq"] == last["seq"]
+        # and it wakes up again
+        again = eng.submit(P_A, max_new_tokens=3, sampling=GREEDY)
+        assert again.wait(120)
+        assert again.result["tokens"] == _ref(model, P_A, 3)
+    finally:
+        eng.close()
+
+
+def test_drafter_engine_fetches_every_step_before_the_next_plan(model):
+    """An n-gram drafter proposes from the tokens the host holds: its
+    engine fetches each step in the iteration that dispatched it (`lag` 0
+    throughout, nothing ever dropped or left in flight)."""
+    prompt = [5, 9, 5, 9, 5, 9, 5, 9, 5, 9, 5]
+    eng = ServeEngine(model, slots=2, max_queue=4, ctx_len=CTX,
+                      spec="ngram", spec_k=4)
+    try:
+        reqs = [eng.submit(p, max_new_tokens=n, sampling=GREEDY)
+                for p, n in ((prompt, 16), (P_A, 5))]
+        for r in reqs:
+            assert r.wait(300) and "error" not in r.result
+        ring = eng.flight.snapshot()
+        assert eng._inflight is None
+    finally:
+        eng.close()
+    assert reqs[0].result["tokens"] == _ref(model, prompt, 16)
+    assert reqs[1].result["tokens"] == _ref(model, P_A, 5)
+    assert any(r["occupancy"] for r in ring)
+    assert all(r["lag"] == 0 and r["dropped"] == 0 for r in ring)
+    assert all(r["fetch_ms"] > 0 for r in ring if r["occupancy"])

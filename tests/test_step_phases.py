@@ -1,7 +1,8 @@
 """Inside the step (ISSUE 26): span ids and parents, the phase spans under
 `serve.step` with their step id, the flight record's host/fetch split, the
 timelines on the recorder's clock, and the named scopes inside the decode
-and prefill programs."""
+and prefill programs. Since ISSUE 37 a step's fetch holds the ids of the
+step dispatched one iteration before (`of_step`, `lag`)."""
 import contextlib
 import re
 import threading
@@ -18,12 +19,15 @@ from cake_tpu.ops import sampling
 from cake_tpu.ops.sampling import SamplingConfig
 from cake_tpu.serve import ServeEngine
 from cake_tpu.serve.flight import FlightRecorder
+from tests.test_serve import _settle
 
 GREEDY = SamplingConfig(temperature=0.0)
 CTX, CHUNK = 128, 16
+# in the order a step runs them: the ids of the step before are fetched and
+# fanned out behind this step's dispatch, and then the chunk goes
 LEAVES = ("serve.sweep", "serve.admit", "serve.plan", "serve.decode_dispatch",
-          "serve.prefill_chunk", "serve.prefill_finish", "serve.fetch",
-          "serve.fanout")
+          "serve.fetch", "serve.fanout", "serve.prefill_chunk",
+          "serve.prefill_finish")
 SCOPES = [name for name, _ in SCOPE_CATALOG]
 SSM = {s for s in SCOPES if s.startswith("cake.ssm")}
 # a scope in an op's name: `/cake.attn/`, or `vmap(cake.attn)/` where the
@@ -143,7 +147,7 @@ def traced(model):
         warm = eng.submit(list(range(3, 40)), max_new_tokens=3,
                           sampling=GREEDY)
         assert warm.wait(600) and "error" not in warm.result
-        seq0 = eng.flight.snapshot()[-1]["seq"]
+        seq0 = _settle(eng)[-1]["seq"]
         RECORDER.clear()
         RECORDER.enable()
         # no prompt is another's prefix: every chunk is computed
@@ -152,6 +156,7 @@ def traced(model):
                 for n in (5, 20, 40)]
         for r in reqs:
             assert r.wait(600) and "error" not in r.result
+        _settle(eng)
         eng.close()
     finally:
         RECORDER.disable()
@@ -182,6 +187,7 @@ def test_every_worked_step_has_one_step_span(traced):
 # begins, so no clock and no load can open a hole between them
 TOUCHING = {("serve.sweep", "serve.admit"), ("serve.admit", "serve.plan"),
             ("serve.plan", "serve.decode_dispatch"),
+            ("serve.decode_dispatch", "serve.fetch"),
             ("serve.fetch", "serve.fanout")}
 
 
@@ -216,30 +222,50 @@ def test_phases_cover_the_step_without_overlap(traced):
     assert len(over) <= max(1, len(steps) // 10), over
 
 
-def test_a_step_that_decoded_fetched_and_fanned_out_once(traced):
+def test_every_dispatched_step_is_fetched_once_one_iteration_later(traced):
+    """A decode step is dispatched by one iteration and fetched and fanned
+    out by the next (`of_step`, `lag` 1: the fetch waited under a program
+    already queued); the first step after idle finds nothing to fetch, and
+    the iteration that has nothing to dispatch fetches what is in flight
+    (`lag` 0) and leaves nothing behind."""
     spans = traced["spans"]
     by_step = {e["args"]["step"]: _children(spans, e)
                for e in spans if e["name"] == "serve.step"}
-    decoded = [r for r in traced["flight"] if r["occupancy"] > 0]
+    decoded = [r["seq"] for r in traced["flight"] if r["occupancy"] > 0]
     assert decoded
-    tokens = finished = 0
+    tokens = finished = dropped = 0
+    fetched = []
     for r in traced["flight"]:
+        kids = {k["name"]: k["args"] for k in by_step[r["seq"]]}
         names = [k["name"] for k in by_step[r["seq"]]]
-        want = 1 if r["occupancy"] > 0 else 0
-        for n in ("serve.decode_dispatch", "serve.fetch", "serve.fanout"):
-            assert names.count(n) == want, (r, names)
-        for k in by_step[r["seq"]]:
-            if k["name"] == "serve.fanout":
-                tokens += k["args"]["tokens"]
-                finished += k["args"]["finished"]
-            if k["name"] == "serve.decode_dispatch":
-                assert k["args"]["slots"] == r["occupancy"]
-                assert k["args"]["bucket"] == r["bucket"]
-                # what the stepping rows hold: at least a prompt token each
-                assert k["args"]["kv_tokens"] == r["kv_tokens"] \
-                    >= r["occupancy"]
+        assert names.count("serve.decode_dispatch") == \
+            (1 if r["occupancy"] > 0 else 0), (r, names)
+        assert names.count("serve.fetch") == names.count("serve.fanout") \
+            <= 1, (r, names)
+        if "serve.fetch" in kids:
+            f, o = kids["serve.fetch"], kids["serve.fanout"]
+            assert f["of_step"] == o["of_step"] < r["seq"]
+            assert f["lag"] == o["lag"] == r["lag"] == \
+                (1 if r["occupancy"] > 0 else 0)
+            assert o["dropped"] == r["dropped"]
+            fetched.append(f["of_step"])
+            tokens += o["tokens"]
+            finished += o["finished"]
+            dropped += o["dropped"]
+        else:
+            assert r["lag"] == 0 and r["dropped"] == 0 and r["fetch_ms"] == 0
+        if "serve.decode_dispatch" in kids:
+            k = kids["serve.decode_dispatch"]
+            assert k["slots"] == r["occupancy"]
+            assert k["bucket"] == r["bucket"]
+            # what the stepping rows hold: at least a prompt token each
+            assert k["kv_tokens"] == r["kv_tokens"] >= r["occupancy"]
+    assert fetched == decoded               # each once, in order
+    assert sum(r["lag"] for r in traced["flight"]) >= len(decoded) - 3
     assert tokens == sum(len(r.result["tokens"]) for r in traced["reqs"])
     assert finished == len(traced["reqs"])
+    # a request's end is learnt one step late: that step's id is dropped
+    assert dropped == len(traced["reqs"])
     chunks = [e for e in spans if e["name"] == "serve.prefill_chunk"]
     finals = [e for e in spans if e["name"] == "serve.prefill_finish"]
     assert len(chunks) == len(finals) == 1 + 2 + 3     # 5, 20, 40 tokens
@@ -248,6 +274,26 @@ def test_a_step_that_decoded_fetched_and_fanned_out_once(traced):
                for e in chunks)
     assert sum(k["args"]["admitted"] for kids in by_step.values()
                for k in kids if k["name"] == "serve.admit") == 3
+
+
+def test_host_and_fetch_add_up_to_the_step(traced):
+    """`host_ms` + `fetch_ms` of the flight record is the step from its
+    first stamp to its last; the `serve.step` span around them adds the
+    writing of the record and of the spans themselves."""
+    step = {e["args"]["step"]: e["dur"] / 1e3
+            for e in traced["spans"] if e["name"] == "serve.step"}
+    kids = {e["args"]["step"]: _children(traced["spans"], e)
+            for e in traced["spans"] if e["name"] == "serve.step"}
+    for r in traced["flight"]:
+        whole = r["host_ms"] + r["fetch_ms"]
+        leaves = kids[r["seq"]]
+        first, last = leaves[0], leaves[-1]
+        stamped = (last["ts"] + last["dur"] - first["ts"]) / 1e3
+        assert abs(whole - stamped) < 0.01, (r, stamped)
+        assert whole <= step[r["seq"]] + 0.01
+        f = [k for k in leaves if k["name"] == "serve.fetch"]
+        if f:
+            assert abs(f[0]["dur"] / 1e3 - r["fetch_ms"]) < 0.01
 
 
 def test_timeline_events_name_their_step(traced):
@@ -262,8 +308,11 @@ def test_timeline_events_name_their_step(traced):
         for e in stamped:
             if e["kind"] != "decode":
                 continue
+            # `step` fanned the token out, `of_step` had dispatched it
+            assert e["of_step"] < e["step"]
             # on one clock: the token was stamped after its step's fetch
             f = fetch[e["step"]]
+            assert f["args"]["of_step"] == e["of_step"]
             t_us = tl["t0_us"] + e["t_ms"] * 1e3
             assert t_us >= f["ts"] + f["dur"] - 1
 
@@ -283,8 +332,49 @@ def test_flight_records_split_at_the_fetch_with_the_recorder_off(model):
     assert recs and all("dispatch_ms" not in r for r in recs)
     assert all(r["host_ms"] >= 0 and r["fetch_ms"] >= 0 for r in recs)
     assert any(r["fetch_ms"] > 0 for r in recs if r["occupancy"])
-    assert all(r["fetch_ms"] == 0 for r in recs if not r["occupancy"])
+    assert all({"lag", "dropped"} <= set(r) for r in recs)
     assert len(RECORDER) == before
+
+
+def test_at_depth_0_a_step_lands_behind_its_own_chunk(model):
+    """An engine with a drafter plans from every id: its step is fetched
+    and fanned out by the iteration that dispatched it (`of_step` is the
+    step itself, `lag` 0), behind the chunk's dispatch, so the chunk runs
+    while the host fans out. The spans cover the step in that order."""
+    eng = ServeEngine(model, slots=2, max_queue=4, ctx_len=CTX,
+                      prefill_chunk=CHUNK, spec="ngram", spec_k=2)
+    try:
+        warm = eng.submit(list(range(3, 12)), max_new_tokens=3,
+                          sampling=GREEDY)
+        assert warm.wait(600) and "error" not in warm.result
+        RECORDER.clear()
+        RECORDER.enable()
+        reqs = [eng.submit(list(range(60 + n, 60 + 2 * n)), max_new_tokens=5,
+                           sampling=GREEDY) for n in (6, 30)]
+        for r in reqs:
+            assert r.wait(600) and "error" not in r.result
+        eng.close()
+    finally:
+        RECORDER.disable()
+        eng.close()
+    spans = [e for e in RECORDER.events() if e["cat"] == "serve"]
+    RECORDER.clear()
+    order = ("serve.decode_dispatch", "serve.prefill_chunk",
+             "serve.prefill_finish", "serve.fetch", "serve.fanout")
+    both = 0
+    for step in (e for e in spans if e["name"] == "serve.step"):
+        kids = [k for k in _children(spans, step) if k["name"] in order]
+        names = [k["name"] for k in kids]
+        assert names == [n for n in order if n in names], names
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"], (a, b)
+        assert ("serve.fetch" in names) == ("serve.decode_dispatch" in names)
+        for k in kids:
+            if k["name"] in ("serve.fetch", "serve.fanout"):
+                assert k["args"]["of_step"] == step["args"]["step"]
+                assert k["args"]["lag"] == 0
+        both += {"serve.prefill_chunk", "serve.fetch"} <= set(names)
+    assert both     # a chunk and a decode step shared an iteration
 
 
 # -- the programs: named scopes ---------------------------------------------

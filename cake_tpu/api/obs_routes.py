@@ -204,6 +204,7 @@ async def flight(request: web.Request) -> web.Response:
             pass
     return web.json_response({
         "capacity": recorder.capacity,
+        "static": recorder.static,
         "count": len(iterations),
         "iterations": iterations,
     })

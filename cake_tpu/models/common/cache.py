@@ -20,10 +20,44 @@ cache (ref worker.rs get_client_context / cache.as_new()).
 """
 from __future__ import annotations
 
+from collections import Counter
+
 import jax
 import jax.numpy as jnp
 
-from .config import LayerSpec, LinearAttnConfig, ModelConfig
+from .config import AttnShape, LayerSpec, LinearAttnConfig, ModelConfig
+
+LANES = 128     # the TPU's minor tile: a buffer's last dim is padded to it
+
+
+def key_row_shape(a: AttnShape) -> tuple[int, ...]:
+    """What one position of a K leaf is, past [rows, length]. Keys whose
+    width is a multiple of the 128 lanes lie as [Hkv, D]. Keys of another
+    width (MiMo-V2's 192) lie JOINED, [Hkv * D], where that is a multiple:
+    nothing is padded, so the runtime keeps the buffer D-minor, a token's
+    keys are one contiguous run, and the one-token scatter, the chunk's
+    scatter and the row operations write the leaf as it lies. At [Hkv, 192]
+    the runtime stores the buffer length-minor (D-minor would pad 192 to 256
+    lanes) and every program that scatters into it first copies it whole to
+    a D-minor layout and back (PERF.md, PR 40). The masked read takes a
+    joined leaf in place (ops.attention.multi_head_attention); the flash
+    kernel is handed the one row it reads with its heads split, which
+    copies that row (layers.attention_forward)."""
+    if a.head_dim % LANES and a.size_k % LANES == 0:
+        return (a.size_k,)
+    return (a.kv_heads, a.head_dim)
+
+
+def keys_joined(lc: dict) -> bool:
+    """Does this positional layer hold its keys joined (key_row_shape)."""
+    return lc["k"].ndim == 3
+
+
+def joined_key_widths(layers: list[dict]) -> dict[int, int]:
+    """Joined width -> how many of these layers hold their keys so; empty
+    for a model whose key widths are all multiples of the lanes."""
+    return dict(Counter(lc["k"].shape[2] for lc in layers
+                        if is_positional(lc) and keys_joined(lc)))
 
 
 def init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
@@ -44,7 +78,7 @@ def init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
     size = max_seq_len if spec.window is None else min(spec.window, max_seq_len)
     a = cfg.attn_shape(spec)       # head count and widths of this layer kind
     return {
-        "k": jnp.zeros((batch, size, a.kv_heads, a.head_dim), dtype),
+        "k": jnp.zeros((batch, size) + key_row_shape(a), dtype),
         "v": jnp.zeros((batch, size, a.kv_heads, a.v_head_dim), dtype),
         "pos": jnp.full((batch, size), -1, jnp.int32),
     }
@@ -83,7 +117,8 @@ def row_state_bytes(layers: list[dict]) -> int:
 def update_kv_cache(layer_cache: dict, k_new, v_new, pos, valid_len=None):
     """Write S new KV entries at absolute positions pos..pos+S-1.
 
-    k_new/v_new: [B, S, Hkv, D]; pos: traced scalar int32.
+    k_new/v_new: [B, S, Hkv, D]; pos: traced scalar int32. Where the layer
+    holds its keys joined (key_row_shape), k_new is written so.
     Ring semantics: slot = position % size. When S > size only the last
     `size` entries are written (the earlier ones would be overwritten anyway),
     keeping scatter indices unique.
@@ -94,6 +129,8 @@ def update_kv_cache(layer_cache: dict, k_new, v_new, pos, valid_len=None):
     """
     size = layer_cache["k"].shape[1]
     s = k_new.shape[1]
+    if keys_joined(layer_cache):
+        k_new = k_new.reshape(k_new.shape[:2] + (-1,))
     if s > size:
         # Keep the last `size` VALID entries: with bucketed-prefill padding
         # the tail of k_new is garbage, so the slice starts at
@@ -360,10 +397,11 @@ def init_paged_layers(cfg: ModelConfig, num_blocks: int, block_tokens: int,
     """(pool_layers, row_layers) for a paged slot pool.
 
     pool_layers[i] holds the physical block pool for full-attention layer
-    i ({k,v: [num_blocks, block_tokens, H, D], pos: [num_blocks,
-    block_tokens]}) and an EMPTY dict elsewhere; row_layers[i] holds the
-    per-slot state for sliding-window rings and recurrent layers
-    (leading batch axis) and an empty dict at pooled positions. Empty
+    i ({k,v: [num_blocks, block_tokens, H, D] (k joined where
+    key_row_shape says so), pos: [num_blocks, block_tokens]}) and an
+    EMPTY dict elsewhere; row_layers[i] holds the per-slot state for
+    sliding-window rings and recurrent layers (leading batch axis) and an
+    empty dict at pooled positions. Empty
     dicts keep both lists layer-aligned pytrees with zero leaves at the
     other list's positions, so they vmap/donate cleanly side by side.
     """
@@ -377,8 +415,8 @@ def init_paged_layers(cfg: ModelConfig, num_blocks: int, block_tokens: int,
         else:
             a = cfg.attn_shape(spec)
             pool.append({
-                "k": jnp.zeros((num_blocks, block_tokens, a.kv_heads,
-                                a.head_dim), dtype),
+                "k": jnp.zeros((num_blocks, block_tokens)
+                               + key_row_shape(a), dtype),
                 "v": jnp.zeros((num_blocks, block_tokens, a.kv_heads,
                                 a.v_head_dim), dtype),
                 "pos": jnp.full((num_blocks, block_tokens), -1, jnp.int32),

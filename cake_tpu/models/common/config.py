@@ -604,11 +604,14 @@ def tiny_config(arch: str = "llama", **over) -> ModelConfig:
                  rms_norm_eps=1e-6)
     if arch == "mimo_v2":
         # both kinds twice, a dense first layer, window layers of other
-        # head counts, keys wider than values, a share of 4 of 8 experts
+        # head counts, keys wider than values and, as the published 192,
+        # no multiple of the lanes while a position's keys are (4 x 96,
+        # 8 x 96: cache.key_row_shape joins them), a share of 4 of 8 experts
         d.update(num_hidden_layers=5, hybrid_layer_pattern=[0, 1, 1, 0, 1],
-                 moe_layer_freq=[0, 1, 1, 1, 1], head_dim=24, v_head_dim=16,
-                 swa_head_dim=24, swa_v_head_dim=16,
-                 swa_num_attention_heads=4, swa_num_key_value_heads=4,
+                 moe_layer_freq=[0, 1, 1, 1, 1], num_attention_heads=8,
+                 num_key_value_heads=4, head_dim=96, v_head_dim=64,
+                 swa_head_dim=96, swa_v_head_dim=64,
+                 swa_num_attention_heads=8, swa_num_key_value_heads=8,
                  partial_rotary_factor=0.334, sliding_window=16,
                  swa_rope_theta=100.0, add_swa_attention_sink_bias=True,
                  add_full_attention_sink_bias=False,

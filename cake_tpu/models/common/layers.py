@@ -18,7 +18,7 @@ from ...ops import (apply_rope, embedding, gelu_mul, linear,
                     make_attention_mask, multi_head_attention, rms_norm,
                     rope_tables, silu_mul)
 from ...ops.moe import moe_ffn
-from .cache import update_kv_cache
+from .cache import keys_joined, update_kv_cache
 from .config import LayerSpec, ModelConfig
 
 
@@ -217,18 +217,21 @@ def decode_kernel_block(s: int, window: int | None, layer_cache, dtype,
     and one whose rows the kernel can split (32-bit, or 16-bit with an even
     number of K/V heads a device), not sharded over its rows or its length (`dp`, `sp`:
     the kernel splits heads over `tp` alone), where the Pallas attention
-    kernels are on, with no sink in its softmax and with keys and values
-    of one width: the kernel takes one D for both. Widened to MiMo-V2's
-    192 / 128 it does not compile: it slices heads with a strided load,
-    which Mosaic refuses on a buffer whose last dim is not 128 ("The last
-    dim size is not 128 in original base memref", described v5e), so such
-    a layer decodes masked. attention_forward alone dispatches on it."""
+    kernels are on, with no sink in its softmax and with keys that lie by
+    head and are as wide as the values: the kernel takes one D for both
+    and a rank-4 K. MiMo-V2's keys of 192 lie joined (cache.key_row_shape);
+    at [.., Hkv, 192] the kernel did not compile either: it slices heads
+    with a strided load, which Mosaic refuses on a buffer whose last dim is
+    not 128 ("The last dim size is not 128 in original base memref",
+    described v5e). Such a layer decodes masked. attention_forward alone
+    dispatches on it."""
     from ...ops.decode_attention import decode_block_k
     from ...ops.flash import flash_enabled
     if (s != 1 or window is not None or sink or layer_cache is None
             or "pos" not in layer_cache or not flash_enabled()):
         return None
-    if layer_cache["v"].shape[3] != layer_cache["k"].shape[3]:
+    if (keys_joined(layer_cache)
+            or layer_cache["v"].shape[3] != layer_cache["k"].shape[3]):
         return None
     if mesh is not None and any(mesh.shape.get(a, 1) > 1
                                 for a in ("dp", "sp")):
@@ -334,9 +337,11 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
         # cache, then flash over the buffer — valid because "append" is
         # only selected when the buffer is unwrapped (index == position)
         new_cache = update_kv_cache(layer_cache, k, v, pos0, valid_len)
-        y = flash_attention(q, new_cache["k"], new_cache["v"],
-                            scale=cfg.attn_scale, valid_len=valid_len,
-                            q_offset=pos0, mesh=mesh)
+        # (the kernel takes keys by head: joined keys are split, a copy
+        # of this one row)
+        y = flash_attention(q, new_cache["k"].reshape(b, -1, hkv, d),
+                            new_cache["v"], scale=cfg.attn_scale,
+                            valid_len=valid_len, q_offset=pos0, mesh=mesh)
         kv_pos = k_all = v_all = None
     elif layer_cache is None:
         kv_pos, k_all, v_all = kv_pos_new, k, v
@@ -364,7 +369,10 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
     else:
         new_cache = None
         kv_pos = jnp.concatenate([layer_cache["pos"], kv_pos_new], axis=1)
-        k_all = jnp.concatenate([layer_cache["k"], k], axis=1)
+        # the in-pass keys as the cache holds its own (joined or not)
+        k_all = jnp.concatenate(
+            [layer_cache["k"],
+             k.reshape((b, s) + layer_cache["k"].shape[2:])], axis=1)
         v_all = jnp.concatenate([layer_cache["v"], v], axis=1)
     if not use_flash:
         q_pos = jnp.broadcast_to(positions[None, :], (b, s))

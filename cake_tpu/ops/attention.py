@@ -37,12 +37,31 @@ def make_attention_mask(q_positions, kv_positions, window: int | None = None,
     return mask
 
 
+def _spread_queries(qf):
+    """qf [B, Sq, Hkv, G, D] -> [B, Sq, Hkv, G, Hkv * D]: a head's D query
+    values in the D columns of its own K/V head and exact zeros in the
+    others, so that contracting with joined keys [B, Skv, Hkv * D] over the
+    whole width gives the head's scores against its own K/V head. The zeros
+    add nothing to a sum, and the keys are never reshaped: splitting Hkv * D
+    lanes at a width that is no multiple of 128 would copy the buffer."""
+    b, sq, hkv, g, d = qf.shape
+    own = jnp.eye(hkv, dtype=jnp.bool_)[None, None, :, None, :, None]
+    return jnp.where(own, qf[:, :, :, :, None, :], 0).reshape(
+        b, sq, hkv, g, hkv * d)
+
+
 def multi_head_attention(q, k, v, mask=None, scale: float | None = None,
                          sink=None):
     """Grouped-query attention.
 
     q: [B, Sq, Hq, D], k: [B, Skv, Hkv, D], v: [B, Skv, Hkv, Dv] with Hq a
-    multiple of Hkv (Dv may differ from D).
+    multiple of Hkv (Dv may differ from D). k may also be JOINED, [B, Skv,
+    Hkv * D], as a cache holds keys whose width is no multiple of the lanes
+    (cache.key_row_shape). Where the queries are the smaller side (a
+    decode or verify step against a pool's rows: Sq * Hq < Skv) it is read
+    as it lies, against queries spread over the joined width
+    (_spread_queries: Hkv times the products, no copy of the keys); else (a
+    chunk against a ring and itself) its heads are split, which copies it.
     mask: bool [B, Sq, Skv] (True = attend) or None for full attention.
     sink: [Hq] or None — a learned logit a head that joins the softmax's
     denominator and carries no value (gpt-oss's sinks, MiMo-V2's
@@ -51,14 +70,18 @@ def multi_head_attention(q, k, v, mask=None, scale: float | None = None,
     Returns [B, Sq, Hq, Dv] in q.dtype.
     """
     b, sq, hq, d = q.shape
-    hkv = k.shape[2]
+    hkv = v.shape[2]
     g = hq // hkv
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
     qf = q.reshape(b, sq, hkv, g, d)
     # scores: [B, Hkv, G, Sq, Skv]
-    scores = jnp.einsum("bskgd,btkd->bkgst", qf, k,
+    if k.ndim == 3 and sq * hq < k.shape[1]:
+        qf, dims = _spread_queries(qf), "bskgc,btc->bkgst"
+    else:
+        k, dims = k.reshape(b, -1, hkv, d), "bskgd,btkd->bkgst"
+    scores = jnp.einsum(dims, qf, k,
                         preferred_element_type=jnp.float32) * scale
     if mask is not None:
         scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)

@@ -116,6 +116,10 @@ def cache_shardings(cache, mesh: Mesh):
         # window) is not divisible.
         if ndim == 4 and name in ("k", "v"):
             spec = P(dp, sp, tp, None)
+        elif ndim == 3 and name == "k":
+            # keys joined [B, T, Hkv * D] (cache.key_row_shape): a `tp`-th
+            # of the joined width is whole heads, as Hkv % tp == 0
+            spec = P(dp, sp, tp)
         elif ndim == 4 and name == "state":     # GDN [B, Hv, Dk, Dv]
             spec = P(dp, tp, None, None)
         elif ndim == 3 and name == "conv":      # GDN conv state [B, C, K-1]

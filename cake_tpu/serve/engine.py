@@ -80,7 +80,7 @@ from ..obs import (RECORDER, SERVE_BATCH_OCCUPANCY, SERVE_E2E_SECONDS,
                    SERVE_QUEUE_WAIT_SECONDS, SERVE_REQUEST_TIMEOUTS,
                    SERVE_SLOTS_BUSY, SERVE_TTFT_SECONDS, TIMELINES, now,
                    set_request_id)
-from ..models.common.cache import row_state_bytes
+from ..models.common.cache import joined_key_widths, row_state_bytes
 from ..ops.sampling import SamplingConfig, config_has_filters
 from ..spec import resolve_drafter
 from ..spec.verify import record_step
@@ -416,6 +416,7 @@ class ServeEngine:
         # supervisor dumps to CAKE_TRACE_DIR on wedge/DOWN — built
         # before the supervisor so the watchdog can always reach it
         self.flight = FlightRecorder()
+        self.flight.static["joined_keys"] = self._joined_keys
         self._step_id = 0           # the running iteration's flight seq
         # running totals the iteration's record takes differences of
         # (_land): tokens emitted, requests a fan-out finished, ids fetched
@@ -463,6 +464,13 @@ class ServeEngine:
         # leaves once (the flight record's `state_bytes`)
         self._row_state_bytes = row_state_bytes(
             self.paged.rows if self._layers is None else self._layers)
+        # the layers whose keys lie joined in the pool (cache.key_row_shape:
+        # a key width that is no multiple of the lanes), by joined width:
+        # health's `kv_pool` and the flight record's static part say so
+        self._joined_keys = [
+            {"width": w, "layers": n} for w, n in sorted(joined_key_widths(
+                self.paged.pool + self.paged.rows if self._layers is None
+                else self._layers).items())]
         # the window layers' ring lengths (the flight record's
         # `ring_tokens`); none for a model without window layers
         self._ring_sizes = [s.window for s in self.model.cfg.layer_specs()
@@ -656,6 +664,7 @@ class ServeEngine:
         pc = self.prefix_cache
         if pc is not None:
             h["prefix_cache"] = pc.occupancy()
+        h["kv_pool"] = {"joined_keys": self._joined_keys}
         # local binding: health() runs on API threads while the scheduler
         # may null self.paged transiently during _rebuild/_fail_all
         paged = self.paged
@@ -666,10 +675,8 @@ class ServeEngine:
                 if req is not None:
                     live[i] = len(req.prompt_ids) \
                         + max(len(req.tokens) - 1, 0)
-            h["kv_pool"] = {
-                **paged.occupancy(live),
-                "preempted_slots": len(self._preempted),
-            }
+            h["kv_pool"].update(paged.occupancy(live),
+                                preempted_slots=len(self._preempted))
             if pc is not None:
                 # the peer directory and `cake top` both want the cache
                 # size next to pool occupancy, not only in prefix_cache

@@ -50,6 +50,10 @@ class FlightRecorder:
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=self.capacity)
         self._seq = 0
+        # what holds for every iteration (the engine writes it once: the
+        # layers whose keys lie joined in the pool); dumped and served
+        # beside the ring
+        self.static: dict = {}
 
     def begin(self) -> int:
         """Reserve the sequence number of the iteration that starts now:
@@ -91,6 +95,7 @@ class FlightRecorder:
                 body = {
                     "reason": reason,
                     "pid": os.getpid(),
+                    "static": dict(self.static),
                     "iterations": [dict(r) for r in self._ring],
                 }
             if extra:

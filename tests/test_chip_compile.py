@@ -164,3 +164,127 @@ def test_decode_kernel_compiles_head_sharded_under_tp_mesh(tp_mesh):
                            ).as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" not in text and "all-to-all" not in text
+
+
+# -- the serve programs over a pool: no pool-shaped operand is converted -------
+
+# two-layer cuts at published head widths, as the benchmark's cells hold them
+_POOLS = {
+    # MiMo-V2.5: one full layer (64 q on 4 K/V heads) and one window layer
+    # (on 8), keys 192 and values 128, 32 rows x 16,384: keys lie joined
+    "mimo_v2": (dict(
+        model_type="mimo_v2", architectures=["MiMoV2ForCausalLM"],
+        vocab_size=19072, hidden_size=4096, intermediate_size=2048,
+        num_hidden_layers=2, hybrid_layer_pattern=[0, 1],
+        moe_layer_freq=[0, 0], num_attention_heads=64,
+        num_key_value_heads=4, head_dim=192, v_head_dim=128,
+        swa_num_attention_heads=64, swa_num_key_value_heads=8,
+        swa_head_dim=192, swa_v_head_dim=128, partial_rotary_factor=0.334,
+        rope_theta=10000000, swa_rope_theta=10000, sliding_window=128,
+        add_swa_attention_sink_bias=True, add_full_attention_sink_bias=False,
+        attention_value_scale=0.707, layernorm_epsilon=1e-5,
+        max_position_embeddings=16384, n_routed_experts=16,
+        num_experts_per_tok=8, moe_intermediate_size=2048, n_group=1,
+        topk_group=1, norm_topk_prob=True, scoring_func="sigmoid",
+        tie_word_embeddings=False, hidden_act="silu"), 32, 16384),
+    # the control, Qwen3-4B's widths (an eighth of its vocabulary, as the
+    # other has): keys 128 wide, 8 rows x 4096
+    "qwen3": (dict(
+        model_type="qwen3", architectures=["Qwen3ForCausalLM"],
+        vocab_size=18992, hidden_size=2560, intermediate_size=9728,
+        num_hidden_layers=2, num_attention_heads=32, num_key_value_heads=8,
+        head_dim=128, rms_norm_eps=1e-6, rope_theta=1000000,
+        max_position_embeddings=4096, tie_word_embeddings=True), 8, 4096),
+}
+_RESULT = re.compile(r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]+)\]\S* "
+                     r"(copy|copy-start|transpose)\(", re.M)
+_FUSED = re.compile(r" fusion\(.*calls=(%[\w.-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[\w.-]+) \(.*?^}", re.M | re.S)
+
+
+def _converted(text, elements):
+    """The copies and transposes of an optimised HLO module whose result
+    holds at least `elements` and is written to memory: one inside a fused
+    computation is its consumer's way of reading (the weighted values'
+    convolution reads V through one) and moves no buffer."""
+    fused = set(_FUSED.findall(text))
+    return [(m.group(3), m.group(1), m.group(2))
+            for c in _COMPUTATION.finditer(text) if c.group(1) not in fused
+            for m in _RESULT.finditer(c.group(0))
+            if np.prod([int(d) for d in m.group(2).split(",")]) >= elements]
+
+
+def _pool_programs(cfg, rows, ctx, one_chip):
+    from cake_tpu.models import TextModel
+    from cake_tpu.models.common.cache import init_cache
+    from cake_tpu.models.common.layers import init_params
+    from cake_tpu.serve.engine import RECENT_N
+    m = TextModel.__new__(TextModel)        # programs alone: no weights
+    m.cfg, m.dtype, m.mesh, m.tokenizer, m.max_cache_len = (
+        cfg, jnp.bfloat16, None, None, ctx)
+    m._build()
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(tree))
+
+    def of(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = described(lambda: init_params(cfg, jax.random.PRNGKey(0),
+                                           jnp.bfloat16))
+    layers = described(lambda: init_cache(cfg, rows, ctx)["layers"])
+    i32, f32 = jnp.int32, jnp.float32
+    with _no_compile_cache():
+        yield "decode", layers, m._decode_slots.lower(
+            params, layers, of(i32, rows), of(i32, rows),
+            of(jnp.uint32, rows, 2), of(i32, rows, RECENT_N), of(f32, rows),
+            of(i32, rows), of(f32, rows), of(f32, rows),
+            of(jnp.bool_, rows)).compile()
+        yield "append256", layers, m._prefill_slot.lower(
+            params, of(i32, 1, 256), layers, of(i32), of(i32), of(i32),
+            flash_mode="append").compile()
+        # a prefix hit restores 256-token blocks into a row, one call each
+        block = described(lambda: m._slot_extract(
+            init_cache(cfg, rows, ctx)["layers"], 0, 0, width=256))
+        yield "splice256", layers, m._slot_splice.lower(
+            layers, block, of(i32), of(jnp.bool_)).compile()
+
+
+@pytest.mark.parametrize("family", list(_POOLS))
+def test_serve_programs_take_the_pool_in_place(one_chip, monkeypatch, family):
+    """`_decode_slots`, `_prefill_slot` (append, 256 tokens) and the prefix
+    cache's `_slot_splice` as the chip would run them (the Pallas kernels
+    on): the optimised HLO holds no copy or transpose whose result is as
+    large as a layer's K or V buffer, the pool is donated through, and the
+    temporaries are a row's, not a pool's: at keys of 192 by head the
+    runtime stored K length-minor and the decode step and the splice each
+    copied 805 MB to a D-minor layout and back around their scatter (1.08
+    GB of temporaries; PERF.md, PR 40). The largest temporary left is the
+    masked decode read's float32 scores, rows x Hq x T."""
+    from cake_tpu.models.common.config import config_from_hf_dict
+    from cake_tpu.ops import flash
+    monkeypatch.setattr(flash, "flash_enabled", lambda: True)
+    hf, rows, ctx = _POOLS[family]
+    cfg = config_from_hf_dict(hf)
+    for name, layers, compiled in _pool_programs(cfg, rows, ctx, one_chip):
+        full = max(layers, key=lambda lc: lc["k"].size)
+        k, pool_shaped = full["k"], min(full["k"].size, full["v"].size)
+        assert (k.ndim == 3) == (family == "mimo_v2")
+        text, mem = compiled.as_text(), compiled.memory_analysis()
+        # the kernels are in it, but for the masked decode of keys of 192
+        assert ("tpu_custom_call" in text) == (
+            name != "splice256" and (family, name) != ("mimo_v2", "decode")
+        ), name
+        big = _converted(text, pool_shaped)
+        assert not big, (name, big)
+        pool_bytes = sum(a.size * a.dtype.itemsize
+                         for a in jax.tree_util.tree_leaves(layers))
+        assert mem.alias_size_in_bytes >= pool_bytes, name
+        scores = rows * cfg.num_attention_heads * ctx * 4
+        limit = (scores if name == "decode" and family == "mimo_v2"
+                 else 0) + 64 * 2 ** 20
+        assert mem.temp_size_in_bytes < limit, (name,
+                                                mem.temp_size_in_bytes)

@@ -37,7 +37,7 @@ import numpy as np
 import pytest
 
 from cake_tpu.models import TextModel, init_params, tiny_config
-from cake_tpu.models.common.cache import truncate_layers
+from cake_tpu.models.common.cache import key_row_shape, truncate_layers
 from cake_tpu.models.common.config import AttnShape, config_from_hf_dict
 from cake_tpu.models.common.layers import (decode_kernel_block,
                                            flash_kernel_mode, make_rope)
@@ -57,10 +57,10 @@ TINY_HF = {
     "architectures": ["MiMoV2ForCausalLM"], "model_type": "mimo_v2",
     "vocab_size": 512, "hidden_size": 64, "intermediate_size": 128,
     "num_hidden_layers": 5, "hybrid_layer_pattern": [0, 1, 1, 0, 1],
-    "moe_layer_freq": [0, 1, 1, 1, 1], "num_attention_heads": 4,
-    "num_key_value_heads": 2, "head_dim": 24, "v_head_dim": 16,
-    "swa_num_attention_heads": 4, "swa_num_key_value_heads": 4,
-    "swa_head_dim": 24, "swa_v_head_dim": 16, "partial_rotary_factor": 0.334,
+    "moe_layer_freq": [0, 1, 1, 1, 1], "num_attention_heads": 8,
+    "num_key_value_heads": 4, "head_dim": 96, "v_head_dim": 64,
+    "swa_num_attention_heads": 8, "swa_num_key_value_heads": 8,
+    "swa_head_dim": 96, "swa_v_head_dim": 64, "partial_rotary_factor": 0.334,
     "rope_theta": 10000000, "swa_rope_theta": 10000, "sliding_window": 16,
     "sliding_window_size": 16, "attention_chunk_size": 16,
     "add_swa_attention_sink_bias": True,
@@ -74,6 +74,11 @@ TINY_HF = {
     "expert_parallel": {"size": 2, "rank": 1},
 }
 WHOLE_HF = {**TINY_HF, "n_routed_experts": 8, "expert_parallel": None}
+# the same at widths whose keys no leaf joins (2 x 24, 4 x 24: rank-4 K)
+NARROW = {"num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 24,
+          "v_head_dim": 16, "swa_num_attention_heads": 4,
+          "swa_num_key_value_heads": 4, "swa_head_dim": 24,
+          "swa_v_head_dim": 16}
 
 
 @pytest.fixture(scope="module")
@@ -204,11 +209,20 @@ def test_sink_softmax_matches_transformers_gpt_oss_attention():
 
 # -- the program against the plain reference ----------------------------------
 
-@pytest.mark.parametrize("hf", [TINY_HF, WHOLE_HF], ids=["share", "whole"])
-def test_program_equals_the_reference_through_the_check(bench, hf):
+@pytest.mark.parametrize("hf,joined,room", [
+    (TINY_HF, True, 2), (WHOLE_HF, True, 3),
+    ({**TINY_HF, **NARROW}, False, 3), ({**WHOLE_HF, **NARROW}, False, 3)],
+    ids=["share", "whole", "share_unjoined", "whole_unjoined"])
+def test_program_equals_the_reference_through_the_check(bench, hf, joined,
+                                                        room):
+    """`room`: how far over the sound bf16 reading every control must read
+    (at the joined widths the share's bias-less selection reads 2.5 x)."""
     check, W, ref = (bench[k] for k in ("check", "weights",
                                         "reference.mimo_v2"))
     cfg = config_from_hf_dict(hf)
+    assert [key_row_shape(cfg.attn_shape(s)) for s in
+            cfg.layer_specs()[:2]] == (
+        [(384,), (768,)] if joined else [(2, 24), (4, 24)])
     assert ref.share(hf)[:2] == (cfg.router_width, cfg.expert_first)
     seed = 2 ** 31 + 39
     sound = {}
@@ -233,7 +247,8 @@ def test_program_equals_the_reference_through_the_check(bench, hf):
                 ref.forward_logits(h, ww, ids, pos,
                                    **({lacking: False} if quant else {}))))
         ctl = check.control(alt, hf, w, served, lacking)
-        assert ctl["pooled"] > 3 * sound[jnp.bfloat16], (lacking, ctl, sound)
+        assert ctl["pooled"] > room * sound[jnp.bfloat16], (lacking, ctl,
+                                                            sound)
     int8 = check.control(ref, hf, w, served, "int8")
     assert int8["pooled"] > 1.3 * sound[jnp.bfloat16], (int8, sound)
     used, needed = ref.experts_used(hf, w, served[-1]["ids"])
@@ -303,15 +318,15 @@ def _row_bytes(layers, row):
 
 def test_row_operations_on_a_pool_of_mixed_layers(model):
     """assign -> extract -> splice -> truncate -> reset on the model's own
-    pool: full buffers of 2 K/V heads, rings of 4, keys 24 wide and values
-    16. The named row changes as specified, every other row keeps its
-    bytes."""
+    pool: full buffers of 4 K/V heads, rings of 8, keys 96 wide and so
+    JOINED (a position's keys are one run of 384 or 768), values 64. The
+    named row changes as specified, every other row keeps its bytes."""
     B = 3
     pool = model.new_cache(B, kv_len=CTX)["layers"]
     assert [(lc["k"].shape[1:], lc["v"].shape[1:]) for lc in pool] == [
-        ((CTX, 2, 24), (CTX, 2, 16)), ((16, 4, 24), (16, 4, 16)),
-        ((16, 4, 24), (16, 4, 16)), ((CTX, 2, 24), (CTX, 2, 16)),
-        ((16, 4, 24), (16, 4, 16))]
+        ((CTX, 384), (CTX, 4, 64)), ((16, 768), (16, 8, 64)),
+        ((16, 768), (16, 8, 64)), ((CTX, 384), (CTX, 4, 64)),
+        ((16, 768), (16, 8, 64))]
     rng = np.random.default_rng(39)
     for row in range(B):                    # every row starts non-empty
         _, pool = model.prefill_chunk(pool, row,
@@ -369,14 +384,18 @@ def test_row_operations_on_a_pool_of_mixed_layers(model):
 
 # -- the prefix cache with a ring smaller than a block --------------------------
 
-@pytest.mark.parametrize("family,window,chunk", [
-    ("mimo_v2", 16, 32), ("mistral", 32, 16)],
-    ids=["ring_smaller_than_block", "ring_of_two_blocks"])
+@pytest.mark.parametrize("family,window,chunk,paged", [
+    ("mimo_v2", 16, 32, {}), ("mistral", 32, 16, {}),
+    ("mimo_v2", 16, 32, {"kv_blocks": 24, "kv_block_tokens": 32})],
+    ids=["ring_smaller_than_block", "ring_of_two_blocks",
+         "joined_keys_in_paged_blocks"])
 def test_prefix_hits_of_any_length_give_the_tokens_of_a_miss(family, window,
-                                                             chunk):
+                                                             chunk, paged):
     """Built for a 16-token ring under 32-token blocks (it used to gate
     itself off, silently); a full-chain hit, a partial-chain hit and a miss
-    give the same greedy tokens, as they do where window >= block."""
+    give the same greedy tokens, as they do where window >= block. The
+    blocks hold MiMo-V2's keys joined, in a row's leaves and in a paged
+    pool's."""
     m = TextModel(tiny_config(family, sliding_window=window),
                   dtype=jnp.float32, max_cache_len=CTX)
     shared = [3 + (i * 11) % 200 for i in range(3 * chunk)]
@@ -385,9 +404,10 @@ def test_prefix_hits_of_any_length_give_the_tokens_of_a_miss(family, window,
                "partial": shared[:2 * chunk] + [5] * 9,     # 2 of them
                "one": shared[:chunk] + [8] * (chunk + 3)}   # 1, then a miss
     eng = ServeEngine(m, slots=2, max_queue=4, ctx_len=CTX,
-                      prefill_chunk=chunk, prefix_cache_mb=64)
+                      prefill_chunk=chunk, prefix_cache_mb=64, **paged)
     try:
         assert eng.prefix_cache is not None
+        assert (eng.paged is not None) == bool(paged)
         for name, hit in (("miss", 0), ("full", 3 * chunk),
                           ("partial", 2 * chunk), ("one", chunk)):
             want, _ = m.generate(list(prompts[name]), max_new_tokens=6,
@@ -504,6 +524,161 @@ def test_loader_and_export_round_trip(tmp_path, fused):
             atol=0 if f32 else 2e-2, err_msg=name)
 
 
+# -- keys joined in the pool --------------------------------------------------------
+
+def _by_head(lc, hkv):
+    """A layer cache with joined keys as the same keys by head."""
+    return {**lc, "k": lc["k"].reshape(lc["k"].shape[:2] + (hkv, -1))}
+
+
+@pytest.mark.parametrize("valid_len", [1, 0], ids=["stepping", "masked_out"])
+@pytest.mark.parametrize("kind,s", [
+    ("full", 1), ("window", 1), ("full", 4), ("window", 24)],
+    ids=["full_decode", "ring_decode_sink", "full_verify_width",
+         "ring_chunk_sink"])
+def test_joined_keys_read_as_the_keys_by_head(model, kind, s, valid_len):
+    """attention_forward over a cache that holds its keys joined against
+    the same cache with its keys by head, float32 to 1e-6, and the caches
+    they leave equal to the byte: a decode step and a verify width (queries
+    spread over the joined width, the keys as they lie), a chunk against a
+    ring (the ring's heads split), with and without a sink, and a row that
+    valid_len 0 masks out of the step."""
+    from cake_tpu.models.common.layers import attention_forward
+    cfg = model.cfg
+    i, spec = next((i, sp) for i, sp in enumerate(cfg.layer_specs())
+                   if (sp.window is not None) == (kind == "window"))
+    assert spec.sink == (kind == "window")
+    a = cfg.attn_shape(spec)
+    p = model.params["layers"][i]["self_attn"]
+    rows, held = 3, 21                      # past the ring's 16: it wrapped
+    lc = model.new_cache(rows, kv_len=CTX)["layers"][i]
+    assert lc["k"].shape[2:] == (a.size_k,)
+    ks = jax.random.split(jax.random.PRNGKey(40), 3)
+    size = lc["k"].shape[1]
+    pos = np.full((rows, size), -1, np.int32)
+    for t in range(max(held - size, 0), held):
+        pos[:, t % size] = t
+    pos[2] = -1                             # a row that holds nothing
+    lc = {"k": jax.random.normal(ks[0], lc["k"].shape),
+          "v": jax.random.normal(ks[1], lc["v"].shape),
+          "pos": jnp.asarray(pos)}
+    x = jax.random.normal(ks[2], (rows, s, cfg.hidden_size))
+    args = (jnp.asarray(held, jnp.int32), model.params["rope"],
+            jnp.asarray(valid_len * s, jnp.int32))
+    got, new = attention_forward(cfg, spec, p, x, lc, *args)
+    want, ref = attention_forward(cfg, spec, p, x, _by_head(lc, a.kv_heads),
+                                  *args)
+    assert ref["k"].ndim == 4 and new["k"].ndim == 3
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    assert np.abs(np.asarray(want)).max() > 1e-3
+    for name in ("k", "v", "pos"):
+        np.testing.assert_array_equal(
+            np.asarray(new[name]), np.asarray(ref[name]).reshape(
+                new[name].shape))
+    if not valid_len:                       # nothing was written
+        np.testing.assert_array_equal(np.asarray(new["k"]),
+                                      np.asarray(lc["k"]))
+
+
+def test_spread_queries_put_each_head_on_its_own_keys():
+    from cake_tpu.ops.attention import _spread_queries
+    qf = jnp.arange(1, 2 * 3 * 2 * 4 + 1, dtype=jnp.float32).reshape(
+        1, 2, 3, 2, 4)                      # [B, Sq, Hkv, G, D]
+    qs = np.asarray(_spread_queries(qf)).reshape(1, 2, 3, 2, 3, 4)
+    for k in range(3):
+        for other in range(3):
+            want = np.asarray(qf[:, :, k]) if other == k else 0
+            np.testing.assert_array_equal(qs[:, :, k, :, other], want)
+
+
+def test_engine_says_which_layers_hold_joined_keys(model):
+    from cake_tpu.models.common.cache import joined_key_widths
+    for paged in (False, True):
+        eng = ServeEngine(model, slots=2, max_queue=2, ctx_len=CTX,
+                          prefill_chunk=32,
+                          **({"kv_blocks": 16, "kv_block_tokens": 16}
+                             if paged else {}))
+        try:
+            want = [{"width": 384, "layers": 2}, {"width": 768, "layers": 3}]
+            assert eng.health()["kv_pool"]["joined_keys"] == want
+            assert eng.flight.static["joined_keys"] == want
+            assert ("occupancy" in eng.health()["kv_pool"]) == paged
+        finally:
+            eng.close()
+    plain = TextModel(tiny_config("qwen3"), dtype=jnp.float32,
+                      max_cache_len=CTX)
+    assert joined_key_widths(plain.new_cache(2)["layers"]) == {}
+
+
+# the families of the benchmark's other cells at tiny widths and their
+# published key width of 128, where no leaf is joined
+_OLDER = {
+    "qwen3": dict(head_dim=128),
+    "qwen3_moe": dict(head_dim=128),
+    "jamba": dict(num_attention_heads=2, num_key_value_heads=1,
+                  hidden_size=256),
+}
+_PROGRAMS = [("decode", 1), ("fresh", 32), ("fresh", 256), ("append", 32),
+             ("append", 256)]
+
+
+def _lowered(m, program, slots=4):
+    layers = jax.eval_shape(lambda: m.new_cache(slots, kv_len=512)["layers"])
+    sds = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: m.params)
+    mode, width = program
+    if mode == "decode":
+        z = lambda dt: sds((slots,), dt)    # noqa: E731
+        traced = m._decode_slots.trace(
+            params, layers, z(jnp.int32), z(jnp.int32),
+            sds((slots, 2), jnp.uint32), sds((slots, 8), jnp.int32),
+            z(jnp.float32), z(jnp.int32), z(jnp.float32), z(jnp.float32),
+            z(jnp.bool_))
+    else:
+        i32 = sds((), jnp.int32)
+        traced = m._prefill_slot.trace(
+            params, sds((1, width), jnp.int32), layers, i32, i32, i32,
+            flash_mode=mode)
+    return traced.lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.fixture(scope="module")
+def older_models():
+    return {fam: TextModel(tiny_config(fam, max_position_embeddings=512,
+                                       **over), dtype=jnp.bfloat16,
+                           max_cache_len=512)
+            for fam, over in _OLDER.items()}
+
+
+@pytest.mark.parametrize("program", _PROGRAMS,
+                         ids=[f"{m}{w}" for m, w in _PROGRAMS])
+@pytest.mark.parametrize("family", list(_OLDER))
+def test_programs_of_128_wide_keys_do_not_reach_the_joined_code(
+        older_models, monkeypatch, family, program):
+    """The 15 programs of the benchmark's older cells (`_decode_slots`,
+    `_prefill_slot` fresh and append at 32 and 256 tokens, three families;
+    Pallas kernels off) lower to the same bytes with everything this rule
+    added taken away: where keys are 128 wide no leaf is joined, no query
+    is spread and no key is reshaped (PR 40 also compared the benchmark's
+    own configurations against its parent: 15 of 15, PERF.md)."""
+    from cake_tpu.models.common import cache, layers
+    from cake_tpu.ops import attention
+    m = older_models[family]
+    assert not cache.joined_key_widths(m.new_cache(1)["layers"])
+    want = _lowered(m, program)
+
+    def never(*a, **k):
+        raise AssertionError("the joined-keys code was reached")
+
+    monkeypatch.setattr(attention, "_spread_queries", never)
+    monkeypatch.setattr(cache, "keys_joined", lambda lc: False)
+    monkeypatch.setattr(layers, "keys_joined", lambda lc: False)
+    m2 = TextModel.__new__(TextModel)       # the same programs, traced anew
+    m2.__dict__.update(m.__dict__)
+    m2._build()
+    assert _lowered(m2, program) == want
+
+
 # -- which path each layer kind takes ----------------------------------------------
 
 def test_attention_paths_by_layer_kind(monkeypatch):
@@ -529,6 +704,9 @@ def test_attention_paths_by_layer_kind(monkeypatch):
                                sink=True) is None
     assert decode_kernel_block(1, 128, cache(192, 128, t=128, hkv=8),
                                jnp.bfloat16, sink=True) is None
+    joined = cache(192, 128)        # as the pool holds keys of 192
+    joined["k"] = joined["k"].reshape(2, 512, 4 * 192)
+    assert decode_kernel_block(1, None, joined, jnp.bfloat16) is None
 
 
 def test_flash_kernel_with_keys_wider_than_values_matches_masked():
@@ -550,9 +728,13 @@ def test_flash_kernel_with_keys_wider_than_values_matches_masked():
                                atol=2e-5)
 
 
-def test_tp_over_four_virtual_devices_gives_the_single_device_logits():
+@pytest.mark.parametrize("step", ["chunk", "decode"])
+def test_tp_over_four_virtual_devices_gives_the_single_device_logits(step):
+    """The joined K leaves split over `tp` on their last axis, whole heads
+    a device (4 x 96 and 8 x 96 over 4); a chunk into a pool row, and a
+    decode step's spread-query read of the sharded leaf."""
     from jax.sharding import Mesh
-    cfg = tiny_config("mimo_v2", num_key_value_heads=4)
+    cfg = tiny_config("mimo_v2")
     params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     ids = [3 + (i * 7) % 200 for i in range(40)]
     want = None
@@ -560,8 +742,16 @@ def test_tp_over_four_virtual_devices_gives_the_single_device_logits():
                             ("tp",))):
         m = TextModel(cfg, params, dtype=jnp.float32, max_cache_len=CTX,
                       mesh=mesh)
-        layers = m.new_cache(2, kv_len=CTX)["layers"]
-        logits, layers = m.prefill_chunk(layers, 1, ids, 0)
+        if step == "chunk":
+            layers = m.new_cache(2, kv_len=CTX)["layers"]
+            assert [lc["k"].ndim for lc in layers] == [3] * 5
+            if mesh is not None:    # a device holds one head of four
+                assert layers[0]["k"].sharding.shard_shape(
+                    layers[0]["k"].shape) == (2, CTX, 96)
+            logits, layers = m.prefill_chunk(layers, 1, ids, 0)
+        else:
+            _, cache = m.prefill(m.new_cache(1, kv_len=CTX), ids)
+            logits, _ = m.decode_logits(cache, 17)
         got = np.asarray(logits[0])
         if want is None:
             want = got

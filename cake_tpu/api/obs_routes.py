@@ -205,6 +205,9 @@ async def flight(request: web.Request) -> web.Response:
     return web.json_response({
         "capacity": recorder.capacity,
         "static": recorder.static,
+        # the iterations that stood still, kept beside the ring (as in
+        # /health's engine block)
+        "stalls": recorder.stalls(),
         "count": len(iterations),
         "iterations": iterations,
     })

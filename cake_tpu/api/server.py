@@ -30,7 +30,8 @@ import time
 from aiohttp import web
 
 from .. import knobs
-from ..obs import API_REQUESTS, API_REQUEST_SECONDS, now
+from ..obs import (API_REQUESTS, API_REQUEST_SECONDS, PROCESS, LoopTick,
+                   now)
 from . import audio as audio_routes
 from . import images as image_routes
 from . import obs_routes
@@ -84,6 +85,16 @@ async def basic_auth_middleware(request, handler):
     return await handler(request)
 
 
+async def _loop_tick(app: web.Application):
+    """The serving loop's own lag, always on (obs/process.py): a 50 ms tick
+    from the app's start to its cleanup — `cake_api_loop_lag_seconds`, the
+    engine block's `loop_lag_ms`, what a stall record reads."""
+    tick = LoopTick(asyncio.get_running_loop(), PROCESS)
+    tick.start()
+    yield
+    tick.stop()
+
+
 def create_app(state: ApiState, basic_auth: str | None = None) -> web.Application:
     app = web.Application(middlewares=[metrics_middleware,
                                        basic_auth_middleware],
@@ -110,6 +121,7 @@ def create_app(state: ApiState, basic_auth: str | None = None) -> web.Applicatio
     app.router.add_get("/api/v1/slo", obs_routes.slo)
     app.router.add_get("/api/v1/flight", obs_routes.flight)
     app.router.add_get("/", ui_routes.index)
+    app.cleanup_ctx.append(_loop_tick)
     # fleet-shared KV tier (CAKE_KVSHARE): blob export/import routes +
     # the per-engine agent. Gated on a paged pool + prefix cache — the
     # contiguous pool has no block plane to share

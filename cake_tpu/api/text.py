@@ -18,8 +18,8 @@ import uuid
 
 from aiohttp import web
 
-from ..obs import (GENERATIONS, TIMELINES, TRACE_HEADER,
-                   current_request_id, set_request_id)
+from ..obs import (GENERATIONS, RECORDER, TIMELINES, TRACE_HEADER,
+                   current_request_id, now, set_request_id)
 from ..ops.sampling import SamplingConfig
 from ..serve import (EngineDown, EngineDraining, PoisonedRequest,
                      QueueDeadlineExceeded, QueueFull,
@@ -706,6 +706,7 @@ async def _sse_drain_inner(request, state: ApiState, cid: str, aiter,
                            ) -> web.StreamResponse:
     await resp.prepare(request)
     created = int(time.time())
+    rid = current_request_id() or cid
 
     def chunk(delta: dict, finish=None) -> bytes:
         payload = {
@@ -738,6 +739,22 @@ async def _sse_drain_inner(request, state: ApiState, cid: str, aiter,
         except (ConnectionError, ConnectionResetError):
             client_gone = True
             cancel()
+
+    async def write_token(text: str) -> None:
+        await write_safe(chunk({"content": text}))
+        # recorder on only: the engine's stream stamped the token when the
+        # scheduler handed it to the loop and when the loop handed it over
+        # here (`aiter.handoff`; the locked fallback's iterator has none);
+        # the span runs from the hand-over until the write returned
+        # (json.dumps + aiohttp's write), `wait_us` is the time before it
+        # (the GIL and the loop's queue)
+        if RECORDER.enabled:
+            handoff = getattr(aiter, "handoff", None)
+            if handoff is not None:
+                t_pump, t_got = handoff
+                RECORDER.add("api.sse_write", int(t_got * 1e6),
+                             int((now() - t_got) * 1e6), cat="api", rid=rid,
+                             wait_us=int((t_got - t_pump) * 1e6))
     try:
         # drain to the DONE sentinel even past EOS: breaking out would
         # abandon pending tokens and drop a worker error raised after the
@@ -749,11 +766,11 @@ async def _sse_drain_inner(request, state: ApiState, cid: str, aiter,
                 continue
             if finish == "length" and tok.text:
                 if matcher is None:
-                    await write_safe(chunk({"content": tok.text}))
+                    await write_token(tok.text)
                     continue
                 safe = matcher.feed(tok.text)
                 if safe:
-                    await write_safe(chunk({"content": safe}))
+                    await write_token(safe)
                 if matcher.stopped:
                     # stop sequence completed: nothing past it is ever
                     # emitted; cancel the producer (frees the engine

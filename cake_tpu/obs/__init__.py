@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from .metrics import (Counter, Gauge, Histogram, LATENCY_BUCKETS,
                       MetricsRegistry, REGISTRY)
+from .process import LoopTick, ProcessWatch
 from .series import Series, SeriesBank
 from .spans import (RECORDER, SPAN_CATALOG, SpanRecorder,
                     current_request_id, jax_trace, new_request_id,
@@ -156,6 +157,51 @@ SERVE_E2E_SECONDS = REGISTRY.histogram(
     "delivery, including queue wait and any preemption/replay), by "
     "request outcome",
     labelnames=("outcome",))
+
+# -- where a run stood still (serve/flight.py's stall records, obs/process.py)
+# Always on: the flight recorder flags an iteration whose wall time plus the
+# gap before it passed max(10 x the ring's median, 500 ms); the process's
+# own witnesses (compiles, collector pauses, the event loop's lag) say
+# whether the program, the process or the machine stood still.
+
+SERVE_STEP_STALLS = REGISTRY.counter(
+    "cake_serve_step_stalls_total",
+    "Scheduler iterations flagged as stalls (wall time plus the gap "
+    "before it over max(10 x the flight ring's median step, 500 ms)), by "
+    "the phase that took most of it (`between` = the gap) and whether a "
+    "compile fell into it (a cold start's do: alert on `compiled=\"no\"`)",
+    labelnames=("phase", "compiled"))   # sweep | admit | plan |
+                                        # decode_dispatch | fetch | fanout |
+                                        # prefill | late_land | between;
+                                        # yes | no
+
+SERVE_STEP_STALL_SECONDS = REGISTRY.counter(
+    "cake_serve_step_stall_seconds_total",
+    "Seconds the flagged iterations took, gap included")
+
+COMPILES = REGISTRY.counter(
+    "cake_compiles_total",
+    "XLA backend compilations this process ran (persistent-cache "
+    "retrievals too), from jax.monitoring")
+
+COMPILE_SECONDS = REGISTRY.counter(
+    "cake_compile_seconds_total",
+    "Seconds spent in those compilations")
+
+GC_PAUSE_SECONDS = REGISTRY.histogram(
+    "cake_gc_pause_seconds",
+    "Collector pauses of 1 ms and more (gc.callbacks; shorter ones cost "
+    "two clock reads and are not observed)")
+
+API_LOOP_LAG_SECONDS = REGISTRY.histogram(
+    "cake_api_loop_lag_seconds",
+    "How late the serving event loop's 50 ms tick fired: the loop's wait "
+    "behind the GIL and its own callbacks")
+
+# process-global, like RECORDER and REGISTRY: one process has one collector,
+# one compiler and (serving) one event loop
+PROCESS = ProcessWatch(COMPILES, COMPILE_SECONDS, GC_PAUSE_SECONDS,
+                       API_LOOP_LAG_SECONDS)
 
 SERVE_QUEUE_TIMEOUTS = REGISTRY.counter(
     "cake_serve_queue_timeouts_total",
@@ -490,4 +536,7 @@ __all__ = [
     "FLEET_SCALE_ACTIONS", "FLEET_SCALE_PENDING_SPAWNS",
     "FLEET_SCALE_MANAGED_REPLICAS",
     "Series", "SeriesBank",
+    "SERVE_STEP_STALLS", "SERVE_STEP_STALL_SECONDS", "COMPILES",
+    "COMPILE_SECONDS", "GC_PAUSE_SECONDS", "API_LOOP_LAG_SECONDS",
+    "PROCESS", "ProcessWatch", "LoopTick",
 ]

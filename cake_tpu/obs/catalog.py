@@ -47,7 +47,7 @@ cluster stages.
 | `GET /api/v1/requests` | recent request ids with retrievable timelines |
 | `GET /api/v1/requests/<id>` | one request's typed lifecycle timeline (`?format=perfetto` for Chrome-trace instant events); on the fleet router this view STITCHES the router tier's events onto the replica's |
 | `GET /api/v1/slo` | the serve TTFT / inter-token / e2e histograms by outcome as JSON, each bucket carrying its sampled exemplar request id |
-| `GET /api/v1/flight` | flight-recorder-on-demand: the scheduler-iteration ring as JSON without waiting for a wedge/DOWN dump, with its `static` part (what holds for every iteration: `joined_keys`, as in `/health`'s `kv_pool`) beside it (`?n=K` for the newest K; 409 without an engine) |
+| `GET /api/v1/flight` | flight-recorder-on-demand: the scheduler-iteration ring as JSON without waiting for a wedge/DOWN dump, with its `static` part (what holds for every iteration: `joined_keys`, as in `/health`'s `kv_pool`) and its `stalls` (the iterations that stood still, kept beside the ring) beside it (`?n=K` for the newest K; 409 without an engine) |
 | `GET /api/v1/fleet/telemetry` | ROUTER ONLY: the fleet telemetry rollup — time-series, burn rates, headroom, outliers (see [telemetry.md](telemetry.md)) |
 | `GET /api/v1/fleet/autoscale` | ROUTER ONLY: the autoscaler's decision ring, policy, and managed-replica lifecycle state (see [autoscaling.md](autoscaling.md); `{"enabled": false}` when the loop is off) |
 
@@ -102,11 +102,28 @@ are children of `serve.step`).
 A timeline snapshot carries `t0_us`, the instant it opened on the span
 recorder's clock (perf_counter microseconds; on Linux also every local
 client's `time.monotonic`): `t0_us + 1000 * t_ms` lays an event beside
-the spans. `obs.spans.sync_mark()` ties that clock to the profiler's: it
-writes a `cake.sync` annotation into the running `jax.profiler` trace
-and records the perf_counter instant taken beside it as a `trace.sync`
-span; `jax_trace()` calls it on entry, so a span export and an xplane
-taken together can be merged.
+the spans. To lay a span export on a `jax.profiler` xplane, write one
+`jax.profiler.TraceAnnotation` beside a `time.perf_counter_ns()` reading
+while the trace runs and subtract the two (the benchmark's `bench.sync`,
+`benchmark/launch_server.py`, does exactly that).
+
+## Outside the step: the gap, the event loop, the SSE writer
+
+What `serve.step` does not cover is counted always, span recorder or not.
+The `_run` loop's own time from one iteration's last stamp to the next
+one's first, when the earlier one left work behind, is the flight
+record's `gap_ms`. The serving event loop re-arms a 50 ms tick and
+records how late it fired (`cake_api_loop_lag_seconds`, the engine
+block's `loop_lag_ms` = `{last, max_60s}`, the ring a stall record reads).
+One span joins them, recorder on only: `api.sse_write` is one streamed
+token through the writer. The scheduler stamps the token as it hands it
+to the loop (`call_soon_threadsafe`), the stream stamps it again as the
+loop hands it over (`ServeEngine.stream`'s iterator carries both as
+`handoff`), and the writer records a span from that hand-over until
+`resp.write` returned (`json.dumps` + aiohttp's write) with `wait_us` =
+the time between the two stamps (the GIL and the loop's queue). With the
+recorder off the scheduler takes no stamp and the writer pays one
+attribute check a token.
 
 ## Engine flight recorder
 
@@ -131,7 +148,13 @@ were, `dropped` = ids fetched and not delivered because their request
 had ended since the dispatch (over the tokens: what the lag wastes),
 spec accepts, queue depth, paged-pool free/used) into a ring
 of the last `CAKE_FLIGHT_RECORDER` iterations: a stuck or slow step says
-which side of the fetch it was on. An iteration that failed or found
+which side of the fetch it was on. The record also covers the whole
+iteration and the gap before it, from the same clock reads: `wall_ms`,
+`ph` = its eight phases in ms (sweep, admit, plan, decode_dispatch,
+fetch, fanout, prefill, late_land: they add up to `wall_ms`), `kind` =
+`decode` / `chunk` / `last_chunk` / `idle`, `of_step` = the iteration
+whose ids it fetched, and `gap_ms` = the `_run` loop's time since the
+previous iteration when that one left work behind, else 0. An iteration that failed or found
 nothing to do leaves its `seq` out of the ring. The supervisor dumps the ring to `CAKE_TRACE_DIR` as JSON
 when the wedge watchdog flags a stuck dispatch or the rebuild budget
 puts the engine DOWN — the post-mortem for the wedge failure mode where
@@ -142,6 +165,35 @@ layers whose keys lie joined in the pool by joined width, which says that
 the rule of `cache.key_row_shape` engaged; a dump carries it too) —
 `cake top` and the profiling workflow inspect a live engine without
 waiting for a failure.
+
+## A run that stood still says where
+
+Always on, beside the ring and never cleared by its turning: an
+iteration whose `wall_ms + gap_ms` passes max(10 x reference, 500 ms) —
+reference = the median `wall_ms` of the ring's last turn (256 records at
+most), taken once a turn — is a STALL. Its flight record's `stall_ms`
+holds the excess (0 on every other record) and a copy is kept (the 64
+newest, plus a count and the total ms of all), joined by what else the
+process saw in that stretch, from the process's own witnesses
+(`cake_tpu/obs/process.py`, installed by `serve.maybe_engine`): `gc_ms`
+(collector pauses of 1 ms and more, `gc.callbacks`), `compiles` /
+`compile_ms` (`jax.monitoring`'s backend compiles, cache retrievals
+too), `loop_lag_ms` (the largest lag of the event loop's tick), and
+`phase`: the largest entry of `ph`, or `between` for the gap. Read it
+so: `fetch` large and nothing else — the device or the runtime; a host
+phase or `between` with `gc_ms` — the collector; with `compiles` — a
+recompile; `loop_lag_ms` of the stall's size, on another thread, with no
+pause of ours — the process or the machine stood still. Served in
+`/health`'s engine block (`stalls` = `{count, total_ms, reference_ms,
+worst}`, every kept record, largest first; `t` is on the recorder's
+clock, so a reader with a window drops the warm-up's compile stalls), in
+`GET /api/v1/flight` and the dump, as one log line a stall (at most one a
+second; a WARNING, or INFO where the iteration compiled, as a start
+without a warm-up does) and in
+`cake_serve_step_stalls_total{phase,compiled}` /
+`cake_serve_step_stall_seconds_total`: alert on `compiled="no"`. The engine block also carries
+`steps_by_kind` (cumulative `{n, ms}` a kind) and `occupancy_sum`: what
+tells two runs apart when neither stalled.
 
 ## Fleet telemetry plane
 

@@ -262,9 +262,13 @@ SPAN_CATALOG: tuple[tuple[str, str], ...] = (
     ("deser", "worker wire phase: payload deserialization (PhaseTimer)"),
     ("fwd", "worker wire phase: stage forward compute (PhaseTimer)"),
     ("ser", "worker wire phase: result serialization (PhaseTimer)"),
-    ("trace.sync", "clock tie: the perf_counter stamp taken beside the "
-                   "`cake.sync` annotation in the profiler's trace "
-                   "(sync_mark)"),
+    ("api.sse_write", "api: one streamed token through the SSE writer, "
+                      "from the instant the event loop handed it over to "
+                      "the instant `resp.write` returned: `json.dumps` and "
+                      "aiohttp's write (args: rid, wait_us = how long it "
+                      "waited between the scheduler's "
+                      "`call_soon_threadsafe` and that hand-over: the GIL "
+                      "and the loop's queue)"),
 )
 
 # device-side vocabulary: every jax.named_scope the programs carry, with the
@@ -311,28 +315,11 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
 )
 
 
-def sync_mark() -> int:
-    """Tie the recorder's clock to the profiler's: a `cake.sync`
-    TraceAnnotation in the running jax.profiler trace, and the
-    perf_counter instant taken beside it recorded as a `trace.sync` span.
-    A reader subtracts the two to lay RECORDER exports and timelines on
-    the xplane's axis. Returns the perf_counter nanosecond stamp."""
-    import jax
-    t_ns = time.perf_counter_ns()
-    with jax.profiler.TraceAnnotation("cake.sync"):
-        time.sleep(0.001)       # wide enough for a viewer to show
-    RECORDER.add("trace.sync", t_ns // 1000,
-                 (time.perf_counter_ns() - t_ns) // 1000, cat="trace",
-                 perf_ns=t_ns)
-    return t_ns
-
-
 @contextlib.contextmanager
 def jax_trace(log_dir: str | None):
     """Wrap a region in a JAX profiler trace (xprof / Perfetto viewable).
     No-op when log_dir is None. Device-side complement to SpanRecorder's
-    host-side spans (ref: tracing-chrome behind --sd-tracing); sync_mark()
-    on entry ties the two clocks."""
+    host-side spans (ref: tracing-chrome behind --sd-tracing)."""
     if not log_dir:
         yield
         return
@@ -340,7 +327,6 @@ def jax_trace(log_dir: str | None):
 
     import jax
     jax.profiler.start_trace(log_dir)
-    sync_mark()
     try:
         yield
     finally:

@@ -73,7 +73,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import knobs
-from ..obs import (RECORDER, SERVE_BATCH_OCCUPANCY, SERVE_E2E_SECONDS,
+from ..obs import (PROCESS, RECORDER, SERVE_BATCH_OCCUPANCY,
+                   SERVE_E2E_SECONDS,
                    SERVE_ITL_SECONDS, SERVE_PREFILL_CHUNKS, SERVE_POISONED,
                    SERVE_PREEMPTIONS, SERVE_QOS_E2E_SECONDS,
                    SERVE_QOS_TTFT_SECONDS, SERVE_QUEUE_TIMEOUTS,
@@ -296,6 +297,34 @@ class ServeRequest:
                 pass
 
 
+class _TokenStream:
+    """What `ServeEngine.stream` hands the API: the async generator over a
+    request's tokens and, beside it, `handoff`: with the span recorder on,
+    (when the scheduler handed the token last yielded to the event loop,
+    when the loop handed it over), else None. The stamps are the stream's,
+    not the token's: the SSE writer reads them for `api.sse_write`."""
+    __slots__ = ("_gen", "_handoff")
+
+    def __init__(self, gen, handoff: list):
+        # the generator writes `handoff[0]`; holding the list (and not the
+        # generator holding this object) keeps abandonment's finalizer a
+        # matter of reference counts
+        self._gen, self._handoff = gen, handoff
+
+    @property
+    def handoff(self) -> tuple[float, float] | None:
+        return self._handoff[0]
+
+    def __aiter__(self):
+        return self
+
+    def __anext__(self):
+        return self._gen.__anext__()
+
+    def aclose(self):
+        return self._gen.aclose()
+
+
 class ServeEngine:
     """Owns the slot pool, the admission queue, and the scheduler thread."""
 
@@ -424,7 +453,12 @@ class ServeEngine:
         # a fetch
         self._emitted = self._retired = self._dropped = 0
         self._fetch_s = 0.0
-        self._chunk_end = None      # (stamp, final) of its prefill chunk
+        self._chunk_kind = None     # `chunk` | `last_chunk` when it ran one
+        self._chunk_end = None      # recorder on: when its dispatch ended
+        # the last stamp of the previous iteration when it left work behind
+        # (busy rows, a queue, a step in flight), else None: the next
+        # iteration's `gap_ms` is its first stamp minus this
+        self._t_prev_end: float | None = None
         self.dead: BaseException | None = None
         # the supervisor needs _stop (watchdog lifetime) — build it after
         # the events, before the scheduler thread can possibly fail
@@ -612,28 +646,37 @@ class ServeEngine:
         loop = asyncio.get_running_loop()
         aq: asyncio.Queue = asyncio.Queue()
 
-        def pump(item):
+        # every queued entry is (item, stamp): with the span recorder on
+        # the stamp is the instant the scheduler handed the item to the
+        # loop, and the writer's `api.sse_write` span reads how long it
+        # waited there (`wait_us`); off, no stamp is taken (None). A token
+        # the engine emitted before the stream subscribed comes from the
+        # backlog, past the pump, and has none either.
+        def pump(item):             # scheduler thread
+            t = now() if RECORDER.enabled else None
             try:
-                loop.call_soon_threadsafe(aq.put_nowait, item)
+                loop.call_soon_threadsafe(aq.put_nowait, (item, t))
             except RuntimeError:
                 pass                    # loop closed; finalizer cancels
 
         for item in req.subscribe(pump):
-            aq.put_nowait(item)
+            aq.put_nowait((item, None))
+        handoff = [None]
 
         async def aiter():
             try:
                 while True:
-                    item = await aq.get()
+                    item, t_pump = await aq.get()
                     if item is ServeRequest.DONE:
                         break
+                    handoff[0] = None if t_pump is None else (t_pump, now())
                     yield item
             finally:
                 req.cancel()
             if "error" in req.result:
                 raise req.result["error"]
 
-        return aiter(), req.result
+        return _TokenStream(aiter(), handoff), req.result
 
     def health(self) -> dict:
         h = {
@@ -686,6 +729,14 @@ class ServeEngine:
         ks = self.kv_share
         if ks is not None:
             h["kvshare"] = ks.health_view()
+        # where a run stood still, and what tells two runs apart when
+        # neither did (flight.py): the stalls beside the ring, the
+        # iterations by kind, the event loop's lag
+        h["stalls"] = self.flight.stalls()
+        h.update(self.flight.totals())
+        lag = PROCESS.loop_lag()
+        if lag is not None:
+            h["loop_lag_ms"] = lag
         if self.spec_drafter is not None:
             h["spec"] = {
                 "drafter": self.spec_drafter.name,
@@ -887,6 +938,9 @@ class ServeEngine:
             ks.run_pending()
         busy = self.pool.busy()
         queued = self.queue.depth() > 0
+        # taken at once: an iteration that fails or finds nothing to do
+        # leaves no stamp for the next one's gap
+        t_prev, self._t_prev_end = self._t_prev_end, None
         if not (busy or queued or self._preempted
                 or self._inflight is not None):
             return False
@@ -1127,11 +1181,12 @@ class ServeEngine:
             keep = self.spec_drafter is None and ks is None
             self._inflight = cur if keep else None
             of_step = t_fan = None
-            t_land = now()
+            t_land = t_mid = now()
             if prev is not None:
                 of_step, t_fan = prev.step, self._land(prev)
+                t_mid = t_fan       # the record's split of fetch | fanout
             t_chunk = now()
-            self._chunk_end = None
+            self._chunk_end = self._chunk_kind = None
             # 5. ...then advance the chosen admission by one chunk, AFTER
             # the lagged fetch: the step fetched has ended, so the chunk
             # queued behind it has begun and at most one other waits
@@ -1168,15 +1223,29 @@ class ServeEngine:
             # flight record: one bounded dict per iteration — the black
             # box the supervisor dumps on wedge/DOWN (see flight.py).
             # fetch_ms is the scheduler blocked on the device, host_ms the
-            # rest of the step: which side of the fetch a slow step was on
+            # rest of the step: which side of the fetch a slow step was on.
+            # `ph` is the same stamps phase by phase (flight.PHASES; the
+            # lagged landing splits at `t_fan`), `gap_ms` the `_run` loop's
+            # own time since the previous iteration, when that left work
             fetch_s = self._fetch_s - fetch_s0
+            wall_ms = round((t_end - t_sweep) * 1e3, 3)
+            fetch_ms = round(fetch_s * 1e3, 3)
+            stamps = (t_sweep, t_admit, t_plan, t_dispatch, t_land, t_mid,
+                      t_chunk, t_late, t_end)
             rec = {
+                "kind": self._chunk_kind
+                or ("decode" if active else "idle"),
+                "wall_ms": wall_ms,
+                "gap_ms": 0.0 if t_prev is None
+                else round((t_sweep - t_prev) * 1e3, 3),
+                "ph": [round((b - a) * 1e3, 3)
+                       for a, b in zip(stamps, stamps[1:])],
                 "occupancy": len(active), "bucket": nb,
                 "kv_tokens": kv_tokens, "ring_tokens": ring_tokens,
                 "state_bytes": state_bytes,
-                "host_ms": round((t_end - t_sweep - fetch_s) * 1e3, 3),
-                "fetch_ms": round(fetch_s * 1e3, 3),
-                "lag": lag, "dropped": dropped,
+                "host_ms": round(wall_ms - fetch_ms, 3),
+                "fetch_ms": fetch_ms,
+                "lag": lag, "of_step": of_step, "dropped": dropped,
                 "queued": self.queue.depth(),
                 "prefilling": len(self._prefills),
                 "spec_accepted": self.spec_accepted - spec_acc0,
@@ -1185,6 +1254,9 @@ class ServeEngine:
                 rec["kv_free"] = self.paged.alloc.free_count
                 rec["kv_used"] = self.paged.alloc.used_count
             self.flight.record(step, **rec)
+            if self.pool.busy_count or self._inflight is not None \
+                    or self.queue.depth():
+                self._t_prev_end = t_end
         return True
 
     def _land(self, fl: _InFlight) -> float:
@@ -1257,9 +1329,8 @@ class ServeEngine:
         if of_step is not None and of_step != step:
             landed(t_land, t_chunk)
         if self._chunk_end is not None:
-            t_done, final = self._chunk_end
-            add("serve.prefill_finish", int(t_done * 1e6), t_late,
-                final=final)
+            add("serve.prefill_finish", int(self._chunk_end * 1e6), t_late,
+                final=self._chunk_kind == "last_chunk")
         if of_step == step:
             landed(t_late, t_end)
 
@@ -1345,9 +1416,10 @@ class ServeEngine:
                         pf.ids[pf.pos:pf.pos + take], pf.pos)
             pf.pos += take
             pf.chunks += 1
+            self._chunk_kind = "last_chunk" if pf.pos >= pf.n else "chunk"
             if RECORDER.enabled:
                 # where serve.prefill_finish begins (_emit_phases)
-                self._chunk_end = (now(), pf.pos >= pf.n)
+                self._chunk_end = now()
             TIMELINES.event(pf.req.id, "prefill_chunk", step=self._step_id,
                             pos0=pf.pos - take, tokens=take,
                             attn=self.model.last_chunk_attn)
@@ -2160,6 +2232,10 @@ def maybe_engine(model, slots: int | None = None,
     inside ServeEngine. Distributed / offloaded models return None —
     the API keeps its locked fallback."""
     from ..models.common.text_model import TextModel
+    # the process's own witnesses of a stall (obs/process.py): compiles and
+    # collector pauses count from here on, for `cake serve` as for every
+    # other embedding of the engine
+    PROCESS.install()
     if not isinstance(model, TextModel):
         return None
     if slots is None:

@@ -14,7 +14,7 @@ import pytest
 
 from cake_tpu.models import TextModel, tiny_config
 from cake_tpu.obs import RECORDER, TIMELINES, SpanRecorder, TimelineStore
-from cake_tpu.obs.spans import SCOPE_CATALOG, SPAN_CATALOG, sync_mark
+from cake_tpu.obs.spans import SCOPE_CATALOG, SPAN_CATALOG
 from cake_tpu.ops import sampling
 from cake_tpu.ops.sampling import SamplingConfig
 from cake_tpu.serve import ServeEngine
@@ -113,21 +113,11 @@ def test_timeline_snapshot_is_on_the_recorders_clock():
     assert chrome["ts"] == int(tl["t0_us"] + tl["events"][0]["t_ms"] * 1e3)
 
 
-def test_sync_mark_records_the_clock_tie():
-    RECORDER.clear()
-    RECORDER.enable()
-    try:
-        t_ns = sync_mark()
-    finally:
-        RECORDER.disable()
-    (ev,) = [e for e in RECORDER.events() if e["name"] == "trace.sync"]
-    assert ev["args"]["perf_ns"] == t_ns and ev["ts"] == t_ns // 1000
-    RECORDER.clear()
-
-
 def test_catalogs_name_the_phases_and_scopes():
     spans = {n for n, _ in SPAN_CATALOG}
-    assert set(LEAVES) | {"serve.step", "trace.sync"} <= spans
+    assert set(LEAVES) | {"serve.step", "api.sse_write"} <= spans
+    # the gap and the loop's lag are counted always and drawn by no span
+    assert not {"trace.sync", "serve.between", "api.loop_tick"} & spans
     assert len(set(SCOPES)) == len(SCOPES) == 16
 
 

@@ -275,7 +275,7 @@ SPAN_CATALOG: tuple[tuple[str, str], ...] = (
 # code it wraps. A scope is metadata on the ops traced inside it (the HLO
 # `op_name` holds `.../cake.attn/...`), so a trace reader can sum device
 # time by part of the model across edits that renumber the fusions. Nested
-# scopes nest in the name: `cake.sample/cake.sample.sort`.
+# scopes nest in the name: `cake.sample/cake.sample.select`.
 SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
     ("cake.embed", "token embedding lookup (layers.embed_tokens)"),
     ("cake.attn", "one layer's attention incl. its KV write "
@@ -306,11 +306,15 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
                     "verify programs' spec_accept (ops.sampling)"),
     ("cake.sample.penalty", "sample_traced: repeat-penalty flag scatter "
                             "and select"),
-    ("cake.sample.sort", "sample_traced: temperature scaling and the one "
-                         "descending sort of the vocabulary that returns "
-                         "both the sorted logits and their ids"),
-    ("cake.sample.top_p", "sample_traced: softmax, cumulative mass and "
-                          "the keep mask"),
+    ("cake.sample.select", "keep_mask: the vocabulary filter in vocabulary "
+                           "order, the ordered keys and both searches "
+                           "(sample_traced and filtered_probs)"),
+    ("cake.sample.top_k", "keep_mask: the search for the k-th largest value "
+                          "and the id that cuts its tied run; no pass with "
+                          "top_k >= V"),
+    ("cake.sample.top_p", "keep_mask: the survivors' softmax weights and "
+                          "the search for the value and id where the mass "
+                          "before a token reaches top_p"),
     ("cake.sample.draw", "sample_traced: gumbel noise and argmax"),
 )
 

@@ -130,6 +130,130 @@ def sample(logits, rng, cfg: SamplingConfig, recent_tokens=None):
     return sample_top_k_top_p(logits, rng, cfg.top_k, cfg.top_p, cfg.temperature)
 
 
+# -- the vocabulary filter, in vocabulary order --------------------------------
+#
+# top-k and top-p both keep a PREFIX of the vocabulary ranked by (value
+# descending, id ascending): a token stays while the weight strictly before it
+# (1 a token for top-k, its probability for top-p) is below the target. A
+# prefix is named by one value threshold and, where a run of tied values
+# straddles the cut, one id threshold inside the run, and both are found by
+# searching: each pass is a handful of masked sums over the row, where the
+# sort this replaces was the largest device op of a decode step (PERF.md,
+# PR 42).
+
+_FAN_BITS = 3                  # a pass tries 2**bits - 1 thresholds at once
+_MAX_PASSES = 32               # of one search; 32 bits need ceil(32/bits)
+_KEY_LOW = -2 ** 31            # below the key of every float but a NaN
+
+
+def _ordered_key(x):
+    """int32 image of float32 in the same order (-0.0 joins +0.0, as the
+    sort's comparator has it): what a search can halve bit by bit."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.int32)
+    return jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+def _search(ok, lo, hi, need):
+    """The smallest x in (lo, hi] with ok(x), for an ok that goes from false
+    to true once as x grows; ok at lo is taken as false and at hi as true
+    without being looked at. ok maps int32[m] candidates to their [m]
+    answers in ONE pass over the row. Returns (x, passes).
+
+    A while_loop whose predicate is the data's: with `need` false it runs
+    no pass, and under vmap it runs while ANY row's interval is open."""
+    m = (1 << _FAN_BITS) - 1
+    j = jnp.arange(1, m + 1, dtype=jnp.uint32)
+
+    def width(lo, hi):          # hi - lo, which 32 signed bits may not hold
+        return jax.lax.bitcast_convert_type(hi - lo, jnp.uint32)
+
+    def cond(c):
+        lo, hi, passes = c
+        return need & (width(lo, hi) > 1) & (passes < _MAX_PASSES)
+
+    def body(c):
+        lo, hi, passes = c
+        w = width(lo, hi)
+        # floor(w * j / 2**bits) without the product's overflow, kept inside
+        off = (w >> _FAN_BITS) * j + (((w & m) * j) >> _FAN_BITS)
+        x = lo + jax.lax.bitcast_convert_type(jnp.clip(off, 1, w - 1),
+                                              jnp.int32)
+        good = ok(x)
+        return (jnp.max(jnp.where(good, lo, x)),
+                jnp.min(jnp.where(good, x, hi)), passes + 1)
+
+    _, hi, passes = jax.lax.while_loop(cond, body, (lo, hi, jnp.int32(0)))
+    return hi, passes
+
+
+def _prefix_cut(key, key_max, weight, target, need):
+    """The prefix of (key descending, id ascending) order that is kept: a
+    token while the weight strictly before it is < target, so every token
+    above one value t and, of the run tied at t, the ids up to one id i.
+    With `need` false everything is kept and no pass is made. Returns the
+    mask and the two searches' pass counts."""
+    v = key.shape[-1]
+    ids = jnp.arange(v, dtype=jnp.int32)
+
+    def weigh(mask):            # [m, V] masks to the [m] weights they hold
+        return jnp.sum(jnp.where(mask, weight[None, :], 0.0), axis=-1)
+
+    t, value_passes = _search(
+        lambda x: weigh(key[None, :] > x[:, None]) < target,
+        jnp.int32(_KEY_LOW), key_max, need)
+    t = jnp.where(need, t, _KEY_LOW)
+    # the run of tokens tied at the cut is kept lowest ids first; it is
+    # searched only if it has a second token and does not fit whole
+    tied = key == t
+    before, run = weigh(jnp.stack([key > t, tied]))
+    split = need & (jnp.sum(tied) > 1) & (before + run >= target)
+    i, id_passes = _search(
+        lambda x: before + weigh(tied[None, :] & (ids[None, :] <= x[:, None]))
+        >= target, jnp.int32(-1), jnp.int32(v - 1), split)
+    return (key > t) | (tied & (ids <= i)), (value_passes, id_passes)
+
+
+def _keep_mask_and_passes(scaled, top_k, top_p):
+    v = scaled.shape[-1]
+    key = _ordered_key(scaled)
+    top = jnp.max(scaled)
+    key_max = _ordered_key(top)
+    with jax.named_scope("cake.sample.top_k"):
+        k = jnp.clip(top_k, 1, v)
+        in_k, passes_k = _prefix_cut(key, key_max,
+                                     jnp.ones((v,), jnp.float32),
+                                     k.astype(jnp.float32), k < v)
+    with jax.named_scope("cake.sample.top_p"):
+        # top-p mass is measured on the top-k-truncated RENORMALIZED
+        # distribution, matching sample_top_k_top_p's softmax-within-top-k;
+        # the target is scaled by the sum and not each weight divided. A
+        # target above 0 keeps the largest token whatever top_p says
+        e = jnp.where(in_k, jnp.exp(scaled - top), 0.0)
+        target = jnp.maximum(top_p * jnp.sum(e), jnp.finfo(jnp.float32).tiny)
+        in_p, passes_p = _prefix_cut(key, key_max, e, target, top_p < 1.0)
+    return in_k & in_p, passes_k + passes_p
+
+
+def keep_mask(scaled, top_k, top_p):
+    """Which of the scaled logits f32[V] the top-k / top-p filter keeps, as
+    bool[V] in vocabulary order, with no sort:
+
+    top_k: the top_k largest values, a tied run at the k-th value cut by
+    LOWEST id first; top_k >= V keeps all. top_p: on the softmax over the
+    top-k survivors, a token stays while the mass strictly before it in
+    (value descending, id ascending) order is < top_p; the largest token
+    always stays; top_p >= 1 keeps all. This is the set a stable descending
+    sort with a rank mask and a running mass would keep, but that a mass is
+    a masked sum here: a token whose preceding mass lies within float32
+    rounding of top_p may fall on the other side.
+
+    What is disabled costs no pass: each search is a while_loop whose
+    predicate the data closes (top_k >= V, top_p >= 1, no tied run
+    straddling a cut)."""
+    with jax.named_scope("cake.sample.select"):
+        return _keep_mask_and_passes(scaled, top_k, top_p)[0]
+
+
 @jax.named_scope("cake.sample")
 def sample_traced(logits, rng, temperature, top_k, top_p, repeat_penalty,
                   recent_tokens):
@@ -143,14 +267,15 @@ def sample_traced(logits, rng, temperature, top_k, top_p, repeat_penalty,
     Disabled values: temperature <= 0 -> argmax, top_p >= 1.0 -> off,
     repeat_penalty == 1.0 -> identity (naturally, via the arithmetic).
 
-    Equivalence to the static `sample` dispatch: temperature <= 0 matches
-    sample_argmax after the same penalty (the sort of the negated logits is
-    stable, so ties break to the lowest id exactly like jnp.argmax); the
-    stochastic paths draw gumbel noise over the full sorted vocab instead
-    of the top-k prefix, so they match in distribution, not per-key.
+    Equivalence to the static `sample` dispatch. EXACT: temperature <= 0
+    is sample_argmax after the same penalty, ties to the lowest id. IN
+    DISTRIBUTION, not per key: the stochastic paths keep the set the static
+    ones keep (keep_mask; the static top-p breaks a tied run at the cut by
+    highest id) and draw gumbel noise over the whole vocabulary in
+    vocabulary order, where the static ones draw it over the sorted prefix.
 
     The named scopes (obs.spans.SCOPE_CATALOG) are metadata for a device
-    trace's reader: which of the four parts the step's time is in.
+    trace's reader: which of the parts the step's time is in.
     """
     v = logits.shape[-1]
     with jax.named_scope("cake.sample.penalty"):
@@ -161,36 +286,22 @@ def sample_traced(logits, rng, temperature, top_k, top_p, repeat_penalty,
         penalized = jnp.where(lf >= 0, lf / repeat_penalty,
                               lf * repeat_penalty)
         lf = jnp.where(flagged, penalized, lf)
-    with jax.named_scope("cake.sample.sort"):
-        # one descending sort serves argmax (rank 0), top-k (rank mask) and
-        # top-p (cumulative-mass mask) — same O(V log V) the static top-p
-        # pays. The sort's own first output is the sorted values (negated:
-        # exact), so nothing gathers [V] by `order`
-        scaled = lf / jnp.maximum(temperature, 1e-6)
-        neg_sorted, order = _sort_with_order(-scaled)  # stable: ties -> low id
-        sorted_logits = -neg_sorted
-    with jax.named_scope("cake.sample.top_p"):
-        rank = jnp.arange(v, dtype=jnp.int32)
-        # top-p mass is measured on the top-k-truncated RENORMALIZED
-        # distribution, matching sample_top_k_top_p's softmax-within-top-k
-        # (with top_k >= V the where is identity, so pure top-p matches too)
-        probs = jax.nn.softmax(
-            jnp.where(rank < top_k, sorted_logits, -jnp.inf))
-        prev_mass = jnp.cumsum(probs) - probs
-        keep = (rank < top_k) & (prev_mass < top_p)
-        keep = keep.at[0].set(True)                    # never mask every token
+    scaled = lf / jnp.maximum(temperature, 1e-6)
+    sampled = temperature > 0.0
+    # a greedy row reads no mask, so it asks for none: its searches are closed
+    keep = keep_mask(scaled, jnp.where(sampled, top_k, v),
+                     jnp.where(sampled, top_p, 1.0))
     with jax.named_scope("cake.sample.draw"):
-        z = jnp.where(keep, sorted_logits, -jnp.inf) + _gumbel(rng, (v,))
-        choice = order[jnp.argmax(z)]
-        return jnp.where(temperature > 0.0, choice,
-                         order[0]).astype(jnp.int32)
+        z = jnp.where(keep, scaled, -jnp.inf) + _gumbel(rng, (v,))
+        return jnp.where(sampled, jnp.argmax(z),
+                         jnp.argmax(scaled)).astype(jnp.int32)
 
 
 def config_has_filters(scfg: "SamplingConfig") -> bool:
     """True when `scfg` actually filters the vocabulary (top-k or
     top-p enabled) — the host-side gate for the verify programs' static
     `use_filters` escape hatch. Greedy and pure-temperature configs
-    return False: their target distribution needs no sort."""
+    return False: their target distribution keeps every token."""
     return scfg.top_k is not None or (
         scfg.top_p is not None and scfg.top_p < 1.0)
 
@@ -212,21 +323,19 @@ def filtered_probs(logits, temperature, top_k, top_p, repeat_penalty,
     argmax of the gumbel-perturbed logits, so it never materializes p).
 
     Same traced pipeline as sample_traced: sign-aware repeat penalty,
-    temperature, one descending sort serving the top-k rank mask and the
-    top-p cumulative-mass mask measured on the top-k-renormalized
-    distribution. temperature <= 0 degenerates to (almost) a point mass at
-    the penalized argmax — ties split evenly, and downstream greedy
-    consumers take jnp.argmax(p), which breaks ties to the lowest id
-    exactly like sample_argmax.
+    temperature, and keep_mask's set (top-k by rank, top-p by the mass
+    before a token on the top-k-renormalized distribution), renormalized.
+    temperature <= 0 degenerates to (almost) a point mass at the penalized
+    argmax — ties split evenly, and downstream greedy consumers take
+    jnp.argmax(p), which breaks ties to the lowest id exactly like
+    sample_argmax.
 
     `use_filters` is a STATIC escape hatch for callers that know top_k
     and top_p are disabled for the whole dispatch (greedy and pure-
-    temperature traffic — the serve engine's common case): the sort that
-    serves the rank and cumulative-mass masks is skipped entirely and p
-    is the plain penalized/tempered softmax. XLA's CPU sort is slow
-    enough that it dominated the batched verify's accept rule; with
-    filters disabled the masks are identity, so skipping the sort is
-    exact (argmax and softmax are permutation-free)."""
+    temperature traffic — the serve engine's common case): p is the plain
+    penalized/tempered softmax. keep_mask gives the same p there (it keeps
+    everything and its searches make no pass); the hatch saves its few
+    passes outside the searches."""
     v = logits.shape[-1]
     lf = logits.astype(jnp.float32)
     idx = jnp.where(recent_tokens < 0, v, recent_tokens)
@@ -236,16 +345,8 @@ def filtered_probs(logits, temperature, top_k, top_p, repeat_penalty,
     scaled = lf / jnp.maximum(temperature, 1e-6)
     if not use_filters:
         return jax.nn.softmax(scaled)
-    neg_sorted, order = _sort_with_order(-scaled)      # stable: ties -> low id
-    sorted_logits = -neg_sorted
-    rank = jnp.arange(v, dtype=jnp.int32)
-    probs = jax.nn.softmax(jnp.where(rank < top_k, sorted_logits, -jnp.inf))
-    prev_mass = jnp.cumsum(probs) - probs
-    keep = (rank < top_k) & (prev_mass < top_p)
-    keep = keep.at[0].set(True)                        # never mask every token
-    kept = jnp.where(keep, probs, 0.0)
-    kept = kept / jnp.maximum(jnp.sum(kept), 1e-30)
-    return jnp.zeros((v,), jnp.float32).at[order].set(kept)
+    return jax.nn.softmax(
+        jnp.where(keep_mask(scaled, top_k, top_p), scaled, -jnp.inf))
 
 
 @jax.named_scope("cake.sample")
@@ -284,13 +385,12 @@ def spec_accept(logits, draft, n_draft, rng, temperature, top_k, top_p,
     prefix length falls out of a cumulative product, and the per-row
     penalty windows are a sliding gather over [recent ; draft]. A
     sequential fori_loop here cost ~1 ms/step on CPU (it serialized k
-    sorts and k threefry folds) and dominated the whole batched-verify
+    filters and k threefry folds) and dominated the whole batched-verify
     dispatch; the vectorized rule is shape-identical and draws the SAME
     per-row uniforms (fold_in(rng, i)), so outcomes are unchanged.
 
     `use_filters` (STATIC) mirrors filtered_probs': pass False when the
-    caller knows every slot in the dispatch has top-k/top-p disabled and
-    the per-row sorts vanish.
+    caller knows every slot in the dispatch has top-k/top-p disabled.
     """
     k = draft.shape[0]
     n = recent_tokens.shape[0]

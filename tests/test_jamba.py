@@ -18,6 +18,7 @@ Pinned here (HF parity is tests/test_hf_parity.py::test_jamba):
 import importlib
 import json
 import os
+import re
 import sys
 import types
 
@@ -260,7 +261,10 @@ def test_decode_program_holds_no_loop_and_the_chunk_holds_the_scan(model):
         jnp.full((slots,), 256, jnp.int32), jnp.ones((slots,), jnp.float32),
         jnp.ones((slots,), jnp.float32), z(jnp.bool_)
     ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
-    assert "stablehlo.while" not in text
+    # but the sampler's four searches (sampling.keep_mask), no loop
+    loops = re.findall(r'loc\("([^"]*/while)"', text)
+    assert text.count("stablehlo.while") == 4
+    assert loops and all("cake.sample.select" in name for name in loops)
     for scope in ("cake.ssm.proj", "cake.ssm.conv",
                   "cake.ssm.scan", "cake.attn", "cake.ffn"):
         assert scope in text, scope

@@ -118,7 +118,7 @@ def test_catalogs_name_the_phases_and_scopes():
     assert set(LEAVES) | {"serve.step", "api.sse_write"} <= spans
     # the gap and the loop's lag are counted always and drawn by no span
     assert not {"trace.sync", "serve.between", "api.loop_tick"} & spans
-    assert len(set(SCOPES)) == len(SCOPES) == 16
+    assert len(set(SCOPES)) == len(SCOPES) == 17
 
 
 # -- the engine: phases of one iteration ------------------------------------
@@ -406,7 +406,8 @@ def test_programs_carry_the_scopes_of_the_catalog(moe_model, program):
         {s for s in SCOPES if not s.startswith("cake.sample")} - SSM
     assert found == want - WINDOW
     if program == "_decode_slots":
-        assert "vmap(cake.sample)/cake.sample.sort/" in text
+        assert ("vmap(cake.sample)/cake.sample.select/cake.sample.top_p/while"
+                in text)
     else:
         assert "/cake.ffn/cake.ffn.route/" in text
 

@@ -120,6 +120,11 @@ class ModelConfig:
     # layers entirely — gemma3/block.rs:62 — which diverges from the HF
     # semantics real checkpoints were trained with, so we follow HF).
     local_rope_theta: float | None = None
+    # the local table's own rotary width and scaling (Laguna: window layers
+    # rotate all of a head under the plain table, full layers half of it
+    # under YaRN); None = the global table's width, never scaled
+    local_partial_rotary_factor: float | None = None
+    local_rope_scaling: RopeScaling | None = None
     hidden_act: str = "silu"       # 'silu' | 'gelu_tanh'
     embed_scale: float | None = None
     model_prefix: str = "model"
@@ -129,12 +134,22 @@ class ModelConfig:
     moe_intermediate_size: int | None = None
     norm_topk_prob: bool = False
     shared_expert_intermediate_size: int | None = None
+    # the shared expert's output passes a sigmoid gate of its own
+    # (Qwen3.5 MoE's `shared_expert_gate`); False: it is added as it is
+    shared_expert_gated: bool = True
+    # the selected experts' weights are multiplied by this after their
+    # normalisation (Laguna's `moe_routed_scaling_factor`)
+    moe_routed_scale: float = 1.0
     moe_gate_act: str = "softmax"  # 'softmax' | 'sigmoid' (Qwen3.5 MoE shared gate)
     decoder_sparse_step: int = 1
     mlp_only_layers: tuple[int, ...] = ()
     # Linear (recurrent) attention
     linear_attn: LinearAttnConfig | None = None
     attn_output_gate: bool = False
+    # one sigmoid gate a query head, from a projection of its own of the
+    # layer's normed input (`g_proj` [heads, hidden]), on every attention
+    # layer at that layer's head count (Laguna's `gating: per-head`)
+    attn_head_gate: bool = False
     # Mamba-1 state-space layers (Jamba); its attention layers are NoPE
     mamba: MambaConfig | None = None
     # Attention logit scale override (None = head_dim**-0.5); Gemma3 models
@@ -218,6 +233,48 @@ class ModelConfig:
     def rotary_dim(self) -> int:
         return int(self.head_dim * self.partial_rotary_factor)
 
+    @property
+    def local_rotary_dim(self) -> int:
+        """The local table's rotary width: its own where it has one."""
+        if self.local_partial_rotary_factor is None:
+            return self.rotary_dim
+        hd = self.swa_attn.head_dim if self.swa_attn else self.head_dim
+        return int(hd * self.local_partial_rotary_factor)
+
+    def rotary_dim_of(self, spec: LayerSpec) -> int:
+        """How many of a head's leading dims a layer of this kind
+        rotates."""
+        return (self.local_rotary_dim if spec.local_rope_table
+                else self.rotary_dim)
+
+    def attention_kinds(self) -> list[dict]:
+        """One entry a distinct kind of attention layer, in layer order:
+        how many layers, their heads, K/V heads, window, the dims they
+        rotate and the table they read (health's static part and the
+        flight record's say which table each kind read)."""
+        kinds: dict = {}
+        for spec in self.layer_specs():
+            if spec.recurrent:
+                continue
+            a = self.attn_shape(spec)
+            local = spec.local_rope_table
+            sc = self.local_rope_scaling if local else self.rope_scaling
+            key = (spec.kind, a.heads, a.kv_heads, spec.window, local)
+            if key not in kinds:
+                kinds[key] = {
+                    "kind": spec.kind, "layers": 0, "heads": a.heads,
+                    "kv_heads": a.kv_heads, "window": spec.window,
+                    "rotary_dim": (self.rotary_dim_of(spec)
+                                   if spec.use_rope else 0),
+                    "rope_theta": ((self.local_rope_theta if local
+                                    else self.rope_theta)
+                                   if spec.use_rope else None),
+                    "rope_scaling": ((sc.rope_type or "llama3")
+                                     if sc is not None and spec.use_rope
+                                     else None)}
+            kinds[key]["layers"] += 1
+        return list(kinds.values())
+
     def is_eos(self, token_id: int) -> bool:
         return token_id in self.eos_token_ids
 
@@ -234,6 +291,7 @@ def _eos_tuple(v) -> tuple[int, ...]:
 def _rope_scaling(d: dict | None) -> RopeScaling | None:
     if not d:
         return None
+    af = d.get("attention_factor")
     return RopeScaling(
         factor=float(d.get("factor", 1.0)),
         high_freq_factor=float(d.get("high_freq_factor", 4.0)),
@@ -241,6 +299,9 @@ def _rope_scaling(d: dict | None) -> RopeScaling | None:
         original_max_position_embeddings=int(
             d.get("original_max_position_embeddings", 8192)),
         rope_type=d.get("rope_type") or d.get("type"),
+        beta_fast=float(d.get("beta_fast") or 32.0),
+        beta_slow=float(d.get("beta_slow") or 1.0),
+        attention_factor=None if af is None else float(af),
     )
 
 
@@ -500,6 +561,106 @@ def _mimo_v2(d):
     ))
 
 
+# the rope types the laguna adapter takes for a layer kind; every other is
+# refused (ops/rope.py would serve it as unscaled rope, with a warning)
+_LAGUNA_ROPE_TYPES = ("default", "linear", "llama3", "yarn")
+
+
+def _laguna(d):
+    """Laguna (poolside Laguna-S-2.1, `model_type: laguna`): layer i
+    attends in full iff `layer_types[i] == "full_attention"`, else over a
+    window of `sliding_window`; the two kinds share K/V heads and head size
+    and differ in query heads (`num_attention_heads_per_layer`, constant
+    within a kind) and in rope (`rope_parameters` by kind: base, scaling
+    and the part of a head that rotates). Every attention layer has a
+    sigmoid gate a head from a projection of its own (`gating: per-head`);
+    q and k are RMS-normed a head (assumed: the Qwen3-MoE lineage whose
+    key names this config keeps). FFNs: dense iff `mlp_layer_types[i] ==
+    "dense"` (or i in `mlp_only_layers`), else a softmax router over
+    `num_experts`, top-k renormalised and multiplied by
+    `moe_routed_scaling_factor`, plus one shared expert added ungated.
+    `expert_parallel: {size, rank}` as `mimo_v2` reads it. What this
+    adapter cannot honour it refuses."""
+    n = int(d["num_hidden_layers"])
+    kinds = list(d["layer_types"])[:n]
+    if len(kinds) != n or set(kinds) - {"full_attention",
+                                         "sliding_attention"}:
+        raise ValueError(f"laguna: layer_types {sorted(set(kinds))} for "
+                         f"{n} layers")
+    is_full = tuple(k == "full_attention" for k in kinds)
+    per_layer = list(d.get("num_attention_heads_per_layer")
+                     or [d["num_attention_heads"]] * n)[:n]
+    heads = {}
+    for full in (True, False):
+        counts = {int(h) for h, f in zip(per_layer, is_full) if f == full}
+        if len(counts) > 1:
+            raise ValueError(
+                "laguna: num_attention_heads_per_layer varies within the "
+                f"{'full' if full else 'sliding'} layers: {sorted(counts)}")
+        heads[full] = counts.pop() if counts else int(
+            d["num_attention_heads"])
+    if d.get("gating", "per-head") != "per-head" or set(
+            d.get("gating_types") or ["per_head"]) != {"per_head"}:
+        raise ValueError(f"laguna: gating {d.get('gating')!r} / "
+                         f"{sorted(set(d.get('gating_types') or []))} "
+                         "(only a per-head gate is implemented)")
+    if d.get("moe_apply_router_weight_on_input"):
+        raise ValueError("laguna: moe_apply_router_weight_on_input true "
+                         "is not implemented")
+    if float(d.get("moe_router_logit_softcapping") or 0.0) != 0.0:
+        raise ValueError("laguna: a router logit softcap other than 0 is "
+                         "not implemented")
+    if d.get("attention_bias"):
+        raise ValueError("laguna: attention_bias true is not implemented")
+    rp = d.get("rope_parameters") or {}
+
+    def rope_of(kind):
+        r = rp.get(kind) or {}
+        rtype = r.get("rope_type") or r.get("type") or "default"
+        if rtype not in _LAGUNA_ROPE_TYPES:
+            raise ValueError(f"laguna: rope_type {rtype!r} of {kind} is "
+                             "not implemented")
+        return (float(r.get("rope_theta", d.get("rope_theta", 10000.0))),
+                None if rtype == "default" else _rope_scaling(r),
+                float(r.get("partial_rotary_factor", 1.0)))
+
+    theta, scaling, partial = rope_of("full_attention")
+    ltheta, lscaling, lpartial = rope_of("sliding_attention")
+    mlp_kinds = list(d.get("mlp_layer_types") or [])[:n]
+    dense = set(int(i) for i in d.get("mlp_only_layers") or ()) | {
+        i for i, k in enumerate(mlp_kinds) if k == "dense"}
+    held = int(d["num_experts"])
+    ep = d.get("expert_parallel") or {"size": 1, "rank": 0}
+    size, rank = int(ep["size"]), int(ep["rank"])
+    if not 0 <= rank < size:
+        raise ValueError(f"laguna: expert_parallel rank {rank} of {size}")
+    hd = int(d["head_dim"])
+    kv = int(d["num_key_value_heads"])
+    return ModelConfig(**_base(
+        d, "laguna", num_attention_heads=heads[True],
+        rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+        qk_norm=True, attn_head_gate=True,
+        rope_theta=theta, rope_scaling=scaling,
+        partial_rotary_factor=partial,
+        local_rope_theta=ltheta, local_rope_scaling=lscaling,
+        local_partial_rotary_factor=lpartial,
+        swa_attn=AttnShape(heads[False], kv, hd, hd),
+        sliding_window=int(d["sliding_window"]),
+        global_layers=is_full,
+        num_experts=held, router_experts=held * size,
+        expert_first=held * rank,
+        num_experts_per_tok=int(d["num_experts_per_tok"]),
+        moe_intermediate_size=int(d["moe_intermediate_size"]),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        moe_routed_scale=float(d.get("moe_routed_scaling_factor") or 1.0),
+        shared_expert_intermediate_size=(
+            d.get("shared_expert_intermediate_size") or None),
+        shared_expert_gated=False,
+        decoder_sparse_step=int(d.get("decoder_sparse_step", 1)),
+        mlp_only_layers=tuple(sorted(dense)),
+    ))
+
+
 def _jamba(d):
     """Jamba / Jamba2 (HF JambaForCausalLM): Mamba-1 layers with attention
     at every `attn_layer_period`-th layer from `attn_layer_offset`
@@ -550,6 +711,7 @@ ARCH_ADAPTERS = {
     "JambaForCausalLM": _jamba,
     "MiMoV2ForCausalLM": _mimo_v2,
     "MiMoV2FlashForCausalLM": _mimo_v2,
+    "LagunaForCausalLM": _laguna,
 }
 
 # short family names (CLI --arch overrides, tests)
@@ -560,7 +722,7 @@ FAMILY_ADAPTERS = {
     "phi4": _phi4, "phi3": _phi4,
     "mistral": _mistral, "gemma3": _gemma3, "falcon3": _falcon3,
     "olmo2": _olmo2, "exaone4": _exaone4, "jamba": _jamba,
-    "mimo_v2": _mimo_v2,
+    "mimo_v2": _mimo_v2, "laguna": _laguna,
 }
 
 
@@ -619,6 +781,33 @@ def tiny_config(arch: str = "llama", **over) -> ModelConfig:
                  n_routed_experts=4, num_experts_per_tok=2,
                  moe_intermediate_size=32, n_group=1, topk_group=1,
                  norm_topk_prob=True, scoring_func="sigmoid",
+                 expert_parallel={"size": 2, "rank": 0})
+    if arch == "laguna":
+        # a dense first layer, both kinds twice in the published order,
+        # window layers of 6 query heads beside full layers of 4 on the
+        # same 2 K/V heads, YaRN over half of a head on the full layers,
+        # a share of 4 of 8 experts beside a shared one
+        d.update(num_hidden_layers=5, num_attention_heads=4,
+                 num_key_value_heads=2, head_dim=16, rms_norm_eps=1e-6,
+                 layer_types=["full_attention", "sliding_attention",
+                              "sliding_attention", "full_attention",
+                              "sliding_attention"],
+                 num_attention_heads_per_layer=[4, 6, 6, 4, 6],
+                 gating="per-head", sliding_window=16,
+                 rope_parameters={
+                     "full_attention": {
+                         "rope_type": "yarn", "rope_theta": 500000,
+                         "factor": 8, "beta_fast": 32, "beta_slow": 1,
+                         "original_max_position_embeddings": 16,
+                         "partial_rotary_factor": 0.5},
+                     "sliding_attention": {
+                         "rope_type": "default", "rope_theta": 10000,
+                         "partial_rotary_factor": 1}},
+                 mlp_layer_types=["dense"] + ["sparse"] * 4,
+                 mlp_only_layers=[0], num_experts=4,
+                 num_experts_per_tok=2, moe_intermediate_size=32,
+                 shared_expert_intermediate_size=32, norm_topk_prob=True,
+                 moe_routed_scaling_factor=2.5,
                  expert_parallel={"size": 2, "rank": 0})
     d.update(over)
     if arch in ("qwen3_5", "qwen3_5_moe"):

@@ -9,8 +9,6 @@ the reference's per-layer Box<dyn Forwarder> dispatch).
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 import jax.numpy as jnp
 
@@ -64,6 +62,10 @@ def init_attention_params(cfg: ModelConfig, spec: LayerSpec, key, dtype):
     if spec.sink:
         p["attention_sink_bias"] = jax.random.normal(
             jax.random.fold_in(key, 4), (a.heads,), dtype)
+    if cfg.attn_head_gate:
+        # gate logits of std ~1: the gates spread over (0.1, 0.9)
+        p["g_proj"] = {"weight": jax.random.normal(
+            jax.random.fold_in(key, 5), (a.heads, h), dtype) / h ** 0.5}
     return p
 
 
@@ -98,8 +100,9 @@ def init_moe_params(cfg: ModelConfig, key, dtype):
     if cfg.shared_expert_intermediate_size:
         p["shared_expert"] = init_mlp_params(
             cfg, ks[4], dtype, inter=cfg.shared_expert_intermediate_size)
-        p["shared_expert_gate"] = {
-            "weight": jax.random.normal(ks[5], (1, h), dtype) * 0.02}
+        if cfg.shared_expert_gated:
+            p["shared_expert_gate"] = {
+                "weight": jax.random.normal(ks[5], (1, h), dtype) * 0.02}
     return p
 
 
@@ -176,9 +179,11 @@ def make_rope(cfg: ModelConfig) -> dict:
     if cfg.local_rope_theta is not None:
         # Gemma3 SWA layers: separate table at rope_local_base_freq, never
         # scaled (HF rotary_emb_local; pinned by tests/test_hf_parity.py);
-        # MiMo-V2 window layers: swa_rope_theta
-        lcos, lsin = rope_tables(cfg.max_seq_len, cfg.rotary_dim,
-                                 cfg.local_rope_theta)
+        # MiMo-V2 window layers: swa_rope_theta; Laguna's: a rotary width
+        # and a scaling of the table's own
+        lcos, lsin = rope_tables(cfg.max_seq_len, cfg.local_rotary_dim,
+                                 cfg.local_rope_theta,
+                                 cfg.local_rope_scaling)
         rope["cos_local"], rope["sin_local"] = lcos, lsin
     return rope
 
@@ -259,6 +264,13 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
     k = linear(x, p["k_proj"]["weight"], p["k_proj"].get("bias"))
     v = linear(x, p["v_proj"]["weight"], p["v_proj"].get("bias"))
 
+    head_gate = None
+    if cfg.attn_head_gate:
+        # one sigmoid gate a query head, from the layer's normed input
+        with jax.named_scope("cake.attn.gate"):
+            head_gate = jax.nn.sigmoid(
+                linear(x, p["g_proj"]["weight"]).astype(jnp.float32))
+
     gate = None
     if gated:
         # q_proj emits 2x heads; per-head [q, gate] interleave -> sigmoid gate
@@ -285,8 +297,9 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
     if spec.use_rope:
         suf = "_local" if spec.local_rope_table else ""
         cos, sin = rope["cos" + suf], rope["sin" + suf]
-        q = apply_rope(q, cos, sin, positions, cfg.rotary_dim)
-        k = apply_rope(k, cos, sin, positions, cfg.rotary_dim)
+        rd = cfg.rotary_dim_of(spec)
+        q = apply_rope(q, cos, sin, positions, rd)
+        k = apply_rope(k, cos, sin, positions, rd)
 
     # Attend over [previous cache ; in-pass K/V]. In-pass keys must be
     # presented in full (not through the ring): with a window-sized ring,
@@ -300,6 +313,10 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
     use_flash = flash_kernel_mode(flash_mode, s, spec.window,
                                   layer_cache is not None,
                                   spec.sink) is not None
+    # the layer's read of its cache (the decode kernel's call or the
+    # masked read), by kind
+    read_scope = "cake.attn.full" if spec.window is None \
+        else "cake.attn.window"
     if flash_mode == "ring" and mesh is not None and spec.window is None:
         # sp-sharded fresh prefill: sequence split over the mesh's sp axis,
         # K/V blocks rotate via collective permute (parallel/ring_attention)
@@ -361,10 +378,11 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
             # up to its frontier pos0 + 1, and no block of a row that
             # valid_len 0 masks out of the step
             from ...ops.decode_attention import decode_attention
-            y = decode_attention(
-                q, k_all, v_all, kv_pos, pos0,
-                None if valid_len is None else valid_len > 0,
-                scale=cfg.attn_scale, block_k=block_k, mesh=mesh)
+            with jax.named_scope(read_scope):
+                y = decode_attention(
+                    q, k_all, v_all, kv_pos, pos0,
+                    None if valid_len is None else valid_len > 0,
+                    scale=cfg.attn_scale, block_k=block_k, mesh=mesh)
             use_flash = True      # skip the masked fallback below
     else:
         new_cache = None
@@ -377,15 +395,19 @@ def attention_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
     if not use_flash:
         q_pos = jnp.broadcast_to(positions[None, :], (b, s))
         mask = make_attention_mask(q_pos, kv_pos, window=spec.window)
-        with (jax.named_scope("cake.attn.window") if spec.window is not None
-              else contextlib.nullcontext()):
+        with jax.named_scope(read_scope):
             y = multi_head_attention(q, k_all, v_all, mask,
                                      scale=cfg.attn_scale, sink=sink)
         if layer_cache is not None and new_cache is None:
             new_cache = update_kv_cache(layer_cache, k, v, pos0, valid_len)
+    if head_gate is not None:
+        with jax.named_scope("cake.attn.gate"):
+            y = y.reshape(b, s, hq, a.v_head_dim) \
+                * head_gate[..., None].astype(y.dtype)
     y = y.reshape(b, s, a.size_o)
     if gate is not None:
-        y = y * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(y.dtype)
+        with jax.named_scope("cake.attn.gate"):
+            y = y * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(y.dtype)
     return linear(y, p["o_proj"]["weight"]), new_cache
 
 
@@ -408,6 +430,9 @@ def moe_forward(cfg: ModelConfig, p: dict, x):
         # selected experts streamed from storage — EAGER only (the host
         # round-trip on the routing indices cannot trace under jit)
         from .expert_provider import moe_ffn_offloaded
+        if cfg.moe_routed_scale != 1.0:
+            raise NotImplementedError(
+                "--expert-offload with a routed scaling factor")
         y = moe_ffn_offloaded(flat, p["gate"]["weight"], p["_provider"],
                               cfg.num_experts_per_tok, cfg.norm_topk_prob,
                               cfg.moe_gate_act, act)
@@ -417,13 +442,21 @@ def moe_forward(cfg: ModelConfig, p: dict, x):
                     cfg.num_experts_per_tok, cfg.norm_topk_prob,
                     cfg.moe_gate_act, act,
                     select_bias=p["gate"].get("e_score_correction_bias"),
-                    first=cfg.expert_first)
+                    first=cfg.expert_first,
+                    routed_scale=cfg.moe_routed_scale)
     if "shared_expert" in p:
-        # always-active shared expert, sigmoid-gated (ref: qwen3_5_moe/moe.rs)
-        sh = mlp_forward(cfg, p["shared_expert"], flat)
-        g = jax.nn.sigmoid(
-            linear(flat, p["shared_expert_gate"]["weight"]).astype(jnp.float32))
-        y = y + sh * g.astype(sh.dtype)
+        # always-active shared expert: sigmoid-gated where the checkpoint
+        # has a `shared_expert_gate` (ref: qwen3_5_moe/moe.rs), else added
+        # as it is (Laguna). In a share of an expert-parallel group it is
+        # what every chip computes alike: counted once when shares add up
+        with jax.named_scope("cake.ffn.shared"):
+            sh = mlp_forward(cfg, p["shared_expert"], flat)
+            if "shared_expert_gate" in p:
+                g = jax.nn.sigmoid(linear(
+                    flat, p["shared_expert_gate"]["weight"]
+                ).astype(jnp.float32))
+                sh = sh * g.astype(sh.dtype)
+            y = y + sh
     return y.reshape(b, s, h)
 
 

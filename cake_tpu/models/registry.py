@@ -23,6 +23,12 @@ Family notes (distinguishers, ref SURVEY §2e):
   exaone4  - 3:1 local(SWA+RoPE)/global(NoPE) (models/exaone4/)
   jamba    - Mamba-1 state-space layers, NoPE attention every
              attn_layer_period-th layer, dense FFNs (models/jamba.py)
+  mimo_v2  - window layers with a sink beside full layers of other head
+             counts, keys 192 / values 128, bias-selecting sigmoid router
+  laguna   - window layers of 72 query heads beside full layers of 48 on
+             the same K/V heads, per-head sigmoid gate, YaRN on full
+             layers / plain rope on window layers, scaled softmax router +
+             ungated shared expert
 """
 from __future__ import annotations
 

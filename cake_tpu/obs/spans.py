@@ -284,6 +284,16 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
                          "ring and the chunk: scores, the softmax (with "
                          "its sink column where the layer has one) and "
                          "the weighted values (layers.attention_forward)"),
+    ("cake.attn.full", "a full layer's read of its cache in a decode or "
+                       "masked step, the counterpart of cake.attn.window: "
+                       "the Pallas decode kernel's call, or the masked "
+                       "scores, softmax and weighted values over the "
+                       "whole buffer (layers.attention_forward)"),
+    ("cake.attn.gate", "the attention output gate: a per-head gate's "
+                       "projection and sigmoid and its product with the "
+                       "heads' outputs (Laguna), or the elementwise "
+                       "gate's sigmoid and product (Qwen3.5) "
+                       "(layers.attention_forward)"),
     ("cake.ssm", "one Mamba layer's state-space mixer, in cake.attn's "
                  "place for that layer kind (layers._attn: "
                  "jamba.mamba_forward)"),
@@ -300,6 +310,9 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
                        "of the model (ops.moe.moe_ffn)"),
     ("cake.ffn.experts", "MoE expert GEMMs and combine, of the experts "
                          "this process holds (ops.moe.moe_ffn)"),
+    ("cake.ffn.shared", "the shared expert every token passes, with its "
+                        "sigmoid gate where the family has one, and its "
+                        "sum into the routed result (layers.moe_forward)"),
     ("cake.lm_head", "final norm and vocabulary projection "
                      "(layers.lm_head_logits)"),
     ("cake.sample", "on-device sampling: sample, sample_traced and the "

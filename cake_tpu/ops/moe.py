@@ -90,9 +90,13 @@ def _expert_act(g, u, act: str):
 
 def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
             norm_topk_prob: bool, gate_act: str = "softmax", act: str = "silu",
-            select_bias=None, first: int = 0):
+            select_bias=None, first: int = 0, routed_scale: float = 1.0):
     """x: [T, H]; router_weight: [R, H]; gate/up_proj: [E, I, H];
     down_proj: [E, H, I]. Returns [T, H] in x.dtype.
+
+    routed_scale multiplies the selected experts' weights after their
+    normalisation (Laguna's `moe_routed_scaling_factor`, DeepSeek-V3's
+    `routed_scaling_factor`), on both dispatch paths.
 
     R > E is one share of an expert-parallel group: the banks hold experts
     first .. first + E - 1 of the R the router scores. Routing and the
@@ -111,6 +115,8 @@ def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
                             preferred_element_type=jnp.float32)
         weights, idx = router_topk(logits, k, norm_topk_prob, gate_act,
                                    select_bias)
+        if routed_scale != 1.0:
+            weights = weights * routed_scale
         if share:
             held = (idx >= first) & (idx < first + e)
             idx = jnp.where(held, idx - first, e)

@@ -19,18 +19,36 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class RopeScaling:
-    """llama3-style frequency scaling (ref: config.rs RopeScaling)."""
+    """Frequency scaling by `rope_type`: llama3 smoothing (ref: config.rs
+    RopeScaling), linear interpolation, or YaRN (`beta_fast`, `beta_slow`,
+    `attention_factor`; None = 0.1 ln(factor) + 1)."""
     factor: float = 8.0
     high_freq_factor: float = 4.0
     low_freq_factor: float = 1.0
     original_max_position_embeddings: int = 8192
     rope_type: str | None = None
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float | None = None
+
+
+def yarn_attention_factor(scaling: RopeScaling | None) -> float:
+    """What YaRN multiplies cos and sin by (so q.k grows by its square):
+    the configuration's `attention_factor` or 0.1 ln(factor) + 1; 1.0 for
+    every other rope_type."""
+    if scaling is None or scaling.rope_type != "yarn":
+        return 1.0
+    if scaling.attention_factor is not None:
+        return float(scaling.attention_factor)
+    return 0.1 * float(np.log(scaling.factor)) + 1.0 \
+        if scaling.factor > 1.0 else 1.0
 
 
 def inv_frequencies(rotary_dim: int, theta: float,
                     scaling: RopeScaling | None = None) -> np.ndarray:
     """Per-pair inverse frequencies, with optional llama3 smoothing
-    (ref: cache.rs:49-80)."""
+    (ref: cache.rs:49-80), linear interpolation or YaRN's blend (HF
+    `_compute_yarn_parameters`, truncated ramp)."""
     inv = 1.0 / (theta ** (np.arange(0, rotary_dim, 2, dtype=np.float64) / rotary_dim))
     if scaling is None or not scaling.factor or scaling.factor == 1.0:
         return inv.astype(np.float64)
@@ -50,25 +68,48 @@ def inv_frequencies(rotary_dim: int, theta: float,
         mid = (1.0 - smooth) * inv / scaling.factor + smooth * inv
         is_mid = (wavelen <= low_wavelen) & (wavelen >= high_wavelen)
         inv = np.where(is_mid, mid, scaled)
+    elif scaling.rope_type == "yarn":
+        # pairs that turn more than beta_fast times within the original
+        # context keep their frequency, those that turn fewer than
+        # beta_slow times are interpolated by the factor, a linear ramp
+        # between the two pair indices in between
+        n, orig = rotary_dim // 2, scaling.original_max_position_embeddings
+
+        def pair_of(turns):
+            return (rotary_dim * np.log(orig / (turns * 2.0 * np.pi))
+                    / (2.0 * np.log(theta)))
+
+        low = max(np.floor(pair_of(scaling.beta_fast)), 0.0)
+        high = min(np.ceil(pair_of(scaling.beta_slow)), rotary_dim - 1.0)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(n, dtype=np.float64) - low)
+                       / (high - low), 0.0, 1.0)
+        inv = inv / scaling.factor * ramp + inv * (1.0 - ramp)
     else:
-        # unimplemented scaling flavors (yarn, dynamic, ...) degrade to
+        # the flavors still not implemented (dynamic, longrope) degrade to
         # unscaled RoPE with a warning — same tolerance posture as the
         # unknown-architecture fallback (config.py ARCH_ADAPTERS)
         import logging
         logging.getLogger(__name__).warning(
-            "rope_type %r not implemented; using unscaled RoPE",
-            scaling.rope_type)
+            "rope_type %r not implemented (dynamic and longrope are not); "
+            "using unscaled RoPE", scaling.rope_type)
     return inv.astype(np.float64)
 
 
 def rope_tables(max_seq_len: int, rotary_dim: int, theta: float,
                 scaling: RopeScaling | None = None,
                 dtype=jnp.float32):
-    """Precompute (cos, sin) of shape [max_seq_len, rotary_dim // 2]."""
+    """Precompute (cos, sin) of shape [max_seq_len, rotary_dim // 2]; under
+    YaRN both carry its attention factor."""
     inv = inv_frequencies(rotary_dim, theta, scaling)
     t = np.arange(max_seq_len, dtype=np.float64)
     freqs = np.outer(t, inv)
-    return jnp.asarray(np.cos(freqs), dtype=dtype), jnp.asarray(np.sin(freqs), dtype=dtype)
+    cos, sin = np.cos(freqs), np.sin(freqs)
+    mscale = yarn_attention_factor(scaling)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
+    return jnp.asarray(cos, dtype=dtype), jnp.asarray(sin, dtype=dtype)
 
 
 def apply_rope(x, cos, sin, positions, rotary_dim: int | None = None,

@@ -46,7 +46,8 @@ def param_pspec(path: tuple[str, ...], mesh: Mesh) -> P:
         if name == "down_proj":
             return P(ep, None, tp)
     if name == "weight" or name == "bias":
-        if parent in ("q_proj", "k_proj", "v_proj"):
+        if parent in ("q_proj", "k_proj", "v_proj", "g_proj"):
+            # (g_proj: Laguna's gate a query head, head-parallel like q)
             return P(tp, None) if name == "weight" else P(tp)
         if parent == "o_proj":
             return P(None, tp)

@@ -446,6 +446,7 @@ class ServeEngine:
         # before the supervisor so the watchdog can always reach it
         self.flight = FlightRecorder()
         self.flight.static["joined_keys"] = self._joined_keys
+        self.flight.static["attention_kinds"] = self._attention_kinds
         self._step_id = 0           # the running iteration's flight seq
         # running totals the iteration's record takes differences of
         # (_land): tokens emitted, requests a fan-out finished, ids fetched
@@ -505,6 +506,10 @@ class ServeEngine:
             {"width": w, "layers": n} for w, n in sorted(joined_key_widths(
                 self.paged.pool + self.paged.rows if self._layers is None
                 else self._layers).items())]
+        # the attention layers by kind: heads, K/V heads, window, rotary
+        # width and the rope table each kind reads (health's static part
+        # and the flight record's)
+        self._attention_kinds = self.model.cfg.attention_kinds()
         # the window layers' ring lengths (the flight record's
         # `ring_tokens`); none for a model without window layers
         self._ring_sizes = [s.window for s in self.model.cfg.layer_specs()
@@ -708,6 +713,7 @@ class ServeEngine:
         if pc is not None:
             h["prefix_cache"] = pc.occupancy()
         h["kv_pool"] = {"joined_keys": self._joined_keys}
+        h["attention_kinds"] = self._attention_kinds
         # local binding: health() runs on API threads while the scheduler
         # may null self.paged transiently during _rebuild/_fail_all
         paged = self.paged

@@ -66,6 +66,9 @@ def params_to_hf_tensors(cfg: ModelConfig, params: dict,
             if "attention_sink_bias" in a:
                 out[f"{lp}.self_attn.attention_sink_bias"] = \
                     _np(a["attention_sink_bias"])
+            if "g_proj" in a:
+                out[f"{lp}.self_attn.g_proj.weight"] = \
+                    _np(a["g_proj"]["weight"])
         if "linear_attn" in layer:
             from ..models.qwen3_5 import export_gdn_params
             out.update(export_gdn_params(cfg, layer["linear_attn"], lp))
@@ -86,8 +89,9 @@ def params_to_hf_tensors(cfg: ModelConfig, params: dict,
                 for proj in ("gate_proj", "up_proj", "down_proj"):
                     out[f"{lp}.mlp.shared_expert.{proj}.weight"] = \
                         _np(mlp["shared_expert"][proj]["weight"])
-                out[f"{lp}.mlp.shared_expert_gate.weight"] = \
-                    _np(mlp["shared_expert_gate"]["weight"])
+                if "shared_expert_gate" in mlp:
+                    out[f"{lp}.mlp.shared_expert_gate.weight"] = \
+                        _np(mlp["shared_expert_gate"]["weight"])
         else:
             if fuse_phi and cfg.fused_gate_up:
                 out[f"{lp}.mlp.gate_up_proj.weight"] = np.concatenate([

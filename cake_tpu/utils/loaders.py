@@ -129,6 +129,9 @@ class ParamLoader:
         if spec.sink:
             p["attention_sink_bias"] = self._dev(self._get_dense(
                 f"{lp}.self_attn.attention_sink_bias"))
+        if cfg.attn_head_gate:
+            p["g_proj"] = {"weight": self._dev(
+                self._get(f"{lp}.self_attn.g_proj.weight"))}
         return p
 
     def _mlp(self, mp: str) -> dict:
@@ -174,8 +177,9 @@ class ParamLoader:
                             for proj, ws in stacked.items()}
         if cfg.shared_expert_intermediate_size:
             p["shared_expert"] = self._mlp(f"{mp}.shared_expert")
-            p["shared_expert_gate"] = {"weight": self._dev(
-                self._get(f"{mp}.shared_expert_gate.weight"))}
+            if cfg.shared_expert_gated:
+                p["shared_expert_gate"] = {"weight": self._dev(
+                    self._get(f"{mp}.shared_expert_gate.weight"))}
         return p
 
     def _layer(self, i: int) -> dict:

@@ -143,7 +143,9 @@ def _compile_decode(sharding, rows, hq, hkv, *, ctx=4096, mesh=None,
     (8, 32, 8),                      # qwen3-4b's pool: 8 slots x 4096
     (16, 32, 4),                     # qwen3-30b-a3b's: 16 slots, 4 K/V heads
     (1, 16, 8),                      # a sequential generate's batch-1 cache
-], ids=["4b-pool", "moe-pool", "batch1"])
+    (32, 48, 8),                     # Laguna's full layers: 6 q heads a K/V
+                                     # head, 12 rows a head pair padded to 16
+], ids=["4b-pool", "moe-pool", "batch1", "laguna-group-of-6"])
 def test_decode_kernel_compiles_on_one_chip(one_chip, rows, hq, hkv):
     """The K and V buffers reach the kernel as they lie: viewing
     [rows, T, Hkv, D] as [rows, T * Hkv, D] is a bitcast in the tiled
@@ -187,6 +189,32 @@ _POOLS = {
         num_experts_per_tok=8, moe_intermediate_size=2048, n_group=1,
         topk_group=1, norm_topk_prob=True, scoring_func="sigmoid",
         tie_word_embeddings=False, hidden_act="silu"), 32, 16384),
+    # Laguna-S-2.1: one full layer (48 q heads, YaRN over half a head) and
+    # one window layer (72 q heads, ring of 512) on the same 8 K/V heads of
+    # 128, a gate a head, 32 rows x 16,384: the full layer decodes through
+    # the kernel, beside a masked ring, in one program
+    "laguna": (dict(
+        model_type="laguna", vocab_size=12544, hidden_size=3072,
+        intermediate_size=2048, num_hidden_layers=2,
+        num_attention_heads=48, num_key_value_heads=8, head_dim=128,
+        max_position_embeddings=16384, rms_norm_eps=1e-6, num_experts=16,
+        num_experts_per_tok=10, moe_intermediate_size=1024,
+        shared_expert_intermediate_size=1024, norm_topk_prob=True,
+        mlp_only_layers=[0, 1], tie_word_embeddings=False,
+        gating="per-head", sliding_window=512,
+        rope_parameters={
+            "full_attention": {
+                "rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+                "original_max_position_embeddings": 8192, "beta_slow": 1,
+                "beta_fast": 32, "attention_factor": 1.4852030263919618,
+                "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 10000,
+                                  "partial_rotary_factor": 1}},
+        layer_types=["full_attention", "sliding_attention"],
+        mlp_layer_types=["dense", "dense"],
+        num_attention_heads_per_layer=[48, 72],
+        moe_routed_scaling_factor=2.5), 32, 16384),
     # the control, Qwen3-4B's widths (an eighth of its vocabulary, as the
     # other has): keys 128 wide, 8 rows x 4096
     "qwen3": (dict(
@@ -263,7 +291,9 @@ def test_serve_programs_take_the_pool_in_place(one_chip, monkeypatch, family):
     runtime stored K length-minor and the decode step and the splice each
     copied 805 MB to a D-minor layout and back around their scatter (1.08
     GB of temporaries; PERF.md, PR 40). The largest temporary left is the
-    masked decode read's float32 scores, rows x Hq x T."""
+    masked decode read's float32 scores, rows x Hq x T. Laguna's full
+    layer (keys 128 = values 128 on 8 K/V heads, no sink) is the first of
+    a window/full model to hold the decode kernel in its decode program."""
     from cake_tpu.models.common.config import config_from_hf_dict
     from cake_tpu.ops import flash
     monkeypatch.setattr(flash, "flash_enabled", lambda: True)
@@ -286,5 +316,9 @@ def test_serve_programs_take_the_pool_in_place(one_chip, monkeypatch, family):
         scores = rows * cfg.num_attention_heads * ctx * 4
         limit = (scores if name == "decode" and family == "mimo_v2"
                  else 0) + 64 * 2 ** 20
+        if (family, name) == ("laguna", "append256"):
+            # a chunk's window layer attends masked over ring + chunk at
+            # 72 heads: float32 scores and their exponentials
+            limit += 2 * 72 * 256 * (512 + 256) * 4
         assert mem.temp_size_in_bytes < limit, (name,
                                                 mem.temp_size_in_bytes)

@@ -107,16 +107,21 @@ def test_the_manifest_holds_with_the_new_entries(manifest):
     assert manifest.validate(ROOT) == []
     m = manifest.load(ROOT)
     cells = [w["name"] for w in m["workloads"]]
-    assert [e["name"] for e in m["per_layer"][-4:]] == list(NEW)
+    # (PR 44 appended two metrics behind them)
+    assert [e["name"] for e in m["per_layer"][-6:-2]] == list(NEW)
     by = {e["name"]: e for e in m["per_layer"]}
     assert by[NEW[0]]["workloads"] == by[NEW[1]]["workloads"] == cells
     assert (by[NEW[0]]["moves"], by[NEW[1]]["moves"]) == ("out_tok_s",
                                                           "itl_p50_ms")
     assert by[NEW[0]]["source"] == by[NEW[1]]["source"] == "program_counter"
-    # the inside hand-off and its twin cover every cell between them, as
-    # the outside hand-off and its twin do
+    # the inside hand-off and its twin cover between them every cell that
+    # judges a tail of the gaps (PR 44's cell judges none), as the outside
+    # hand-off and its twin do
+    tails = {e["name"]: e.get("workloads", cells) for e in m["end_to_end"]}
     assert sorted(by[NEW[2]]["workloads"] + by[NEW[3]]["workloads"]) == \
-        sorted(cells)
+        sorted(tails["itl_p95_ms"] + tails["itl_p99_ms"])
+    assert set(cells) - set(tails["itl_p95_ms"] + tails["itl_p99_ms"]) == \
+        {"laguna-s-2.1-l9-ep16.codeassist"}
     for inside, outside in zip(INSIDE, ("api.handoff_p95_ms",
                                         "api.handoff_p95_ms.tail99")):
         assert by[inside]["workloads"] == by[outside]["workloads"]
@@ -128,7 +133,9 @@ def test_the_manifest_holds_with_the_new_entries(manifest):
 def test_benchmark_json_only_gained_entries_at_the_end():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         m = json.load(f)
-    assert len(m["workloads"]) == 5 and len(m["configs"]) == 4
+    assert len(m["workloads"]) == 6 and len(m["configs"]) == 5
     names = [e["name"] for e in m["per_layer"]]
     assert len(names) == len(set(names))
-    assert names.index("engine.prefix_hit_share") == len(names) - 5
+    assert names.index("engine.prefix_hit_share") == len(names) - 7
+    assert names[-2:] == ["programs.decode.attn_full_ms",
+                          "programs.decode.ffn_shared_ms"]

@@ -191,6 +191,20 @@ def make_rope(cfg: ModelConfig) -> dict:
     return rope
 
 
+def cut_rope(rope: dict, rows: int) -> dict:
+    """Every table's first `rows` rows: the positions a model whose caches
+    end at `rows` can reach. A window layer's positions run past its ring
+    to the row's frontier, so the local tables keep as many. The runtime
+    keeps a table narrower than the lanes positions-minor, and XLA lays
+    the WHOLE table out by rows in front of the gather of a step's few
+    positions, in every execution: at 1,048,576 published positions that
+    copy was 2.5 of a 13.5 ms decode step (PERF.md, PR 49). A position past
+    the cut (a padded chunk's tail, a free pool row's carry) gathers the
+    last row, as it gathered its own before: such rows are masked."""
+    return {name: table if table.shape[0] <= rows else table[:rows]
+            for name, table in rope.items()}
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------

@@ -46,8 +46,8 @@ from .cache import (grow_cache, kv_capacity, paged_block_of,
                     slot_extract_block_layers, slot_reset_layers,
                     slot_splice_block_layers, truncate_layers)
 from .config import ModelConfig
-from .layers import (embed_tokens, flash_kernel_mode, forward_layers,
-                     lm_head_logits)
+from .layers import (cut_rope, embed_tokens, flash_kernel_mode,
+                     forward_layers, lm_head_logits)
 
 PREFILL_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096, 8192)
 
@@ -283,6 +283,9 @@ class TextModel:
         if params is None:
             params = init_params_sharded(mesh, cfg,
                                          jax.random.PRNGKey(seed), dtype)
+        # the tables end where this model's caches do
+        params = {**params,
+                  "rope": cut_rope(params["rope"], self.max_cache_len)}
         self.params = shard_params(params, mesh)
         self._rng = jax.random.PRNGKey(seed)
         self.last_prefill_mode: str | None = None

@@ -447,6 +447,7 @@ class ServeEngine:
         self.flight = FlightRecorder()
         self.flight.static["joined_keys"] = self._joined_keys
         self.flight.static["attention_kinds"] = self._attention_kinds
+        self.flight.static.update(self._rope_held)
         self._step_id = 0           # the running iteration's flight seq
         # running totals the iteration's record takes differences of
         # (_land): tokens emitted, requests a fan-out finished, ids fetched
@@ -510,6 +511,12 @@ class ServeEngine:
         # width and the rope table each kind reads (health's static part
         # and the flight record's)
         self._attention_kinds = self.model.cfg.attention_kinds()
+        # the rope tables the model holds: a model cut to its reach holds
+        # max_cache_len rows of each (0 / 0 where no layer rotates)
+        tables = list(self.model.params["rope"].values())
+        self._rope_held = {
+            "rope_rows": max((t.shape[0] for t in tables), default=0),
+            "rope_bytes": sum(t.nbytes for t in tables)}
         # the window layers' ring lengths (the flight record's
         # `ring_tokens`); none for a model without window layers
         self._ring_sizes = [s.window for s in self.model.cfg.layer_specs()
@@ -714,6 +721,7 @@ class ServeEngine:
             h["prefix_cache"] = pc.occupancy()
         h["kv_pool"] = {"joined_keys": self._joined_keys}
         h["attention_kinds"] = self._attention_kinds
+        h.update(self._rope_held)
         # local binding: health() runs on API threads while the scheduler
         # may null self.paged transiently during _rebuild/_fail_all
         paged = self.paged

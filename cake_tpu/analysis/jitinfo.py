@@ -31,6 +31,7 @@ KNOWN_DONATING_METHODS: dict[str, tuple[int, ...]] = {
     "slot_assign": (0,),
     "slot_release": (0,),
     "slot_splice": (0,),
+    "slot_join": (2, 3, 4, 5),          # toks, pos, rngs, recents
     "verify_tokens": (0,),              # cache
     "prefill": (0,),
     "decode_logits": (0,),

@@ -554,6 +554,31 @@ class TextModel:
         def _slot_splice(layers, src_layers, slot, final):
             return slot_splice_block_layers(layers, src_layers, slot, final)
 
+        @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 5))
+        def _slot_join(logits, base_rng, toks, pos, rngs, recents, temps,
+                       top_ks, top_ps, penalties, active, ints, floats):
+            """A prompt's end as ONE program: derive the request's key from
+            the engine's base key and its admission sequence number, sample
+            the first token off the final chunk's `logits` [1, V] with the
+            decode step's own sample_traced (empty recent window), and
+            write every per-slot carry at `slot`. ints = [slot, seq, prompt
+            length, top_k] int32, floats = [temperature, top_p, repeat
+            penalty] f32: all traced, so ONE executable per pool shape
+            whatever the slot and the sampling. Donates what _decode_slots
+            donates; the engine keeps its own handles of the rest."""
+            slot, seq, n, top_k = ints[0], ints[1], ints[2], ints[3]
+            temp, top_p, pen = floats[0], floats[1], floats[2]
+            rng, sk = jax.random.split(jax.random.fold_in(base_rng, seq))
+            recent = jnp.full(recents.shape[1:], -1, jnp.int32)
+            tid = sample_traced(logits[0], sk, temp, top_k, top_p, pen,
+                                recent)
+            return (toks.at[slot].set(tid), pos.at[slot].set(n),
+                    rngs.at[slot].set(rng),
+                    recents.at[slot].set(recent.at[-1].set(tid)),
+                    temps.at[slot].set(temp), top_ks.at[slot].set(top_k),
+                    top_ps.at[slot].set(top_p), penalties.at[slot].set(pen),
+                    active.at[slot].set(True))
+
         # -- paged KV: decode/prefill through a block table ----------------
         # Full-attention KV lives in a shared physical block pool
         # ([num_blocks, block_tokens, ...] per layer); a slot addresses its
@@ -752,6 +777,7 @@ class TextModel:
         self._prefill_slot = _prefill_slot
         self._slot_extract = _slot_extract
         self._slot_splice = _slot_splice
+        self._slot_join = _slot_join
         self._decode_slots_paged = _decode_slots_paged
         self._prefill_slot_paged = _prefill_slot_paged
         self._paged_row_snapshot = _paged_row_snapshot
@@ -861,6 +887,23 @@ class TextModel:
         first-token sample off the prefill logits)."""
         return self._sample_traced(logits, rng, temp, top_k, top_p, penalty,
                                    recent)
+
+    def slot_join(self, logits, base_rng, toks, pos, rngs, recents, temps,
+                  top_ks, top_ps, penalties, active, *, slot: int, seq: int,
+                  n: int, temp: float, top_k: int, top_p: float,
+                  penalty: float):
+        """Hand slot `slot` to the batched decode at a prompt's end: ONE
+        dispatch and two small host arrays. `logits` [1, V] are the final
+        chunk's; the key is fold_in(base_rng, seq); the first token, its
+        recent window, position `n` and the sampling params (disabled
+        values as sample_traced takes them: top_k >= vocab, top_p 1.0) are
+        written at `slot`, which becomes active. toks, pos, rngs and
+        recents are donated. Returns the nine carries in argument order."""
+        return self._slot_join(
+            logits, base_rng, toks, pos, rngs, recents, temps, top_ks,
+            top_ps, penalties, active,
+            jax.device_put(np.array([slot, seq, n, top_k], np.int32)),
+            jax.device_put(np.array([temp, top_p, penalty], np.float32)))
 
     # -- paged-KV slot programs (serve engine, CAKE_KV_BLOCKS > 0) ----------
 

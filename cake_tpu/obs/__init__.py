@@ -91,6 +91,11 @@ SERVE_PREFILL_CHUNKS = REGISTRY.histogram(
     "whole prompt fit one chunk)",
     buckets=(1, 2, 4, 8, 16, 32, 64, 128))
 
+SERVE_SLOT_JOINS = REGISTRY.counter(
+    "cake_serve_slot_joins_total",
+    "Slots handed to the batched decode at a prompt's end, one program "
+    "each (TextModel.slot_join): equals the `prefill_done` timeline events")
+
 SERVE_PREFIX_HITS = REGISTRY.counter(
     "cake_serve_prefix_cache_hits_total",
     "Admissions that spliced at least one cached prefix block")
@@ -516,7 +521,8 @@ __all__ = [
     "GENERATIONS", "API_REQUESTS", "API_REQUEST_SECONDS",
     "WORKER_FWD_SECONDS", "HOP_SECONDS", "WORKER_HEARTBEAT",
     "SERVE_QUEUE_DEPTH", "SERVE_SLOTS_BUSY", "SERVE_QUEUE_WAIT_SECONDS",
-    "SERVE_BATCH_OCCUPANCY", "SERVE_PREFILL_CHUNKS", "SERVE_PREFIX_HITS",
+    "SERVE_BATCH_OCCUPANCY", "SERVE_PREFILL_CHUNKS", "SERVE_SLOT_JOINS",
+    "SERVE_PREFIX_HITS",
     "SERVE_PREFIX_MISSES", "SERVE_PREFIX_EVICTIONS", "SERVE_PREFIX_BYTES",
     "SERVE_PREFIX_STATE_BYTES",
     "SERVE_QUEUE_TIMEOUTS", "SERVE_STEP_FAILURES", "SERVE_ENGINE_REBUILDS",

@@ -152,7 +152,10 @@ which side of the fetch it was on. The record also covers the whole
 iteration and the gap before it, from the same clock reads: `wall_ms`,
 `ph` = its eight phases in ms (sweep, admit, plan, decode_dispatch,
 fetch, fanout, prefill, late_land: they add up to `wall_ms`), `kind` =
-`decode` / `chunk` / `last_chunk` / `idle`, `of_step` = the iteration
+`decode` / `chunk` / `last_chunk` / `idle`, `joined` = the slots the
+iteration handed to the batched decode, one `_slot_join` program each (1
+on a `last_chunk` record, else 0; their sum is
+`cake_serve_slot_joins_total`), `of_step` = the iteration
 whose ids it fetched, and `gap_ms` = the `_run` loop's time since the
 previous iteration when that one left work behind, else 0. An iteration that failed or found
 nothing to do leaves its `seq` out of the ring. The supervisor dumps the ring to `CAKE_TRACE_DIR` as JSON

@@ -79,8 +79,8 @@ from ..obs import (PROCESS, RECORDER, SERVE_BATCH_OCCUPANCY,
                    SERVE_PREEMPTIONS, SERVE_QOS_E2E_SECONDS,
                    SERVE_QOS_TTFT_SECONDS, SERVE_QUEUE_TIMEOUTS,
                    SERVE_QUEUE_WAIT_SECONDS, SERVE_REQUEST_TIMEOUTS,
-                   SERVE_SLOTS_BUSY, SERVE_TTFT_SECONDS, TIMELINES, now,
-                   set_request_id)
+                   SERVE_SLOT_JOINS, SERVE_SLOTS_BUSY, SERVE_TTFT_SECONDS,
+                   TIMELINES, now, set_request_id)
 from ..models.common.cache import joined_key_widths, row_state_bytes
 from ..ops.sampling import SamplingConfig, config_has_filters
 from ..spec import resolve_drafter
@@ -165,6 +165,14 @@ class _Prefill:
         self.next_block = 0     # next prefix-cache block index to capture
         self.hit_tokens = 0     # tokens skipped via prefix-cache splice
         self.keys: list = []    # per-block hash chain (computed once)
+
+
+def _traced_sampling(scfg: SamplingConfig, vocab: int) -> dict:
+    """A request's sampling params as the traced carries hold them, with
+    sample_traced's disabled values (top_k >= vocab, top_p 1.0)."""
+    return {"temp": scfg.temperature, "top_k": scfg.top_k or vocab,
+            "top_p": scfg.top_p if scfg.top_p is not None else 1.0,
+            "penalty": scfg.repeat_penalty}
 
 
 class _InFlight:
@@ -452,8 +460,8 @@ class ServeEngine:
         # running totals the iteration's record takes differences of
         # (_land): tokens emitted, requests a fan-out finished, ids fetched
         # and not delivered (their request had ended), seconds blocked in
-        # a fetch
-        self._emitted = self._retired = self._dropped = 0
+        # a fetch; and (_complete_prefill) slots joined to the decode batch
+        self._emitted = self._retired = self._dropped = self._joined = 0
         self._fetch_s = 0.0
         self._chunk_kind = None     # `chunk` | `last_chunk` when it ran one
         self._chunk_end = None      # recorder on: when its dispatch ended
@@ -977,6 +985,7 @@ class ServeEngine:
             t_sweep = now()
             emitted0, retired0 = self._emitted, self._retired
             dropped0, fetch_s0 = self._dropped, self._fetch_s
+            joined0 = self._joined
             # 1. cancel sweeps: decoding slots, mid-prefill slots, and
             # abandoned-while-queued requests (those would otherwise pin
             # queue capacity and 429 live clients while slots sit idle).
@@ -1197,7 +1206,7 @@ class ServeEngine:
             of_step = t_fan = None
             t_land = t_mid = now()
             if prev is not None:
-                of_step, t_fan = prev.step, self._land(prev)
+                of_step, t_fan = prev.step, self._land(prev, t_land)
                 t_mid = t_fan       # the record's split of fetch | fanout
             t_chunk = now()
             self._chunk_end = self._chunk_kind = None
@@ -1220,7 +1229,7 @@ class ServeEngine:
             # the chunk's dispatch: the chunk runs while the host fans out
             t_late = now()
             if cur is not None and not keep:
-                of_step, t_fan = cur.step, self._land(cur)
+                of_step, t_fan = cur.step, self._land(cur, t_late)
             t_end = now()
             tokens = self._emitted - emitted0
             dropped = self._dropped - dropped0
@@ -1249,6 +1258,7 @@ class ServeEngine:
             rec = {
                 "kind": self._chunk_kind
                 or ("decode" if active else "idle"),
+                "joined": self._joined - joined0,
                 "wall_ms": wall_ms,
                 "gap_ms": 0.0 if t_prev is None
                 else round((t_sweep - t_prev) * 1e3, 3),
@@ -1273,19 +1283,20 @@ class ServeEngine:
                 self._t_prev_end = t_end
         return True
 
-    def _land(self, fl: _InFlight) -> float:
+    def _land(self, fl: _InFlight, t0: float) -> float:
         """Fetch one dispatched step's ids and fan them out to their
-        streams; returns the stamp between the two. `_step` lands the
-        previous step once a successor is queued behind it (or its own, at
-        depth 0); `_land_inflight` lands it out of that order. Either way
-        the iteration's record takes its `fetch_ms`, tokens and `dropped`
-        from the running totals this moves.
+        streams. `t0` is the caller's stamp at the call: the fetch is timed
+        from it, so the record's `fetch` phase and its `fetch_ms` come from
+        the same two clock reads. Returns the stamp between the fetch and
+        the fan-out. `_step` lands the previous step once a successor is
+        queued behind it (or its own, at depth 0); `_land_inflight` lands
+        it out of that order. Either way the iteration's record takes its
+        `fetch_ms`, tokens and `dropped` from the running totals this moves.
 
         The fetch is where an async device failure (or a wedge) of that
         step materializes: armed with ITS request set, whichever iteration
         dispatched it and whatever was dispatched since; a crash in the
         fan-out implicates the same set."""
-        t0 = now()
         self.supervisor.arm("decode", fl.ids)
         # lint: disable=host-sync — THE one planned fetch per iteration: the
         # packed ids ([input;sampled], or [input;n_acc;next] on a
@@ -1304,7 +1315,7 @@ class ServeEngine:
         host first. Under no span of its own."""
         fl, self._inflight = self._inflight, None
         if fl is not None:
-            self._land(fl)
+            self._land(fl, now())
 
     def _emit_phases(self, step: int, t: tuple, t_fan: float | None, *,
                      admitted: int, slots: int, bucket: int, kv_tokens: int,
@@ -1460,23 +1471,21 @@ class ServeEngine:
     def _complete_prefill(self, pf: _Prefill, logits):
         """Final chunk done: sample the first token (device-resident — it
         rides the next decode iteration's packed fetch) and hand the slot
-        to the batched decode."""
+        to the batched decode, in ONE dispatch behind the chunk's
+        (TextModel.slot_join: the key derivation, the sample and every
+        carry's write at `slot`). The scheduler ships two small arrays and
+        goes round to dispatch the next decode step while the chunk runs."""
         req, slot, scfg = pf.req, pf.slot, pf.req.sampling
-        rng = jax.random.fold_in(self._base_rng, self._seq)
-        self._seq += 1
-        rng, sk = jax.random.split(rng)
-        recent = jnp.full((RECENT_N,), -1, jnp.int32)
-        tid = self.model.sample_one(
-            logits[0], sk, jnp.float32(scfg.temperature),
-            jnp.int32(scfg.top_k or self._vocab),
-            jnp.float32(scfg.top_p if scfg.top_p is not None else 1.0),
-            jnp.float32(scfg.repeat_penalty), recent)
-        self._rngs = self._rngs.at[slot].set(rng)
-        self._recents = self._recents.at[slot].set(recent.at[-1].set(tid))
-        self._toks = self._toks.at[slot].set(tid)
-        self._pos = self._pos.at[slot].set(pf.n)
-        self._set_slot_sampling(slot, scfg)
-        self._act = self._act.at[slot].set(True)
+        seq, self._seq = self._seq, self._seq + 1
+        (self._toks, self._pos, self._rngs, self._recents, self._temps,
+         self._top_ks, self._top_ps, self._pens,
+         self._act) = self.model.slot_join(
+            logits, self._base_rng, self._toks, self._pos, self._rngs,
+            self._recents, self._temps, self._top_ks, self._top_ps,
+            self._pens, self._act, slot=slot, seq=seq, n=pf.n,
+            **_traced_sampling(scfg, self._vocab))
+        self._joined += 1
+        SERVE_SLOT_JOINS.inc()
         self._prefills.remove(pf)
         req.budget = min(req.max_new_tokens - 1, self.ctx - pf.n - 1)
         req._first_pending = True       # emitted at the next decode fetch
@@ -1508,12 +1517,13 @@ class ServeEngine:
 
     def _set_slot_sampling(self, slot: int, scfg: SamplingConfig):
         """Write a request's sampling params into the slot's traced
-        carries (same disabled-value conventions as sample_traced)."""
-        self._temps = self._temps.at[slot].set(scfg.temperature)
-        self._top_ks = self._top_ks.at[slot].set(scfg.top_k or self._vocab)
-        self._top_ps = self._top_ps.at[slot].set(
-            scfg.top_p if scfg.top_p is not None else 1.0)
-        self._pens = self._pens.at[slot].set(scfg.repeat_penalty)
+        carries, one scatter each: crash replay and preemption resume (a
+        prompt's end writes them inside the join)."""
+        v = _traced_sampling(scfg, self._vocab)
+        self._temps = self._temps.at[slot].set(v["temp"])
+        self._top_ks = self._top_ks.at[slot].set(v["top_k"])
+        self._top_ps = self._top_ps.at[slot].set(v["top_p"])
+        self._pens = self._pens.at[slot].set(v["penalty"])
 
     def _abort_prefill(self, pf: _Prefill, error: BaseException | None,
                        register: bool = True):

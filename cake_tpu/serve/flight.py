@@ -23,7 +23,9 @@ The record covers the whole iteration and the gap before it, from the
 clock reads the step takes anyway: `wall_ms` (first stamp to last), `ph`
 (its eight phases in ms, in PHASES' order: they add up to `wall_ms`),
 `kind` (`decode` | `chunk` | `last_chunk` | `idle`: whether it carried a
-prefill chunk, the prompt's last, or had no row to step) and `gap_ms`
+prefill chunk, the prompt's last, or had no row to step), `joined` (the
+slots it handed to the batched decode, one `_slot_join` program each: 1 on
+a `last_chunk` record, else 0) and `gap_ms`
 (from the previous iteration's last stamp to this one's first, when that
 one left work behind: the `_run` loop's own time; else 0).
 

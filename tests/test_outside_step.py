@@ -202,7 +202,8 @@ def test_the_clock_reads_a_step_and_a_token_cost(model, monkeypatch,
                                                  recorder_on):
     """Recorder off: `_step` reads the clock eight times an iteration, as
     its parent did (the phase stamps; both deadline sweeps are off here),
-    `_land` twice a landing, and the stream's pump and iterator never.
+    `_land` once a landing (it is timed from `_step`'s stamp at the call),
+    and the stream's pump and iterator never.
     On: one more a chunk (where `serve.prefill_finish` begins), and the
     pump and the iterator one each a token and one each for DONE."""
     clock = _CountingClock()
@@ -242,7 +243,7 @@ def test_the_clock_reads_a_step_and_a_token_cost(model, monkeypatch,
     landed = sum(1 for r in ring if r["of_step"] is not None) - landed0
     assert chunks == 2 and len(got) == 5
     assert clock.by["_step"] == 8 * len(recs)
-    assert clock.by["_land"] == 2 * landed
+    assert clock.by["_land"] == landed
     assert clock.by.get("_advance_prefill", 0) == \
         (chunks if recorder_on else 0)
     assert not any(hasattr(t, "handoff") for t in got)  # the stream's

@@ -606,8 +606,12 @@ def test_engine_round_robin_concurrent_admissions(model):
             if r1.done.is_set() and r2.done.is_set():
                 break
             time.sleep(0.001)
-        assert saw_both, "second admission waited out the first's prefill"
         assert r1.wait(120) and r2.wait(120)
+        # the poll above can sleep through the dozen iterations that hold
+        # both on a loaded machine; the flight records saw every one
+        saw_both = saw_both or any(r["prefilling"] == 2
+                                   for r in eng.flight.snapshot())
+        assert saw_both, "second admission waited out the first's prefill"
         assert r1.result["tokens"] == _ref(model, p1, 5)
         assert r2.result["tokens"] == _ref(model, p2, 5)
     finally:

@@ -273,24 +273,59 @@ def test_every_dispatched_step_is_fetched_once_one_iteration_later(traced):
                for k in kids if k["name"] == "serve.admit") == 3
 
 
+# the stamp of the record (`ph[i]` runs from stamp i to stamp i + 1) at
+# which a leaf span ends; the fan-out of a step landed behind its own chunk
+# (depth 0) ends at the last stamp
+ENDS_AT = {"serve.sweep": 1, "serve.admit": 2, "serve.plan": 3,
+           "serve.decode_dispatch": 4, "serve.fetch": 5, "serve.fanout": 6,
+           "serve.prefill_finish": 7}
+
+
 def test_host_and_fetch_add_up_to_the_step(traced):
     """`host_ms` + `fetch_ms` of the flight record is the step from its
     first stamp to its last; the `serve.step` span around them adds the
-    writing of the record and of the spans themselves."""
+    writing of the record and of the spans themselves. The leaves are cut
+    at the record's own stamps: from the first leaf's start to the last
+    one's end, plus the record's phases behind that leaf (the stamps a
+    step without a chunk, or with a lagged landing, takes after its last
+    span: a loaded machine can park the thread between two of them for
+    milliseconds), is the step, to the rounding of the record."""
     step = {e["args"]["step"]: e["dur"] / 1e3
             for e in traced["spans"] if e["name"] == "serve.step"}
     kids = {e["args"]["step"]: _children(traced["spans"], e)
             for e in traced["spans"] if e["name"] == "serve.step"}
     for r in traced["flight"]:
         whole = r["host_ms"] + r["fetch_ms"]
+        assert abs(whole - r["wall_ms"]) < 0.002
+        assert abs(sum(r["ph"]) - r["wall_ms"]) < 0.01, r
         leaves = kids[r["seq"]]
         first, last = leaves[0], leaves[-1]
+        assert first["name"] == "serve.sweep"
+        end = 8 if last["args"].get("of_step") == r["seq"] \
+            else ENDS_AT[last["name"]]
         stamped = (last["ts"] + last["dur"] - first["ts"]) / 1e3
-        assert abs(whole - stamped) < 0.01, (r, stamped)
+        assert abs(whole - stamped - sum(r["ph"][end:])) < 0.01, (r, stamped)
         assert whole <= step[r["seq"]] + 0.01
         f = [k for k in leaves if k["name"] == "serve.fetch"]
         if f:
+            # the span is the record's `fetch` phase, stamp for stamp, and
+            # `fetch_ms` is timed from the same stamp (`_land`'s `t0`)
+            assert abs(f[0]["dur"] / 1e3 - r["ph"][4]) < 0.01
             assert abs(f[0]["dur"] / 1e3 - r["fetch_ms"]) < 0.01
+
+
+def test_a_last_chunk_record_says_it_joined_a_slot(traced):
+    """`joined` on the flight record: 1 on the iteration that ended a
+    prompt (one `_slot_join` program), 0 on every other; `serve.
+    prefill_finish` follows every chunk with `final` set on those."""
+    recs = traced["flight"]
+    assert all(r["joined"] == int(r["kind"] == "last_chunk") for r in recs)
+    assert sum(r["joined"] for r in recs) == len(traced["reqs"])
+    fin = {e["args"]["step"]: e["args"]["final"] for e in traced["spans"]
+           if e["name"] == "serve.prefill_finish"}
+    chunked = {r["seq"]: r["kind"] == "last_chunk" for r in recs
+               if r["kind"] in ("chunk", "last_chunk")}
+    assert fin == chunked and any(fin.values()) and not all(fin.values())
 
 
 def test_timeline_events_name_their_step(traced):

@@ -62,9 +62,18 @@ class MambaConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class RetentionConfig:
+    """Power retention (Brumby's mixer, models/brumby.py): the weight of a
+    key is (q . k)^power under a gate a key/value head; the row's state is
+    the symmetric power of its keys against its values, float32."""
+    power: int = 2
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """Resolved per-layer behavior, consumed by the generic decoder block."""
-    kind: str = "full"            # 'full' | 'swa' | 'linear' | 'mamba'
+    # 'full' | 'swa' | 'linear' | 'mamba' | 'retention'
+    kind: str = "full"
     use_rope: bool = True
     local_rope_table: bool = False  # Gemma3 SWA layers: rope_local_base_freq
     window: int | None = None     # sliding-window size when kind == 'swa'
@@ -172,6 +181,8 @@ class ModelConfig:
     attn_head_gate: bool = False
     # Mamba-1 state-space layers (Jamba); its attention layers are NoPE
     mamba: MambaConfig | None = None
+    # power retention in every layer (Brumby): no layer keeps keys or values
+    retention: RetentionConfig | None = None
     # Attention logit scale override (None = head_dim**-0.5); Gemma3 models
     # may set query_pre_attn_scalar.
     attn_scale: float | None = None
@@ -199,6 +210,9 @@ class ModelConfig:
             attn = i in self.mamba.attn_layers
             return LayerSpec(kind="full" if attn else "mamba", use_rope=False,
                              norm_style=self.norm_style, recurrent=not attn)
+        if self.retention is not None:
+            return LayerSpec(kind="retention", use_rope=True,
+                             norm_style=self.norm_style, recurrent=True)
         if self.linear_attn is not None and i < len(self.linear_attn.layer_types):
             if self.linear_attn.layer_types[i] == "linear_attention":
                 return LayerSpec(kind="linear", use_rope=False,
@@ -776,6 +790,31 @@ def _jamba(d):
         rms_norm_eps=float(d.get("rms_norm_eps", 1e-6))))
 
 
+def _brumby(d):
+    """Brumby (manifestai Brumby-14B-Base, `model_type: brumby`): Qwen3's
+    block, key for key, with the softmax attention of EVERY layer replaced
+    by power retention (arXiv:2507.04239; models/brumby.py). The published
+    config has no key for the power: 2, the released model's, unless
+    `retention_power` says otherwise. What this adapter cannot honour it
+    refuses."""
+    if d.get("use_sliding_window") or d.get("sliding_window") is not None:
+        raise ValueError("brumby: a sliding window "
+                         f"({d.get('sliding_window')}) is not implemented: "
+                         "every layer is power retention over the whole row")
+    if d.get("rope_scaling"):
+        raise ValueError(f"brumby: rope_scaling {d['rope_scaling']} is not "
+                         "implemented")
+    if d.get("attention_bias"):
+        raise ValueError("brumby: attention_bias true is not implemented")
+    power = int(d.get("retention_power", 2))
+    if power != 2:
+        raise ValueError(f"brumby: retention power {power} is not "
+                         "implemented (the state is the symmetric SQUARE "
+                         "of the keys)")
+    return ModelConfig(**_base(d, "brumby", qk_norm=True,
+                               retention=RetentionConfig(power=power)))
+
+
 # HF architectures string -> adapter (ref: cake/mod.rs arch_str_to_text_model_arch;
 # unknown strings fall back to llama, matching the reference)
 ARCH_ADAPTERS = {
@@ -810,6 +849,7 @@ FAMILY_ADAPTERS = {
     "mistral": _mistral, "gemma3": _gemma3, "falcon3": _falcon3,
     "olmo2": _olmo2, "exaone4": _exaone4, "jamba": _jamba,
     "mimo_v2": _mimo_v2, "laguna": _laguna, "solar_open2": _solar_open2,
+    "brumby": _brumby,
 }
 
 
@@ -911,6 +951,12 @@ def tiny_config(arch: str = "llama", **over) -> ModelConfig:
                  moe_intermediate_size=32, norm_topk_prob=True,
                  routed_scaling_factor=1,
                  expert_parallel={"size": 2, "rank": 0})
+    if arch == "brumby":
+        # power retention in every layer: 4 query heads on 2 key/value
+        # heads of width 8, a state of 36 x 8 a head
+        d.update(head_dim=8, rms_norm_eps=1e-6, rope_theta=1000000,
+                 sliding_window=None, use_sliding_window=False,
+                 rope_scaling=None, attention_bias=False)
     d.update(over)
     if arch in ("qwen3_5", "qwen3_5_moe"):
         d["text_config"] = dict(d)

@@ -6,7 +6,8 @@ loader and the exporter (utils/) ask `mixer_of` and branch on nothing else,
 so a new layer kind is a row in its own module and an arm here.
 
 Rows: attention, `full` and `swa` (layers.MIXER), the gated delta net
-(qwen3_5.MIXER), Kimi Delta Attention (kda.MIXER), Mamba (jamba.MIXER).
+(qwen3_5.MIXER), Kimi Delta Attention (kda.MIXER), Mamba (jamba.MIXER), power
+retention (brumby.MIXER).
 """
 from __future__ import annotations
 
@@ -45,6 +46,8 @@ def mixer_of(cfg: ModelConfig, spec: LayerSpec) -> Mixer:
     stays out of the others' import path."""
     if spec.kind == "mamba":
         from ..jamba import MIXER
+    elif spec.kind == "retention":
+        from ..brumby import MIXER
     elif spec.kind == "linear" and cfg.linear_attn.kda:
         from ..kda import MIXER
     elif spec.kind == "linear":

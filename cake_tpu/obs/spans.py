@@ -280,8 +280,9 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
     ("cake.embed", "token embedding lookup (layers.embed_tokens)"),
     ("cake.attn", "one layer's attention incl. its KV write "
                   "(layers._attn enters the scopes of the layer's row in "
-                  "models/common/mixers.py: layers.attention_forward, or "
-                  "a delta-rule mixer under cake.attn.linear)"),
+                  "models/common/mixers.py: layers.attention_forward, "
+                  "a delta-rule mixer under cake.attn.linear, or power "
+                  "retention under cake.attn.retention)"),
     ("cake.attn.window", "a window layer's masked attention over its "
                          "ring and the chunk: scores, the softmax (with "
                          "its sink column where the layer has one) and "
@@ -310,6 +311,21 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
                               "token, a scan along a chunk's tokens: "
                               "qwen3_5.delta_rule_scan) and the gated "
                               "output norm"),
+    ("cake.attn.retention", "a power-retention layer's whole mixer, inside "
+                            "cake.attn (the row brumby.MIXER: "
+                            "retention_forward)"),
+    ("cake.attn.retention.proj", "its projections: q, k, v and the gate's, "
+                                 "the per-head norms, rope, logsigmoid, "
+                                 "and the output's"),
+    ("cake.attn.retention.expand", "phi of q and k: the symmetric squares "
+                                   "(brumby.phi, two 0/1 picks and a "
+                                   "product)"),
+    ("cake.attn.retention.scan", "every pass over the row's state S and "
+                                 "normaliser z: the products inside the "
+                                 "chunk, the read-out against the carried "
+                                 "state, the division and the decayed "
+                                 "update (brumby.retention_chunk; a "
+                                 "decode step is its C = 1)"),
     ("cake.ssm", "one Mamba layer's state-space mixer, in cake.attn's "
                  "place for that layer kind (the row jamba.MIXER: "
                  "mamba_forward)"),

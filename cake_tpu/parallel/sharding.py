@@ -183,6 +183,11 @@ def check_tp_divisibility(cfg: ModelConfig, mesh: Mesh):
             f"--tp {tp} is not supported for {cfg.arch}: its attention has "
             "one KV head, which cannot be split, and its Mamba projections "
             "and state have no placement over tp (they replicate)")
+    if cfg.retention is not None and tp > 1:
+        raise ValueError(
+            f"--tp {tp} is not supported for {cfg.arch}: its key/value "
+            "heads would split, but no placement of a power-retention "
+            "row's state over tp is written or measured")
     for a in {cfg.attn_shape(s) for s in cfg.layer_specs()
               if not s.recurrent}:
         if a.kv_heads % tp or a.heads % tp:

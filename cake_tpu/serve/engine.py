@@ -564,9 +564,13 @@ class ServeEngine:
             log.warning(
                 "prefix cache of %s MB asked for and not built: %s",
                 self._prefix_mb,
-                PrefixCache.refusal(self.ctx, self.chunk, self._prefix_mb)
+                PrefixCache.refusal(
+                    self.ctx, self.chunk, self._prefix_mb,
+                    PrefixCache.block_bytes(self.model, self.ctx,
+                                            self.chunk))
                 if self.paged is None else PagedPrefixCache.refusal_paged(
-                    self.paged, self.chunk, self._prefix_mb))
+                    self.paged, self.chunk, self._prefix_mb,
+                    PagedPrefixCache.unit_bytes(self.paged, self.chunk)))
         return pc
 
     # -- client surface (any thread) ----------------------------------------

@@ -110,23 +110,46 @@ class PrefixCache:
         self.version = 0
 
     @staticmethod
-    def refusal(ctx: int, block: int, capacity_mb: float) -> str | None:
+    def block_bytes(model, ctx: int, block: int) -> int:
+        """What ONE block of this model weighs: what `slot_extract` would
+        copy out of a row, from shapes alone (nothing is allocated)."""
+        import jax
+
+        from ..models.common.cache import (init_cache,
+                                           slot_extract_block_layers)
+        row = jax.eval_shape(lambda: init_cache(
+            model.cfg, 1, ctx, model.dtype)["layers"])
+        return _tree_bytes(jax.eval_shape(
+            lambda layers: slot_extract_block_layers(layers, 0, 0, block),
+            row))
+
+    @staticmethod
+    def refusal(ctx: int, block: int, capacity_mb: float,
+                block_bytes: int = 0) -> str | None:
         """Why no cache is built for these sizes (the engine logs it when
         one was asked for), or None. A window smaller than the block is no
-        reason: the ring rides each block as boundary state."""
+        reason: the ring rides each block as boundary state. A block that
+        alone outweighs the capacity is: every insert would copy it out
+        of the row only to throw it away."""
         if capacity_mb <= 0:
             return "capacity 0"
         if block > ctx:
             return (f"a block of {block} tokens (the prefill chunk) does "
                     f"not fit a row of {ctx}")
+        if block_bytes > capacity_mb * 1024 * 1024:
+            return (f"one block of {block} tokens is {block_bytes} B of "
+                    "this model's row, more than the whole capacity")
         return None
 
     @classmethod
     def build(cls, model, ctx: int, block: int,
               capacity_mb: float) -> "PrefixCache | None":
-        """None when disabled (capacity <= 0) or when a block does not
-        fit a row (`refusal`)."""
-        if cls.refusal(ctx, block, capacity_mb) is not None:
+        """None when disabled (capacity <= 0), when a block does not fit a
+        row or when one block outweighs the capacity (`refusal`), decided
+        from shapes, before any block is extracted."""
+        if capacity_mb <= 0 or cls.refusal(
+                ctx, block, capacity_mb,
+                cls.block_bytes(model, ctx, block)) is not None:
             return None
         return cls(model, block, int(capacity_mb * 1024 * 1024))
 
@@ -197,8 +220,7 @@ class PrefixCache:
         blk = _Block(tokens=ids, layers=entry_layers,
                      nbytes=_tree_bytes(entry_layers),
                      state_bytes=_tree_bytes(entry_layers, state_only=True))
-        if blk.nbytes > self.capacity:
-            return                          # could never fit; don't thrash
+        # (one block fits: `build` refused a capacity under `block_bytes`)
         while self.bytes + blk.nbytes > self.capacity and self._blocks:
             _, old = self._blocks.popitem(last=False)
             self.bytes -= old.nbytes
@@ -272,16 +294,26 @@ class PagedPrefixCache(PrefixCache):
                             # single int so /health reads it race-free)
 
     @staticmethod
-    def refusal_paged(paged, unit: int, capacity_mb: float) -> str | None:
+    def unit_bytes(paged, unit: int) -> int:
+        """What one share unit weighs: its pinned blocks and the boundary
+        snapshot of a slot's rows (`insert`'s own arithmetic)."""
+        snap = sum(leaf.nbytes // leaf.shape[0]
+                   for lc in paged.rows for leaf in lc.values())
+        return unit // paged.bt * paged.block_bytes + snap
+
+    @staticmethod
+    def refusal_paged(paged, unit: int, capacity_mb: float,
+                      unit_bytes: int = 0) -> str | None:
         if unit % paged.bt:
             return (f"the share unit of {unit} tokens (the prefill chunk) "
                     f"is no multiple of the {paged.bt}-token blocks")
-        return PrefixCache.refusal(paged.ctx, unit, capacity_mb)
+        return PrefixCache.refusal(paged.ctx, unit, capacity_mb, unit_bytes)
 
     @classmethod
     def build_paged(cls, model, paged, unit: int,
                     capacity_mb: float) -> "PagedPrefixCache | None":
-        if cls.refusal_paged(paged, unit, capacity_mb) is not None:
+        if cls.refusal_paged(paged, unit, capacity_mb,
+                             cls.unit_bytes(paged, unit)) is not None:
             return None
         return cls(model, paged, unit, int(capacity_mb * 1024 * 1024))
 
@@ -332,8 +364,7 @@ class PagedPrefixCache(PrefixCache):
             snap_bytes = _tree_bytes(snap)
             state_bytes = _tree_bytes(snap, state_only=True)
         nbytes = len(pids) * self.paged.block_bytes + snap_bytes
-        if nbytes > self.capacity:
-            return                          # could never fit; don't thrash
+        # (one unit fits: `build_paged` refused a capacity under it)
         while self.bytes + nbytes > self.capacity and self._blocks:
             self._evict_lru()
         for pid in pids:

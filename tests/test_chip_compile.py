@@ -331,3 +331,74 @@ def test_serve_programs_take_the_pool_in_place(one_chip, monkeypatch, family):
             limit += 2 * 72 * 256 * (512 + 256) * 4
         assert mem.temp_size_in_bytes < limit, (name,
                                                 mem.temp_size_in_bytes)
+
+
+def test_retention_programs_take_the_state_in_place(one_chip, monkeypatch):
+    """Brumby's widths (40 / 8 heads of 128: a state of 128 x 8,320 float32
+    a key/value head), two layers, an eighth of the vocabulary, 16 rows:
+    `_decode_slots` holds ONE call of the state kernel a layer
+    (ops/retention_state.py: a tile is read once for the read-out and the
+    update) and `_prefill_slot` the chunk form; both donate the pool
+    through, no copy or transpose of a pool-shaped buffer stands in either,
+    and the temporaries are a chunk's (phi of 256 tokens' queries, 338 MB
+    in float32), never a second copy of the state."""
+    from cake_tpu.models import brumby
+    from cake_tpu.models.common.config import config_from_hf_dict
+    monkeypatch.setattr(brumby, "state_kernel_enabled", lambda: True)
+    rows, ctx = 16, 4096
+    cfg = config_from_hf_dict(dict(
+        model_type="brumby", vocab_size=18992, hidden_size=5120,
+        intermediate_size=17408, num_hidden_layers=2,
+        num_attention_heads=40, num_key_value_heads=8, head_dim=128,
+        rms_norm_eps=1e-6, rope_theta=1000000, rope_scaling=None,
+        sliding_window=None, use_sliding_window=False,
+        attention_bias=False, max_position_embeddings=32768,
+        tie_word_embeddings=False))
+    from cake_tpu.models import TextModel
+    from cake_tpu.models.common.cache import init_cache
+    from cake_tpu.models.common.layers import cut_rope, init_params
+    from cake_tpu.serve.engine import RECENT_N
+    m = TextModel.__new__(TextModel)        # programs alone: no weights
+    m.cfg, m.dtype, m.mesh, m.tokenizer, m.max_cache_len = (
+        cfg, jnp.bfloat16, None, None, ctx)
+    m._build()
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(tree))
+
+    def of(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def held():
+        p = init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)
+        return {**p, "rope": cut_rope(p["rope"], ctx)}
+
+    params = described(held)
+    layers = described(lambda: init_cache(cfg, rows, ctx)["layers"])
+    assert layers[0]["state"].shape == (rows, 8, 128, 8320)
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(layers))
+    i32, f32 = jnp.int32, jnp.float32
+    with _no_compile_cache():
+        decode = m._decode_slots.lower(
+            params, layers, of(i32, rows), of(i32, rows),
+            of(jnp.uint32, rows, 2), of(i32, rows, RECENT_N), of(f32, rows),
+            of(i32, rows), of(f32, rows), of(f32, rows),
+            of(jnp.bool_, rows)).compile()
+        chunk = m._prefill_slot.lower(
+            params, of(i32, 1, 256), layers, of(i32), of(i32), of(i32),
+            flash_mode="off").compile()
+    for name, compiled, kernels, limit in (
+            ("decode", decode, 2, 64 * 2 ** 20),
+            ("chunk256", chunk, 0, 2 ** 30)):
+        text, mem = compiled.as_text(), compiled.memory_analysis()
+        assert text.count('custom_call_target="tpu_custom_call"') \
+            == kernels, name
+        big = _converted(text, layers[0]["state"].size // rows)
+        assert not big, (name, big)
+        assert mem.alias_size_in_bytes >= pool_bytes, name
+        assert mem.temp_size_in_bytes < limit, (name,
+                                                mem.temp_size_in_bytes)

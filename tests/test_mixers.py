@@ -23,7 +23,7 @@ from cake_tpu.utils.safetensors_io import TensorStorage, save_safetensors
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 PACKAGE = ROOT / "cake_tpu"
-FAMILY_MODULES = ("jamba", "kda", "qwen3_5")
+FAMILY_MODULES = ("jamba", "kda", "qwen3_5", "brumby")
 
 
 def _rows(cfg):
@@ -75,11 +75,12 @@ SCOPES = [
     ("qwen3_5", 0, ("cake.attn", "cake.attn.linear")),
     ("solar_open2", 1, ("cake.attn", "cake.attn.linear")),
     ("jamba", 0, ("cake.ssm",)),
+    ("brumby", 0, ("cake.attn", "cake.attn.retention")),
 ]
 
 
 @pytest.mark.parametrize("arch,layer,scopes", SCOPES,
-                         ids=["attention", "gdn", "kda", "mamba"])
+                         ids=["attention", "gdn", "kda", "mamba", "retention"])
 def test_a_rows_forward_runs_under_exactly_its_scopes(arch, layer, scopes):
     cfg = tiny_config(arch)
     spec = cfg.layer_spec(layer)
@@ -104,8 +105,9 @@ def test_a_rows_forward_runs_under_exactly_its_scopes(arch, layer, scopes):
     inner = {part for stack in stacks for part in stack[1 + len(scopes):-1]
              if part.startswith("cake.")}
     assert all(part.startswith(scopes[-1] + ".") for part in inner), inner
-    assert ("cake.attn.linear" in {s[2] for s in stacks if len(s) > 2}) \
-        == (len(scopes) == 2)
+    second = {s[2] for s in stacks if len(s) > 2}
+    for nested in ("cake.attn.linear", "cake.attn.retention"):
+        assert (nested in second) == (scopes[-1] == nested)
 
 
 def _sources():
@@ -118,8 +120,8 @@ def test_no_file_but_the_table_compares_a_layer_kind():
     `spec.kind` with a recurrent kind's name, tests a params tree for a
     mixer's key or indexes it by one."""
     kind = re.compile(
-        r"""\.kind\s*(==|!=|in|not\s+in)\s*[(\[]?\s*["'](linear|mamba)["']"""
-        r"""|["'](linear|mamba)["']\s*(==|!=)\s*\w+\.kind""")
+        r"""\.kind\s*(==|!=|in|not\s+in)\s*[(\[]?\s*["'](linear|mamba|retention)["']"""
+        r"""|["'](linear|mamba|retention)["']\s*(==|!=)\s*\w+\.kind""")
     key = re.compile(
         r"""["'](mamba|linear_attn)["']\s+(not\s+)?in\s+\w"""
         r"""|\w\[["'](mamba|linear_attn)["']\]""")
@@ -149,9 +151,10 @@ def test_only_the_table_imports_a_familys_row_or_its_functions():
     assert not found, "\n".join(found)
 
 
-# ModelConfig.attention_kinds() of the six benchmark configurations and of
-# the tiny configs that have a delta-rule or a Mamba layer, as the parent of
-# PR 51 returned them (what /health and the flight record print)
+# ModelConfig.attention_kinds() of the benchmark configurations and of the
+# tiny configs that have a delta-rule, a Mamba or a retention layer: the six
+# of PR 51 as its parent returned them, Brumby's since PR 53 (what /health
+# and the flight record print)
 def _attn(kind, layers, heads, kv_heads, window, rotary_dim, rope_theta,
           rope_scaling):
     return {"kind": kind, "layers": layers, "heads": heads,
@@ -179,6 +182,16 @@ KINDS = {
     "solar-open2-l8-ep32": [
         _attn("full", 2, 64, 8, None, 0, None, None),
         _linear(6, 64, 128, 128, "channel", 4, 25165824)],
+    "brumby-14b-l8": [
+        {"kind": "retention", "layers": 8, "power": 2, "heads": 40,
+         "kv_heads": 8, "key_dim": 128, "state_width": 8256,
+         "padded_width": 8320, "rotary_dim": 128, "rope_theta": 1000000.0,
+         "state_bytes": 274759680}],
+    "tiny:brumby": [
+        {"kind": "retention", "layers": 4, "power": 2, "heads": 4,
+         "kv_heads": 2, "key_dim": 8, "state_width": 36,
+         "padded_width": 128, "rotary_dim": 8, "rope_theta": 1000000.0,
+         "state_bytes": 36864}],
     "tiny:jamba": [_attn("full", 1, 4, 2, None, 0, None, None)],
     "tiny:qwen3_5": [_linear(3, 4, 16, 16, "head", 4, 12288),
                      _attn("full", 1, 4, 2, None, 4, 10000.0, None)],

@@ -107,8 +107,8 @@ def test_the_manifest_holds_with_the_new_entries(manifest):
     assert manifest.validate(ROOT) == []
     m = manifest.load(ROOT)
     cells = [w["name"] for w in m["workloads"]]
-    # (PRs 44 and 48 appended two metrics each behind them)
-    assert [e["name"] for e in m["per_layer"][-8:-4]] == list(NEW)
+    # (PRs 44 and 48 appended two metrics each behind them, PR 53 three)
+    assert [e["name"] for e in m["per_layer"][-11:-7]] == list(NEW)
     by = {e["name"]: e for e in m["per_layer"]}
     assert by[NEW[0]]["workloads"] == by[NEW[1]]["workloads"] == cells
     assert (by[NEW[0]]["moves"], by[NEW[1]]["moves"]) == ("out_tok_s",
@@ -121,7 +121,8 @@ def test_the_manifest_holds_with_the_new_entries(manifest):
     assert sorted(by[NEW[2]]["workloads"] + by[NEW[3]]["workloads"]) == \
         sorted(tails["itl_p95_ms"] + tails["itl_p99_ms"])
     assert set(cells) - set(tails["itl_p95_ms"] + tails["itl_p99_ms"]) == \
-        {"laguna-s-2.1-l9-ep16.codeassist", "solar-open2-l8-ep32.agent"}
+        {"laguna-s-2.1-l9-ep16.codeassist", "solar-open2-l8-ep32.agent",
+         "brumby-14b-l8.continuation"}
     for inside, outside in zip(INSIDE, ("api.handoff_p95_ms",
                                         "api.handoff_p95_ms.tail99")):
         assert by[inside]["workloads"] == by[outside]["workloads"]
@@ -133,11 +134,14 @@ def test_the_manifest_holds_with_the_new_entries(manifest):
 def test_benchmark_json_only_gained_entries_at_the_end():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         m = json.load(f)
-    assert len(m["workloads"]) == 7 and len(m["configs"]) == 6
+    assert len(m["workloads"]) == 8 and len(m["configs"]) == 7
     names = [e["name"] for e in m["per_layer"]]
     assert len(names) == len(set(names))
-    assert names.index("engine.prefix_hit_share") == len(names) - 9
-    assert names[-4:] == ["programs.decode.attn_full_ms",
+    assert names.index("engine.prefix_hit_share") == len(names) - 12
+    assert names[-7:] == ["programs.decode.attn_full_ms",
                           "programs.decode.ffn_shared_ms",
                           "programs.decode.attn_linear_ms",
-                          "programs.prefill.attn_linear_ms"]
+                          "programs.prefill.attn_linear_ms",
+                          "programs.decode.attn_retention_ms",
+                          "programs.prefill.attn_retention_ms",
+                          "retention_state_roofline"]

@@ -30,7 +30,7 @@ KNOWN_DONATING_METHODS: dict[str, tuple[int, ...]] = {
     "row_reset": (0,),
     "slot_assign": (0,),
     "slot_release": (0,),
-    "slot_splice": (0,),
+    "slot_restore": (0,),
     "slot_join": (2, 3, 4, 5),          # toks, pos, rngs, recents
     "verify_tokens": (0,),              # cache
     "prefill": (0,),

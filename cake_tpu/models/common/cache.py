@@ -301,32 +301,126 @@ def slot_extract_block_layers(pool_layers: list[dict], slot, start,
     return out
 
 
-def slot_splice_block_layers(pool_layers: list[dict], src_layers: list[dict],
-                             slot, final) -> list[dict]:
-    """Scatter a cached prefix block (slot_extract_block_layers output) into
-    pool row `slot` WITHOUT resetting the rest of the row, so consecutive
-    blocks of a matched prefix chain merge — admission then only prefills
-    the suffix. Entries land at position % row_size (the slot_assign remap);
-    the row must have been wiped at release, so everything outside the
-    spliced prefix is still empty.
+def restore_runs(size: int, block: int, n: int) -> list[list[int]] | None:
+    """What a positional buffer of `size` entries keeps of a piece of `n`
+    chain blocks of `block` tokens each, from shapes alone: the blocks it
+    reads (by their index in the piece), grouped into the runs they lie in
+    the row as. A block is a run wherever buffer and block divide one
+    another: a buffer of q blocks keeps the piece's last min(n, q), a ring
+    narrower than a block the last block's own tail, which is the ring
+    whole. They join into ONE run where the piece is ALIGNED (its first
+    block a multiple of n: slot_restore_chain_layers' contract) and n and q
+    divide one another, since a run that starts on a multiple of its own
+    length cannot wrap; else each is a run of its own. None: no run rule
+    covers the shape (a ring that is no multiple of the block, nor the
+    block of it), every block is read and scattered entry by entry."""
+    if block % size == 0:
+        return [[n - 1]]
+    if size % block:
+        return None
+    q = size // block
+    k = min(n, q)
+    kept = list(range(n - k, n))
+    return [kept] if max(n, q) % k == 0 else [[b] for b in kept]
 
-    `final` (traced bool): recurrent state is a block-END snapshot, so
-    only the LAST block of the chain may install it.
-    """
+
+def _write_run(lc: dict, src: dict, slot, start) -> dict:
+    """Write `src` (leaves [L, ...], no batch axis) over the L entries of
+    row `slot` from index `start` on, as one slab a leaf. Where a source
+    `pos` is -1 the row keeps what it had, bytes and position: the
+    scatter's drop rule, as a select over the slab."""
+    length = src["pos"].shape[0]
+    keep = src["pos"] >= 0
+    out = {}
+    for name, buf in lc.items():
+        at = (slot, start) + (jnp.int32(0),) * (buf.ndim - 2)
+        old = jax.lax.dynamic_slice(buf, at, (1, length) + buf.shape[2:])
+        new = jnp.where(keep.reshape((1, length) + (1,) * (buf.ndim - 2)),
+                        src[name][None].astype(buf.dtype), old)
+        out[name] = jax.lax.dynamic_update_slice(buf, new, at)
+    return out
+
+
+def _scatter_block(lc: dict, src: dict, slot) -> dict:
+    """One block's entries into row `slot` at position % size, entry by
+    entry (pos -1: dropped): what a run cannot express."""
+    size = lc["k"].shape[1]
+    pos = src["pos"][0]                                    # [width]
+    slots = jnp.where(pos >= 0, pos % size, size)          # OOB -> dropped
+    return {"k": lc["k"].at[slot, slots].set(src["k"][0], mode="drop"),
+            "v": lc["v"].at[slot, slots].set(src["v"][0], mode="drop"),
+            "pos": lc["pos"].at[slot, slots].set(pos, mode="drop")}
+
+
+def restore_reads(pool_layers: list[dict], chain: list[list[dict]],
+                  block: int) -> list[list[dict]]:
+    """The chain with every layer of a block that slot_restore_chain_layers
+    does not read replaced by {}: a ring's overwritten blocks, every
+    recurrent snapshot but the last. What the jitted program is handed, so
+    what is not read is not an argument either."""
+    n = len(chain)
+    reads = []
+    for pl in pool_layers:
+        runs = restore_runs(pl["pos"].shape[1], block, n) \
+            if is_positional(pl) else [[n - 1]]
+        reads.append(range(n) if runs is None else sum(runs, []))
+    return [[lc if b in reads[i] else {} for i, lc in enumerate(blk)]
+            for b, blk in enumerate(chain)]
+
+
+def slot_restore_chain_layers(pool_layers: list[dict],
+                              chain: list[list[dict]], slot, first_block,
+                              block: int, final) -> list[dict]:
+    """Restore a piece of a matched prefix chain (`chain`: consecutive
+    blocks, slot_extract_block_layers output each, the first one block
+    `first_block` of the prompt) into pool row `slot` WITHOUT resetting the
+    rest of the row, so the pieces of a chain merge and admission prefills
+    the suffix alone. The row holds afterwards what block-by-block scatters
+    at position % row_size leave, and each byte of it is written once:
+
+      * a positional layer (restore_runs): the blocks the buffer keeps,
+        concatenated into one slab where they lie as one run (a full
+        buffer: the whole piece at first_block * block; a ring: its last
+        blocks, or the last block's tail), else a slab a block. A ring's
+        earlier blocks are not read: a block captured at a chunk boundary
+        holds every position it spans, so the later ones overwrite them
+        whole (a `pos` of -1 in a block that IS read keeps what the row
+        had). A shape no run rule covers takes the scatter, block by
+        block;
+      * a recurrent layer: its state is a block-END snapshot, so the
+        piece's LAST block installs it, once, and only where `final`
+        (traced bool: the chain's last piece).
+
+    `slot`, `first_block`, `final` may be traced; `block` and the piece's
+    length are static (one program per length). The piece must be ALIGNED,
+    first_block a multiple of its length (TextModel.slot_restore checks
+    it): pieces of powers of two, largest first, always are. The row must
+    have been wiped at release, so everything outside the restored prefix
+    is still empty."""
+    n = len(chain)
     out = []
-    for pl, sl in zip(pool_layers, src_layers):
+    for i, pl in enumerate(pool_layers):
         if not is_positional(pl):
-            new = {n: jnp.where(final, sl[n][0], pl[n][slot]) for n in pl}
-            out.append({n: pl[n].at[slot].set(new[n]) for n in pl})
+            snap = chain[-1][i]
+            out.append({name: pl[name].at[slot].set(
+                jnp.where(final, snap[name][0], pl[name][slot]))
+                for name in pl})
             continue
-        size = pl["k"].shape[1]
-        pos = sl["pos"][0]                                 # [width]
-        slots = jnp.where(pos >= 0, pos % size, size)      # OOB -> dropped
-        out.append({
-            "k": pl["k"].at[slot, slots].set(sl["k"][0], mode="drop"),
-            "v": pl["v"].at[slot, slots].set(sl["v"][0], mode="drop"),
-            "pos": pl["pos"].at[slot, slots].set(pos, mode="drop"),
-        })
+        size = pl["pos"].shape[1]
+        runs = restore_runs(size, block, n)
+        if runs is None:
+            for blk in chain:
+                pl = _scatter_block(pl, blk[i], slot)
+            out.append(pl)
+            continue
+        m = min(block, size)            # entries a block holds of this layer
+        for run in runs:
+            src = {name: jnp.concatenate([chain[b][i][name][0] for b in run])
+                   for name in pl}
+            # the run's first stored position, at its place in the row
+            start = ((first_block + run[0] + 1) * block - m) % size
+            pl = _write_run(pl, src, slot, start)
+        out.append(pl)
     return out
 
 

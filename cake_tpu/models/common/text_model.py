@@ -43,8 +43,9 @@ from ...ops.sampling import (SamplingConfig, config_has_filters,
 from .cache import (grow_cache, kv_capacity, paged_block_of,
                     paged_block_window, paged_gather_layer,
                     paged_scatter_blocks, slot_assign_layers,
-                    slot_extract_block_layers, slot_reset_layers,
-                    slot_splice_block_layers, truncate_layers)
+                    restore_reads, slot_extract_block_layers,
+                    slot_reset_layers, slot_restore_chain_layers,
+                    truncate_layers)
 from .config import ModelConfig
 from .layers import (cut_rope, embed_tokens, flash_kernel_mode,
                      forward_layers, lm_head_logits)
@@ -550,9 +551,13 @@ class TextModel:
         def _slot_extract(layers, slot, start, width):
             return slot_extract_block_layers(layers, slot, start, width)
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _slot_splice(layers, src_layers, slot, final):
-            return slot_splice_block_layers(layers, src_layers, slot, final)
+        @functools.partial(jax.jit, donate_argnums=(0,),
+                           static_argnames=("block",))
+        def _slot_restore(layers, chain, at, block):
+            """at = [slot, first block, final] int32, one transfer: all
+            traced, so ONE executable per piece length whatever they are."""
+            return slot_restore_chain_layers(layers, chain, at[0], at[1],
+                                             block, at[2] != 0)
 
         @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 5))
         def _slot_join(logits, base_rng, toks, pos, rngs, recents, temps,
@@ -776,7 +781,7 @@ class TextModel:
         self._slot_reset = _slot_reset
         self._prefill_slot = _prefill_slot
         self._slot_extract = _slot_extract
-        self._slot_splice = _slot_splice
+        self._slot_restore = _slot_restore
         self._slot_join = _slot_join
         self._decode_slots_paged = _decode_slots_paged
         self._prefill_slot_paged = _prefill_slot_paged
@@ -863,14 +868,25 @@ class TextModel:
         return self._slot_extract(layers, jnp.asarray(slot, jnp.int32),
                                   jnp.asarray(start, jnp.int32), width=width)
 
-    def slot_splice(self, layers, src_layers, slot: int, final: bool):
-        """Scatter a cached prefix block into pool row `slot` without
-        resetting the rest of the row (prefix-cache hit). `final` marks the
-        last block of the matched chain — the only one whose linear-attn
-        state snapshot is installed."""
-        return self._slot_splice(layers, src_layers,
-                                 jnp.asarray(slot, jnp.int32),
-                                 jnp.asarray(final))
+    def slot_restore(self, layers, chain: list, slot: int, first_block: int,
+                     block: int, final: bool):
+        """Restore a piece of a matched prefix chain (`chain`: consecutive
+        `slot_extract` blocks of `block` tokens, the first one block
+        `first_block` of the prompt) into pool row `slot` without resetting
+        the rest of the row (prefix-cache hit; the pool is donated). ONE
+        dispatch whatever the piece's length, and one executable per
+        length: slot, first block and `final` are traced. `final` marks the
+        chain's last piece, the only one whose last block installs its
+        recurrent snapshot. The piece must be aligned (first_block a
+        multiple of its length), which keeps its runs from wrapping in a
+        ring (cache.restore_runs)."""
+        if first_block % len(chain):
+            raise ValueError(
+                f"a piece of {len(chain)} blocks cannot start at block "
+                f"{first_block}: pieces are aligned to their length")
+        return self._slot_restore(
+            layers, restore_reads(layers, chain, block),
+            np.asarray([slot, first_block, final], np.int32), block=block)
 
     def slot_assign(self, layers, src_cache: dict, slot: int):
         """Re-home a batch-1 prefilled cache into pool row `slot` (row is

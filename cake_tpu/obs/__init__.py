@@ -100,6 +100,17 @@ SERVE_PREFIX_HITS = REGISTRY.counter(
     "cake_serve_prefix_cache_hits_total",
     "Admissions that spliced at least one cached prefix block")
 
+SERVE_PREFIX_RESTORE_DISPATCHES = REGISTRY.counter(
+    "cake_serve_prefix_restore_dispatches_total",
+    "Restore programs a prefix hit dispatched (TextModel.slot_restore): "
+    "one a power-of-two piece of the matched chain, so one for a chain of "
+    "32 blocks and two for one of 33")
+
+SERVE_PREFIX_RESTORE_BLOCKS = REGISTRY.counter(
+    "cake_serve_prefix_restore_blocks_total",
+    "Cached prefix blocks those programs restored into a row; over "
+    "cake_serve_prefix_restore_dispatches_total, the blocks a dispatch")
+
 SERVE_PREFIX_MISSES = REGISTRY.counter(
     "cake_serve_prefix_cache_misses_total",
     "Admissions that found no reusable prefix block")
@@ -522,7 +533,8 @@ __all__ = [
     "WORKER_FWD_SECONDS", "HOP_SECONDS", "WORKER_HEARTBEAT",
     "SERVE_QUEUE_DEPTH", "SERVE_SLOTS_BUSY", "SERVE_QUEUE_WAIT_SECONDS",
     "SERVE_BATCH_OCCUPANCY", "SERVE_PREFILL_CHUNKS", "SERVE_SLOT_JOINS",
-    "SERVE_PREFIX_HITS",
+    "SERVE_PREFIX_HITS", "SERVE_PREFIX_RESTORE_DISPATCHES",
+    "SERVE_PREFIX_RESTORE_BLOCKS",
     "SERVE_PREFIX_MISSES", "SERVE_PREFIX_EVICTIONS", "SERVE_PREFIX_BYTES",
     "SERVE_PREFIX_STATE_BYTES",
     "SERVE_QUEUE_TIMEOUTS", "SERVE_STEP_FAILURES", "SERVE_ENGINE_REBUILDS",

@@ -155,7 +155,12 @@ fetch, fanout, prefill, late_land: they add up to `wall_ms`), `kind` =
 `decode` / `chunk` / `last_chunk` / `idle`, `joined` = the slots the
 iteration handed to the batched decode, one `_slot_join` program each (1
 on a `last_chunk` record, else 0; their sum is
-`cake_serve_slot_joins_total`), `of_step` = the iteration
+`cake_serve_slot_joins_total`), `restores` / `restored` = the restore
+programs the iteration's prefix hits dispatched in its admit phase and the
+cached blocks they restored (one `_slot_restore` program a power-of-two
+piece of the matched chain: 1 / 32 for a hit of 32 blocks, 2 / 33 for one
+of 33; their sums are `cake_serve_prefix_restore_dispatches_total` and
+`cake_serve_prefix_restore_blocks_total`), `of_step` = the iteration
 whose ids it fetched, and `gap_ms` = the `_run` loop's time since the
 previous iteration when that one left work behind, else 0. An iteration that failed or found
 nothing to do leaves its `seq` out of the ring. The supervisor dumps the ring to `CAKE_TRACE_DIR` as JSON

@@ -990,6 +990,9 @@ class ServeEngine:
             emitted0, retired0 = self._emitted, self._retired
             dropped0, fetch_s0 = self._dropped, self._fetch_s
             joined0 = self._joined
+            pc = self.prefix_cache
+            restores0, restored0 = (pc.restores, pc.restored) if pc \
+                else (0, 0)
             # 1. cancel sweeps: decoding slots, mid-prefill slots, and
             # abandoned-while-queued requests (those would otherwise pin
             # queue capacity and 429 live clients while slots sit idle).
@@ -1053,9 +1056,13 @@ class ServeEngine:
             busy0 = self.pool.busy_count
             # 2. preempted slots resume FIRST (oldest-first, as soon as a
             # slot + enough blocks free up — their clients are mid-stream),
-            # then every queued request takes a free slot (cheap: at most
-            # a prefix-cache splice — the prefill itself is chunked
-            # below), so multiple admissions are in flight concurrently
+            # then every queued request takes a free slot, so several
+            # admissions are in flight at once. The prefill itself is
+            # chunked below; what an admission costs HERE, in front of the
+            # decode dispatch, is a prefix hit's restore: one program a
+            # power-of-two piece of the matched chain (PrefixCache.splice;
+            # the record's `restores` / `restored`), or in paged mode a
+            # table publish and a row install
             if self._preempted:
                 self._resume_preempted()
             while self.pool.free_count > 0 and self._start_admission():
@@ -1263,6 +1270,8 @@ class ServeEngine:
                 "kind": self._chunk_kind
                 or ("decode" if active else "idle"),
                 "joined": self._joined - joined0,
+                "restores": pc.restores - restores0 if pc else 0,
+                "restored": pc.restored - restored0 if pc else 0,
                 "wall_ms": wall_ms,
                 "gap_ms": 0.0 if t_prev is None
                 else round((t_sweep - t_prev) * 1e3, 3),

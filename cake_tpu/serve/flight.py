@@ -25,7 +25,11 @@ clock reads the step takes anyway: `wall_ms` (first stamp to last), `ph`
 `kind` (`decode` | `chunk` | `last_chunk` | `idle`: whether it carried a
 prefill chunk, the prompt's last, or had no row to step), `joined` (the
 slots it handed to the batched decode, one `_slot_join` program each: 1 on
-a `last_chunk` record, else 0) and `gap_ms`
+a `last_chunk` record, else 0), `restores` / `restored` (the restore
+programs its admissions' prefix hits dispatched in the admit phase, in front
+of the decode step, and the cached blocks they restored: one program a
+power-of-two piece of a matched chain, so 1 / 32 for a hit of 32 blocks, 2 /
+33 for one of 33, 0 / 0 without a hit and in paged mode) and `gap_ms`
 (from the previous iteration's last stamp to this one's first, when that
 one left work behind: the `_run` loop's own time; else 0).
 

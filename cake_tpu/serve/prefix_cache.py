@@ -9,10 +9,15 @@ holds at the block's END: a recurrent layer's conv/state snapshot, and of
 a window layer whose ring is smaller than the block the ring itself, the
 block's last `window` positions) is copied out of the pool row right
 after the chunk that completed it, and a later admission whose prompt
-starts with the same tokens splices the matched chain back into its row
-and prefills only the suffix. A ring lands by position % window, so each
+starts with the same tokens restores the matched chain into its row and
+prefills only the suffix. A ring lands by position % window, so each
 block of a chain overwrites it whole and the chain's end leaves exactly
-what a prefill to that boundary leaves.
+what a prefill to that boundary leaves. The restore is ONE program a
+power-of-two piece of the chain (`splice`; 32 blocks: one dispatch), which
+writes only what the row keeps: a full buffer the piece as one slab, a
+ring its last blocks, a recurrent layer the last snapshot
+(cache.slot_restore_chain_layers). Block by block it was 32 dispatches of
+1.1 ms each on the host in front of a decode step (PERF.md §6 PR 54).
 
 Matching is a hash CHAIN, which gives longest-prefix-match without a trie:
 block b's key is blake2b(prompt[: (b+1)*block]) — equal key chains iff
@@ -38,7 +43,7 @@ on the engine's scheduler thread — no locking; the entries are plain jnp
 arrays, so eviction is a dict pop and the buffers free with their last
 reference.
 
-Greedy outputs are BIT-identical between a hit and a miss: splicing
+Greedy outputs are BIT-identical between a hit and a miss: the restore
 copies the exact bytes prefill wrote, and the suffix chunks land on the
 same chunk-bucket boundaries either way (block size == chunk size), so
 every matmul sees the same shapes and inputs.
@@ -53,7 +58,8 @@ import numpy as np
 
 from ..obs import (SERVE_PREFIX_BYTES, SERVE_PREFIX_EVICTIONS,
                    SERVE_PREFIX_HITS, SERVE_PREFIX_MISSES,
-                   SERVE_PREFIX_STATE_BYTES)
+                   SERVE_PREFIX_RESTORE_BLOCKS,
+                   SERVE_PREFIX_RESTORE_DISPATCHES, SERVE_PREFIX_STATE_BYTES)
 
 __all__ = ["PrefixCache", "PagedPrefixCache"]
 
@@ -104,6 +110,9 @@ class PrefixCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        # restore programs dispatched by hits, and the blocks they restored
+        self.restores = 0
+        self.restored = 0
         # membership version: bumped on every insert/evict (NOT on LRU
         # touches) so the kvshare inventory mirror refreshes only when
         # the key set actually changed
@@ -195,12 +204,23 @@ class PrefixCache:
         return matched
 
     def splice(self, layers, slot: int, keys: list[bytes], matched: int):
-        """Write the matched chain's KV into pool row `slot` (row must be
-        freshly wiped). Returns the updated pool layers."""
-        for b in range(matched):
-            layers = self.model.slot_splice(
-                layers, self._blocks[keys[b]].layers, slot,
-                final=(b == matched - 1))
+        """Restore the matched chain into pool row `slot` (row must be
+        freshly wiped). Returns the updated pool layers. The chain goes to
+        the model in power-of-two pieces, largest first (32 blocks: one
+        dispatch; 37: 32 + 4 + 1), so a hit costs popcount(matched)
+        dispatches of at most log2(row / block) + 1 programs, and every
+        piece starts on a multiple of its length (`slot_restore`)."""
+        b = 0
+        while b < matched:
+            n = 1 << ((matched - b).bit_length() - 1)
+            layers = self.model.slot_restore(
+                layers, [self._blocks[k].layers for k in keys[b:b + n]],
+                slot, b, self.block, final=(b + n == matched))
+            b += n
+            self.restores += 1
+            SERVE_PREFIX_RESTORE_DISPATCHES.inc()
+        self.restored += matched
+        SERVE_PREFIX_RESTORE_BLOCKS.inc(matched)
         return layers
 
     def insert(self, layers, slot: int, prompt_ids: list[int],
@@ -257,6 +277,8 @@ class PrefixCache:
             "hits": self.hits,
             "misses": self.misses,
             "evictions": self.evictions,
+            "restores": self.restores,
+            "restored_blocks": self.restored,
         }
 
 

@@ -250,7 +250,7 @@ def _converted(text, elements):
 
 def _pool_programs(cfg, rows, ctx, one_chip):
     from cake_tpu.models import TextModel
-    from cake_tpu.models.common.cache import init_cache
+    from cake_tpu.models.common.cache import init_cache, restore_reads
     from cake_tpu.models.common.layers import cut_rope, init_params
     from cake_tpu.serve.engine import RECENT_N
     m = TextModel.__new__(TextModel)        # programs alone: no weights
@@ -283,21 +283,24 @@ def _pool_programs(cfg, rows, ctx, one_chip):
         yield "append256", layers, m._prefill_slot.lower(
             params, of(i32, 1, 256), layers, of(i32), of(i32), of(i32),
             flash_mode="append").compile()
-        # a prefix hit restores 256-token blocks into a row, one call each
+        # a prefix hit restores a chain of 256-token blocks into a row: 32
+        # of them (the cells' shared 8k) are one program, handed only what
+        # the row keeps of them
         block = described(lambda: m._slot_extract(
             init_cache(cfg, rows, ctx)["layers"], 0, 0, width=256))
-        yield "splice256", layers, m._slot_splice.lower(
-            layers, block, of(i32), of(jnp.bool_)).compile()
+        yield "restore32x256", layers, m._slot_restore.lower(
+            layers, restore_reads(layers, [block] * 32, 256), of(i32, 3),
+            block=256).compile()
 
 
 @pytest.mark.parametrize("family", list(_POOLS))
 def test_serve_programs_take_the_pool_in_place(one_chip, monkeypatch, family):
     """`_decode_slots`, `_prefill_slot` (append, 256 tokens) and the prefix
-    cache's `_slot_splice` as the chip would run them (the Pallas kernels
+    cache's `_slot_restore` of a 32-block chain as the chip would run them (the Pallas kernels
     on): the optimised HLO holds no copy or transpose whose result is as
     large as a layer's K or V buffer, the pool is donated through, and the
     temporaries are a row's, not a pool's: at keys of 192 by head the
-    runtime stored K length-minor and the decode step and the splice each
+    runtime stored K length-minor and the decode step and a block's splice each
     copied 805 MB to a D-minor layout and back around their scatter (1.08
     GB of temporaries; PERF.md, PR 40). The largest temporary left is the
     masked decode read's float32 scores, rows x Hq x T. Laguna's full
@@ -315,7 +318,7 @@ def test_serve_programs_take_the_pool_in_place(one_chip, monkeypatch, family):
         text, mem = compiled.as_text(), compiled.memory_analysis()
         # the kernels are in it, but for the masked decode of keys of 192
         assert ("tpu_custom_call" in text) == (
-            name != "splice256" and (family, name) != ("mimo_v2", "decode")
+            name != "restore32x256" and (family, name) != ("mimo_v2", "decode")
         ), name
         big = _converted(text, min(pool_shaped, _TABLE_1M))
         assert not big, (name, big)

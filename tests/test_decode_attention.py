@@ -206,7 +206,7 @@ def _serve_run(model):
         admit(0, p0[lo:hi], lo)
     # row 1: a 32-token prefix spliced in from row 0, then its own tail
     blk = model.slot_extract(layers, 0, 0, 32)
-    layers = model.slot_splice(layers, blk, 1, True)
+    layers = model.slot_restore(layers, [blk], 1, 0, 32, True)
     admit(1, prompt(9), 32)
     # row 2: a short prompt in one chunk
     admit(2, prompt(5))

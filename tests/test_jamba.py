@@ -132,7 +132,7 @@ def _row_bytes(layers, row):
 
 
 def test_row_operations_on_a_pool_with_mamba_layers(model):
-    """assign -> extract -> splice -> truncate -> reset on the model's own
+    """assign -> extract -> restore -> truncate -> reset on the model's own
     pool (Mamba, Mamba, a full buffer, Mamba): the named row changes as
     specified, every other row keeps its bytes, and no operation needed to
     know the kind: `conv` and `ssm` carry no `pos` leaf."""
@@ -179,8 +179,8 @@ def test_row_operations_on_a_pool_with_mamba_layers(model):
     wiped = model.slot_release(jax.tree_util.tree_map(jnp.copy, out), 2)
     for final in (False, True):
         keep = jax.tree_util.tree_map(jnp.copy, wiped)
-        got = model.slot_splice(jax.tree_util.tree_map(jnp.copy, wiped),
-                                blk, 2, final)
+        got = model.slot_restore(jax.tree_util.tree_map(jnp.copy, wiped),
+                                 [blk], 2, 1, 8, final)
         others_untouched(got, keep, 2)
         for i in (0, 1, 3):                 # the state: the last block only
             for name in blk[i]:

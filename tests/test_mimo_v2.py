@@ -329,7 +329,7 @@ def _row_bytes(layers, row):
 
 
 def test_row_operations_on_a_pool_of_mixed_layers(model):
-    """assign -> extract -> splice -> truncate -> reset on the model's own
+    """assign -> extract -> restore -> truncate -> reset on the model's own
     pool: full buffers of 4 K/V heads, rings of 8, keys 96 wide and so
     JOINED (a position's keys are one run of 384 or 768), values 64. The
     named row changes as specified, every other row keeps its bytes."""
@@ -348,7 +348,7 @@ def test_row_operations_on_a_pool_of_mixed_layers(model):
         for r in set(range(B)) - {row}:
             assert _row_bytes(new, r) == _row_bytes(old, r), r
 
-    ids = rng.integers(1, 256, 40).tolist()
+    ids = rng.integers(1, 256, 64).tolist()
     src = model.new_cache(1, kv_len=64)
     _, src = model.prefill(src, ids)
     keep = jax.tree_util.tree_map(jnp.copy, pool)
@@ -356,14 +356,14 @@ def test_row_operations_on_a_pool_of_mixed_layers(model):
     others_untouched(out, keep, 1)
     for i, lo in enumerate(out):
         held = np.sort(np.asarray(lo["pos"][1]))
-        want = np.arange(40) if i in (0, 3) else np.arange(24, 40)
+        want = np.arange(64) if i in (0, 3) else np.arange(48, 64)
         np.testing.assert_array_equal(held[held >= 0], want)
 
-    # a block of 32 at 8..39: a full buffer gives all of it, a ring of 16
+    # block 1 of 32 (32..63): a full buffer gives all of it, a ring of 16
     # its last 16 positions, which is all the ring holds
-    blk = model.slot_extract(out, 1, 8, 32)
+    blk = model.slot_extract(out, 1, 32, 32)
     for i, lc in enumerate(blk):
-        want = np.arange(8, 40) if i in (0, 3) else np.arange(24, 40)
+        want = np.arange(32, 64) if i in (0, 3) else np.arange(48, 64)
         np.testing.assert_array_equal(np.asarray(lc["pos"][0]), want)
         src_lc = src["layers"][i]
         at = [int(np.where(np.asarray(src_lc["pos"][0]) == p)[0][0])
@@ -374,17 +374,17 @@ def test_row_operations_on_a_pool_of_mixed_layers(model):
 
     wiped = model.slot_release(jax.tree_util.tree_map(jnp.copy, out), 2)
     keep = jax.tree_util.tree_map(jnp.copy, wiped)
-    got = model.slot_splice(wiped, blk, 2, True)
+    got = model.slot_restore(wiped, [blk], 2, 1, 32, True)
     others_untouched(got, keep, 2)
     for i, lc in enumerate(got):
         pos = np.asarray(lc["pos"][2])
-        want = np.arange(8, 40) if i in (0, 3) else np.arange(24, 40)
+        want = np.arange(32, 64) if i in (0, 3) else np.arange(48, 64)
         np.testing.assert_array_equal(np.sort(pos[pos >= 0]), want)
         if i not in (0, 3):                 # the ring, whole, by pos % 16
             assert _row_bytes([lc], 2) == _row_bytes([out[i]], 1)
 
-    cut = truncate_layers(got, jnp.asarray(30))
-    assert all(int(jnp.max(lc["pos"][2])) == 29 for lc in cut)
+    cut = truncate_layers(got, jnp.asarray(56))
+    assert all(int(jnp.max(lc["pos"][2])) == 55 for lc in cut)
     keep = jax.tree_util.tree_map(jnp.copy, cut)
     clr = model.slot_release(cut, 1)
     others_untouched(clr, keep, 1)

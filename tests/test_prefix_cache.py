@@ -44,7 +44,7 @@ PROMPT = [3 + (i * 7) % 200 for i in range(50)]
 
 
 def test_slot_extract_splice_roundtrip(model):
-    """Blocks copied out of a prefilled row and spliced into a clean row of
+    """Blocks copied out of a prefilled row and restored into a clean row of
     ANOTHER pool reproduce the original prefix bytes exactly, leave the
     rest of the row empty, and touch no neighbor."""
     chunk = 16
@@ -60,8 +60,10 @@ def test_slot_extract_splice_roundtrip(model):
                 np.arange(b * chunk, (b + 1) * chunk))
 
     layers2 = model.new_cache(3, kv_len=64)["layers"]
-    layers2 = model.slot_splice(layers2, blocks[0], 2, final=False)
-    layers2 = model.slot_splice(layers2, blocks[1], 2, final=True)
+    layers2 = model.slot_restore(layers2, blocks[:1], 2, 0, chunk,
+                                 final=False)
+    layers2 = model.slot_restore(layers2, blocks[1:], 2, 1, chunk,
+                                 final=True)
     for lc_src, lc_dst in zip(layers, layers2):
         np.testing.assert_array_equal(np.asarray(lc_src["k"][1, :32]),
                                       np.asarray(lc_dst["k"][2, :32]))
@@ -72,10 +74,12 @@ def test_slot_extract_splice_roundtrip(model):
         assert int(jnp.max(lc_dst["pos"][2, 32:])) == -1
         assert float(jnp.abs(lc_dst["k"][0]).max()) == 0.0   # neighbors
         assert float(jnp.abs(lc_dst["k"][1]).max()) == 0.0
+    with pytest.raises(ValueError, match="aligned"):    # 2 blocks at 1
+        model.slot_restore(layers2, blocks, 2, 1, chunk, final=True)
 
 
 def test_spliced_prefix_continues_bitwise(model):
-    """Prefilling the SUFFIX on top of a spliced prefix yields the same
+    """Prefilling the SUFFIX on top of a restored prefix yields the same
     final logits as prefilling the whole prompt into the row — the
     hit-path numerics are the miss-path numerics."""
     chunk = 16
@@ -86,11 +90,182 @@ def test_spliced_prefix_continues_bitwise(model):
     blocks = [model.slot_extract(miss, 0, b * chunk, chunk)
               for b in range(3)]
     hit = model.new_cache(2, kv_len=64)["layers"]
-    for b, blk in enumerate(blocks):
-        hit = model.slot_splice(hit, blk, 1, final=(b == 2))
+    hit = model.slot_restore(hit, blocks[:2], 1, 0, chunk, final=False)
+    hit = model.slot_restore(hit, blocks[2:], 1, 2, chunk, final=True)
     hit_logits, hit = model.prefill_chunk(hit, 1, PROMPT[48:], 48)
     np.testing.assert_array_equal(np.asarray(hit_logits),
                                   np.asarray(ref_logits))
+
+
+# ---------------------------------------------------------------------------
+# one restore of a chain == block-by-block scatters, for every layer kind
+# ---------------------------------------------------------------------------
+
+# kind -> (family, config overrides, block): every kind of row a chain meets
+_KINDS = {
+    "full": ("llama", {}, 8),
+    "ring_narrower_than_a_block": ("mimo_v2", {}, 32),      # ring of 16
+    "ring_of_two_blocks": ("mistral", {"sliding_window": 16}, 8),
+    "joined_keys": ("mimo_v2", {"sliding_window": 64}, 8),  # k [T, 384]
+    "recurrent_snapshot": ("solar_open2", {}, 8),           # KDA + NoPE full
+    "retention": ("brumby", {}, 8),
+    "ring_no_multiple_of_the_block": ("mistral", {"sliding_window": 24}, 16),
+}
+_ROWS, _SRC, _DST = 3, 1, 2
+_PROGRAMS: dict = {}
+
+
+def _programs(kind):
+    """A TextModel's programs for this kind's tiny config: no weights."""
+    if kind not in _PROGRAMS:
+        family, over, block = _KINDS[kind]
+        m = TextModel.__new__(TextModel)
+        m.cfg, m.dtype, m.mesh, m.tokenizer, m.max_cache_len = (
+            tiny_config(family, **over), jnp.float32, None, None,
+            64 * block)
+        m._build()
+        _PROGRAMS[kind] = m
+    return _PROGRAMS[kind]
+
+
+def _np_pool(model, rng, wiped=_DST):
+    """A pool of _ROWS rows as numpy leaves, every row non-empty garbage
+    but `wiped`, which is empty (pos -1, zeros) as a released row is."""
+    pool = []
+    for lc in model.new_cache(_ROWS)["layers"]:
+        out = {}
+        for name, buf in lc.items():
+            a = (rng.integers(0, 7, buf.shape) if name == "pos"
+                 else rng.standard_normal(buf.shape)).astype(buf.dtype)
+            a[wiped] = -1 if name == "pos" else 0
+            out[name] = a
+        pool.append(out)
+    return pool
+
+
+def _row_at(pool, table, end):
+    """`pool` with row _SRC as a prefill to exactly `end` tokens leaves it:
+    a positional layer holds its last `size` positions at p % size, with
+    the bytes `table` gives that absolute position; a recurrent layer the
+    state `table` gives that boundary."""
+    out = []
+    for lc, tab in zip(pool, table):
+        new = {n: a.copy() for n, a in lc.items()}
+        if "pos" in lc:
+            size = lc["pos"].shape[1]
+            held = np.arange(max(0, end - size), end)
+            new["pos"][_SRC] = -1
+            new["pos"][_SRC, held % size] = held
+            for n in ("k", "v"):
+                new[n][_SRC, held % size] = tab[n][held]
+        else:
+            for n in lc:
+                new[n][_SRC] = tab[n][end]
+        out.append(new)
+    return out
+
+
+def _scatter_chain(pool, chain):
+    """What the engine did before there was a chain restore, in numpy:
+    block by block, every entry to position % size (pos -1: dropped), the
+    last block's recurrent snapshot installed."""
+    out = [{n: a.copy() for n, a in lc.items()} for lc in pool]
+    for b, blk in enumerate(chain):
+        for lo, lb in zip(out, blk):
+            if "pos" not in lo:
+                if b == len(chain) - 1:
+                    for n in lo:
+                        lo[n][_DST] = np.asarray(lb[n][0])
+                continue
+            size = lo["pos"].shape[1]
+            for j, p in enumerate(np.asarray(lb["pos"][0])):
+                if p >= 0:
+                    for n in lo:
+                        lo[n][_DST, p % size] = np.asarray(lb[n][0, j])
+    return out
+
+
+@pytest.mark.parametrize("matched", [1, 2, 3, 32])
+@pytest.mark.parametrize("kind", list(_KINDS))
+def test_chain_restore_equals_block_by_block_scatters(kind, matched):
+    """The pool after ONE restore of a chain (popcount(matched) dispatches:
+    3 = 2 + 1) equals, leaf for leaf and bit for bit, the pool after the
+    block-by-block scatters it replaced: full rows, rings narrower than a
+    block and of two blocks, joined keys, recurrent and retention state,
+    and a ring no run rule covers (which keeps the scatter). Row 2 of 3,
+    the rows beside it untouched; block 0 holds a `pos` of -1, which keeps
+    what the row had."""
+    import jax
+    model = _programs(kind)
+    block = _KINDS[kind][2]
+    rng = np.random.default_rng(54)
+    pool = _np_pool(model, rng)
+    total = matched * block
+    table = [{n: rng.standard_normal((total + 1,) + a.shape[2 if "pos" in lc
+                                                            else 1:]
+                                     ).astype(a.dtype)
+              for n, a in lc.items() if n != "pos"} for lc in pool]
+    prompt = [int(t) for t in rng.integers(1, 200, total + 1)]
+    pc = PrefixCache(model, block, 1 << 30)
+    keys = pc.chain_keys(prompt)
+    assert len(keys) == matched
+    for b in range(matched):
+        at = jax.tree_util.tree_map(jnp.asarray,
+                                    _row_at(pool, table, (b + 1) * block))
+        pc.insert(at, _SRC, prompt, b, keys)
+    first = pc._blocks[keys[0]].layers
+    for lc in first:                       # the drop rule
+        if "pos" in lc:
+            lc["pos"] = lc["pos"].at[0, 3].set(-1)
+    assert pc.match(prompt, keys) == matched
+    got = pc.splice(jax.tree_util.tree_map(jnp.asarray, pool), _DST, keys,
+                    matched)
+    assert (pc.restores, pc.restored) == (bin(matched).count("1"), matched)
+    want = _scatter_chain(pool, [pc._blocks[k].layers for k in keys])
+    for i, (lg, lw) in enumerate(zip(got, want)):
+        assert lg.keys() == lw.keys()
+        for n in lw:
+            assert lg[n].dtype == lw[n].dtype, (i, n)
+            assert np.asarray(lg[n]).tobytes() == lw[n].tobytes(), (i, n)
+
+
+def test_restoring_blocks_that_hold_nothing_leaves_the_pool():
+    """A piece whose every `pos` is -1 (what `slot_extract` gives of an
+    empty row), not final, is a no-op under the drop rule: the pool comes
+    back byte for byte, recurrent state included."""
+    import jax
+    model = _programs("recurrent_snapshot")
+    pool = _np_pool(model, np.random.default_rng(5), wiped=0)
+    layers = jax.tree_util.tree_map(jnp.asarray, pool)
+    blk = model.slot_extract(layers, 0, 0, 8)
+    got = model.slot_restore(layers, [blk] * 4, 0, 0, 8, final=False)
+    for lg, lw in zip(got, pool):
+        for n in lw:
+            assert np.asarray(lg[n]).tobytes() == lw[n].tobytes(), n
+
+
+def test_restore_reads_only_what_the_row_keeps():
+    """From shapes alone: a full buffer keeps the piece whole as one run; a
+    ring its last blocks (one run where piece and ring divide one another),
+    a ring narrower than a block the last block's tail; a ring that is no
+    multiple of the block has no run rule. What is not read is not handed
+    to the program."""
+    from cake_tpu.models.common.cache import restore_reads, restore_runs
+    assert restore_runs(16384, 256, 32) == [list(range(32))]
+    assert restore_runs(512, 256, 32) == [[30, 31]]
+    assert restore_runs(512, 256, 1) == [[0]]
+    assert restore_runs(768, 256, 2) == [[0], [1]]      # may wrap: apart
+    assert restore_runs(128, 256, 32) == [[31]]
+    assert restore_runs(384, 256, 4) is None
+    model = _programs("recurrent_snapshot")
+    pool = model.new_cache(_ROWS)["layers"]
+    blk = model.slot_extract(pool, 0, 0, 8)
+    reads = restore_reads(pool, [blk] * 4, 8)
+    state = [i for i, lc in enumerate(pool) if "pos" not in lc]
+    assert state and all(not reads[b][i] for b in range(3) for i in state)
+    assert all(reads[3][i].keys() == pool[i].keys() for i in state)
+    assert all(reads[b][i] for b in range(4)
+               for i in range(len(pool)) if i not in state)
 
 
 # ---------------------------------------------------------------------------
@@ -189,6 +364,39 @@ def test_engine_prefix_eviction_under_pressure(model):
         r = eng.submit(prompts[0], max_new_tokens=6, sampling=GREEDY)
         assert r.wait(120)
         assert r.result["tokens"] == refs[0]
+    finally:
+        eng.close()
+
+
+def test_engine_three_block_hit_is_two_restore_dispatches(model):
+    """A hit of 3 blocks goes to the device as popcount(3) = 2 restore
+    programs (2 blocks + 1), by the cache's count, the /metrics counters
+    and the admitting step's flight record; its greedy stream is the
+    miss's, bit for bit."""
+    from cake_tpu.obs import (SERVE_PREFIX_RESTORE_BLOCKS,
+                              SERVE_PREFIX_RESTORE_DISPATCHES)
+    d0 = SERVE_PREFIX_RESTORE_DISPATCHES.value()
+    b0 = SERVE_PREFIX_RESTORE_BLOCKS.value()
+    eng = ServeEngine(model, slots=2, max_queue=4, ctx_len=CTX,
+                      prefill_chunk=16, prefix_cache_mb=64)
+    try:
+        miss = eng.submit(PROMPT, max_new_tokens=8, sampling=GREEDY)
+        assert miss.wait(120)
+        assert miss.stats["prefix_hit_tokens"] == 0
+        pc = eng.prefix_cache
+        assert (pc.restores, pc.restored) == (0, 0)
+        hit = eng.submit(PROMPT, max_new_tokens=8, sampling=GREEDY)
+        assert hit.wait(120)
+        assert hit.stats["prefix_hit_tokens"] == 48         # 3 blocks of 16
+        assert hit.result["tokens"] == miss.result["tokens"]
+        assert (pc.restores, pc.restored) == (2, 3)
+        assert SERVE_PREFIX_RESTORE_DISPATCHES.value() - d0 == 2
+        assert SERVE_PREFIX_RESTORE_BLOCKS.value() - b0 == 3
+        occ = eng.health()["prefix_cache"]
+        assert (occ["restores"], occ["restored_blocks"]) == (2, 3)
+        recs = eng.flight.snapshot()
+        assert sum(r["restores"] for r in recs) == 2
+        assert [r["restored"] for r in recs if r["restores"]] == [3]
     finally:
         eng.close()
 

@@ -123,12 +123,12 @@ def test_row_operations_go_by_the_leaves():
     """What a row of state is, is read off the layer's leaves (a `pos`
     leaf: entries by position; none: recurrent state copied whole), never
     off a kind's name: a pool whose recurrent layer carries leaves the code
-    has never heard of goes assign -> extract -> splice (final False, then
-    True) -> truncate -> reset, the named row changes as specified and
+    has never heard of goes assign -> extract -> restore (a chain of one,
+    final False, then True) -> truncate -> reset, the named row changes as specified and
     every other row keeps its bytes. Pure cache ops, no model, no cfg."""
     from cake_tpu.models.common.cache import (
         is_positional, slot_assign_layers, slot_extract_block_layers,
-        slot_reset_layers, slot_splice_block_layers, truncate_layers)
+        slot_reset_layers, slot_restore_chain_layers, truncate_layers)
     B, FULL, RING, H, D = 3, 32, 8, 2, 4
     keys = iter(jax.random.split(jax.random.PRNGKey(0), 32))
 
@@ -194,12 +194,13 @@ def test_row_operations_go_by_the_leaves():
         assert blk[2][name].shape == (1,) + out[2][name].shape[1:]
         assert same_bytes(blk[2][name][0], out[2][name][1])
 
-    # splice into a wiped row 2: the block's entries land by position; the
-    # state is a block-end snapshot, installed by the final block only
+    # restore into a wiped row 2: the block's entries land by position; the
+    # state is a block-end snapshot, installed by the final piece only
     wiped = slot_reset_layers(out, jnp.asarray(2))
     for final in (False, True):
-        got = slot_splice_block_layers(wiped, blk, jnp.asarray(2),
-                                       jnp.asarray(final))
+        got = slot_restore_chain_layers(wiped, [blk], jnp.asarray(2),
+                                        jnp.asarray(1), 4,
+                                        jnp.asarray(final))
         others_untouched(got, wiped, 2)
         for lg, lb in zip(got[:2], blk[:2]):
             size = lg["pos"].shape[1]

@@ -399,9 +399,6 @@ _knob("CAKE_FLIGHT_RECORDER", int, 256, "obs",
       "wedge flag or DOWN classification")
 
 # -- ops / kernels --------------------------------------------------------
-_knob("CAKE_MOE_RAGGED", bool, True, "ops",
-      "ragged-dot MoE expert combine (falls back to the dense combine "
-      "when off)")
 _knob("CAKE_TPU_FLASH", bool, True, "ops",
       "the Pallas attention kernels (prefill and decode) on TPU backends "
       "(CPU always uses the reference path)")

@@ -1,44 +1,40 @@
-"""Mixture-of-Experts routing and dispatch.
+"""Mixture-of-Experts routing and the experts' combine.
 
 Reference semantics (ref: models/qwen3_moe/moe.rs, qwen3_5_moe/moe.rs):
 softmax (or sigmoid) router -> top-k experts -> optional weight
 renormalization -> weighted sum of expert FFNs (+ always-active shared
 expert gated by sigmoid for Qwen3.5 MoE).
 
-TPU formulation: experts are stacked [E, ...] tensors with two dispatch
-strategies sharing one router:
+TPU formulation: experts are stacked [E, ...] tensors and ONE dispatch, the
+dense combine: every held expert runs on every token and a [T, E] combine
+matrix (zero outside the top-k) selects. It streams the banks once and has
+no gather, sort or scatter; above EXPERT_BLOCK_TOKENS it walks the tokens
+in blocks so its [T, E, *] temporaries stay bounded. There is no
+sort-based dispatch over `lax.ragged_dot_general` (FLOPs in proportion to
+k/E on paper): on the chip it took 4.4 x the dense combine's time at 32
+tokens, 7.5 x at 256, 1.5 x at 2048 and 1.05 x at 4096 (PERF.md section
+5), and gave zeros for most rows of two share shapes (section 6, PR 48).
 
-  * dense combine (decode, T < RAGGED_MIN_TOKENS): every expert runs on
-    every token and a [T, E] combine matrix (zero outside top-k) selects —
-    for T of 1-8 this is a batched matvec with zero gather/scatter
-    overhead, cheaper than any routing machinery.
-  * sort-based ragged dispatch (prefill): the T*k (token, expert)
-    assignments are sorted by expert and each expert multiplies only its
-    contiguous slice via `lax.ragged_dot_general` (TPU ragged segment-GEMM
-    over the stored [E, I, H] banks, no transpose/relayout) — FLOPs scale
-    with k/E instead of E/E (ref: qwen3_moe/moe.rs top-8 over 128 experts
-    = 16x prefill FLOP reduction; SURVEY hard-part #4).
-
-Both paths compute identical expert math; tests/test_moe_ragged.py pins
-them against each other and tests/test_hf_parity.py pins the
-router+combine semantics to transformers.
+tests/test_moe_ragged.py pins the combine against a hand-written
+reference and tests/test_hf_parity.py pins the router+combine semantics to
+transformers.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-# below this many tokens the dense combine wins (decode / tiny chunks):
-# the ragged path's sort/gather/scatter overhead only pays off once the
-# per-expert GEMMs are big enough to tile the MXU
-RAGGED_MIN_TOKENS = 32
-
-
-def _ragged_enabled() -> bool:
-    """CAKE_MOE_RAGGED=0 pins every shape to the dense combine (escape
-    hatch if a backend mishandles ragged_dot_general)."""
-    from .. import knobs
-    return knobs.get("CAKE_MOE_RAGGED")
+# Tokens the dense combine takes in one pass; more are walked in blocks of
+# this many. The chip's sweep (TPU v5e, PR 55: moe_ffn alone, bf16, E 128,
+# top 8, H 2048, I 768; ms a call; PERF.md section 5):
+#   T       32    256    512    1024    2048    4096
+#   whole   1.65  1.97   3.71   7.58    16.34   32.65
+#   walked  -     -      -      7.38    14.73   29.43  (blocks of 512)
+# Up to 256 the combine streams the 1.2 GB of banks (75 % of 819 GB/s); at
+# 512 it is bound by the MXU (85 % of 197 TFLOP/s) and a walk holds it
+# there (256: 30.05 at 4096, 1024: 30.45, 2048: 35.04; whole: 77 %) with
+# 0.1 GB of temporaries where the whole pass takes 0.8 GB at 4096.
+EXPERT_BLOCK_TOKENS = 512
 
 
 def router_topk(logits, k: int, norm_topk_prob: bool, gate_act: str = "softmax",
@@ -96,7 +92,7 @@ def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
 
     routed_scale multiplies the selected experts' weights after their
     normalisation (Laguna's `moe_routed_scaling_factor`, DeepSeek-V3's
-    `routed_scaling_factor`), on both dispatch paths.
+    `routed_scaling_factor`).
 
     R > E is one share of an expert-parallel group: the banks hold experts
     first .. first + E - 1 of the R the router scores. Routing and the
@@ -105,21 +101,10 @@ def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
     index is moved to E, which the dense combine drops). Nothing stands in
     for the other shares.
 
-    Static dispatch on T (a compile-time shape): ragged segment-GEMM for
-    prefill-sized batches of a model that holds all its experts, dense
-    combine for decode and, at every T, for a share: most of a share's
-    T x k assignments belong to experts held elsewhere (97 % at 10 held of
-    320), so the ragged path would gather and multiply T x k rows to use a
-    few of them, and on the chip it did worse than waste them: in a
-    256-token chunk at 10 held of 320 (width 1,280) it gave ZERO for most
-    rows with a held expert, in the served program and jitted by itself;
-    at 16 of 256 (width 1,024) jitted by itself but not in that model's
-    served program; at 16 of 256 of width 2,048 and for a whole model
-    nowhere (`lax.ragged_dot_general` alone is right at each of those
-    shapes: the fault depends on what is compiled around it and is not
-    found; PERF.md section 6, PR 48). The dense combine of a share
-    computes E held experts for T tokens, 2.6-3.7 x faster there than
-    the ragged path was.
+    One dispatch at every T, for a share as for a whole model: the dense
+    combine, walked in blocks of EXPERT_BLOCK_TOKENS where T (a
+    compile-time shape) is larger; a program of at most that many tokens
+    is the four einsums and nothing else.
     """
     e = gate_proj.shape[0]
     share = router_weight.shape[0] != e
@@ -135,51 +120,25 @@ def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
             idx = jnp.where(held, idx - first, e)
             weights = jnp.where(held, weights, 0.0)
 
-    with jax.named_scope("cake.ffn.experts"):
-        if (x.shape[0] >= RAGGED_MIN_TOKENS and not share
-                and _ragged_enabled()):
-            return _moe_ragged(x, weights, idx, gate_proj, up_proj,
-                               down_proj, act)
-        w_te = combine_weights(weights, idx, e).astype(x.dtype)
-        g = jnp.einsum("th,eih->tei", x, gate_proj)         # [T, E, I]
-        u = jnp.einsum("th,eih->tei", x, up_proj)
+    def combine(xb, wb):
+        """Every held expert on each token of xb [B, H]; wb [B, E] picks.
+        On the chip XLA keeps one [B, E, I] of temporaries."""
+        g = jnp.einsum("th,eih->tei", xb, gate_proj)        # [B, E, I]
+        u = jnp.einsum("th,eih->tei", xb, up_proj)
         a = _expert_act(g, u, act)
-        y_e = jnp.einsum("tei,ehi->teh", a, down_proj)      # [T, E, H]
-        return jnp.einsum("te,teh->th", w_te, y_e).astype(x.dtype)
+        y_e = jnp.einsum("tei,ehi->teh", a, down_proj)      # [B, E, H]
+        return jnp.einsum("te,teh->th", wb, y_e).astype(xb.dtype)
 
-
-def _ragged_dn(lhs_contract: int, rhs_contract: int):
-    from jax.lax import RaggedDotDimensionNumbers
-    return RaggedDotDimensionNumbers(
-        dot_dimension_numbers=(((lhs_contract,), (rhs_contract,)), ((), ())),
-        lhs_ragged_dimensions=[0], rhs_group_dimensions=[0])
-
-
-def _moe_ragged(x, weights, idx, gate_proj, up_proj, down_proj, act: str):
-    """Sort the T*k assignments by expert; each expert GEMMs only its own
-    contiguous token slice. Exact — group sizes come from the real
-    assignment counts, so nothing is dropped or padded (no capacity
-    factor), and the FLOPs are (k/E) * dense. For a model that holds all
-    its experts (every assignment falls in some group): a share takes the
-    dense combine (moe_ffn)."""
-    from jax.lax import ragged_dot_general
-    t, h = x.shape
-    k = idx.shape[1]
-    e = gate_proj.shape[0]
-
-    flat_expert = idx.reshape(t * k)
-    order = jnp.argsort(flat_expert)                    # stable
-    tok_of = order // k                                 # [T*k]
-    xs = x[tok_of]                                      # [T*k, H]
-    group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
-
-    g = ragged_dot_general(xs, gate_proj, group_sizes, _ragged_dn(1, 2))
-    u = ragged_dot_general(xs, up_proj, group_sizes, _ragged_dn(1, 2))
-    a = _expert_act(g, u, act).astype(x.dtype)          # [T*k, I]
-    y = ragged_dot_general(a, down_proj, group_sizes, _ragged_dn(1, 2))
-    # combine in f32: the dense path's einsum accumulates on the MXU in
-    # f32, so the bf16 scatter-add here must not be the lower-precision one
-    w_flat = weights.reshape(t * k)[order]                 # f32 from router
-    out = jnp.zeros((t, h), jnp.float32)
-    out = out.at[tok_of].add(y.astype(jnp.float32) * w_flat[:, None])
-    return out.astype(x.dtype)
+    with jax.named_scope("cake.ffn.experts"):
+        w_te = combine_weights(weights, idx, e).astype(x.dtype)
+        t, block = x.shape[0], EXPERT_BLOCK_TOKENS
+        if t <= block:
+            return combine(x, w_te)
+        cut = t - t % block
+        out = jax.lax.map(lambda xw: combine(*xw),
+                          (x[:cut].reshape(-1, block, x.shape[1]),
+                           w_te[:cut].reshape(-1, block, e))
+                          ).reshape(cut, -1)
+        if cut < t:
+            out = jnp.concatenate([out, combine(x[cut:], w_te[cut:])])
+        return out

@@ -438,8 +438,8 @@ def test_knobs_typed_get_and_empty_fallback(monkeypatch):
     assert knobs.get("CAKE_SERVE_SLOTS") == 7
     monkeypatch.setenv("CAKE_SERVE_SLOTS", "")
     assert knobs.get("CAKE_SERVE_SLOTS") == 4       # empty == unset
-    monkeypatch.setenv("CAKE_MOE_RAGGED", "0")
-    assert knobs.get("CAKE_MOE_RAGGED") is False
+    monkeypatch.setenv("CAKE_TPU_FLASH", "0")
+    assert knobs.get("CAKE_TPU_FLASH") is False
     monkeypatch.delenv("CAKE_SPEC", raising=False)
     assert knobs.get("CAKE_SPEC") is None
     assert knobs.get_str("CAKE_SPEC") == ""

@@ -44,7 +44,6 @@ from cake_tpu.models import TextModel, init_params, tiny_config
 from cake_tpu.models.common.config import (AttnShape, config_from_hf_dict)
 from cake_tpu.models.common.layers import (decode_kernel_block, make_rope,
                                            moe_forward)
-from cake_tpu.ops.moe import RAGGED_MIN_TOKENS
 from cake_tpu.ops.rope import (RopeScaling, inv_frequencies, rope_tables,
                                yarn_attention_factor)
 from cake_tpu.ops.sampling import SamplingConfig
@@ -359,8 +358,8 @@ def test_prefix_hits_through_a_ring_two_blocks_long_give_the_references_tokens(
 
 # -- the shares add up ----------------------------------------------------------
 
-@pytest.mark.parametrize("tokens", [8, RAGGED_MIN_TOKENS + 8],
-                         ids=["dense_combine", "ragged"])
+@pytest.mark.parametrize("tokens", [8, 64],
+                         ids=["dense_combine", "chunk_width"])
 def test_the_shares_add_up_with_the_shared_expert_counted_once(bench,
                                                                 tokens):
     """32 experts as 4 shares of 8, top 4, scaled by 2.5: the held experts'

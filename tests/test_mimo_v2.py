@@ -42,7 +42,7 @@ from cake_tpu.models.common.config import AttnShape, config_from_hf_dict
 from cake_tpu.models.common.layers import (decode_kernel_block,
                                            flash_kernel_mode, make_rope)
 from cake_tpu.ops import make_attention_mask, multi_head_attention
-from cake_tpu.ops.moe import RAGGED_MIN_TOKENS, moe_ffn, router_topk
+from cake_tpu.ops.moe import moe_ffn, router_topk
 from cake_tpu.ops.sampling import SamplingConfig
 from cake_tpu.serve import ServeEngine
 
@@ -274,8 +274,8 @@ def _share(bank, x, first=0, count=None):
                    select_bias=bank["bias"], first=first)
 
 
-@pytest.mark.parametrize("tokens", [8, RAGGED_MIN_TOKENS + 8],
-                         ids=["dense_combine", "ragged"])
+@pytest.mark.parametrize("tokens", [8, 64],
+                         ids=["dense_combine", "chunk_width"])
 def test_the_shares_of_an_expert_layer_add_up(tokens):
     """8 experts as 2 shares of 4: routing and normalisation over all 8,
     each share the part its own experts give."""
@@ -289,32 +289,29 @@ def test_the_shares_of_an_expert_layer_add_up(tokens):
 
 
 def test_a_share_takes_the_dense_combine_at_every_width():
-    """Most of a share's assignments belong to experts held elsewhere: the
-    ragged path would gather T x k rows to use a few, and on the chip it
-    gave zeros for most held rows of one served share (PERF.md, PR 48). A chunk of a share lowers to no ragged_dot_general; a whole
-    model's still does."""
+    """A chunk of a share, like a whole model's, lowers to no
+    ragged_dot_general and no sort (on the chip that path gave zeros for
+    most held rows of one served share, PERF.md PR 48, and was slower at
+    every width, PR 55), and gives what the whole layer gives of the
+    held experts."""
     bank = _bank(seed=42)
-    x = jax.random.normal(jax.random.PRNGKey(8), (RAGGED_MIN_TOKENS + 8, 32))
-    share = str(jax.make_jaxpr(lambda t: _share(bank, t, 4, 4))(x))
-    whole = str(jax.make_jaxpr(lambda t: _share(bank, t))(x))
-    assert "ragged_dot_general" not in share
-    assert "ragged_dot_general" in whole
-    # and gives what the whole layer's ragged path gives of those experts
-    from cake_tpu.ops import moe
-    logits = jnp.einsum("th,eh->te", x, bank["router"])
-    w, idx = moe.router_topk(logits, 2, True, "sigmoid", bank["bias"])
-    held = (idx >= 4) & (idx < 8)
-    want = moe._moe_ragged(
-        x, jnp.where(held, w, 0.0), jnp.where(held, idx, 0),
-        bank["gate"], bank["up"], bank["down"], "silu")
-    np.testing.assert_allclose(np.asarray(_share(bank, x, 4, 4)),
-                               np.asarray(want), atol=1e-5)
+    x = jax.random.normal(jax.random.PRNGKey(8), (64, 32))
+    for lowered in (jax.make_jaxpr(lambda t: _share(bank, t, 4, 4))(x),
+                    jax.make_jaxpr(lambda t: _share(bank, t))(x)):
+        assert "ragged_dot_general" not in str(lowered)
+        assert " sort[" not in str(lowered)
+    # the whole layer with the experts held elsewhere (0..3) silenced
+    rest = {**bank, "down": bank["down"].at[:4].set(0.0)}
+    got = _share(bank, x, 4, 4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_share(rest, x)),
+                               atol=1e-5)
+    assert float(jnp.abs(got).max()) > 1e-3
 
 
 def test_a_token_whose_experts_all_live_elsewhere_gets_nothing():
     bank = _bank(seed=43)
     bank["bias"] = jnp.where(jnp.arange(8) < 4, -10.0, 10.0)    # 4..7 win
-    for tokens in (4, RAGGED_MIN_TOKENS):
+    for tokens in (4, 32):
         x = jax.random.normal(jax.random.PRNGKey(9), (tokens, 32))
         assert float(jnp.abs(_share(bank, x, 0, 4)).max()) == 0.0
         np.testing.assert_allclose(np.asarray(_share(bank, x, 4, 4)),

@@ -49,9 +49,22 @@ def key_row_shape(a: AttnShape) -> tuple[int, ...]:
     return (a.kv_heads, a.head_dim)
 
 
+def latent_row_width(width: int) -> int:
+    """The lanes a latent layer's entry takes: its width (DeepSeek-V2: 576)
+    rounded up to whole 128-lane tiles (640), the pad zeros. At [rows, T,
+    576] the runtime stores the leaf length-minor (576-minor would pad it
+    to 640 all the same) and every program that reads it 576-minor (the
+    Pallas read, XLA's scores) first copies the WHOLE buffer, 1.0 GB a
+    layer at 32 x 24,576 (the described-v5e compile, PR 56): PR 40's lesson
+    with a leaf that has no heads to join. Padded by hand the leaf lies as
+    the kernel reads it and nothing copies it."""
+    return -(-width // LANES) * LANES
+
+
 def keys_joined(lc: dict) -> bool:
-    """Does this positional layer hold its keys joined (key_row_shape)."""
-    return lc["k"].ndim == 3
+    """Does this positional layer hold its keys joined (key_row_shape); a
+    layer without keys by head (a latent one) does not."""
+    return "k" in lc and lc["k"].ndim == 3
 
 
 def joined_key_widths(layers: list[dict]) -> dict[int, int]:
@@ -65,7 +78,16 @@ def positional_leaves(lead: tuple[int, int], a: AttnShape, dtype) -> dict:
     """The leaves of a layer whose entries are addressed by position, given
     the two axes in front of a position: (batch, size) for a row's buffer
     or ring, (num_blocks, block_tokens) for the paged pool. The one place
-    that says what they are; `pos` is what cache.is_positional reads."""
+    that says what they are; `pos` is what cache.is_positional reads. A
+    latent layer (AttnShape.latent) holds ONE vector a position, the normed
+    latent and the roped shared key part side by side, and no K or V by
+    head, padded with zeros to whole lane tiles (latent_row_width). Everything
+    below goes by the leaves it finds: whatever is not `pos` is an entry's
+    bytes, [.., .., *entry]."""
+    if a.latent:
+        return {"kv": jnp.zeros(lead + (latent_row_width(a.head_dim),),
+                                dtype),
+                "pos": jnp.full(lead, -1, jnp.int32)}
     return {"k": jnp.zeros(lead + key_row_shape(a), dtype),
             "v": jnp.zeros(lead + (a.kv_heads, a.v_head_dim), dtype),
             "pos": jnp.full(lead, -1, jnp.int32)}
@@ -116,10 +138,19 @@ def row_state_bytes(layers: list[dict]) -> int:
 
 
 def update_kv_cache(layer_cache: dict, k_new, v_new, pos, valid_len=None):
-    """Write S new KV entries at absolute positions pos..pos+S-1.
+    """write_entries for a layer of keys and values by head. k_new/v_new:
+    [B, S, Hkv, D]; where the layer holds its keys joined (key_row_shape),
+    k_new is written so."""
+    if keys_joined(layer_cache):
+        k_new = k_new.reshape(k_new.shape[:2] + (-1,))
+    return write_entries(layer_cache, {"k": k_new, "v": v_new}, pos,
+                         valid_len)
 
-    k_new/v_new: [B, S, Hkv, D]; pos: traced scalar int32. Where the layer
-    holds its keys joined (key_row_shape), k_new is written so.
+
+def write_entries(layer_cache: dict, new: dict, pos, valid_len=None):
+    """Write S new entries at absolute positions pos..pos+S-1: `new` holds
+    [B, S, *entry] for every leaf of the layer but `pos`; pos: traced
+    scalar int32.
     Ring semantics: slot = position % size. When S > size only the last
     `size` entries are written (the earlier ones would be overwritten anyway),
     keeping scatter indices unique.
@@ -128,10 +159,8 @@ def update_kv_cache(layer_cache: dict, k_new, v_new, pos, valid_len=None):
     valid_len are padding — their slots are remapped out-of-bounds so the
     scatter drops them (jax default scatter mode drops OOB writes).
     """
-    size = layer_cache["k"].shape[1]
-    s = k_new.shape[1]
-    if keys_joined(layer_cache):
-        k_new = k_new.reshape(k_new.shape[:2] + (-1,))
+    size = layer_cache["pos"].shape[1]
+    s = next(iter(new.values())).shape[1]
     if s > size:
         # Keep the last `size` VALID entries: with bucketed-prefill padding
         # the tail of k_new is garbage, so the slice starts at
@@ -140,8 +169,8 @@ def update_kv_cache(layer_cache: dict, k_new, v_new, pos, valid_len=None):
             start = jnp.asarray(s - size, jnp.int32)
         else:
             start = jnp.clip(valid_len - size, 0, s - size).astype(jnp.int32)
-        k_new = jax.lax.dynamic_slice_in_dim(k_new, start, size, axis=1)
-        v_new = jax.lax.dynamic_slice_in_dim(v_new, start, size, axis=1)
+        new = {n: jax.lax.dynamic_slice_in_dim(a, start, size, axis=1)
+               for n, a in new.items()}
         offset = start
         s = size
     else:
@@ -151,10 +180,11 @@ def update_kv_cache(layer_cache: dict, k_new, v_new, pos, valid_len=None):
     slots = positions % size
     if valid_len is not None:
         slots = jnp.where(idx < valid_len, slots, size)    # OOB -> dropped
-    k = layer_cache["k"].at[:, slots].set(k_new, mode="drop")
-    v = layer_cache["v"].at[:, slots].set(v_new, mode="drop")
-    p = layer_cache["pos"].at[:, slots].set(positions[None, :], mode="drop")
-    return {"k": k, "v": v, "pos": p}
+    out = {n: layer_cache[n].at[:, slots].set(a, mode="drop")
+           for n, a in new.items()}
+    out["pos"] = layer_cache["pos"].at[:, slots].set(positions[None, :],
+                                                     mode="drop")
+    return out
 
 
 def kv_capacity(cfg: ModelConfig, cache: dict,
@@ -169,6 +199,22 @@ def kv_capacity(cfg: ModelConfig, cache: dict,
     return min(caps) if caps else None
 
 
+def _entries_then_pos(lc: dict) -> list[str]:
+    """A positional layer's leaf names, `pos` last: the order the row
+    operations have always written them in (K, V, then positions), so a
+    layer of keys and values lowers to the program it lowered to before
+    the operations went by the leaves."""
+    return sorted(lc, key=lambda name: name == "pos")
+
+
+def _empty(lead: tuple, like, name: str):
+    """An empty buffer of `like`'s entries behind the axes `lead`: zeros,
+    and -1 for the `pos` leaf."""
+    shape = lead + like.shape[2:]
+    return jnp.full(shape, -1, like.dtype) if name == "pos" \
+        else jnp.zeros(shape, like.dtype)
+
+
 def grow_layer_kv(lc: dict, new_size: int) -> dict:
     """Re-home a KV layer cache into a larger buffer.
 
@@ -177,21 +223,15 @@ def grow_layer_kv(lc: dict, new_size: int) -> dict:
     sliding-window rings (remap). Empty slots (pos == -1) are dropped via
     the OOB-scatter trick used by update_kv_cache.
     """
-    old_size = lc["k"].shape[1]
+    b, old_size = lc["pos"].shape
     if new_size <= old_size:
         return lc
-    b = lc["k"].shape[0]
     pos = lc["pos"]                                        # [B, old]
     slots = jnp.where(pos >= 0, pos % new_size, new_size)  # OOB -> dropped
     bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-    k = jnp.zeros((b, new_size) + lc["k"].shape[2:], lc["k"].dtype)
-    v = jnp.zeros((b, new_size) + lc["v"].shape[2:], lc["v"].dtype)
-    p = jnp.full((b, new_size), -1, jnp.int32)
-    return {
-        "k": k.at[bidx, slots].set(lc["k"], mode="drop"),
-        "v": v.at[bidx, slots].set(lc["v"], mode="drop"),
-        "pos": p.at[bidx, slots].set(pos, mode="drop"),
-    }
+    return {name: _empty((b, new_size), lc[name], name)
+            .at[bidx, slots].set(lc[name], mode="drop")
+            for name in _entries_then_pos(lc)}
 
 
 def grow_cache(cfg: ModelConfig, cache: dict, new_len: int,
@@ -253,17 +293,14 @@ def slot_assign_layers(pool_layers: list[dict], src_layers: list[dict],
         if not is_positional(pl):
             out.append({n: pl[n].at[slot].set(sl[n][0]) for n in pl})
             continue
-        size = pl["k"].shape[1]
+        size = pl["pos"].shape[1]
         pos = sl["pos"][0]                                 # [src_size]
         slots = jnp.where(pos >= 0, pos % size, size)      # OOB -> dropped
-        k = jnp.zeros((size,) + pl["k"].shape[2:], pl["k"].dtype)
-        v = jnp.zeros((size,) + pl["v"].shape[2:], pl["v"].dtype)
-        p = jnp.full((size,), -1, jnp.int32)
-        out.append({
-            "k": pl["k"].at[slot].set(k.at[slots].set(sl["k"][0], mode="drop")),
-            "v": pl["v"].at[slot].set(v.at[slots].set(sl["v"][0], mode="drop")),
-            "pos": pl["pos"].at[slot].set(p.at[slots].set(pos, mode="drop")),
-        })
+        empty = {name: _empty((size,), pl[name], name)
+                 for name in _entries_then_pos(pl)}
+        out.append({name: pl[name].at[slot].set(
+            row.at[slots].set(sl[name][0], mode="drop"))
+            for name, row in empty.items()})
     return out
 
 
@@ -274,7 +311,7 @@ def slot_extract_block_layers(pool_layers: list[dict], slot, start,
     insert path. Must be called right after prefill has advanced the row to
     exactly start+width:
 
-      * full/SWA layers: gather the block's K/V/pos through the ring map
+      * positional layers: gather the block's entries through the ring map
         (index = position % buffer). A ring smaller than the block gives
         the block's LAST `size` positions, all it holds of it: the row's
         state at the boundary, as recurrent state is. Spliced back by
@@ -292,12 +329,11 @@ def slot_extract_block_layers(pool_layers: list[dict], slot, start,
         if not is_positional(pl):
             out.append({n: pl[n][slot][None] for n in pl})
             continue
-        size = pl["k"].shape[1]
+        size = pl["pos"].shape[1]
         n = min(width, size)
         idx = (start + width - n + jnp.arange(n, dtype=jnp.int32)) % size
-        out.append({"k": pl["k"][slot][idx][None],
-                    "v": pl["v"][slot][idx][None],
-                    "pos": pl["pos"][slot][idx][None]})
+        out.append({name: pl[name][slot][idx][None]
+                    for name in _entries_then_pos(pl)})
     return out
 
 
@@ -344,12 +380,11 @@ def _write_run(lc: dict, src: dict, slot, start) -> dict:
 def _scatter_block(lc: dict, src: dict, slot) -> dict:
     """One block's entries into row `slot` at position % size, entry by
     entry (pos -1: dropped): what a run cannot express."""
-    size = lc["k"].shape[1]
+    size = lc["pos"].shape[1]
     pos = src["pos"][0]                                    # [width]
     slots = jnp.where(pos >= 0, pos % size, size)          # OOB -> dropped
-    return {"k": lc["k"].at[slot, slots].set(src["k"][0], mode="drop"),
-            "v": lc["v"].at[slot, slots].set(src["v"][0], mode="drop"),
-            "pos": lc["pos"].at[slot, slots].set(pos, mode="drop")}
+    return {name: lc[name].at[slot, slots].set(src[name][0], mode="drop")
+            for name in _entries_then_pos(lc)}
 
 
 def restore_reads(pool_layers: list[dict], chain: list[list[dict]],
@@ -540,14 +575,14 @@ def paged_gather_layer(pl: dict, table_row, frontier) -> dict:
     nblocks, bt = pl["pos"].shape
     mapped = table_row < nblocks                           # [M]
     safe = jnp.where(mapped, table_row, 0)
-    k = pl["k"][safe].reshape((-1,) + pl["k"].shape[2:])
-    v = pl["v"][safe].reshape((-1,) + pl["v"].shape[2:])
+    out = {name: buf[safe].reshape((-1,) + buf.shape[2:])
+           for name, buf in pl.items() if name != "pos"}
     pos = pl["pos"][safe]                                  # [M, bt]
     blk = jnp.arange(table_row.shape[0], dtype=jnp.int32)[:, None]
     own = jnp.logical_and(mapped[:, None], pos // bt == blk)
     own = jnp.logical_and(own, pos < frontier)
-    pos = jnp.where(own, pos, -1).reshape(-1)
-    return {"k": k, "v": v, "pos": pos}
+    out["pos"] = jnp.where(own, pos, -1).reshape(-1)
+    return out
 
 
 def paged_block_of(view_lc: dict, wb, bt: int) -> dict:
@@ -555,12 +590,8 @@ def paged_block_of(view_lc: dict, wb, bt: int) -> dict:
     row view — the write-back unit after a forward advanced the view.
     Returns {k: [bt, H, D], v: [bt, H, D], pos: [bt]}."""
     start = wb * bt
-    return {
-        "k": jax.lax.dynamic_slice_in_dim(view_lc["k"], start, bt, axis=0),
-        "v": jax.lax.dynamic_slice_in_dim(view_lc["v"], start, bt, axis=0),
-        "pos": jax.lax.dynamic_slice_in_dim(view_lc["pos"], start, bt,
-                                            axis=0),
-    }
+    return {name: jax.lax.dynamic_slice_in_dim(a, start, bt, axis=0)
+            for name, a in view_lc.items()}
 
 
 def paged_block_window(view_lcs: list[dict], table_row, first_pos, n_written,
@@ -592,8 +623,7 @@ def paged_block_window(view_lcs: list[dict], table_row, first_pos, n_written,
         return jax.lax.dynamic_slice_in_dim(
             a, shift * bt, nwb * bt, axis=0).reshape((nwb, bt) + a.shape[1:])
 
-    return pids, [{n: cut(lc[n]) for n in ("k", "v", "pos")} if lc else {}
-                  for lc in view_lcs]
+    return pids, [{n: cut(a) for n, a in lc.items()} for lc in view_lcs]
 
 
 def paged_scatter_blocks(pl: dict, pids, blk: dict) -> dict:
@@ -602,9 +632,8 @@ def paged_scatter_blocks(pl: dict, pids, blk: dict) -> dict:
     num_blocks are DROPPED (the masked-slot / beyond-frontier guard);
     live pids are exclusively owned by their writer (refcounted blocks
     are forked before any write), so the scatter is injective."""
-    return {"k": pl["k"].at[pids].set(blk["k"], mode="drop"),
-            "v": pl["v"].at[pids].set(blk["v"], mode="drop"),
-            "pos": pl["pos"].at[pids].set(blk["pos"], mode="drop")}
+    return {name: buf.at[pids].set(blk[name], mode="drop")
+            for name, buf in pl.items()}
 
 
 def cache_reset(cache: dict) -> dict:

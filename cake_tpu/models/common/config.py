@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 from typing import Any
 
@@ -70,9 +71,32 @@ class RetentionConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentAttnConfig:
+    """Multi-head latent attention (DeepSeek-V2's MLA, models/deepseek_v2.py):
+    queries through a low-rank pair, keys and values expanded from ONE
+    normed latent a token beside one roped key part all heads share. A
+    head's queries and keys are `qk_nope_head_dim + qk_rope_head_dim` wide,
+    its values `v_head_dim`; a position of a row holds `row_width` numbers."""
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def row_width(self) -> int:
+        """[normed latent ; roped shared key part]."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """Resolved per-layer behavior, consumed by the generic decoder block."""
-    # 'full' | 'swa' | 'linear' | 'mamba' | 'retention'
+    # 'full' | 'swa' | 'linear' | 'mamba' | 'retention' | 'latent'
     kind: str = "full"
     use_rope: bool = True
     local_rope_table: bool = False  # Gemma3 SWA layers: rope_local_base_freq
@@ -97,6 +121,11 @@ class AttnShape:
     kv_heads: int
     head_dim: int
     v_head_dim: int
+    # a latent layer (LatentAttnConfig) as its ABSORBED read sees it: every
+    # query head against one shared key `head_dim` = row_width wide whose
+    # first `v_head_dim` = kv_lora_rank columns are the value; a position
+    # holds that one vector and no K or V by head (cache.positional_leaves)
+    latent: bool = False
 
     @property
     def size_q(self) -> int:
@@ -183,6 +212,9 @@ class ModelConfig:
     mamba: MambaConfig | None = None
     # power retention in every layer (Brumby): no layer keeps keys or values
     retention: RetentionConfig | None = None
+    # latent attention in every layer (DeepSeek-V2): a position holds one
+    # latent, no keys or values by head
+    latent_attn: LatentAttnConfig | None = None
     # Attention logit scale override (None = head_dim**-0.5); Gemma3 models
     # may set query_pre_attn_scalar.
     attn_scale: float | None = None
@@ -202,6 +234,12 @@ class ModelConfig:
     # the router scores (0 = it holds them all)
     router_experts: int = 0
     expert_first: int = 0
+    # group-limited routing (DeepSeek-V2's `group_limited_greedy`): the
+    # router's experts lie in `moe_n_group` contiguous groups, a token keeps
+    # the `moe_topk_group` groups whose best expert scores highest and takes
+    # its top-k among those alone; 1 / 1 is plain top-k
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
 
     # ---- per-layer resolution ----
 
@@ -213,6 +251,10 @@ class ModelConfig:
         if self.retention is not None:
             return LayerSpec(kind="retention", use_rope=True,
                              norm_style=self.norm_style, recurrent=True)
+        if self.latent_attn is not None:
+            return LayerSpec(kind="latent", use_rope=True,
+                             is_moe=self._layer_is_moe(i),
+                             norm_style=self.norm_style)
         if self.linear_attn is not None and i < len(self.linear_attn.layer_types):
             if self.linear_attn.layer_types[i] == "linear_attention":
                 return LayerSpec(kind="linear", use_rope=False,
@@ -254,6 +296,10 @@ class ModelConfig:
         """Head counts and widths of a layer of this kind."""
         if spec.kind == "swa" and self.swa_attn is not None:
             return self.swa_attn
+        if spec.kind == "latent":
+            la = self.latent_attn
+            return AttnShape(self.num_attention_heads, 1, la.row_width,
+                             la.kv_lora_rank, latent=True)
         return AttnShape(self.num_attention_heads, self.num_key_value_heads,
                          self.head_dim, self.v_head_dim or self.head_dim)
 
@@ -265,6 +311,8 @@ class ModelConfig:
 
     @property
     def rotary_dim(self) -> int:
+        if self.latent_attn is not None:
+            return self.latent_attn.qk_rope_head_dim
         return int(self.head_dim * self.partial_rotary_factor)
 
     @property
@@ -288,11 +336,13 @@ class ModelConfig:
         flight record's say which table each kind read). The delta-rule
         layers have one entry of their own: heads, key and value widths,
         what a decay number covers, the conv kernel and the float32 state
-        a row holds over all of them (the conv tails lie beside it). Each
+        a row holds over all of them (the conv tails lie beside it); the
+        latent layers theirs: ranks, head widths and the bytes a token
+        holds in them (`row_bytes`, bfloat16). Each
         entry is its mixer's `describe` of one layer (mixers.py); layers
         whose entries differ in nothing else add up."""
         from .mixers import mixer_of
-        summed = ("layers", "state_bytes")
+        summed = ("layers", "state_bytes", "row_bytes")
         kinds: dict = {}
         for spec in self.layer_specs():
             describe = mixer_of(self, spec).describe
@@ -815,6 +865,101 @@ def _brumby(d):
                                retention=RetentionConfig(power=power)))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek's `yarn_get_mscale`: 0.1 mscale ln(factor) + 1 past a
+    factor of 1."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _deepseek_v2(d):
+    """DeepSeek-V2 (deepseek-ai DeepSeek-V2, `model_type: deepseek_v2`;
+    arXiv:2405.04434): every layer attends through a latent (MLA,
+    models/deepseek_v2.py): queries through `q_lora_rank`, keys and values
+    expanded from ONE normed latent of `kv_lora_rank` a token beside one
+    roped key part of `qk_rope_head_dim` that all heads share. Rope is YaRN
+    on those dims; cos and sin carry mscale / mscale_all_dim and the
+    softmax scale carries mscale_all_dim's factor SQUARED. The first
+    `first_k_dense_replace` FFNs are dense, every other a softmax router over
+    `n_routed_experts` in `n_group` contiguous groups (`group_limited_greedy`:
+    the `topk_group` groups with the best expert, then the top-k among
+    them), weights as scored (`norm_topk_prob` false) times
+    `routed_scaling_factor`, plus `n_shared_experts` shared ones as one
+    ungated SwiGLU. `expert_parallel: {size, rank}` as `mimo_v2` reads it; a
+    share holds whole groups. What this adapter cannot honour it refuses."""
+    if d.get("q_lora_rank") is None:
+        raise ValueError("deepseek_v2: q_lora_rank null (a full-rank query "
+                         "projection, V2-Lite's) is not implemented")
+    method = d.get("topk_method", "greedy")
+    if method not in ("group_limited_greedy", "greedy"):
+        raise ValueError(f"deepseek_v2: topk_method {method!r} is not "
+                         "implemented")
+    if d.get("scoring_func", "softmax") != "softmax":
+        raise ValueError(f"deepseek_v2: scoring_func {d['scoring_func']!r}")
+    if int(d.get("moe_layer_freq") or 1) != 1:
+        raise ValueError(f"deepseek_v2: moe_layer_freq {d['moe_layer_freq']} "
+                         "is not implemented")
+    if d.get("attention_bias"):
+        raise ValueError("deepseek_v2: attention_bias true is not "
+                         "implemented")
+    if d.get("norm_topk_prob") and float(
+            d.get("routed_scaling_factor") or 1.0) != 1.0:
+        raise ValueError("deepseek_v2: norm_topk_prob true beside a "
+                         "routed_scaling_factor (which the published gate "
+                         "then leaves out) is not implemented")
+    rs = d.get("rope_scaling") or None
+    scaling, m = None, 1.0
+    if rs is not None:
+        if (rs.get("type") or rs.get("rope_type")) != "yarn":
+            raise ValueError(f"deepseek_v2: rope_scaling {rs} (only yarn is "
+                             "implemented)")
+        factor = float(rs["factor"])
+        all_dim = float(rs.get("mscale_all_dim") or 0.0)
+        scaling = dataclasses.replace(
+            _rope_scaling(rs), rope_type="yarn",
+            attention_factor=yarn_mscale(factor, float(rs.get("mscale", 1)))
+            / yarn_mscale(factor, all_dim))
+        m = yarn_mscale(factor, all_dim) if all_dim else 1.0
+    la = LatentAttnConfig(
+        q_lora_rank=int(d["q_lora_rank"]),
+        kv_lora_rank=int(d["kv_lora_rank"]),
+        qk_nope_head_dim=int(d["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(d["qk_rope_head_dim"]),
+        v_head_dim=int(d["v_head_dim"]))
+    held = int(d["n_routed_experts"])
+    ep = d.get("expert_parallel") or {"size": 1, "rank": 0}
+    size, rank = int(ep["size"]), int(ep["rank"])
+    if not 0 <= rank < size:
+        raise ValueError(f"deepseek_v2: expert_parallel rank {rank} of "
+                         f"{size}")
+    grouped = method == "group_limited_greedy"
+    n_group = int(d.get("n_group") or 1) if grouped else 1
+    topk_group = int(d.get("topk_group") or 1) if grouped else 1
+    width = held * size
+    if n_group > 1 and (width % n_group or held % (width // n_group)
+                        or not 1 <= topk_group <= n_group):
+        raise ValueError(
+            f"deepseek_v2: {held} held of {width} experts in {n_group} "
+            f"groups (top {topk_group}): a share holds whole groups")
+    inter = int(d["moe_intermediate_size"])
+    return ModelConfig(**_base(
+        d, "deepseek_v2", head_dim=la.qk_head_dim, v_head_dim=la.v_head_dim,
+        rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+        rope_scaling=scaling, latent_attn=la,
+        attn_scale=la.qk_head_dim ** -0.5 * m * m,
+        num_experts=held, router_experts=width, expert_first=held * rank,
+        num_experts_per_tok=int(d["num_experts_per_tok"]),
+        moe_intermediate_size=inter,
+        norm_topk_prob=bool(d.get("norm_topk_prob", False)),
+        moe_routed_scale=float(d.get("routed_scaling_factor") or 1.0),
+        moe_n_group=n_group, moe_topk_group=topk_group,
+        shared_expert_intermediate_size=(
+            int(d.get("n_shared_experts") or 0) * inter or None),
+        shared_expert_gated=False,
+        mlp_only_layers=tuple(range(int(d.get("first_k_dense_replace")
+                                        or 0))),
+    ))
+
+
 # HF architectures string -> adapter (ref: cake/mod.rs arch_str_to_text_model_arch;
 # unknown strings fall back to llama, matching the reference)
 ARCH_ADAPTERS = {
@@ -838,6 +983,7 @@ ARCH_ADAPTERS = {
     "MiMoV2ForCausalLM": _mimo_v2,
     "MiMoV2FlashForCausalLM": _mimo_v2,
     "LagunaForCausalLM": _laguna,
+    "DeepseekV2ForCausalLM": _deepseek_v2,
 }
 
 # short family names (CLI --arch overrides, tests)
@@ -849,7 +995,7 @@ FAMILY_ADAPTERS = {
     "mistral": _mistral, "gemma3": _gemma3, "falcon3": _falcon3,
     "olmo2": _olmo2, "exaone4": _exaone4, "jamba": _jamba,
     "mimo_v2": _mimo_v2, "laguna": _laguna, "solar_open2": _solar_open2,
-    "brumby": _brumby,
+    "brumby": _brumby, "deepseek_v2": _deepseek_v2,
 }
 
 
@@ -957,6 +1103,26 @@ def tiny_config(arch: str = "llama", **over) -> ModelConfig:
         d.update(head_dim=8, rms_norm_eps=1e-6, rope_theta=1000000,
                  sliding_window=None, use_sliding_window=False,
                  rope_scaling=None, attention_bias=False)
+    if arch == "deepseek_v2":
+        # latent attention in every layer: 4 heads of 16 + 8 (nope + rope)
+        # with values of 16, through ranks 24 and 32, so a row is 40 wide;
+        # YaRN with the factor's square in the scale; a dense first layer,
+        # then a share of 4 of 8 experts (2 of the router's 4 groups, of
+        # which a token keeps 2) beside two shared ones
+        d.update(num_hidden_layers=3, rms_norm_eps=1e-6, q_lora_rank=24,
+                 kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                 v_head_dim=16, attention_bias=False,
+                 rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                               "beta_slow": 1, "mscale": 0.707,
+                               "mscale_all_dim": 0.707,
+                               "original_max_position_embeddings": 16},
+                 first_k_dense_replace=1, moe_layer_freq=1,
+                 n_routed_experts=4, n_shared_experts=2,
+                 num_experts_per_tok=3, moe_intermediate_size=32,
+                 n_group=4, topk_group=2,
+                 topk_method="group_limited_greedy", scoring_func="softmax",
+                 norm_topk_prob=False, routed_scaling_factor=16,
+                 expert_parallel={"size": 2, "rank": 0})
     d.update(over)
     if arch in ("qwen3_5", "qwen3_5_moe"):
         d["text_config"] = dict(d)

@@ -470,9 +470,10 @@ def moe_forward(cfg: ModelConfig, p: dict, x):
         # selected experts streamed from storage — EAGER only (the host
         # round-trip on the routing indices cannot trace under jit)
         from .expert_provider import moe_ffn_offloaded
-        if cfg.moe_routed_scale != 1.0:
+        if cfg.moe_routed_scale != 1.0 or cfg.moe_n_group > 1:
             raise NotImplementedError(
-                "--expert-offload with a routed scaling factor")
+                "--expert-offload with a routed scaling factor or "
+                "group-limited routing")
         y = moe_ffn_offloaded(flat, p["gate"]["weight"], p["_provider"],
                               cfg.num_experts_per_tok, cfg.norm_topk_prob,
                               cfg.moe_gate_act, act)
@@ -483,7 +484,8 @@ def moe_forward(cfg: ModelConfig, p: dict, x):
                     cfg.moe_gate_act, act,
                     select_bias=p["gate"].get("e_score_correction_bias"),
                     first=cfg.expert_first,
-                    routed_scale=cfg.moe_routed_scale)
+                    routed_scale=cfg.moe_routed_scale,
+                    n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group)
     if "shared_expert" in p:
         # always-active shared expert: sigmoid-gated where the checkpoint
         # has a `shared_expert_gate` (ref: qwen3_5_moe/moe.rs), else added

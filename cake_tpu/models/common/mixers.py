@@ -7,7 +7,7 @@ so a new layer kind is a row in its own module and an arm here.
 
 Rows: attention, `full` and `swa` (layers.MIXER), the gated delta net
 (qwen3_5.MIXER), Kimi Delta Attention (kda.MIXER), Mamba (jamba.MIXER), power
-retention (brumby.MIXER).
+retention (brumby.MIXER), latent attention (deepseek_v2.MIXER).
 """
 from __future__ import annotations
 
@@ -48,6 +48,8 @@ def mixer_of(cfg: ModelConfig, spec: LayerSpec) -> Mixer:
         from ..jamba import MIXER
     elif spec.kind == "retention":
         from ..brumby import MIXER
+    elif spec.kind == "latent":
+        from ..deepseek_v2 import MIXER
     elif spec.kind == "linear" and cfg.linear_attn.kda:
         from ..kda import MIXER
     elif spec.kind == "linear":

@@ -281,8 +281,9 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
     ("cake.attn", "one layer's attention incl. its KV write "
                   "(layers._attn enters the scopes of the layer's row in "
                   "models/common/mixers.py: layers.attention_forward, "
-                  "a delta-rule mixer under cake.attn.linear, or power "
-                  "retention under cake.attn.retention)"),
+                  "a delta-rule mixer under cake.attn.linear, power "
+                  "retention under cake.attn.retention, or latent "
+                  "attention under cake.attn.latent)"),
     ("cake.attn.window", "a window layer's masked attention over its "
                          "ring and the chunk: scores, the softmax (with "
                          "its sink column where the layer has one) and "
@@ -326,6 +327,26 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
                                  "state, the division and the decayed "
                                  "update (brumby.retention_chunk; a "
                                  "decode step is its C = 1)"),
+    ("cake.attn.latent", "a latent-attention layer's whole mixer, inside "
+                         "cake.attn (the row deepseek_v2.MIXER: "
+                         "latent_forward); the scatter of the step's rows "
+                         "lies in it, outside its parts"),
+    ("cake.attn.latent.proj", "its projections: q_a and q_b, kv_a, both "
+                              "low-rank norms, rope on the queries' and the "
+                              "shared key's rope part, and the output's"),
+    ("cake.attn.latent.absorb", "W_uk folded into the queries (a step that "
+                                "reads a cache scores against the latents "
+                                "themselves) and W_uv out of the weighted "
+                                "latents"),
+    ("cake.attn.latent.expand", "keys and values of every head from the "
+                                "latents (kv_b_proj): the stateless pass, "
+                                "which attends in the expanded form"),
+    ("cake.attn.latent.read", "scores, softmax and weighted sum over the "
+                              "cache: the call of the Pallas kernel "
+                              "cake_latent_decode_attention (a row walked "
+                              "to its frontier, a decode step and a chunk "
+                              "alike) or XLA's masked ops over the whole "
+                              "buffer (ops.latent_attention)"),
     ("cake.ssm", "one Mamba layer's state-space mixer, in cake.attn's "
                  "place for that layer kind (the row jamba.MIXER: "
                  "mamba_forward)"),
@@ -338,8 +359,9 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
                       "gated output"),
     ("cake.ffn", "one layer's feed-forward (layers._ffn: mlp_forward or "
                  "moe_forward)"),
-    ("cake.ffn.route", "MoE router: logits and top-k over every expert "
-                       "of the model (ops.moe.moe_ffn)"),
+    ("cake.ffn.route", "MoE router: logits, the group mask where routing "
+                       "is group-limited, and top-k over every expert of "
+                       "the model (ops.moe.moe_ffn)"),
     ("cake.ffn.experts", "MoE expert GEMMs and combine, of the experts "
                          "this process holds (ops.moe.moe_ffn)"),
     ("cake.ffn.shared", "the shared expert every token passes, with its "

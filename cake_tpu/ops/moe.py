@@ -37,8 +37,22 @@ import jax.numpy as jnp
 EXPERT_BLOCK_TOKENS = 512
 
 
+def group_limited(probs, n_group: int, topk_group: int):
+    """probs [T, E] with every expert outside a token's `topk_group` best
+    groups set to 0: the E experts lie in `n_group` contiguous groups, a
+    group's score is its best expert's (DeepSeek-V2's
+    `group_limited_greedy`; the paper's device-limited routing, a group a
+    device)."""
+    t, e = probs.shape
+    best = jnp.max(probs.reshape(t, n_group, e // n_group), axis=-1)
+    _, kept = jax.lax.top_k(best, topk_group)                  # [T, M]
+    keep = jnp.any(kept[:, :, None]
+                   == jnp.arange(n_group, dtype=kept.dtype), axis=1)
+    return jnp.where(jnp.repeat(keep, e // n_group, axis=1), probs, 0.0)
+
+
 def router_topk(logits, k: int, norm_topk_prob: bool, gate_act: str = "softmax",
-                select_bias=None):
+                select_bias=None, n_group: int = 1, topk_group: int = 1):
     """logits: [T, E] -> (weights [T, k] f32, idx [T, k] int32).
 
     softmax gate: probabilities over experts then top-k (Qwen3 MoE).
@@ -47,6 +61,8 @@ def router_topk(logits, k: int, norm_topk_prob: bool, gate_act: str = "softmax",
     the scores alone (DeepSeek-V3 `noaux_tc`, MiMo-V2's
     `e_score_correction_bias`), normalised over the selected as
     DeepseekV3TopkRouter does (+ 1e-20).
+    n_group > 1: the top-k is taken within each token's `topk_group` best
+    groups (group_limited); 1 is plain top-k, and traces nothing more.
     """
     lf = logits.astype(jnp.float32)
     if gate_act == "softmax":
@@ -55,6 +71,8 @@ def router_topk(logits, k: int, norm_topk_prob: bool, gate_act: str = "softmax",
         probs = jax.nn.sigmoid(lf)
     else:
         raise ValueError(f"unknown gate activation {gate_act}")
+    if n_group > 1:
+        probs = group_limited(probs, n_group, topk_group)
     if select_bias is not None:
         _, idx = jax.lax.top_k(probs + select_bias.astype(jnp.float32), k)
         weights = jnp.take_along_axis(probs, idx, axis=-1)
@@ -86,13 +104,15 @@ def _expert_act(g, u, act: str):
 
 def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
             norm_topk_prob: bool, gate_act: str = "softmax", act: str = "silu",
-            select_bias=None, first: int = 0, routed_scale: float = 1.0):
+            select_bias=None, first: int = 0, routed_scale: float = 1.0,
+            n_group: int = 1, topk_group: int = 1):
     """x: [T, H]; router_weight: [R, H]; gate/up_proj: [E, I, H];
     down_proj: [E, H, I]. Returns [T, H] in x.dtype.
 
     routed_scale multiplies the selected experts' weights after their
     normalisation (Laguna's `moe_routed_scaling_factor`, DeepSeek-V3's
-    `routed_scaling_factor`).
+    `routed_scaling_factor`); n_group / topk_group limit the top-k to a
+    token's best groups of the R experts (router_topk).
 
     R > E is one share of an expert-parallel group: the banks hold experts
     first .. first + E - 1 of the R the router scores. Routing and the
@@ -112,7 +132,7 @@ def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
         logits = jnp.einsum("th,eh->te", x, router_weight,
                             preferred_element_type=jnp.float32)
         weights, idx = router_topk(logits, k, norm_topk_prob, gate_act,
-                                   select_bias)
+                                   select_bias, n_group, topk_group)
         if routed_scale != 1.0:
             weights = weights * routed_scale
         if share:

@@ -46,8 +46,12 @@ def param_pspec(path: tuple[str, ...], mesh: Mesh) -> P:
         if name == "down_proj":
             return P(ep, None, tp)
     if name == "weight" or name == "bias":
-        if parent in ("q_proj", "k_proj", "v_proj", "g_proj"):
-            # (g_proj: Laguna's gate a query head, head-parallel like q)
+        if parent in ("q_proj", "k_proj", "v_proj", "g_proj", "q_b_proj",
+                      "kv_b_proj"):
+            # (g_proj: Laguna's gate a query head, head-parallel like q;
+            # q_b_proj, kv_b_proj: a latent layer's expansions, rows by
+            # head; its q_a, kv_a and their norms replicate, as its rows
+            # of latents do: every device reads the one shared key)
             return P(tp, None) if name == "weight" else P(tp)
         if parent == "o_proj":
             return P(None, tp)
@@ -190,7 +194,8 @@ def check_tp_divisibility(cfg: ModelConfig, mesh: Mesh):
             "row's state over tp is written or measured")
     for a in {cfg.attn_shape(s) for s in cfg.layer_specs()
               if not s.recurrent}:
-        if a.kv_heads % tp or a.heads % tp:
+        # (a latent layer's one shared key is held whole by every device)
+        if (not a.latent and a.kv_heads % tp) or a.heads % tp:
             raise ValueError(
                 f"tp={tp} must divide heads {a.heads}/{a.kv_heads}")
     if cfg.intermediate_size % tp:

@@ -66,8 +66,8 @@ class PagedKV:
         # device bytes one physical block costs across every pooled layer
         # (the prefix cache's capacity accounting unit)
         self.block_bytes = sum(
-            int(np.prod(pl[n].shape[1:])) * pl[n].dtype.itemsize
-            for pl in self.pool if pl for n in ("k", "v", "pos"))
+            int(np.prod(leaf.shape[1:])) * leaf.dtype.itemsize
+            for pl in self.pool for leaf in pl.values())
         self.tables = jnp.full((slots, self.max_blocks), self.NULL,
                                jnp.int32)
         # eviction hook: () -> int, blocks actually freed (wired to the
@@ -225,7 +225,7 @@ class PagedKV:
         for pl in self.pool:
             if not pl:
                 continue
-            for name in ("k", "v", "pos"):
+            for name in pl:
                 pl[name] = pl[name].at[dst].set(pl[name][src])
 
     def release_slot(self, slot: int) -> None:
@@ -265,8 +265,7 @@ class PagedKV:
             # lint: disable=host-sync — preemption IS the planned swap to host;
             # this whole method is the slow path that frees HBM
             blob["layers"].append(
-                {n: np.asarray(pl[n][ids]) for n in ("k", "v", "pos")}
-                if pl else {})
+                {n: np.asarray(leaf[ids]) for n, leaf in pl.items()})
         if self.has_rows:
             # lint: disable=host-sync — row state rides the same swap blob
             blob["rows"] = jax.tree_util.tree_map(
@@ -296,7 +295,7 @@ class PagedKV:
         for pl, saved in zip(self.pool, blob["layers"]):
             if not pl:
                 continue
-            for name in ("k", "v", "pos"):
+            for name in pl:
                 pl[name] = pl[name].at[dst].set(jnp.asarray(saved[name]))
         if self.has_rows and blob["rows"] is not None:
             self.rows = self.model.row_install(

@@ -52,6 +52,8 @@ def params_to_hf_tensors(cfg: ModelConfig, params: dict,
     ckpt = {}
     if cfg.mamba is not None:
         from ..models.jamba import CHECKPOINT_NAMES as ckpt
+    elif cfg.latent_attn is not None:
+        from ..models.deepseek_v2 import CHECKPOINT_NAMES as ckpt
     ffn = ckpt.get("mlp", "mlp")
 
     if "embed_tokens" in params:
@@ -88,8 +90,9 @@ def params_to_hf_tensors(cfg: ModelConfig, params: dict,
                     out[f"{lp}.mlp.experts.{e}.{proj}.weight"] = \
                         _np(mlp["experts"][proj][e])
             if "shared_expert" in mlp:
+                shared = ckpt.get("shared_expert", "shared_expert")
                 for proj in ("gate_proj", "up_proj", "down_proj"):
-                    out[f"{lp}.mlp.shared_expert.{proj}.weight"] = \
+                    out[f"{lp}.mlp.{shared}.{proj}.weight"] = \
                         _np(mlp["shared_expert"][proj]["weight"])
                 if "shared_expert_gate" in mlp:
                     out[f"{lp}.mlp.shared_expert_gate.weight"] = \

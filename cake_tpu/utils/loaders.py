@@ -94,6 +94,9 @@ class ParamLoader:
         if self.cfg.mamba is not None:
             from ..models.jamba import CHECKPOINT_NAMES
             return CHECKPOINT_NAMES.get(name, name)
+        if self.cfg.latent_attn is not None:
+            from ..models.deepseek_v2 import CHECKPOINT_NAMES
+            return CHECKPOINT_NAMES.get(name, name)
         return name
 
     def _norm(self, name: str):
@@ -177,7 +180,8 @@ class ParamLoader:
             p["experts"] = {proj: self._dev(np.stack(ws))
                             for proj, ws in stacked.items()}
         if cfg.shared_expert_intermediate_size:
-            p["shared_expert"] = self._mlp(f"{mp}.shared_expert")
+            p["shared_expert"] = self._mlp(
+                f"{mp}.{self._ckpt('shared_expert')}")
             if cfg.shared_expert_gated:
                 p["shared_expert_gate"] = {"weight": self._dev(
                     self._get(f"{mp}.shared_expert_gate.weight"))}

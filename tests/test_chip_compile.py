@@ -405,3 +405,56 @@ def test_retention_programs_take_the_state_in_place(one_chip, monkeypatch):
         assert mem.alias_size_in_bytes >= pool_bytes, name
         assert mem.temp_size_in_bytes < limit, (name,
                                                 mem.temp_size_in_bytes)
+
+
+def test_latent_programs_take_the_pool_in_place(one_chip, monkeypatch):
+    """DeepSeek-V2's widths (128 heads, a row of 576 numbers in 640 lanes),
+    layer 0 and one sparse layer with a group of 4 of the router's 32
+    experts, an eighth of the vocabulary, 32 rows x 24,576 (the cell's
+    pool): `_decode_slots` and `_prefill_slot` hold ONE call of
+    `cake_latent_decode_attention` a layer (a decode step and a chunk take
+    the same kernel), both donate the pool through, and no copy or
+    transpose of a buffer as large as one row's latents stands in them or
+    in the prefix restore. The temporaries are named: a decode step's are
+    the routed experts' [32, E, I] activations and the logits; a
+    256-token chunk's are its absorbed queries and weighted latents,
+    [256, 128, 640] and [256, 128, 512] bfloat16 (42 + 34 MB), the
+    layer-0 FFN's [256, 12288] pair and the routed experts' [256, E, I]."""
+    from cake_tpu.models import deepseek_v2
+    from cake_tpu.models.common.config import config_from_hf_dict
+    monkeypatch.setattr(deepseek_v2, "kernel_enabled", lambda: True)
+    rows, ctx = 32, 24576
+    cfg = config_from_hf_dict(dict(
+        model_type="deepseek_v2", vocab_size=12800, hidden_size=5120,
+        intermediate_size=12288, num_hidden_layers=2,
+        num_attention_heads=128, num_key_value_heads=128,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rms_norm_eps=1e-6,
+        rope_theta=10000, max_position_embeddings=163840,
+        rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 0.707,
+                      "mscale_all_dim": 0.707,
+                      "original_max_position_embeddings": 4096},
+        first_k_dense_replace=1, moe_layer_freq=1, n_routed_experts=4,
+        n_shared_experts=2, num_experts_per_tok=6,
+        moe_intermediate_size=1536, n_group=8, topk_group=3,
+        topk_method="group_limited_greedy", scoring_func="softmax",
+        norm_topk_prob=False, routed_scaling_factor=16,
+        expert_parallel={"size": 8, "rank": 0}, tie_word_embeddings=False))
+    for name, layers, compiled in _pool_programs(cfg, rows, ctx, one_chip):
+        assert [sorted(lc) for lc in layers] == [["kv", "pos"]] * 2
+        assert layers[0]["kv"].shape == (rows, ctx, 640)
+        text, mem = compiled.as_text(), compiled.memory_analysis()
+        kernels = text.count('custom_call_target="tpu_custom_call"')
+        assert kernels == (0 if name == "restore32x256" else 2), name
+        # (a chunk's absorbed queries are laid out once a layer for the
+        # kernel's [tokens x heads, 640] blocks: 42 MB, no part of the pool)
+        big = [c for c in _converted(text, ctx * 640)
+               if c[2] != "1,256,128,640"]
+        assert not big, (name, big)
+        pool_bytes = sum(a.size * a.dtype.itemsize
+                         for a in jax.tree_util.tree_leaves(layers))
+        assert mem.alias_size_in_bytes >= pool_bytes, name
+        print(name, "temporaries", mem.temp_size_in_bytes)
+        assert mem.temp_size_in_bytes < 320 * 2 ** 20, (
+            name, mem.temp_size_in_bytes)

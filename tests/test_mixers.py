@@ -23,7 +23,7 @@ from cake_tpu.utils.safetensors_io import TensorStorage, save_safetensors
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 PACKAGE = ROOT / "cake_tpu"
-FAMILY_MODULES = ("jamba", "kda", "qwen3_5", "brumby")
+FAMILY_MODULES = ("jamba", "kda", "qwen3_5", "brumby", "deepseek_v2")
 
 
 def _rows(cfg):
@@ -76,11 +76,13 @@ SCOPES = [
     ("solar_open2", 1, ("cake.attn", "cake.attn.linear")),
     ("jamba", 0, ("cake.ssm",)),
     ("brumby", 0, ("cake.attn", "cake.attn.retention")),
+    ("deepseek_v2", 0, ("cake.attn", "cake.attn.latent")),
 ]
 
 
 @pytest.mark.parametrize("arch,layer,scopes", SCOPES,
-                         ids=["attention", "gdn", "kda", "mamba", "retention"])
+                         ids=["attention", "gdn", "kda", "mamba", "retention",
+                              "latent"])
 def test_a_rows_forward_runs_under_exactly_its_scopes(arch, layer, scopes):
     cfg = tiny_config(arch)
     spec = cfg.layer_spec(layer)
@@ -106,7 +108,8 @@ def test_a_rows_forward_runs_under_exactly_its_scopes(arch, layer, scopes):
              if part.startswith("cake.")}
     assert all(part.startswith(scopes[-1] + ".") for part in inner), inner
     second = {s[2] for s in stacks if len(s) > 2}
-    for nested in ("cake.attn.linear", "cake.attn.retention"):
+    for nested in ("cake.attn.linear", "cake.attn.retention",
+                   "cake.attn.latent"):
         assert (nested in second) == (scopes[-1] == nested)
 
 
@@ -120,8 +123,8 @@ def test_no_file_but_the_table_compares_a_layer_kind():
     `spec.kind` with a recurrent kind's name, tests a params tree for a
     mixer's key or indexes it by one."""
     kind = re.compile(
-        r"""\.kind\s*(==|!=|in|not\s+in)\s*[(\[]?\s*["'](linear|mamba|retention)["']"""
-        r"""|["'](linear|mamba|retention)["']\s*(==|!=)\s*\w+\.kind""")
+        r"""\.kind\s*(==|!=|in|not\s+in)\s*[(\[]?\s*["'](linear|mamba|retention|latent)["']"""
+        r"""|["'](linear|mamba|retention|latent)["']\s*(==|!=)\s*\w+\.kind""")
     key = re.compile(
         r"""["'](mamba|linear_attn)["']\s+(not\s+)?in\s+\w"""
         r"""|\w\[["'](mamba|linear_attn)["']\]""")
@@ -187,6 +190,18 @@ KINDS = {
          "kv_heads": 8, "key_dim": 128, "state_width": 8256,
          "padded_width": 8320, "rotary_dim": 128, "rope_theta": 1000000.0,
          "state_bytes": 274759680}],
+    "deepseek-v2-l5-ep8": [
+        {"kind": "latent", "layers": 5, "heads": 128, "q_lora_rank": 1536,
+         "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+         "qk_rope_head_dim": 64, "v_head_dim": 128, "row_width": 576,
+         "row_lanes": 640, "rotary_dim": 64, "rope_theta": 10000.0,
+         "rope_scaling": "yarn", "row_bytes": 5760}],
+    "tiny:deepseek_v2": [
+        {"kind": "latent", "layers": 3, "heads": 4, "q_lora_rank": 24,
+         "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+         "v_head_dim": 16, "row_width": 40, "row_lanes": 128,
+         "rotary_dim": 8, "rope_theta": 10000.0, "rope_scaling": "yarn",
+         "row_bytes": 240}],
     "tiny:brumby": [
         {"kind": "retention", "layers": 4, "power": 2, "heads": 4,
          "kv_heads": 2, "key_dim": 8, "state_width": 36,
@@ -211,18 +226,19 @@ def test_attention_kinds_reports_what_it_reported(name):
     assert json.dumps(got) == json.dumps(KINDS[name])
 
 
-@pytest.mark.parametrize("arch", ["qwen3", "mimo_v2"])
+@pytest.mark.parametrize("arch", ["qwen3", "mimo_v2", "deepseek_v2"])
 def test_a_pools_blocks_and_a_rows_buffer_hold_the_same_leaves(arch):
     """cache.positional_leaves is the one spelling: the paged pool's blocks
     and a row's buffer agree past the two axes in front of a position
-    (MiMo-V2: keys joined in both)."""
+    (MiMo-V2: keys joined in both; DeepSeek-V2: one latent a position)."""
     cfg = tiny_config(arch)
     pool, rows = cache_mod.init_paged_layers(cfg, 6, 8, 2, 32, jnp.float32)
     for i, spec in enumerate(cfg.layer_specs()):
         row = cache_mod.init_layer_cache(cfg, spec, 2, 32, jnp.float32)
         blocks = pool[i] or rows[i]
         assert bool(pool[i]) == cache_mod.layer_is_pooled(spec)
-        assert blocks.keys() == row.keys() == {"k", "v", "pos"}
+        assert blocks.keys() == row.keys() == (
+            {"kv", "pos"} if arch == "deepseek_v2" else {"k", "v", "pos"})
         for name in row:
             assert blocks[name].shape[2:] == row[name].shape[2:]
             assert blocks[name].dtype == row[name].dtype

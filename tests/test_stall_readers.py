@@ -107,8 +107,9 @@ def test_the_manifest_holds_with_the_new_entries(manifest):
     assert manifest.validate(ROOT) == []
     m = manifest.load(ROOT)
     cells = [w["name"] for w in m["workloads"]]
-    # (PRs 44 and 48 appended two metrics each behind them, PR 53 three)
-    assert [e["name"] for e in m["per_layer"][-11:-7]] == list(NEW)
+    # (PRs 44 and 48 appended two metrics each behind them, PRs 53 and 56
+    # three each)
+    assert [e["name"] for e in m["per_layer"][-14:-10]] == list(NEW)
     by = {e["name"]: e for e in m["per_layer"]}
     assert by[NEW[0]]["workloads"] == by[NEW[1]]["workloads"] == cells
     assert (by[NEW[0]]["moves"], by[NEW[1]]["moves"]) == ("out_tok_s",
@@ -122,7 +123,7 @@ def test_the_manifest_holds_with_the_new_entries(manifest):
         sorted(tails["itl_p95_ms"] + tails["itl_p99_ms"])
     assert set(cells) - set(tails["itl_p95_ms"] + tails["itl_p99_ms"]) == \
         {"laguna-s-2.1-l9-ep16.codeassist", "solar-open2-l8-ep32.agent",
-         "brumby-14b-l8.continuation"}
+         "brumby-14b-l8.continuation", "deepseek-v2-l5-ep8.longdoc"}
     for inside, outside in zip(INSIDE, ("api.handoff_p95_ms",
                                         "api.handoff_p95_ms.tail99")):
         assert by[inside]["workloads"] == by[outside]["workloads"]
@@ -134,14 +135,17 @@ def test_the_manifest_holds_with_the_new_entries(manifest):
 def test_benchmark_json_only_gained_entries_at_the_end():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         m = json.load(f)
-    assert len(m["workloads"]) == 8 and len(m["configs"]) == 7
+    assert len(m["workloads"]) == 9 and len(m["configs"]) == 8
     names = [e["name"] for e in m["per_layer"]]
     assert len(names) == len(set(names))
-    assert names.index("engine.prefix_hit_share") == len(names) - 12
-    assert names[-7:] == ["programs.decode.attn_full_ms",
+    assert names.index("engine.prefix_hit_share") == len(names) - 15
+    assert names[-10:] == ["programs.decode.attn_full_ms",
                           "programs.decode.ffn_shared_ms",
                           "programs.decode.attn_linear_ms",
                           "programs.prefill.attn_linear_ms",
                           "programs.decode.attn_retention_ms",
                           "programs.prefill.attn_retention_ms",
-                          "retention_state_roofline"]
+                          "retention_state_roofline",
+                          "programs.decode.attn_latent_ms",
+                          "programs.prefill.attn_latent_ms",
+                          "latent_read_roofline"]

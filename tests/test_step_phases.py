@@ -35,10 +35,11 @@ WINDOW = {"cake.attn.window"}
 # scopes of mechanisms these families lack: a gate on the attention output,
 # a shared expert (tests/test_laguna.py finds them in a model that has them),
 # a delta-rule mixer (tests/test_solar_open2.py), power retention
-# (tests/test_brumby.py)
+# (tests/test_brumby.py), latent attention (tests/test_deepseek_v2.py)
 GATED = {"cake.attn.gate", "cake.ffn.shared"} | {
     s for s in SCOPES if s.startswith(("cake.attn.linear",
-                                       "cake.attn.retention"))}
+                                       "cake.attn.retention",
+                                       "cake.attn.latent"))}
 # a scope in an op's name: `/cake.attn/`, or `vmap(cake.attn)/` where the
 # batching transform wraps the outermost one
 SCOPE_RE = r"[/(](cake\.[a-z_.]+)(?=[/)])"
@@ -125,7 +126,7 @@ def test_catalogs_name_the_phases_and_scopes():
     assert set(LEAVES) | {"serve.step", "api.sse_write"} <= spans
     # the gap and the loop's lag are counted always and drawn by no span
     assert not {"trace.sync", "serve.between", "api.loop_tick"} & spans
-    assert len(set(SCOPES)) == len(SCOPES) == 28
+    assert len(set(SCOPES)) == len(SCOPES) == 33
 
 
 # -- the engine: phases of one iteration ------------------------------------

@@ -204,7 +204,7 @@ async def flight(request: web.Request) -> web.Response:
             pass
     return web.json_response({
         "capacity": recorder.capacity,
-        "static": recorder.static,
+        "static": recorder.static_view(),
         # the iterations that stood still, kept beside the ring (as in
         # /health's engine block)
         "stalls": recorder.stalls(),

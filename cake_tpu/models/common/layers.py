@@ -14,6 +14,7 @@ import contextlib
 import jax
 import jax.numpy as jnp
 
+from ...obs import PROCESS
 from ...ops import (apply_rope, embedding, gelu_mul, linear,
                     make_attention_mask, multi_head_attention, rms_norm,
                     rope_tables, silu_mul)
@@ -166,6 +167,7 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
     return params
 
 
+@PROCESS.phase("boot.rope")
 def make_rope(cfg: ModelConfig) -> dict:
     if not any(spec.use_rope for spec in cfg.layer_specs()):
         return {}       # no layer rotates (Jamba): no table of max_seq_len
@@ -184,6 +186,7 @@ def make_rope(cfg: ModelConfig) -> dict:
     return rope
 
 
+@PROCESS.phase("boot.rope")
 def cut_rope(rope: dict, rows: int) -> dict:
     """Every table's first `rows` rows: the positions a model whose caches
     end at `rows` can reach. A window layer's positions run past its ring

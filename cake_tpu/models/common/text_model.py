@@ -35,8 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...obs import (DECODE_TOKEN_SECONDS, GENERATED_TOKENS, RECORDER,
-                    TTFT_SECONDS, now)
+from ...obs import (DECODE_TOKEN_SECONDS, GENERATED_TOKENS, PROCESS,
+                    RECORDER, TTFT_SECONDS, now)
 from ...ops.sampling import (SamplingConfig, config_has_filters,
                              push_recent_token, sample, sample_traced,
                              spec_accept)
@@ -257,9 +257,13 @@ class TextModel:
     # host fetch overlaps the next chunk's device compute
     STREAM_DEPTH = 2
 
+    @PROCESS.phase("boot.model")
     def __init__(self, cfg: ModelConfig, params: dict | None = None,
                  tokenizer=None, dtype=jnp.bfloat16, seed: int = 42,
                  max_cache_len: int | None = None, mesh=None):
+        # a process that never enabled the compile cache still witnesses
+        # its program builds from here on
+        PROCESS.install()
         self.cfg = cfg
         self.dtype = dtype
         self.tokenizer = tokenizer

@@ -202,12 +202,31 @@ SERVE_STEP_STALL_SECONDS = REGISTRY.counter(
 
 COMPILES = REGISTRY.counter(
     "cake_compiles_total",
-    "XLA backend compilations this process ran (persistent-cache "
-    "retrievals too), from jax.monitoring")
+    "Programs whose backend stage this process ran (jax.monitoring, from "
+    "before its first program on): XLA compilations (`cache` = miss, or "
+    "off where the persistent cache was not asked) and executables "
+    "retrieved from the persistent cache and loaded (hit)",
+    labelnames=("cache",))          # hit | miss | off
 
 COMPILE_SECONDS = REGISTRY.counter(
     "cake_compile_seconds_total",
-    "Seconds spent in those compilations")
+    "Seconds spent in those backend stages",
+    labelnames=("cache",))
+
+PROGRAM_BUILD_SECONDS = REGISTRY.counter(
+    "cake_program_build_seconds_total",
+    "Seconds of Python this process spent building programs before their "
+    "backend stage: `trace` (the function to a jaxpr; a program's own "
+    "trace, the functions traced inside it are in its time) and `lower` "
+    "(the jaxpr to an MLIR module). Paid with a warm cache too",
+    labelnames=("stage",))          # trace | lower
+
+SERVE_INBAND_COMPILES = REGISTRY.counter(
+    "cake_serve_inband_compiles_total",
+    "Programs the serve engine built while serving a request: a prefill "
+    "chunk's bucket, a restore piece's length or a join first met after "
+    "the warm-up (the request's timeline has a `compile` event)",
+    labelnames=("program",))
 
 GC_PAUSE_SECONDS = REGISTRY.histogram(
     "cake_gc_pause_seconds",
@@ -221,8 +240,12 @@ API_LOOP_LAG_SECONDS = REGISTRY.histogram(
 
 # process-global, like RECORDER and REGISTRY: one process has one collector,
 # one compiler and (serving) one event loop
-PROCESS = ProcessWatch(COMPILES, COMPILE_SECONDS, GC_PAUSE_SECONDS,
-                       API_LOOP_LAG_SECONDS)
+PROCESS = ProcessWatch(COMPILES, COMPILE_SECONDS, PROGRAM_BUILD_SECONDS,
+                       GC_PAUSE_SECONDS, API_LOOP_LAG_SECONDS,
+                       recorder=RECORDER)
+# switched on, the recorder is handed the process's start-up (and every
+# program built since) with its past stamps
+RECORDER.source = PROCESS.hand_over
 
 SERVE_QUEUE_TIMEOUTS = REGISTRY.counter(
     "cake_serve_queue_timeouts_total",
@@ -561,6 +584,7 @@ __all__ = [
     "FLEET_SCALE_MANAGED_REPLICAS",
     "Series", "SeriesBank",
     "SERVE_STEP_STALLS", "SERVE_STEP_STALL_SECONDS", "COMPILES",
-    "COMPILE_SECONDS", "GC_PAUSE_SECONDS", "API_LOOP_LAG_SECONDS",
+    "COMPILE_SECONDS", "PROGRAM_BUILD_SECONDS", "SERVE_INBAND_COMPILES",
+    "GC_PAUSE_SECONDS", "API_LOOP_LAG_SECONDS",
     "PROCESS", "ProcessWatch", "LoopTick",
 ]

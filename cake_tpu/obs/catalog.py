@@ -125,6 +125,53 @@ the time between the two stamps (the GIL and the loop's queue). With the
 recorder off the scheduler takes no stamp and the writer pays one
 attribute check a token.
 
+## Start-up: what the process did before it was ready
+
+How long a replica takes to become useful is accounted for from inside,
+always on (`cake_tpu/obs/process.py`; installed by
+`utils.compile_cache.enable_compile_cache`, the CLI's and the benchmark's
+first call into the package, and again, idempotently, by `TextModel` and
+`serve.maybe_engine`). `jax.monitoring` hands a listener every stage of
+every program JAX builds, by name: `trace` (the Python function to a
+jaxpr), `lower` (the jaxpr to an MLIR module) and `backend` (XLA's
+compile, or on a persistent-cache hit the executable's retrieval and
+load). One record each is kept in a ring of 4,096: `{t_end, seconds,
+stage, program, cache, phase}`. Functions traced inside a program
+(`matmul`, an inner `jit`) emit trace events of their own within the outer
+one's time; a trace is kept only where the next `lower` event of its
+thread names it. `cache` (backend records) is `hit`, `miss`, or `off`
+where the persistent cache was not asked. Beside the builds, a dozen
+boot phases a process (two clock reads and an append each): `boot.model`
+(`TextModel.__init__`), `boot.rope` (`layers.make_rope` and `cut_rope`),
+`boot.engine` (`ServeEngine.__init__`), `boot.engine.pool` (the pool's
+and the prefix cache's allocation, waited for), and the process's own age
+when the watch was installed (the interpreter's start and the imports
+before it; from `/proc/self/stat`). A build carries the phase open on its thread.
+
+Read it in `/health`'s engine block and `GET /api/v1/flight`'s `static`
+(so in every flight dump), under `boot`: `{age_at_install_s, phases:
+[{name, t_s, dur_s}], programs: [{program, builds, trace_s, lower_s,
+backend_s, hits, misses}] (the 32 costliest), builds, hits, misses,
+trace_s, lower_s, cache_load_s, compile_s, handed: {spans, seconds} (what
+the span recorder was handed, and what that cost)}`: `trace_s + lower_s` is
+Python's part, paid with a warm cache too; `cache_load_s` the backend time
+of hits; `compile_s` that of misses. In `/metrics`:
+`cake_compiles_total{cache}`, `cake_compile_seconds_total{cache}`,
+`cake_program_build_seconds_total{stage}`. As spans: when the recorder is
+switched on it is handed every build (`process.compile`) and every phase
+(`cat="boot"`, nested by `parent`) since the process began, with their
+past stamps, and each later one as it happens; they are held beside the
+ring, outlive its turnover and `clear()`, and come first in an export, so
+a Chrome trace begins with the start-up.
+
+**Which step recompiled.** The engine reads the watch's count of backend
+stages around a dispatch that may build a program (a prefill chunk with
+its block captures and join, a prefix hit's restore): one integer compare
+when nothing was built. Where it grew, the request's timeline takes a
+`compile` event (`program`, `ms`, `cache`, `step`) and
+`cake_serve_inband_compiles_total{program}` counts it; a stall record's
+`compiled` names the programs behind its `compiles`.
+
 ## Engine flight recorder
 
 The serve engine appends one record per scheduler iteration (`seq` = the
@@ -173,7 +220,8 @@ layers whose keys lie joined in the pool by joined width, which says that
 the rule of `cache.key_row_shape` engaged, and `attention_kinds`, the
 attention layers by kind with their heads, K/V heads, window, rotary width
 and rope scaling, and `rope_rows` / `rope_bytes`, what the rope tables the
-model holds come to; a dump carries them all) —
+model holds come to, and `boot`, the process's account of its start-up
+read at that instant; a dump carries them all) —
 `cake top` and the profiling workflow inspect a live engine without
 waiting for a failure.
 
@@ -186,10 +234,10 @@ most), taken once a turn — is a STALL. Its flight record's `stall_ms`
 holds the excess (0 on every other record) and a copy is kept (the 64
 newest, plus a count and the total ms of all), joined by what else the
 process saw in that stretch, from the process's own witnesses
-(`cake_tpu/obs/process.py`, installed by `serve.maybe_engine`): `gc_ms`
+(`cake_tpu/obs/process.py`): `gc_ms`
 (collector pauses of 1 ms and more, `gc.callbacks`), `compiles` /
-`compile_ms` (`jax.monitoring`'s backend compiles, cache retrievals
-too), `loop_lag_ms` (the largest lag of the event loop's tick), and
+`compile_ms` / `compiled` (`jax.monitoring`'s backend compiles, cache
+retrievals too, and the programs' names), `loop_lag_ms` (the largest lag of the event loop's tick), and
 `phase`: the largest entry of `ph`, or `between` for the gap. Read it
 so: `fetch` large and nothing else — the device or the runtime; a host
 phase or `between` with `gc_ms` — the collector; with `compiles` — a

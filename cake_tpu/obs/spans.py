@@ -69,6 +69,18 @@ def _now_us() -> int:
 _next_id = itertools.count(1).__next__
 
 
+def new_span_id() -> int:
+    """An id for a span whose children are recorded before it is: hand it
+    to them as `parent`, and to `add` / `hold` as `sid` when the span
+    itself is recorded."""
+    return _next_id()
+
+
+# held spans past this go to the ring like any other (a process that keeps
+# compiling while it is traced must not grow without bound)
+HELD_MAX = 8192
+
+
 class SpanRecorder:
     """Bounded ring buffer of Chrome-trace complete events."""
 
@@ -76,6 +88,12 @@ class SpanRecorder:
         if max_events is None:
             max_events = knobs.get("CAKE_TRACE_EVENTS")
         self._events: deque = deque(maxlen=max_events)
+        # spans that outlive the ring's turnover and `clear()`: the
+        # process's start-up (obs/process.py), first in every export
+        self._held: list = []
+        # called by enable() with nothing: whoever has spans from before
+        # the recorder was on (the process's watch) hands them over then
+        self.source = None
         self._lock = threading.Lock()
         self._export_seq = 0
         self._open = threading.local()      # per-thread stack: _stack()
@@ -85,11 +103,15 @@ class SpanRecorder:
 
     def enable(self):
         self.enabled = True
+        if self.source is not None:
+            self.source()
 
     def disable(self):
         self.enabled = False
 
     def clear(self):
+        """Empty the ring. The held spans stay: they are the start-up's,
+        recorded once."""
         with self._lock:
             self._events.clear()
 
@@ -99,18 +121,34 @@ class SpanRecorder:
     # -- recording -----------------------------------------------------------
 
     def add(self, name: str, ts_us: int, dur_us: int, cat: str = "phase",
-            parent: int | None = None, **args) -> int | None:
+            parent: int | None = None, sid: int | None = None,
+            **args) -> int | None:
         """Record a complete event from externally measured timestamps
         (microseconds on the perf_counter clock). `parent` is the id of
         the span that caused it; left out, it is the span open on this
-        thread, if any. Returns the new span's id (None when disabled)."""
+        thread, if any. `sid` is an id `new_span_id` gave beforehand (its
+        children are recorded already). Returns the new span's id (None
+        when disabled)."""
         if not self.enabled:
             return None
         if parent is None:
             stack = self._stack()
             parent = stack[-1] if stack else None
-        return self._record(name, ts_us, dur_us, cat, _next_id(), parent,
-                            args)
+        return self._record(name, ts_us, dur_us, cat, sid or _next_id(),
+                            parent, args)
+
+    def hold(self, name: str, ts_us: int, dur_us: int, cat: str,
+             tid: int, parent: int | None = None, sid: int | None = None,
+             **args) -> int | None:
+        """`add`, for a span that must outlive the ring's turnover and
+        `clear()`: kept beside the ring, first in `events()` and in every
+        export. It is recorded from past stamps, maybe by another thread
+        than made it: `tid` is its thread, its parent and its request id
+        (in `args`) are those given, never the caller's."""
+        if not self.enabled:
+            return None
+        return self._record(name, ts_us, dur_us, cat, sid or _next_id(),
+                            parent, args, held_tid=tid)
 
     def _stack(self) -> list:
         """Ids of the spans open on the calling thread, innermost last."""
@@ -120,32 +158,41 @@ class SpanRecorder:
             self._open.stack = []
             return self._open.stack
 
-    def _record(self, name, ts_us, dur_us, cat, sid, parent, args) -> int:
+    def _record(self, name, ts_us, dur_us, cat, sid, parent, args,
+                held_tid: int | None = None) -> int:
         args["id"] = sid
         if parent is not None:
             args["parent"] = parent
-        rid = _request_id.get()
+        held = held_tid is not None
+        rid = None if held else _request_id.get()
         if rid is not None:
             args.setdefault("request_id", rid)
         ev = {"name": name, "cat": cat, "ph": "X", "ts": int(ts_us),
               "dur": max(int(dur_us), 0), "pid": os.getpid(),
-              "tid": threading.get_ident(), "args": args}
+              "tid": held_tid if held else threading.get_ident(),
+              "args": args}
         with self._lock:
-            self._events.append(ev)
+            if held and len(self._held) < HELD_MAX:
+                self._held.append(ev)
+            else:
+                self._events.append(ev)
         return sid
 
     @contextlib.contextmanager
-    def span(self, name: str, cat: str = "phase", **args):
+    def span(self, name: str, cat: str = "phase",
+             parent: int | None = None, **args):
         """Record the wrapped block as one complete event; yields the
         span's id (None when disabled). Spans opened inside the block on
-        this thread get it as their parent. Disabled-path cost is a single
+        this thread get it as their parent; its own is `parent`, or left
+        out the span open on this thread. Disabled-path cost is a single
         attribute check."""
         if not self.enabled:
             yield None
             return
         stack = self._stack()
         sid = _next_id()
-        parent = stack[-1] if stack else None
+        if parent is None:
+            parent = stack[-1] if stack else None
         stack.append(sid)
         t0 = _now_us()
         try:
@@ -173,8 +220,10 @@ class SpanRecorder:
     # -- export --------------------------------------------------------------
 
     def events(self) -> list[dict]:
+        """The held spans (the process's start-up), then the ring."""
         with self._lock:
-            return [dict(e) for e in self._events]
+            return [dict(e) for e in self._held] \
+                + [dict(e) for e in self._events]
 
     def to_chrome_trace(self) -> dict:
         return {"traceEvents": self.events(), "displayTimeUnit": "ms"}
@@ -244,6 +293,20 @@ SPAN_CATALOG: tuple[tuple[str, str], ...] = (
     ("serve.prefill_finish", "serve engine: prefix-cache block capture "
                              "and, on the last chunk, first-token sample "
                              "and slot activation (args: final)"),
+    ("serve.capture_blocks", "serve engine, inside serve.prefill_finish: "
+                             "the prefix-cache capture of the blocks the "
+                             "chunk completed (args: step, blocks)"),
+    ("prefix.insert", "prefix cache, inside serve.capture_blocks: one "
+                      "block's insertion (args: block, known = 1 when its "
+                      "key was held already and nothing was extracted); "
+                      "the contiguous cache's: the paged one pins pool "
+                      "blocks by reference and records none"),
+    ("prefix.extract", "prefix cache, inside prefix.insert: the "
+                       "`slot_extract` dispatch that copies the block out "
+                       "of the pool row (the host's part of it; the "
+                       "program runs behind the chunk)"),
+    ("prefix.evict", "prefix cache, inside prefix.insert: least-recently "
+                     "used blocks dropped to make room"),
     ("serve.fetch", "serve engine: the one device->host fetch of packed "
                     "ids, those of the step iteration `of_step` "
                     "dispatched; the scheduler is blocked on the device "
@@ -262,6 +325,27 @@ SPAN_CATALOG: tuple[tuple[str, str], ...] = (
     ("deser", "worker wire phase: payload deserialization (PhaseTimer)"),
     ("fwd", "worker wire phase: stage forward compute (PhaseTimer)"),
     ("ser", "worker wire phase: result serialization (PhaseTimer)"),
+    ("process.compile", "process (obs/process.py), cat `process`, held "
+                        "beside the ring: one stage of one program's "
+                        "build, from jax.monitoring (args: program, stage "
+                        "= trace | lower | backend, cache = hit | miss | "
+                        "off on the backend stage, phase = the boot phase "
+                        "open on its thread, request_id when a request's "
+                        "dispatch built it in-band); handed over with its "
+                        "past stamps when the recorder is switched on"),
+    ("boot.model", "process, cat `boot`, held: TextModel.__init__ whole "
+                   "(params placed, rope tables cut, the jitted programs "
+                   "defined; nothing compiles here)"),
+    ("boot.rope", "process, cat `boot`, held: layers.make_rope building "
+                  "the rope tables on the host and placing them, and "
+                  "layers.cut_rope cutting them (a child of boot.model "
+                  "there; make_rope is called by whoever builds the "
+                  "params, before the model's constructor)"),
+    ("boot.engine", "process, cat `boot`, held: ServeEngine.__init__ "
+                    "whole"),
+    ("boot.engine.pool", "process, cat `boot`, held, inside boot.engine: "
+                         "the slot pool's and the prefix cache's "
+                         "allocation, waited for"),
     ("api.sse_write", "api: one streamed token through the SSE writer, "
                       "from the instant the event loop handed it over to "
                       "the instant `resp.write` returned: `json.dumps` and "

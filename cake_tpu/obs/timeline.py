@@ -76,6 +76,10 @@ EVENT_KINDS: dict[str, str] = {
                      "`pos0`, `tokens`, `attn`: `flash-fresh` / `flash-append` "
                      "when the chunk's program holds the Pallas kernel, "
                      "`masked` for the XLA path)",
+    "compile": "a dispatch for this request built a program in-band: a "
+               "chunk bucket, a restore piece's length or a join first "
+               "met while serving (`program`, `ms` of its backend stage, "
+               "`cache` = hit | miss | off, `step`)",
     "prefill_done": "prompt fully prefilled; first token sampled "
                     "(`chunks`, `hit_tokens`)",
     "first_token": "first token fetched to the host (client-visible "

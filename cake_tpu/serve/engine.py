@@ -74,13 +74,14 @@ import numpy as np
 
 from .. import knobs
 from ..obs import (PROCESS, RECORDER, SERVE_BATCH_OCCUPANCY,
-                   SERVE_E2E_SECONDS,
+                   SERVE_E2E_SECONDS, SERVE_INBAND_COMPILES,
                    SERVE_ITL_SECONDS, SERVE_PREFILL_CHUNKS, SERVE_POISONED,
                    SERVE_PREEMPTIONS, SERVE_QOS_E2E_SECONDS,
                    SERVE_QOS_TTFT_SECONDS, SERVE_QUEUE_TIMEOUTS,
                    SERVE_QUEUE_WAIT_SECONDS, SERVE_REQUEST_TIMEOUTS,
                    SERVE_SLOT_JOINS, SERVE_SLOTS_BUSY, SERVE_TTFT_SECONDS,
                    TIMELINES, now, set_request_id)
+from ..obs.spans import new_span_id
 from ..models.common.cache import joined_key_widths, row_state_bytes
 from ..ops.sampling import SamplingConfig, config_has_filters
 from ..spec import resolve_drafter
@@ -336,6 +337,7 @@ class _TokenStream:
 class ServeEngine:
     """Owns the slot pool, the admission queue, and the scheduler thread."""
 
+    @PROCESS.phase("boot.engine")
     def __init__(self, model, slots: int = 4, max_queue: int = 64,
                  ctx_len: int | None = None, seed: int = 0,
                  prefill_chunk: int | None = None,
@@ -438,8 +440,13 @@ class ServeEngine:
         self._seed = seed
         self._vocab = model.cfg.vocab_size
         self._base_rng = jax.random.PRNGKey(seed)
-        self._init_device_state()
-        self.prefix_cache = self._build_prefix_cache()
+        with PROCESS.phase("boot.engine.pool"):
+            self._init_device_state()
+            self.prefix_cache = self._build_prefix_cache()
+            # the zeros are born on the device asynchronously: waited for,
+            # so the phase is the allocation's and not its dispatch's
+            jax.block_until_ready(self._layers if self.paged is None else
+                                  (self.paged.pool, self.paged.rows))
         self._reqs: list[ServeRequest | None] = [None] * slots
         self._prefills: list[_Prefill] = []   # in-flight chunked admissions
         self._rr = 0                          # round-robin cursor over them
@@ -465,6 +472,7 @@ class ServeEngine:
         self._fetch_s = 0.0
         self._chunk_kind = None     # `chunk` | `last_chunk` when it ran one
         self._chunk_end = None      # recorder on: when its dispatch ended
+        self._finish_id = None      # and serve.prefill_finish's span id
         # the last stamp of the previous iteration when it left work behind
         # (busy rows, a queue, a step in flight), else None: the next
         # iteration's `gap_ms` is its first stamp minus this
@@ -760,6 +768,9 @@ class ServeEngine:
         # iterations by kind, the event loop's lag
         h["stalls"] = self.flight.stalls()
         h.update(self.flight.totals())
+        # what the process did to become useful, and every program it has
+        # built since, by name and stage (obs/process.py)
+        h["boot"] = PROCESS.boot()
         lag = PROCESS.loop_lag()
         if lag is not None:
             h["loop_lag_ms"] = lag
@@ -1220,7 +1231,7 @@ class ServeEngine:
                 of_step, t_fan = prev.step, self._land(prev, t_land)
                 t_mid = t_fan       # the record's split of fetch | fanout
             t_chunk = now()
-            self._chunk_end = self._chunk_kind = None
+            self._chunk_end = self._chunk_kind = self._finish_id = None
             # 5. ...then advance the chosen admission by one chunk, AFTER
             # the lagged fetch: the step fetched has ended, so the chunk
             # queued behind it has begun and at most one other waits
@@ -1368,6 +1379,7 @@ class ServeEngine:
             landed(t_land, t_chunk)
         if self._chunk_end is not None:
             add("serve.prefill_finish", int(self._chunk_end * 1e6), t_late,
+                sid=self._finish_id,
                 final=self._chunk_kind == "last_chunk")
         if of_step == step:
             landed(t_late, t_end)
@@ -1415,8 +1427,11 @@ class ServeEngine:
                 pf.keys = self.prefix_cache.chain_keys(pf.ids)
                 matched = self.prefix_cache.match(pf.ids, pf.keys)
                 if matched:
+                    built = PROCESS.backend_count
                     self._layers = self.prefix_cache.splice(
                         self._layers, pf.slot, pf.keys, matched)
+                    if PROCESS.backend_count != built:
+                        self._note_compiles(pf.req, built)
                     pf.pos = matched * self.chunk
                     pf.next_block = matched
                     pf.hit_tokens = pf.pos
@@ -1437,6 +1452,9 @@ class ServeEngine:
         decode. Returns True while the job remains in flight."""
         take = min(self.chunk, pf.n - pf.pos)
         set_request_id(pf.req.id)
+        # the programs this may build in-band: the chunk's bucket, a block's
+        # extract, the join (one integer compare when none was)
+        built = PROCESS.backend_count
         try:
             with RECORDER.span("serve.prefill_chunk", cat="serve",
                                tokens=take, pos0=pf.pos, slot=pf.slot,
@@ -1456,8 +1474,10 @@ class ServeEngine:
             pf.chunks += 1
             self._chunk_kind = "last_chunk" if pf.pos >= pf.n else "chunk"
             if RECORDER.enabled:
-                # where serve.prefill_finish begins (_emit_phases)
+                # where serve.prefill_finish begins (_emit_phases), and the
+                # id it will have: its children are recorded before it is
                 self._chunk_end = now()
+                self._finish_id = new_span_id()
             TIMELINES.event(pf.req.id, "prefill_chunk", step=self._step_id,
                             pos0=pf.pos - take, tokens=take,
                             attn=self.model.last_chunk_attn)
@@ -1479,7 +1499,19 @@ class ServeEngine:
                 raise
             return False
         finally:
+            if PROCESS.backend_count != built:
+                self._note_compiles(pf.req, built)
             set_request_id(None)
+
+    def _note_compiles(self, req: ServeRequest, built: int):
+        """A dispatch for `req` built programs in-band (PROCESS's count of
+        backend stages grew past `built` around it): its timeline says
+        which, and which step paid."""
+        for rec in PROCESS.built_since(built):
+            SERVE_INBAND_COMPILES.inc(program=rec["program"])
+            TIMELINES.event(req.id, "compile", program=rec["program"],
+                            ms=round(rec["seconds"] * 1e3, 3),
+                            cache=rec["cache"], step=self._step_id)
 
     def _complete_prefill(self, pf: _Prefill, logits):
         """Final chunk done: sample the first token (device-resident — it
@@ -1522,11 +1554,19 @@ class ServeEngine:
         drift between them. Returns the next uncaptured block index."""
         if self.prefix_cache is None:
             return next_block
-        while (next_block + 1) * self.chunk <= min(pos, n - 1):
-            self.prefix_cache.insert(self._layers, slot, ids, next_block,
-                                     keys)
-            next_block += 1
-        return next_block
+        last = min(pos, n - 1) // self.chunk    # blocks complete by now
+        if next_block >= last:
+            return next_block
+        # recorder on: a child of the step's serve.prefill_finish, with the
+        # cache's own spans beneath it (a replay's capture, outside a
+        # step's chunk, hangs under whatever span is open)
+        with RECORDER.span("serve.capture_blocks", cat="serve",
+                           parent=self._finish_id, step=self._step_id,
+                           blocks=last - next_block):
+            for block in range(next_block, last):
+                self.prefix_cache.insert(self._layers, slot, ids, block,
+                                         keys)
+        return last
 
     def _set_slot_sampling(self, slot: int, scfg: SamplingConfig):
         """Write a request's sampling params into the slot's traced

@@ -39,7 +39,8 @@ the ring's last turn, recomputed once a turn — is flagged: its record's
 `stall_ms` is the excess (0 on every other record), and a copy is kept
 among `stalls` (the 64 newest, with a count and the total ms of all),
 joined by what else the process saw in that stretch (obs/process.py:
-`gc_ms`, `compiles` / `compile_ms`, `loop_lag_ms`) and `phase`, the
+`gc_ms`, `compiles` / `compile_ms` / `compiled` = the programs' names,
+`loop_lag_ms`) and `phase`, the
 largest entry of `ph` or `between` for the gap. That is the verdict:
 `fetch` large and nothing else, the device or the runtime; a host phase
 or `between` with `gc_ms`, the collector; with `compiles`, a recompile;
@@ -120,8 +121,13 @@ class FlightRecorder:
         self._occupancy_sum = 0
         # what holds for every iteration (the engine writes it once: the
         # layers whose keys lie joined in the pool); dumped and served
-        # beside the ring
+        # beside the ring, through `static_view`
         self.static: dict = {}
+
+    def static_view(self) -> dict:
+        """`static`, and under `boot` the process's account of its
+        start-up and of every program built since, read now."""
+        return {**self.static, "boot": self._watch.boot()}
 
     def begin(self) -> int:
         """Reserve the sequence number of the iteration that starts now:
@@ -203,11 +209,12 @@ class FlightRecorder:
             log.log(
                 logging.INFO if compiled else logging.WARNING,
                 "scheduler iteration %s %s: %.0f ms (limit %.0f), "
-                "mostly in %s; gc %.0f ms, %d compile(s) %.0f ms, event "
+                "mostly in %s; gc %.0f ms, %d compile(s) %.0f ms %s, event "
                 "loop lag %.0f ms", stall["seq"],
                 "compiled" if compiled else "stood still", total,
                 total - stall["stall_ms"], phase, saw["gc_ms"],
-                saw["compiles"], saw["compile_ms"], saw["loop_lag_ms"])
+                saw["compiles"], saw["compile_ms"],
+                sorted(set(saw["compiled"])), saw["loop_lag_ms"])
 
     def _settle(self) -> None:
         """Finish the newest stall for a reader that came before the next
@@ -258,13 +265,13 @@ class FlightRecorder:
             return None
         try:
             os.makedirs(trace_dir, exist_ok=True)
-            stalls = self.stalls()
+            stalls, static = self.stalls(), self.static_view()
             with self._lock:
                 seq = self._seq
                 body = {
                     "reason": reason,
                     "pid": os.getpid(),
-                    "static": dict(self.static),
+                    "static": static,
                     "stalls": stalls,
                     "iterations": [dict(r) for r in self._ring],
                 }

@@ -56,7 +56,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from ..obs import (SERVE_PREFIX_BYTES, SERVE_PREFIX_EVICTIONS,
+from ..obs import (RECORDER, SERVE_PREFIX_BYTES, SERVE_PREFIX_EVICTIONS,
                    SERVE_PREFIX_HITS, SERVE_PREFIX_MISSES,
                    SERVE_PREFIX_RESTORE_BLOCKS,
                    SERVE_PREFIX_RESTORE_DISPATCHES, SERVE_PREFIX_STATE_BYTES)
@@ -229,28 +229,38 @@ class PrefixCache:
         the chunk boundary that completed the block — the row then holds
         exactly prefix_len tokens, so the linear-attention snapshot is the
         exact prefix state. Dedupes on key; evicts LRU past capacity."""
-        end = (block_index + 1) * self.block
-        ids = np.asarray(prompt_ids[:end], np.int32)
         key = keys[block_index]
-        if key in self._blocks:
-            self._blocks.move_to_end(key)
-            return
-        entry_layers = self.model.slot_extract(
-            layers, slot, block_index * self.block, self.block)
-        blk = _Block(tokens=ids, layers=entry_layers,
-                     nbytes=_tree_bytes(entry_layers),
-                     state_bytes=_tree_bytes(entry_layers, state_only=True))
-        # (one block fits: `build` refused a capacity under `block_bytes`)
-        while self.bytes + blk.nbytes > self.capacity and self._blocks:
-            _, old = self._blocks.popitem(last=False)
-            self.bytes -= old.nbytes
-            self.evictions += 1
+        known = key in self._blocks
+        # recorder on: which of these holds the scheduler behind a chunk
+        with RECORDER.span("prefix.insert", cat="serve", block=block_index,
+                           known=int(known)):
+            if known:
+                self._blocks.move_to_end(key)
+                return
+            end = (block_index + 1) * self.block
+            ids = np.asarray(prompt_ids[:end], np.int32)
+            with RECORDER.span("prefix.extract", cat="serve"):
+                entry_layers = self.model.slot_extract(
+                    layers, slot, block_index * self.block, self.block)
+            blk = _Block(tokens=ids, layers=entry_layers,
+                         nbytes=_tree_bytes(entry_layers),
+                         state_bytes=_tree_bytes(entry_layers,
+                                                 state_only=True))
+            # (one block fits: `build` refused a capacity under
+            # `block_bytes`)
+            if self.bytes + blk.nbytes > self.capacity and self._blocks:
+                with RECORDER.span("prefix.evict", cat="serve"):
+                    while self.bytes + blk.nbytes > self.capacity \
+                            and self._blocks:
+                        _, old = self._blocks.popitem(last=False)
+                        self.bytes -= old.nbytes
+                        self.evictions += 1
+                        self.version += 1
+                        SERVE_PREFIX_EVICTIONS.inc()
+            self._blocks[key] = blk
+            self.bytes += blk.nbytes
             self.version += 1
-            SERVE_PREFIX_EVICTIONS.inc()
-        self._blocks[key] = blk
-        self.bytes += blk.nbytes
-        self.version += 1
-        self.publish_bytes()
+            self.publish_bytes()
 
     # -- introspection ------------------------------------------------------
 

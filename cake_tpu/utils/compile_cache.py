@@ -22,7 +22,8 @@ def default_cache_dir() -> str:
 
 def enable_compile_cache() -> str:
     """Call once per process before the first compile. Returns the
-    directory in effect.
+    directory in effect. The process's watch of its own program builds
+    (obs.PROCESS) is installed here, so it sees that first compile too.
 
     The key covers the ops' metadata too: the programs' named scopes
     (obs.spans.SCOPE_CATALOG) live there, and JAX's default key ignores
@@ -30,6 +31,9 @@ def enable_compile_cache() -> str:
     executables whose device trace names the wrong parts, or none. The
     price is a recompile after an edit that moves a traced line."""
     import jax
+
+    from ..obs import PROCESS
+    PROCESS.install()
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get(ENV)
     if placed:

@@ -18,6 +18,15 @@ from cake_tpu.ops.diffusion import (DpmSolverPP, cfg_combine,
 from cake_tpu.utils.wav import decode_wav, encode_wav
 
 
+@pytest.fixture
+def rng():
+    """This file's own generator, fresh for every test: the session's
+    (conftest.py) hands out draws that depend on which files ran before in
+    the worker, and `test_mmdit_forward_shapes_and_conditioning` compares
+    two random conditionings against a threshold some draws fall under."""
+    return np.random.default_rng(42)
+
+
 # ------------------------------------------------------------- schedulers
 
 def test_flow_matching_schedule():

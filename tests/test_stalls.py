@@ -12,7 +12,7 @@ from cake_tpu.serve import flight as flight_mod
 from cake_tpu.serve.flight import (PHASES, STALL_FLOOR_MS, STALLS_KEPT,
                                    FlightRecorder)
 
-NOTHING = {"gc_ms": 0.0, "compiles": 0, "compile_ms": 0.0,
+NOTHING = {"gc_ms": 0.0, "compiles": 0, "compile_ms": 0.0, "compiled": [],
            "loop_lag_ms": 0.0}
 
 
@@ -34,6 +34,9 @@ class Watch:
     def between(self, t0, t1):
         self.asked.append((t0, t1))
         return dict(self.saw)
+
+    def boot(self):
+        return {"phases": []}
 
 
 def _rec(fr, clock, wall, gap=0.0, at=None, **more):
@@ -235,13 +238,16 @@ def test_the_dump_carries_the_stalls(tmp_path, monkeypatch):
     assert body["stalls"]["count"] == 1
     assert body["stalls"]["worst"][0]["phase"] == "decode_dispatch"
     assert len(body["iterations"]) == 1
+    assert body["static"]["boot"] == {"phases": []}     # the watch's, read now
 
 
 # -- the process's own witnesses ---------------------------------------------
 
 def _watch():
     reg = obs.MetricsRegistry()
-    return ProcessWatch(reg.counter("c"), reg.counter("cs"),
+    return ProcessWatch(reg.counter("c", labelnames=("cache",)),
+                        reg.counter("cs", labelnames=("cache",)),
+                        reg.counter("bs", labelnames=("stage",)),
                         reg.histogram("g"), reg.histogram("l")), reg
 
 
@@ -268,16 +274,19 @@ def test_the_compile_listener_counts_backend_compiles(monkeypatch):
     w, reg = _watch()
     clock = Clock(50.0)
     monkeypatch.setattr("cake_tpu.obs.process.now", clock)
-    w._on_compile("/jax/core/compile/jaxpr_trace_duration", 9.0)
-    w._on_compile("/jax/core/compile/backend_compile_duration", 0.25)
+    w._on_build("/jax/core/compile/jaxpr_trace_duration", 9.0)
+    w._on_build("/jax/core/compile/backend_compile_duration", 0.25)
     clock.t = 60.0
-    w._on_compile("/jax/core/compile/backend_compile_duration", 1.5,
-                  fun_name="f")
-    assert reg.counter("c").value() == 2
-    assert reg.counter("cs").value() == pytest.approx(1.75)
+    w._on_build("/jax/core/compile/backend_compile_duration", 1.5,
+                fun_name="jit(f)")
+    # no cache event came before either: the persistent cache was not asked
+    assert w._m_compiles.value(cache="off") == 2
+    assert w._m_compile_s.value(cache="off") == pytest.approx(1.75)
+    assert w._m_build_s.value(stage="trace") == 0   # nothing lowered it
     assert w.between(49.0, 51.0) == {**NOTHING, "compiles": 1,
-                                     "compile_ms": 250.0}
+                                     "compile_ms": 250.0, "compiled": [""]}
     assert w.between(49.0, 61.0)["compile_ms"] == 1750.0
+    assert w.between(49.0, 61.0)["compiled"] == ["", "f"]
 
 
 def test_the_loop_lag_ring_and_its_largest_overlapping_sample(monkeypatch):

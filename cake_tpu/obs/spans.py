@@ -438,10 +438,10 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
                                 "which attends in the expanded form"),
     ("cake.attn.latent.read", "scores, softmax and weighted sum over the "
                               "cache: the call of the Pallas kernel "
-                              "cake_latent_decode_attention (a row walked "
-                              "to its frontier, a decode step and a chunk "
-                              "alike) or XLA's masked ops over the whole "
-                              "buffer (ops.latent_attention)"),
+                              "cake_latent_decode_attention (by shape: a decode step loops over a row's "  # noqa: E501
+                              "key steps inside the body, a chunk or a "
+                              "verify step has a grid step a key block) or "
+                              "XLA's masked ops over the whole buffer (ops.latent_attention)"),  # noqa: E501
     ("cake.ssm", "one Mamba layer's state-space mixer, in cake.attn's "
                  "place for that layer kind (the row jamba.MIXER: "
                  "mamba_forward)"),

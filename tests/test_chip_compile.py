@@ -508,6 +508,15 @@ def test_latent_programs_take_the_pool_in_place(one_chip, monkeypatch):
         print(name, "temporaries", mem.temp_size_in_bytes)
         assert mem.temp_size_in_bytes < 320 * 2 ** 20, (
             name, mem.temp_size_in_bytes)
+        if name == "decode":
+            # ONE custom call a latent layer whichever body the step's
+            # shape takes (PR 63: a decode step's own), and the 6 MB the
+            # configuration's memory_plan states
+            assert len(re.findall(
+                r"custom-call\([^\n]*cake_latent_decode_attention",
+                text[text.index("\nENTRY "):])) == 2
+            assert abs(mem.temp_size_in_bytes - 6e6) < 2e6, \
+                mem.temp_size_in_bytes
 
 
 def test_ling3_programs_take_a_row_of_both_kinds_in_place(one_chip,
@@ -567,3 +576,7 @@ def test_ling3_programs_take_a_row_of_both_kinds_in_place(one_chip,
         print(name, "temporaries", mem.temp_size_in_bytes)
         assert mem.temp_size_in_bytes < 256 * 2 ** 20, (
             name, mem.temp_size_in_bytes)
+        if name == "decode":
+            # the 55.7 MB the configuration's memory_plan states
+            assert abs(mem.temp_size_in_bytes - 55.7e6) < 2e6, \
+                mem.temp_size_in_bytes

@@ -265,20 +265,59 @@ def _rows(key, b, s, h, d, t, held):
     return q, kv, jnp.where(idx < held[:, None], idx, -1).astype(jnp.int32)
 
 
-@pytest.mark.parametrize("b,s,h,pos0,limit", [
-    (4, 1, 8, [5, 300, 0, 511], [6, 301, 0, 512]),
-    (1, 16, 8, [200], [216]),
-    (1, 16, 8, [200], [209]),
-    (2, 8, 128, [0, 130], [8, 138]),
-], ids=["decode_rows", "chunk", "padded_chunk", "heads128"])
-def test_kernel_interpreted_matches_the_masked_read(b, s, h, pos0, limit):
-    """A decode step walks each row to its own frontier and gives zeros for
-    a row the step masks out (limit 0); a chunk's query blocks hold tq
-    tokens x all heads and each token sees its own prefix."""
+KERNEL_CASES = {
+    # name: (s, heads, buffer, pos0 a row, limit a row, holes (row, index))
+    "decode_rows": (1, 8, 512, [5, 300, 0, 511], [6, 301, 0, 512], ()),
+    "chunk": (16, 8, 512, [200], [216], ()),
+    "padded_chunk": (16, 8, 512, [200], [209], ()),
+    "heads128": (8, 128, 512, [0, 130], [8, 138], ()),
+    # the decode body: a row's key steps (2048 latents = 4 blocks where the
+    # buffer divides) are a loop inside the kernel. A frontier below one
+    # block, inside the first step's second block, at an exact multiple of
+    # the step, one past it, two steps on, and at the buffer's end
+    "decode_h128_frontiers": (
+        1, 128, 6144, [99, 700, 2047, 2048, 4500, 6143],
+        [100, 701, 2048, 2049, 4501, 6144], ()),
+    "decode_h32_frontiers": (
+        1, 32, 6144, [99, 700, 2047, 2048, 4500, 6143],
+        [100, 701, 2048, 2049, 4501, 6144], ()),
+    # rows the step masks out (limit 0) between live rows, first and last
+    "decode_h128_idle_rows_between": (
+        1, 128, 4096, [0, 3000, 0, 0, 40, 2100, 9],
+        [0, 3001, 0, 0, 41, 2101, 0], ()),
+    "decode_h32_idle_rows_between": (
+        1, 32, 4096, [2500, 7, 4095, 0], [2501, 0, 4096, 0], ()),
+    # buffers no 2048 divides: key steps of 1024 (two blocks), of one block
+    # of 512, of one block of 128
+    "decode_only_1024_divides": (1, 32, 3072, [511, 1023, 1024, 3071],
+                                 [512, 1024, 1025, 3072], ()),
+    "decode_only_512_divides": (1, 32, 1536, [511, 512, 1535, 0],
+                                [512, 513, 1536, 0], ()),
+    "decode_only_128_divides": (1, 8, 640, [127, 128, 300, 639],
+                                [128, 129, 301, 640], ()),
+    # the whole mask stands on every block: an empty entry (-1) BELOW a
+    # row's frontier, in an interior key step and in the last, is not read
+    "decode_h128_hole_below_the_frontier": (
+        1, 128, 4096, [3700, 2100], [3701, 2101],
+        ((0, 3), (0, 600), (0, 2050), (0, 3699), (1, 2048), (1, 511))),
+    "decode_h32_hole_below_the_frontier": (
+        1, 32, 1536, [1400, 520], [1401, 521], ((0, 0), (0, 1399), (1, 512))),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_kernel_interpreted_matches_the_masked_read(case):
+    """A decode step walks each row to its own frontier in key steps of
+    several blocks where the buffer divides, and gives zeros for a row the
+    step masks out (limit 0); a chunk's query blocks hold tq tokens x all
+    heads and each token sees its own prefix."""
     from cake_tpu.ops.latent_attention import (latent_attention,
                                                latent_read, query_tokens)
-    d, dv, t = 128, 96, 512
+    s, h, t, pos0, limit, holes = KERNEL_CASES[case]
+    b, d, dv = len(pos0), 128, 96
     q, kv, kv_pos = _rows(jax.random.PRNGKey(5), b, s, h, d, t, limit)
+    for row, index in holes:
+        kv_pos = kv_pos.at[row, index].set(-1)
     pos0, limit = jnp.asarray(pos0, jnp.int32), jnp.asarray(limit, jnp.int32)
     got = latent_attention(q, kv, kv_pos, pos0, limit, dv, 0.1,
                            interpret=True)
@@ -289,6 +328,67 @@ def test_kernel_interpreted_matches_the_masked_read(b, s, h, pos0, limit):
                                atol=3e-6)
     assert not np.asarray(got)[~live].any()
     assert query_tokens(s, h) * h <= max(512, h)
+
+
+@pytest.mark.parametrize("s", [16, 3, 2, "slot_verify"])
+def test_a_chunk_and_a_verify_step_never_reach_the_decode_body(
+        s, monkeypatch):
+    """The form is chosen by shape: a call of more than one token a row (a
+    chunk; `slot_verify`'s [last token, drafts], whose odd lengths have
+    query blocks of ONE token) keeps `_latent_kernel`, a decode step alone
+    takes `_decode_kernel`."""
+    import functools
+
+    from cake_tpu.models import deepseek_v2
+    from cake_tpu.ops import latent_attention as la
+    traced = []
+
+    def never(*a, **k):
+        raise AssertionError("the decode body was traced")
+
+    def chunk_body(*a, _kernel=la._latent_kernel, **k):
+        traced.append(k["tq"])
+        return _kernel(*a, **k)
+
+    monkeypatch.setattr(la, "_decode_kernel", never)
+    monkeypatch.setattr(la, "_latent_kernel", chunk_body)
+    la._entry.cache_clear()
+    try:
+        if s == "slot_verify":
+            monkeypatch.setattr(deepseek_v2, "kernel_enabled", lambda: True)
+            monkeypatch.setattr(
+                deepseek_v2, "latent_attention", functools.partial(
+                    deepseek_v2.latent_attention, interpret=True))
+            m = TextModel(tiny_config("deepseek_v2"), dtype=jnp.float32,
+                          max_cache_len=CTX)
+            prompt = [3 + (i * 11) % 200 for i in range(20)]
+            _, cache = m.prefill(m.new_cache(1, kv_len=CTX), prompt)
+            packed, cache, _ = m.verify_tokens(
+                cache, 7, [9, 11], 2, len(prompt), jax.random.PRNGKey(0),
+                jnp.full((4,), -1, jnp.int32), GREEDY)
+            assert np.asarray(packed).shape == (2,)
+            # the prompt's chunk and the verify step of 3 tokens (query
+            # blocks of one token: 3 is odd), every latent layer
+            assert 1 in traced and max(traced) > 1, traced
+            with pytest.raises(AssertionError, match="decode body"):
+                m.decode_logits(cache, 5)
+            return
+        h, d, dv, t = 8, 128, 96, 1024
+        q, kv, kv_pos = _rows(jax.random.PRNGKey(s), 2, s, h, d, t,
+                              [700 + s, 30 + s])
+        pos0 = jnp.asarray([700, 30], jnp.int32)
+        got = la.latent_attention(q, kv, kv_pos, pos0, pos0 + s, dv, 0.1,
+                                  interpret=True)
+        want = la.latent_read(q, kv, kv_pos,
+                              pos0[:, None] + jnp.arange(s)[None, :], dv, 0.1)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=3e-6)
+        assert traced == [la.query_tokens(s, h)]
+        with pytest.raises(AssertionError, match="decode body"):
+            la.latent_attention(q[:, :1], kv, kv_pos, pos0, pos0 + 1, dv,
+                                0.1, interpret=True)
+    finally:
+        la._entry.cache_clear()
 
 
 def test_the_mixer_with_the_kernel_in_equals_the_mixer_without(monkeypatch):

@@ -91,6 +91,14 @@ class LatentAttnConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # the normed query latent and the normed key/value latent are multiplied
+    # by these before `q_b_proj` / `kv_b_proj` (LongCat-Flash's
+    # `mla_scale_q_lora` / `mla_scale_kv_lora`: (hidden / rank)^1/2); the
+    # shared rope key part is not. A row still holds the UNSCALED normed
+    # latent: `kv_scale` is folded where W_uk and W_uv are absorbed
+    # (deepseek_v2.latent_forward). 1.0 traces nothing
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
 
     @property
     def qk_head_dim(self) -> int:
@@ -120,6 +128,13 @@ class LayerSpec:
     # addressed by position; what its mixer says (mixers.Mixer.recurrent),
     # set where layer_spec chooses the kind
     recurrent: bool = False
+    # a shortcut-connected pair of entries of the layer list (LongCat-Flash:
+    # ONE published layer is two sub-layers, each a mixer and a dense FFN):
+    # 'open' also runs the sparse layer `moe` on its post-attention norm's
+    # output and HOLDS the result back, 'close' adds it after its own dense
+    # FFN; layers.forward_layers carries the value between the two. None:
+    # the layer stands alone
+    shortcut: str | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -255,6 +270,14 @@ class ModelConfig:
     # (Ling-3.0 and DeepSeek-V3's `noaux_tc`: 2, taken of score + bias);
     # 1: its best member alone
     moe_group_score_top: int = 1
+    # the router's LAST `moe_zero_experts` outputs are identity experts
+    # (LongCat-Flash's `zero_expert_num`, `zero_expert_type: identity`): no
+    # bank backs them and no share holds them; a pick of one returns the
+    # token itself times its weight
+    moe_zero_experts: int = 0
+    # the layer list is the SUB-layers of shortcut-connected layers, in
+    # pairs (LayerSpec.shortcut): `num_hidden_layers` counts sub-layers
+    shortcut_pairs: bool = False
 
     # ---- per-layer resolution ----
 
@@ -273,6 +296,10 @@ class ModelConfig:
                 return LayerSpec(kind="linear", use_rope=False,
                                  is_moe=self._layer_is_moe(i),
                                  norm_style=self.norm_style, recurrent=True)
+        if self.latent_attn is not None and self.shortcut_pairs:
+            return LayerSpec(kind="latent", use_rope=True,
+                             norm_style=self.norm_style,
+                             shortcut="close" if i % 2 else "open")
         if self.latent_attn is not None:
             return LayerSpec(kind="latent", use_rope=True,
                              is_moe=self._layer_is_moe(i),
@@ -322,9 +349,32 @@ class ModelConfig:
 
     @property
     def router_width(self) -> int:
-        """How many experts the router scores: an uncut model holds them
-        all."""
-        return self.router_experts or self.num_experts
+        """How many outputs the router scores: the experts of the whole
+        expert-parallel group (an uncut model holds them all) and, behind
+        them, the identity experts, which NO share holds."""
+        return (self.router_experts or self.num_experts) \
+            + self.moe_zero_experts
+
+    def sparse_layers(self) -> dict | None:
+        """The sparse layers' static description (health's static part and
+        the flight record's, beside `attention_kinds`): how many there are,
+        what the router scores and what of it this process holds. None: no
+        layer is sparse."""
+        specs = self.layer_specs()
+        sparse = sum(s.is_moe or s.shortcut == "open" for s in specs)
+        if not sparse:
+            return None
+        routed = self.router_experts or self.num_experts
+        out = {"layers": sparse, "router_width": self.router_width,
+               "routed_experts": routed,
+               "identity_experts": self.moe_zero_experts,
+               "held": self.num_experts, "held_from": self.expert_first,
+               "top_k": self.num_experts_per_tok,
+               "routed_scale": self.moe_routed_scale}
+        if self.shortcut_pairs:
+            out["shortcut_pairs"] = [[i, i + 1] for i, s in enumerate(specs)
+                                     if s.shortcut == "open"]
+        return out
 
     @property
     def rotary_dim(self) -> int:
@@ -600,10 +650,13 @@ def _qwen3_5_moe(d):
 
 def _expert_share(d: dict, arch: str, held: int, n_group: int = 1,
                   topk_group: int = 1) -> tuple[int, int]:
-    """(router width, first held expert) of `expert_parallel: {size, rank}`,
-    this repo's key for one share of an expert-parallel group: `held`
-    experts of size x held, contiguous from rank x held. Under group-limited
-    routing a share holds whole groups."""
+    """(experts of the group, first held expert) of `expert_parallel: {size,
+    rank}`, this repo's key for one share of an expert-parallel group:
+    `held` experts of size x held, contiguous from rank x held. Under
+    group-limited routing a share holds whole groups. The router may score
+    further outputs that NO share holds (LongCat-Flash's identity experts,
+    `ModelConfig.moe_zero_experts`, behind the group's experts): they are
+    no part of the count returned here."""
     ep = d.get("expert_parallel") or {"size": 1, "rank": 0}
     size, rank = int(ep["size"]), int(ep["rank"])
     if not 0 <= rank < size:
@@ -932,6 +985,13 @@ def _deepseek_v2(d):
     if d.get("attention_bias"):
         raise ValueError("deepseek_v2: attention_bias true is not "
                          "implemented")
+    for key, has in (("mla_scale_q_lora", "LatentAttnConfig.q_scale"),
+                     ("mla_scale_kv_lora", "LatentAttnConfig.kv_scale"),
+                     ("zero_expert_num", "ModelConfig.moe_zero_experts")):
+        if d.get(key):
+            raise ValueError(f"deepseek_v2: {key} {d[key]} is not "
+                             "implemented for this family (`longcat_flash` "
+                             f"has it: {has})")
     if d.get("norm_topk_prob") and float(
             d.get("routed_scaling_factor") or 1.0) != 1.0:
         raise ValueError("deepseek_v2: norm_topk_prob true beside a "
@@ -1093,6 +1153,79 @@ def _ling3(d):
     ))
 
 
+def _longcat_flash(d):
+    """LongCat-Flash (meituan-longcat LongCat-Flash-Chat; `model_type:
+    longcat_flash` is the name its Transformers port goes by, unconfirmed
+    offline). ONE of the `num_layers` published layers is a
+    shortcut-connected block (models/longcat_flash.py has the equations):
+    two latent-attention sub-layers (MLA, models/deepseek_v2.py, with the
+    normed latents scaled by (hidden / rank)^1/2 where `mla_scale_q_lora` /
+    `mla_scale_kv_lora` say so), each with a dense SwiGLU of
+    `ffn_hidden_size`, and ONE sparse layer that reads the first
+    sub-layer's post-attention norm and whose output joins the stream
+    behind the second sub-layer's FFN. Here the layer list is the
+    2 x `num_layers` SUB-layers (`shortcut_pairs`), each with a cache entry
+    of its own. The router: softmax over `n_routed_experts` +
+    `zero_expert_num` outputs, the top `moe_topk` of score +
+    `e_score_correction_bias`, weights the scores alone, not normalised,
+    times `routed_scaling_factor`; the last `zero_expert_num` outputs are
+    identity experts. `expert_parallel: {size, rank}` as `mimo_v2` reads
+    it: `n_routed_experts` is what this process holds. What this adapter
+    cannot honour it refuses."""
+    if d.get("attention_method", "MLA") != "MLA":
+        raise ValueError(f"longcat_flash: attention_method "
+                         f"{d['attention_method']!r} (only MLA is "
+                         "implemented)")
+    zeros = int(d.get("zero_expert_num") or 0)
+    if zeros and d.get("zero_expert_type", "identity") != "identity":
+        raise ValueError(f"longcat_flash: zero_expert_type "
+                         f"{d['zero_expert_type']!r} (only identity "
+                         "experts are implemented)")
+    if d.get("attention_bias"):
+        raise ValueError("longcat_flash: attention_bias true is not "
+                         "implemented")
+    if d.get("rope_scaling"):
+        raise ValueError(f"longcat_flash: rope_scaling {d['rope_scaling']} "
+                         "is not implemented (the published config has "
+                         "plain rope)")
+    scale = float(d.get("routed_scaling_factor") or 1.0)
+    if d.get("norm_topk_prob") and scale != 1.0:
+        raise ValueError("longcat_flash: norm_topk_prob true beside a "
+                         "routed_scaling_factor is not implemented")
+    if d.get("router_bias"):
+        raise ValueError("longcat_flash: router_bias true (a bias on the "
+                         "router's logits) is not implemented; the "
+                         "selection bias e_score_correction_bias is")
+    if d.get("q_lora_rank") is None:
+        raise ValueError("longcat_flash: q_lora_rank null is not "
+                         "implemented for this family")
+    hidden, ql, kvl = (int(d["hidden_size"]), int(d["q_lora_rank"]),
+                       int(d["kv_lora_rank"]))
+    la = LatentAttnConfig(
+        q_lora_rank=ql, kv_lora_rank=kvl,
+        qk_nope_head_dim=int(d["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(d["qk_rope_head_dim"]),
+        v_head_dim=int(d["v_head_dim"]),
+        q_scale=(hidden / ql) ** 0.5 if d.get("mla_scale_q_lora") else 1.0,
+        kv_scale=((hidden / kvl) ** 0.5 if d.get("mla_scale_kv_lora")
+                  else 1.0))
+    held = int(d["n_routed_experts"])
+    width, first = _expert_share(d, "longcat_flash", held)
+    base = {**d, "num_hidden_layers": 2 * int(d["num_layers"]),
+            "intermediate_size": int(d["ffn_hidden_size"])}
+    return ModelConfig(**_base(
+        base, "longcat_flash", head_dim=la.qk_head_dim,
+        v_head_dim=la.v_head_dim, latent_attn=la, shortcut_pairs=True,
+        attn_scale=la.qk_head_dim ** -0.5,
+        num_experts=held, router_experts=width, expert_first=first,
+        moe_zero_experts=zeros,
+        num_experts_per_tok=int(d["moe_topk"]),
+        moe_intermediate_size=int(d["expert_ffn_hidden_size"]),
+        norm_topk_prob=bool(d.get("norm_topk_prob", False)),
+        moe_routed_scale=scale, moe_select_bias=True,
+    ))
+
+
 # HF architectures string -> adapter (ref: cake/mod.rs arch_str_to_text_model_arch;
 # unknown strings fall back to llama, matching the reference)
 ARCH_ADAPTERS = {
@@ -1117,6 +1250,7 @@ ARCH_ADAPTERS = {
     "MiMoV2FlashForCausalLM": _mimo_v2,
     "LagunaForCausalLM": _laguna,
     "DeepseekV2ForCausalLM": _deepseek_v2,
+    "LongcatFlashForCausalLM": _longcat_flash,
 }
 
 # short family names (CLI --arch overrides, tests)
@@ -1129,6 +1263,7 @@ FAMILY_ADAPTERS = {
     "olmo2": _olmo2, "exaone4": _exaone4, "jamba": _jamba,
     "mimo_v2": _mimo_v2, "laguna": _laguna, "solar_open2": _solar_open2,
     "brumby": _brumby, "deepseek_v2": _deepseek_v2, "ling3": _ling3,
+    "longcat_flash": _longcat_flash,
 }
 
 
@@ -1275,6 +1410,22 @@ def tiny_config(arch: str = "llama", **over) -> ModelConfig:
                  topk_group=2, score_function="sigmoid",
                  moe_router_enable_expert_bias=True, norm_topk_prob=True,
                  routed_scaling_factor=2.5,
+                 expert_parallel={"size": 2, "rank": 0})
+    if arch == "longcat_flash":
+        # two shortcut layers = four latent sub-layers (4 heads of 16 + 8,
+        # values of 16, ranks 24 and 32, both latents scaled), a dense FFN
+        # each, a sparse layer a pair: a share of 4 of 8 experts under a
+        # router of 8 + 4 identity outputs, top 3, unnormalised x 6
+        for key in ("num_hidden_layers", "intermediate_size",
+                    "num_key_value_heads"):
+            d.pop(key)
+        d.update(num_layers=2, ffn_hidden_size=128, expert_ffn_hidden_size=32,
+                 q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+                 qk_rope_head_dim=8, v_head_dim=16, attention_bias=False,
+                 attention_method="MLA", mla_scale_q_lora=True,
+                 mla_scale_kv_lora=True, n_routed_experts=4,
+                 zero_expert_num=4, zero_expert_type="identity", moe_topk=3,
+                 routed_scaling_factor=6,
                  expert_parallel={"size": 2, "rank": 0})
     d.update(over)
     if arch in ("qwen3_5", "qwen3_5_moe"):

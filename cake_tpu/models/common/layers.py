@@ -116,6 +116,9 @@ def init_layer_params(cfg: ModelConfig, spec: LayerSpec, key, dtype):
     p: dict = {m.param_key: m.init_params(cfg, spec, ks[0], dtype)}
     p["mlp"] = (init_moe_params(cfg, ks[1], dtype) if spec.is_moe
                 else init_mlp_params(cfg, ks[1], dtype))
+    if spec.shortcut == "open":
+        # the pair's ONE sparse layer, beside this sub-layer's dense FFN
+        p["moe"] = init_moe_params(cfg, jax.random.fold_in(key, 7), dtype)
     # fresh buffer per norm: donation/aliasing breaks if leaves share storage
     def ones():
         return jnp.ones(_norm_shape(cfg), dtype)
@@ -473,10 +476,11 @@ def moe_forward(cfg: ModelConfig, p: dict, x):
         # selected experts streamed from storage — EAGER only (the host
         # round-trip on the routing indices cannot trace under jit)
         from .expert_provider import moe_ffn_offloaded
-        if cfg.moe_routed_scale != 1.0 or cfg.moe_n_group > 1:
+        if (cfg.moe_routed_scale != 1.0 or cfg.moe_n_group > 1
+                or cfg.moe_zero_experts):
             raise NotImplementedError(
-                "--expert-offload with a routed scaling factor or "
-                "group-limited routing")
+                "--expert-offload with a routed scaling factor, "
+                "group-limited routing or identity experts")
         y = moe_ffn_offloaded(flat, p["gate"]["weight"], p["_provider"],
                               cfg.num_experts_per_tok, cfg.norm_topk_prob,
                               cfg.moe_gate_act, act)
@@ -489,7 +493,8 @@ def moe_forward(cfg: ModelConfig, p: dict, x):
                     first=cfg.expert_first,
                     routed_scale=cfg.moe_routed_scale,
                     n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
-                    group_score_top=cfg.moe_group_score_top)
+                    group_score_top=cfg.moe_group_score_top,
+                    zero_experts=cfg.moe_zero_experts)
     if "shared_expert" in p:
         # always-active shared expert: sigmoid-gated where the checkpoint
         # has a `shared_expert_gate` (ref: qwen3_5_moe/moe.rs), else added
@@ -559,6 +564,46 @@ def block_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x,
     return x, layer_cache
 
 
+def shortcut_forward(cfg: ModelConfig, spec: LayerSpec, p: dict, x, held,
+                     layer_cache: dict, pos0, rope: dict, valid_len=None,
+                     flash_mode: str = "off", mesh=None):
+    """One SUB-layer of a shortcut-connected pair (LayerSpec.shortcut;
+    models/longcat_flash.py has the block's equations): the pre-norm block
+    of a mixer and a dense FFN, and
+      'open'   the pair's sparse layer `moe` also reads this sub-layer's
+               post-attention norm; its output is HELD BACK (returned, not
+               added), so the next sub-layer's mixer and FFN never see it;
+      'close'  `held` is added last, behind this sub-layer's own FFN.
+    Returns (x, what is held after this sub-layer, layer_cache)."""
+    eps = cfg.rms_norm_eps
+    h = rms_norm(x, p["input_layernorm"]["weight"], eps)
+    attn_out, layer_cache = _attn(cfg, spec, p, h, layer_cache, pos0, rope,
+                                  valid_len, flash_mode, mesh)
+    x = x + attn_out
+    h = rms_norm(x, p["post_attention_layernorm"]["weight"], eps)
+    with jax.named_scope("cake.ffn"):
+        if spec.shortcut == "open":
+            held = moe_forward(cfg, p["moe"], h)
+        with jax.named_scope("cake.ffn.dense"):
+            x = x + mlp_forward(cfg, p["mlp"], h)
+            if spec.shortcut == "close":
+                x, held = x + held, None
+    return x, held, layer_cache
+
+
+def _layer(cfg, spec, p, x, held, lc, pos0, rope, valid_len=None,
+           flash_mode="off", mesh=None):
+    """(x, held, layer cache) of one entry of the layer list: a block that
+    stands alone, or one sub-layer of a shortcut pair with the value the
+    pair carries."""
+    if spec.shortcut is None:
+        x, lc = block_forward(cfg, spec, p, x, lc, pos0, rope, valid_len,
+                              flash_mode, mesh)
+        return x, held, lc
+    return shortcut_forward(cfg, spec, p, x, held, lc, pos0, rope, valid_len,
+                            flash_mode, mesh)
+
+
 def forward_layers(cfg: ModelConfig, params: dict, x, cache: dict, pos0,
                    layer_range: tuple[int, int] | None = None, valid_len=None,
                    flash_mode: str = "off", mesh=None):
@@ -567,17 +612,25 @@ def forward_layers(cfg: ModelConfig, params: dict, x, cache: dict, pos0,
     worker.rs op-batch execution, but compiled as ONE device program)."""
     lo, hi = layer_range or (0, len(params["layers"]))
     specs = cfg.layer_specs()[lo:hi]
+    if specs and (specs[0].shortcut == "close"
+                  or specs[-1].shortcut == "open"):
+        raise ValueError(
+            f"{cfg.arch}: layer_range ({lo}, {hi}) separates an opening "
+            "sub-layer of a shortcut pair from its closing one: what the "
+            "pair's sparse layer gives is carried between the two inside "
+            "one program (a stage holds whole pairs)")
     rope = params["rope"]
+    held = None     # what an open shortcut pair carries to its closing entry
     if cache is None:       # stateless (training / encoder use)
         for j, spec in enumerate(specs):
-            x, _ = block_forward(cfg, spec, params["layers"][j], x, None,
-                                 pos0, rope, valid_len)
+            x, held, _ = _layer(cfg, spec, params["layers"][j], x, held,
+                                None, pos0, rope, valid_len)
         return x, None
     new_layers = list(cache["layers"])
     for j, spec in enumerate(specs):
-        x, new_layers[j] = block_forward(cfg, spec, params["layers"][j], x,
-                                         cache["layers"][j], pos0, rope,
-                                         valid_len, flash_mode, mesh=mesh)
+        x, held, new_layers[j] = _layer(cfg, spec, params["layers"][j], x,
+                                        held, cache["layers"][j], pos0, rope,
+                                        valid_len, flash_mode, mesh=mesh)
     advance = x.shape[1] if valid_len is None else valid_len
     new_cache = {"layers": new_layers, "pos": pos0 + advance}
     return x, new_cache

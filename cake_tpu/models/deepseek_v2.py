@@ -1,7 +1,8 @@
 """Multi-head latent attention (MLA, arXiv:2405.04434): the mixer of every
 layer of DeepSeek-V2 (`model_type: deepseek_v2`), whose block is otherwise
-the pre-norm one with a dense or a sparse FFN, and of one layer in six of
-Ling-3.0-flash (`ling3`), beside Kimi Delta Attention.
+the pre-norm one with a dense or a sparse FFN, of one layer in six of
+Ling-3.0-flash (`ling3`), beside Kimi Delta Attention, and of both
+sub-layers of a LongCat-Flash layer (`longcat_flash`, models/longcat_flash.py).
 
 For the normed input x of a token, H heads, widths `qk_nope` + `qk_rope` a
 query and key head, `v` a value head, ranks `q_lora` and `kv_lora`:
@@ -20,6 +21,15 @@ query and key head, `v` a value head, ranks `q_lora` and `kv_lora`:
            o_lat^h = sum p c_kv;  o^h = W_uv^h o_lat^h
   gated    `cfg.attn_head_gate` (Ling-3.0's `head_wise` gate): o^h times
            sigmoid(W_g x)[h], W_g [H, hidden], before W_o, in either form
+  scaled   `la.q_scale`, `la.kv_scale` (LongCat-Flash's `mla_scale_q_lora`,
+           `mla_scale_kv_lora`: (hidden / rank)^1/2): W_qb reads q_scale c_q
+           and W_kvb reads kv_scale c_kv; rope(k_pe) is NOT scaled. A row
+           still holds [c_kv ; rope(k_pe)], unscaled, as the other latent
+           families store it, and the absorbed form carries kv_scale where
+           W_uk and W_uv are absorbed: q_lat^h = kv_scale W_uk^h^T q_nope^h,
+           o^h = kv_scale W_uv^h o_lat^h. So cache.py, both pools and the
+           prefix cache see nothing new, and a prefix hit restores what a
+           fresh prefill writes
 The scale s is `cfg.attn_scale`: (nope + rope)^-1/2 times the SQUARE of
 YaRN's mscale_all_dim factor, which DeepSeek-V2 puts there and not on cos
 and sin (those carry mscale / mscale_all_dim; config._deepseek_v2).
@@ -120,6 +130,8 @@ def latent_forward(cfg, spec, p, x, layer_cache, pos0, rope, valid_len=None,
         else:
             c_q = rms_norm(linear(x, p["q_a_proj"]["weight"]),
                            p["q_a_layernorm"]["weight"], eps)
+            if la.q_scale != 1.0:
+                c_q = c_q * jnp.asarray(la.q_scale, c_q.dtype)
             q = linear(c_q, p["q_b_proj"]["weight"])
         q = q.reshape(b, s, h, dn + dr)
         ckv = linear(x, p["kv_a_proj_with_mqa"]["weight"])     # [B, S, r+dr]
@@ -132,6 +144,8 @@ def latent_forward(cfg, spec, p, x, layer_cache, pos0, rope, valid_len=None,
     q_pos = jnp.broadcast_to(positions[None, :], (b, s))
     if layer_cache is None:
         with jax.named_scope("cake.attn.latent.expand"):
+            if la.kv_scale != 1.0:
+                c_kv = c_kv * jnp.asarray(la.kv_scale, c_kv.dtype)
             kv = jnp.einsum("bsc,hnc->bshn", c_kv, w_kvb)
             k = jnp.concatenate(
                 [kv[..., :dn],
@@ -154,6 +168,10 @@ def latent_forward(cfg, spec, p, x, layer_cache, pos0, rope, valid_len=None,
         new_cache = write_entries(layer_cache, {"kv": row}, pos0, valid_len)
         with jax.named_scope("cake.attn.latent.absorb"):
             q_lat = jnp.einsum("bshn,hnc->bshc", q_nope, w_kvb[:, :dn])
+            if la.kv_scale != 1.0:
+                # k_nope = W_uk (kv_scale c_kv): the scale rides on the
+                # absorbed query, the row keeps c_kv as it is
+                q_lat = q_lat * jnp.asarray(la.kv_scale, q_lat.dtype)
             q_abs = _in_lanes([q_lat, q_pe], width)
         kv, kv_pos = new_cache["kv"], new_cache["pos"]
         with jax.named_scope("cake.attn.latent.read"):
@@ -170,6 +188,9 @@ def latent_forward(cfg, spec, p, x, layer_cache, pos0, rope, valid_len=None,
                                     cfg.attn_scale)
         with jax.named_scope("cake.attn.latent.absorb"):
             o = jnp.einsum("bshc,hvc->bshv", o_lat, w_kvb[:, dn:])
+            if la.kv_scale != 1.0:
+                # v = W_uv (kv_scale c_kv), alike
+                o = o * jnp.asarray(la.kv_scale, o.dtype)
     if cfg.attn_head_gate:
         # one sigmoid gate a head, from the layer's normed input
         with jax.named_scope("cake.attn.gate"):
@@ -186,8 +207,11 @@ def describe_latent(cfg, spec) -> dict:
     """A latent layer's entry of ModelConfig.attention_kinds(): the heads,
     the ranks and the three head widths, what a position of a row holds
     (`row_width` numbers, in `row_lanes` as it lies) and its bytes in
-    bfloat16, the served dtype, summed over the layers."""
+    bfloat16, the served dtype, summed over the layers; the latents' scales
+    where a family has them (a row holds the unscaled latent)."""
     la = cfg.latent_attn
+    scaled = {} if la.q_scale == la.kv_scale == 1.0 else {
+        "q_scale": la.q_scale, "kv_scale": la.kv_scale}
     return {"kind": spec.kind, "layers": 1, "heads": cfg.num_attention_heads,
             "q_lora_rank": la.q_lora_rank, "kv_lora_rank": la.kv_lora_rank,
             "qk_nope_head_dim": la.qk_nope_head_dim,
@@ -197,7 +221,7 @@ def describe_latent(cfg, spec) -> dict:
             "rotary_dim": la.qk_rope_head_dim, "rope_theta": cfg.rope_theta,
             "rope_scaling": (cfg.rope_scaling.rope_type
                              if cfg.rope_scaling is not None else None),
-            "row_bytes": 2 * la.row_width}
+            "row_bytes": 2 * la.row_width, **scaled}
 
 
 # -- checkpoint IO -----------------------------------------------------------

@@ -41,13 +41,13 @@ cluster stages.
 | endpoint | serves |
 |---|---|
 | `GET /metrics` | Prometheus text exposition 0.0.4 of every instrument below (per process; worker-side series live in each worker process) |
-| `GET /health` | JSON liveness: worker last-seen ages, gray/hard cluster degradation, the serve-engine block (`alive` / `wedged` / `down` / `draining`, queue depth, `prefilling`, prefix-cache and `kv_pool` occupancy — the paged block carries a first-class `occupancy` field in [0, 1]; every pool's block carries `joined_keys`, one `{width, layers}` a joined width: how many layers hold a position's keys as one run of Hkv x D because the key width is no multiple of the 128 lanes, empty for a model whose key widths all are; beside it `attention_kinds`, one entry a kind of attention layer: `{kind, layers, heads, kv_heads, window, rotary_dim, rope_theta, rope_scaling}`, so a run says which rope table each kind read, and one for the delta-rule layers: `{kind: linear, layers, heads, key_dim, value_dim, decay: head | channel, conv_kernel, state_bytes}`, the float32 state a row holds over all of them; one for the latent layers: `{kind: latent, layers, heads, q_lora_rank (null: a full-rank query), kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim, row_width, row_lanes, rotary_dim, rope_theta, rope_scaling, row_bytes}`, and a model may report a linear and a latent entry together (one pool row then holds both kinds); `rope_rows` and `rope_bytes`, the rows and bytes of the rope tables the model HOLDS: a model built with `max_cache_len` below the published reach keeps the first `max_cache_len` rows of each table, 0 / 0 where no layer rotates; the `prefix_cache` block's `state_bytes` is the part of its `bytes` that is recurrent layers' boundary snapshots); 503 while degraded |
+| `GET /health` | JSON liveness: worker last-seen ages, gray/hard cluster degradation, the serve-engine block (`alive` / `wedged` / `down` / `draining`, queue depth, `prefilling`, prefix-cache and `kv_pool` occupancy — the paged block carries a first-class `occupancy` field in [0, 1]; every pool's block carries `joined_keys`, one `{width, layers}` a joined width: how many layers hold a position's keys as one run of Hkv x D because the key width is no multiple of the 128 lanes, empty for a model whose key widths all are; beside it `attention_kinds`, one entry a kind of attention layer: `{kind, layers, heads, kv_heads, window, rotary_dim, rope_theta, rope_scaling}`, so a run says which rope table each kind read, and one for the delta-rule layers: `{kind: linear, layers, heads, key_dim, value_dim, decay: head | channel, conv_kernel, state_bytes}`, the float32 state a row holds over all of them; one for the latent layers: `{kind: latent, layers, heads, q_lora_rank (null: a full-rank query), kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim, row_width, row_lanes, rotary_dim, rope_theta, rope_scaling, row_bytes}`, with `q_scale` and `kv_scale` where a family scales its latents (a row holds the unscaled one), and a model may report a linear and a latent entry together (one pool row then holds both kinds); `sparse_layers`, where the model has any: `{layers, router_width, routed_experts, identity_experts, held, held_from, top_k, routed_scale}` (what the router scores: the experts of the whole group and, behind them, identity experts no share holds; what of it this process holds) and `shortcut_pairs`, the pairs of entries of the layer list between which a sparse layer's output is carried (LongCat-Flash: every entry is a latent sub-layer, `attention_kinds` counts 2 x `num_layers` of them); `rope_rows` and `rope_bytes`, the rows and bytes of the rope tables the model HOLDS: a model built with `max_cache_len` below the published reach keeps the first `max_cache_len` rows of each table, 0 / 0 where no layer rotates; the `prefix_cache` block's `state_bytes` is the part of its `bytes` that is recurrent layers' boundary snapshots); 503 while degraded |
 | `GET /api/v1/stats` | last generation's timing snapshot (TTFT, tok/s, per-hop RTT split), with its `request_id` (the cross-tier trace id) and `completion_id` |
 | `GET /api/v1/trace` | Chrome-trace JSON of the span ring buffer (`?clear=1` drains; 409 while the recorder is disabled) |
 | `GET /api/v1/requests` | recent request ids with retrievable timelines |
 | `GET /api/v1/requests/<id>` | one request's typed lifecycle timeline (`?format=perfetto` for Chrome-trace instant events); on the fleet router this view STITCHES the router tier's events onto the replica's |
 | `GET /api/v1/slo` | the serve TTFT / inter-token / e2e histograms by outcome as JSON, each bucket carrying its sampled exemplar request id |
-| `GET /api/v1/flight` | flight-recorder-on-demand: the scheduler-iteration ring as JSON without waiting for a wedge/DOWN dump, with its `static` part (what holds for every iteration: `joined_keys`, as in `/health`'s `kv_pool`, and `attention_kinds`, `rope_rows` and `rope_bytes`, as in `/health`) and its `stalls` (the iterations that stood still, kept beside the ring) beside it (`?n=K` for the newest K; 409 without an engine) |
+| `GET /api/v1/flight` | flight-recorder-on-demand: the scheduler-iteration ring as JSON without waiting for a wedge/DOWN dump, with its `static` part (what holds for every iteration: `joined_keys`, as in `/health`'s `kv_pool`, and `attention_kinds`, `sparse_layers`, `rope_rows` and `rope_bytes`, as in `/health`) and its `stalls` (the iterations that stood still, kept beside the ring) beside it (`?n=K` for the newest K; 409 without an engine) |
 | `GET /api/v1/fleet/telemetry` | ROUTER ONLY: the fleet telemetry rollup — time-series, burn rates, headroom, outliers (see [telemetry.md](telemetry.md)) |
 | `GET /api/v1/fleet/autoscale` | ROUTER ONLY: the autoscaler's decision ring, policy, and managed-replica lifecycle state (see [autoscaling.md](autoscaling.md); `{"enabled": false}` when the loop is off) |
 
@@ -219,7 +219,8 @@ read-only snapshot, with the record's `static` part: `joined_keys`, the
 layers whose keys lie joined in the pool by joined width, which says that
 the rule of `cache.key_row_shape` engaged, and `attention_kinds`, the
 attention layers by kind with their heads, K/V heads, window, rotary width
-and rope scaling, and `rope_rows` / `rope_bytes`, what the rope tables the
+and rope scaling, and `sparse_layers`, what the router of a model's sparse
+layers scores and what of it is held here, and `rope_rows` / `rope_bytes`, what the rope tables the
 model holds come to, and `boot`, the process's account of its start-up
 read at that instant; a dump carries them all) —
 `cake top` and the profiling workflow inspect a live engine without

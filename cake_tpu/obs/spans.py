@@ -453,17 +453,30 @@ SCOPE_CATALOG: tuple[tuple[str, str], ...] = (
                       "one token, a scan along a chunk's tokens) and the "
                       "gated output"),
     ("cake.ffn", "one layer's feed-forward (layers._ffn: mlp_forward or "
-                 "moe_forward)"),
+                 "moe_forward; layers.shortcut_forward: a shortcut "
+                 "sub-layer's dense FFN and, where it opens a pair, the "
+                 "pair's sparse layer)"),
+    ("cake.ffn.dense", "a shortcut sub-layer's dense FFN with its sum into "
+                       "the stream and, where the sub-layer closes a pair, "
+                       "the sum of the sparse layer's held-back output "
+                       "(layers.shortcut_forward); older families' dense "
+                       "FFNs enter no such scope"),
     ("cake.ffn.route", "MoE router: logits, the group mask where routing "
                        "is group-limited (a group scored by its best "
                        "member, or by the sum of its two best of score + "
-                       "bias: a second top-k), and top-k over every expert "
-                       "of the model (ops.moe.moe_ffn)"),
+                       "bias: a second top-k), and top-k over every output "
+                       "of the router: every expert of the model and the "
+                       "identity experts where a family has them "
+                       "(ops.moe.moe_ffn)"),
     ("cake.ffn.experts", "MoE expert GEMMs and combine, of the experts "
                          "this process holds (ops.moe.moe_ffn)"),
     ("cake.ffn.shared", "the shared expert every token passes, with its "
                         "sigmoid gate where the family has one, and its "
                         "sum into the routed result (layers.moe_forward)"),
+    ("cake.ffn.zero", "the identity experts' term: the summed weights of a "
+                      "token's picks among the router's last `zero_experts` "
+                      "outputs, their product with the layer's input and "
+                      "its sum into the routed result (ops.moe.moe_ffn)"),
     ("cake.lm_head", "final norm and vocabulary projection "
                      "(layers.lm_head_logits)"),
     ("cake.sample", "on-device sampling: sample, sample_traced and the "

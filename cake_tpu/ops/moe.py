@@ -119,7 +119,8 @@ def _expert_act(g, u, act: str):
 def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
             norm_topk_prob: bool, gate_act: str = "softmax", act: str = "silu",
             select_bias=None, first: int = 0, routed_scale: float = 1.0,
-            n_group: int = 1, topk_group: int = 1, group_score_top: int = 1):
+            n_group: int = 1, topk_group: int = 1, group_score_top: int = 1,
+            zero_experts: int = 0):
     """x: [T, H]; router_weight: [R, H]; gate/up_proj: [E, I, H];
     down_proj: [E, H, I]. Returns [T, H] in x.dtype.
 
@@ -134,6 +135,14 @@ def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
     give, and an assignment to an expert held elsewhere adds nothing (its
     index is moved to E, which the dense combine drops). Nothing stands in
     for the other shares.
+
+    zero_experts > 0: the router's LAST that many outputs are identity
+    experts (LongCat-Flash's `zero_expert_type: identity`). No bank backs
+    them and no share holds them: a pick of one gives the token itself times
+    its weight, so the result gains (sum of a token's identity picks'
+    weights) x. That term belongs to the chip a token lives on, for every
+    row it serves: when shares add up it counts ONCE, as a shared expert
+    does. 0 traces nothing.
 
     One dispatch at every T, for a share as for a whole model: the dense
     combine, walked in blocks of EXPERT_BLOCK_TOKENS where T (a
@@ -150,6 +159,7 @@ def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
                                    group_score_top)
         if routed_scale != 1.0:
             weights = weights * routed_scale
+        picked, picked_w = idx, weights
         if share:
             held = (idx >= first) & (idx < first + e)
             idx = jnp.where(held, idx - first, e)
@@ -168,12 +178,20 @@ def moe_ffn(x, router_weight, gate_proj, up_proj, down_proj, k: int,
         w_te = combine_weights(weights, idx, e).astype(x.dtype)
         t, block = x.shape[0], EXPERT_BLOCK_TOKENS
         if t <= block:
-            return combine(x, w_te)
-        cut = t - t % block
-        out = jax.lax.map(lambda xw: combine(*xw),
-                          (x[:cut].reshape(-1, block, x.shape[1]),
-                           w_te[:cut].reshape(-1, block, e))
-                          ).reshape(cut, -1)
-        if cut < t:
-            out = jnp.concatenate([out, combine(x[cut:], w_te[cut:])])
-        return out
+            out = combine(x, w_te)
+        else:
+            cut = t - t % block
+            out = jax.lax.map(lambda xw: combine(*xw),
+                              (x[:cut].reshape(-1, block, x.shape[1]),
+                               w_te[:cut].reshape(-1, block, e))
+                              ).reshape(cut, -1)
+            if cut < t:
+                out = jnp.concatenate([out, combine(x[cut:], w_te[cut:])])
+    if zero_experts:
+        with jax.named_scope("cake.ffn.zero"):
+            zero_w = jnp.sum(jnp.where(
+                picked >= router_weight.shape[0] - zero_experts, picked_w,
+                0.0), axis=-1)                                  # [T] f32
+            out = (out.astype(jnp.float32)
+                   + zero_w[:, None] * x.astype(jnp.float32)).astype(x.dtype)
+    return out

@@ -462,6 +462,8 @@ class ServeEngine:
         self.flight = FlightRecorder()
         self.flight.static["joined_keys"] = self._joined_keys
         self.flight.static["attention_kinds"] = self._attention_kinds
+        if self._sparse_layers is not None:
+            self.flight.static["sparse_layers"] = self._sparse_layers
         self.flight.static.update(self._rope_held)
         self._step_id = 0           # the running iteration's flight seq
         # running totals the iteration's record takes differences of
@@ -527,6 +529,9 @@ class ServeEngine:
         # width and the rope table each kind reads (health's static part
         # and the flight record's)
         self._attention_kinds = self.model.cfg.attention_kinds()
+        # the sparse layers: what the router scores (experts of the group,
+        # identity experts), what of it is held here, the shortcut pairs
+        self._sparse_layers = self.model.cfg.sparse_layers()
         # the rope tables the model holds: a model cut to its reach holds
         # max_cache_len rows of each (0 / 0 where no layer rotates)
         tables = list(self.model.params["rope"].values())
@@ -741,6 +746,8 @@ class ServeEngine:
             h["prefix_cache"] = pc.occupancy()
         h["kv_pool"] = {"joined_keys": self._joined_keys}
         h["attention_kinds"] = self._attention_kinds
+        if self._sparse_layers is not None:
+            h["sparse_layers"] = self._sparse_layers
         h.update(self._rope_held)
         # local binding: health() runs on API threads while the scheduler
         # may null self.paged transiently during _rebuild/_fail_all

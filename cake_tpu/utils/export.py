@@ -49,6 +49,9 @@ def params_to_hf_tensors(cfg: ModelConfig, params: dict,
     where the family fuses that too."""
     out: dict[str, np.ndarray] = {}
     pre = cfg.model_prefix
+    if cfg.shortcut_pairs:
+        from ..models.longcat_flash import refuse_checkpoint
+        refuse_checkpoint("exporting")
     ckpt = {}
     if cfg.mamba is not None:
         from ..models.jamba import CHECKPOINT_NAMES as ckpt

@@ -190,6 +190,9 @@ class ParamLoader:
     def _layer(self, i: int) -> dict:
         cfg = self.cfg
         spec = cfg.layer_spec(i)
+        if spec.shortcut is not None:
+            from ..models.longcat_flash import refuse_checkpoint
+            refuse_checkpoint("loading")
         lp = f"{self.prefix}.layers.{i}"
         m = mixer_of(cfg, spec)
         p: dict = {m.param_key: m.load_params(self, lp, spec)}

@@ -580,3 +580,58 @@ def test_ling3_programs_take_a_row_of_both_kinds_in_place(one_chip,
             # the 55.7 MB the configuration's memory_plan states
             assert abs(mem.temp_size_in_bytes - 55.7e6) < 2e6, \
                 mem.temp_size_in_bytes
+
+
+def test_longcat_programs_take_eight_latent_leaves_in_place(one_chip,
+                                                            monkeypatch):
+    """The benchmark's LongCat-Flash configuration as it is served (4
+    shortcut layers = 8 latent sub-layers of 64 heads, 8 dense FFNs of
+    12,288, 4 sparse layers of 16 held experts under a 768-wide router, an
+    eighth of the vocabulary), 32 rows x 6,144: `_decode_slots` and a
+    256-token chunk hold the latent read eight times (a query block of 64
+    heads, a shape the kernel had not seen), both donate all eight leaves
+    through, and no leaf is copied or transposed; the value a pair carries
+    is a [tokens, 6144] activation and costs no buffer of its own worth
+    naming."""
+    import json as json_mod
+    from cake_tpu.models import deepseek_v2
+    from cake_tpu.models.common.config import config_from_hf_dict
+    from cake_tpu.ops import flash
+    monkeypatch.setattr(flash, "flash_enabled", lambda: True)
+    monkeypatch.setattr(deepseek_v2, "kernel_enabled", lambda: True)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "longcat-flash-chat-l4-ep32.json")) as f:
+        hf = json_mod.load(f)
+    del hf["benchmark"]
+    cfg = config_from_hf_dict(hf)
+    rows, ctx = 32, 6144
+    for name, layers, compiled in _pool_programs(cfg, rows, ctx, one_chip):
+        assert [sorted(lc) for lc in layers] == [["kv", "pos"]] * 8
+        assert layers[0]["kv"].shape == (rows, ctx, 640)
+        text, mem = compiled.as_text(), compiled.memory_analysis()
+        entry = text[text.index("\nENTRY "):]
+        reads = len(re.findall(
+            r"custom-call\([^\n]*cake_latent_decode_attention", entry))
+        assert reads == (0 if name.startswith("restore") else 8), name
+        # nothing as large as one row's latents is copied or transposed
+        # (a chunk's absorbed queries are laid out once a sub-layer for the
+        # kernel's [tokens x heads, 640] blocks: 21 MB, no part of the pool)
+        big = _converted(text, ctx * 640)
+        assert len([c for c in big if c[2] == "1,256,64,640"]) == (
+            8 if name == "append256" else 0), (name, big)
+        big = [c for c in big if c[2] != "1,256,64,640"]
+        assert not big, (name, big)
+        pool_bytes = sum(a.size * a.dtype.itemsize
+                         for a in jax.tree_util.tree_leaves(layers))
+        assert pool_bytes == 8 * rows * ctx * (640 * 2 + 4)
+        assert mem.alias_size_in_bytes >= pool_bytes, name
+        print(name, "arguments", mem.argument_size_in_bytes, "temporaries",
+              mem.temp_size_in_bytes)
+        # the 19.4 / 175.9 / 1.4 MB the configuration's memory_plan states
+        want = {"decode": 19.4e6, "append256": 175.9e6,
+                "restore32x256": 1.4e6}[name]
+        assert abs(mem.temp_size_in_bytes - want) < 0.1 * want + 1e6, (
+            name, mem.temp_size_in_bytes)
+        assert abs(mem.argument_size_in_bytes - 12.37e9) < 0.05e9 \
+            or name.startswith("restore"), mem.argument_size_in_bytes

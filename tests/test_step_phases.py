@@ -35,8 +35,11 @@ WINDOW = {"cake.attn.window"}
 # scopes of mechanisms these families lack: a gate on the attention output,
 # a shared expert (tests/test_laguna.py finds them in a model that has them),
 # a delta-rule mixer (tests/test_solar_open2.py), power retention
-# (tests/test_brumby.py), latent attention (tests/test_deepseek_v2.py)
-GATED = {"cake.attn.gate", "cake.ffn.shared"} | {
+# (tests/test_brumby.py), latent attention (tests/test_deepseek_v2.py),
+# identity experts and a shortcut pair's dense FFNs
+# (tests/test_longcat_flash.py)
+GATED = {"cake.attn.gate", "cake.ffn.shared", "cake.ffn.zero",
+         "cake.ffn.dense"} | {
     s for s in SCOPES if s.startswith(("cake.attn.linear",
                                        "cake.attn.retention",
                                        "cake.attn.latent"))}
@@ -126,7 +129,7 @@ def test_catalogs_name_the_phases_and_scopes():
     assert set(LEAVES) | {"serve.step", "api.sse_write"} <= spans
     # the gap and the loop's lag are counted always and drawn by no span
     assert not {"trace.sync", "serve.between", "api.loop_tick"} & spans
-    assert len(set(SCOPES)) == len(SCOPES) == 33
+    assert len(set(SCOPES)) == len(SCOPES) == 35
 
 
 # -- the engine: phases of one iteration ------------------------------------
